@@ -12,8 +12,14 @@ MFU = achieved FLOP/s ÷ peak FLOP/s, with the standard 6·P·T transformer
 training FLOP count (fwd 2·P·T + bwd 4·P·T, P = non-embedding params,
 T = tokens) per Kaplan et al. / PaLM appendix B.
 
-    python benchmarks/lm_bench.py                 # real chip
+    python benchmarks/lm_bench.py                 # on the chip
     LM_PRESET=tiny python benchmarks/lm_bench.py  # CPU smoke
+
+Without a TPU the bench is an error: a tokens/s or MFU figure from the CPU
+is not a measurement of this system. ``LM_PRESET=tiny`` is the explicit
+smoke that runs anywhere; it reports under its own metric names
+(``transformer_lm_smoke_tokens_per_sec``, ``moe_lm_smoke_tokens_per_sec``)
+and no MFU. Every result names the platform, device kind and count.
 
 With ``--history PATH`` the final record (tokens/s + MFU) appends to the
 same schema-versioned JSONL store bench.py uses (benchmarks/history.py);
@@ -44,18 +50,50 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-# bf16 peak of one v5e chip (TFLOP/s); override for other parts
-PEAK_TFLOPS = float(os.environ.get("LM_PEAK_TFLOPS", "197"))
+# bf16 peak of one chip in TFLOP/s, keyed by jax's ``device_kind``
+# (Google Cloud documentation, "TPU v5e": 197). MFU against a peak assumed
+# for whatever device turned up is not a number; an unknown kind is an error.
+PEAK_BF16_TFLOPS = {"TPU v5 lite": 197.0}
 
 PRESETS = {
     # ~GPT-2 medium: d=1024, 24 layers, 16 heads
-    "medium": dict(num_layers=24, d_model=1024, num_heads=16,
+    "medium": dict(num_layers=24, d_model=1024, num_heads=16, vocab=32768,
                    batch=8, seq=1024, warmup=5, rounds=5, iters=5),
-    "small": dict(num_layers=12, d_model=768, num_heads=12,
+    "small": dict(num_layers=12, d_model=768, num_heads=12, vocab=32768,
                   batch=8, seq=1024, warmup=5, rounds=5, iters=5),
-    "tiny": dict(num_layers=2, d_model=64, num_heads=2,
+    # the smoke: f32, runs anywhere, reported under its own metric name
+    "tiny": dict(num_layers=2, d_model=64, num_heads=2, vocab=256,
                  batch=2, seq=64, warmup=1, rounds=2, iters=2),
 }
+SMOKE_PRESET = "tiny"
+
+
+def resolve_preset():
+    """``(name, device)`` for this run: ``LM_PRESET`` (default ``medium``)
+    and the first JAX device. Anything but the smoke preset needs a TPU
+    whose peak is in ``PEAK_BF16_TFLOPS``."""
+    import jax
+
+    name = os.environ.get("LM_PRESET", "medium")
+    dev = jax.devices()[0]
+    if name != SMOKE_PRESET:
+        if dev.platform != "tpu":
+            sys.exit(f"lm_bench: no TPU (JAX found platform "
+                     f"{dev.platform!r}); a benchmark needs the chip. "
+                     f"LM_PRESET={SMOKE_PRESET} is the smoke that runs "
+                     f"anywhere, under its own metric name.")
+        if dev.device_kind not in PEAK_BF16_TFLOPS:
+            sys.exit(f"lm_bench: no bf16 peak recorded for device kind "
+                     f"{dev.device_kind!r}; add it to PEAK_BF16_TFLOPS "
+                     f"with its source")
+    return name, dev
+
+
+def device_stamp(dev):
+    import jax
+
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "devices": len(jax.devices())}
 
 
 def parse_args(argv=None):
@@ -105,9 +143,12 @@ def run_moe(args):
     import horovod_tpu as hvd
     from horovod_tpu.ops import compression as comp
     from horovod_tpu.parallel import expert as epar
+    from horovod_tpu.utils import compile_cache
 
     hvd.init()
-    on_tpu = jax.default_backend() == "tpu"
+    compile_cache.enable()
+    preset, dev = resolve_preset()
+    smoke = preset == SMOKE_PRESET
     world = jax.device_count()
 
     n_experts = int(os.environ.get("LM_MOE_EXPERTS", "8"))
@@ -116,16 +157,16 @@ def run_moe(args):
         sys.exit(f"LM_MOE_EP={ep} must divide both the device count "
                  f"({world}) and LM_MOE_EXPERTS ({n_experts})")
     dp = world // ep
-    d_model = int(os.environ.get("LM_MOE_D", "1024" if on_tpu else "64"))
+    d_model = int(os.environ.get("LM_MOE_D", "64" if smoke else "1024"))
     hidden_mult = int(os.environ.get("LM_MOE_HIDDEN_MULT",
-                                     "4" if on_tpu else "2"))
-    vocab = int(os.environ.get("LM_VOCAB", "32768" if on_tpu else "256"))
+                                     "2" if smoke else "4"))
+    vocab = int(os.environ.get("LM_VOCAB", PRESETS[preset]["vocab"]))
     n_tokens = int(os.environ.get("LM_MOE_TOKENS",
-                                  "65536" if on_tpu else "2048"))
+                                  "2048" if smoke else "65536"))
     n_tokens = max(world, n_tokens // world * world)
     cf = float(os.environ.get("LM_MOE_CF", "1.25"))
-    warmup = int(os.environ.get("LM_MOE_WARMUP", "3" if on_tpu else "1"))
-    iters = int(os.environ.get("LM_MOE_ITERS", "20" if on_tpu else "4"))
+    warmup = int(os.environ.get("LM_MOE_WARMUP", "1" if smoke else "3"))
+    iters = int(os.environ.get("LM_MOE_ITERS", "4" if smoke else "20"))
 
     mesh = epar.make_dp_ep_mesh(dp, ep)
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -201,10 +242,11 @@ def run_moe(args):
         total = time.perf_counter() - t0
 
         tok_per_s = n_tokens * iters / total
-        mfu = 6.0 * n_active * tok_per_s / (world * PEAK_TFLOPS * 1e12)
         entry = {
             "tokens_per_sec": round(tok_per_s, 1),
-            "mfu_pct": round(100 * mfu, 2) if on_tpu else None,
+            "mfu_pct": None if smoke else round(
+                100 * 6.0 * n_active * tok_per_s
+                / (world * PEAK_BF16_TFLOPS[dev.device_kind] * 1e12), 2),
             "loss": round(float(loss), 4),
         }
         if stats is not None:
@@ -227,11 +269,13 @@ def run_moe(args):
     print(f"# dispatch bytes vs bf16: {json.dumps(ratios)}", file=sys.stderr)
 
     result = {
-        "metric": "moe_lm_tokens_per_sec",
+        "metric": ("moe_lm_smoke_tokens_per_sec" if smoke
+                   else "moe_lm_tokens_per_sec"),
         # the shipped quantized default is the headline number the
         # regression gate tracks
         "value": results["capacity-int8"]["tokens_per_sec"],
         "unit": "tok/s",
+        **device_stamp(dev),
         "configs": results,
         "wire_byte_ratio_vs_bf16": ratios,
         "experts": n_experts, "ep": ep, "capacity_factor": cf,
@@ -284,16 +328,18 @@ def main(argv=None):
     from horovod_tpu import spmd
     from horovod_tpu.models.transformer import (
         TransformerLM, lm_loss, lm_loss_chunked)
+    from horovod_tpu.utils import compile_cache
 
     hvd.init()
-    on_tpu = jax.default_backend() == "tpu"
-    cfg = dict(PRESETS[os.environ.get("LM_PRESET",
-                                      "medium" if on_tpu else "tiny")])
+    compile_cache.enable()
+    preset, dev = resolve_preset()
+    smoke = preset == SMOKE_PRESET
+    cfg = dict(PRESETS[preset])
     if os.environ.get("LM_BATCH"):
         cfg["batch"] = int(os.environ["LM_BATCH"])
     if os.environ.get("LM_SEQ"):
         cfg["seq"] = int(os.environ["LM_SEQ"])
-    vocab = int(os.environ.get("LM_VOCAB", "32768" if on_tpu else "256"))
+    vocab = int(os.environ.get("LM_VOCAB", cfg["vocab"]))
     batch, seq = cfg["batch"] * hvd.num_replicas(), cfg["seq"]
 
     # perf levers (each delta measured in docs/benchmarks.md):
@@ -349,7 +395,7 @@ def main(argv=None):
     model = TransformerLM(
         vocab_size=vocab, num_layers=cfg["num_layers"],
         num_heads=cfg["num_heads"], d_model=cfg["d_model"],
-        max_seq_len=seq, dtype=jnp.bfloat16 if on_tpu else jnp.float32,
+        max_seq_len=seq, dtype=jnp.float32 if smoke else jnp.bfloat16,
         remat=remat, attn_fn=attn_fn)
 
     rng = np.random.RandomState(0)
@@ -382,14 +428,14 @@ def main(argv=None):
     mesh = hvd.mesh()
     params = spmd.replicate(params, mesh)
     opt_state = spmd.replicate(opt_state, mesh)
-    tokens = spmd.shard_batch(tokens, mesh)
-    targets = spmd.shard_batch(targets, mesh)
+    data = spmd.shard_batch((tokens, targets), mesh)
 
     if chunked:
         chunk_tokens = int(os.environ.get("LM_LOSS_CHUNK", "2048"))
         loss_unroll = int(os.environ.get("LM_LOSS_UNROLL", "1"))
 
-        def loss_fn(p, x, y):
+        def loss_fn(p, batch):
+            x, y = batch
             hid = model.apply({"params": p}, x, return_hidden=True)
             return lm_loss_chunked(hid, p["tok_emb"]["embedding"], y,
                                    chunk_tokens=chunk_tokens,
@@ -398,89 +444,93 @@ def main(argv=None):
         # unchunked full-logit loss, but the weight-tied head matmul in
         # bf16 with f32 accumulation (the MXU-native contraction the
         # chunked path uses) instead of the model's f32 attend
-        def loss_fn(p, x, y):
+        def loss_fn(p, batch):
+            x, y = batch
             hid = model.apply({"params": p}, x, return_hidden=True)
             emb_t = p["tok_emb"]["embedding"].astype(jnp.bfloat16).T
             logits = jnp.dot(hid.astype(jnp.bfloat16), emb_t,
                              preferred_element_type=jnp.float32)
             return lm_loss(logits, y)
     else:
-        def loss_fn(p, x, y):
+        def loss_fn(p, batch):
+            x, y = batch
             return lm_loss(model.apply({"params": p}, x), y)
 
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    repl = NamedSharding(mesh, P())
-
     if fused_opt:
-        def _step(p, opt, x, y):
-            loss, grads = jax.value_and_grad(loss_fn)(p, x, y)
+        # fused_adamw is an init/apply pair, not an optax transformation,
+        # so it cannot go through make_train_step; its Pallas call sits
+        # outside any shard_map, which a multi-chip jit cannot partition
+        if hvd.num_replicas() > 1:
+            sys.exit("LM_FUSED_OPT=1 runs on one chip only")
+
+        def _step(p, opt, batch):
+            loss, grads = jax.value_and_grad(loss_fn)(p, batch)
             p, opt = tx.apply(grads, opt, p)
             return p, opt, loss
+
+        step = jax.jit(_step, donate_argnums=(0, 1) if donate else ())
     else:
-        def _step(p, opt, x, y):
-            loss, grads = jax.value_and_grad(loss_fn)(p, x, y)
-            updates, opt = tx.update(grads, opt, p)
-            return optax.apply_updates(p, updates), opt, loss
+        # the product's builder: on more than one chip it is what makes
+        # the Pallas attention partitionable (spmd.make_train_step)
+        zero1 = os.environ.get("LM_ZERO1", "0") == "1"
+        if zero1:
+            # shard AdamW m/v 1/N over the replica axis (optim/zero.py); a
+            # single-chip mesh degenerates to replicated
+            from horovod_tpu.optim.zero import shard_opt_state
 
-    opt_sh = repl
-    if os.environ.get("LM_ZERO1", "0") == "1":
-        # shard AdamW m/v 1/N over the replica axis (optim/zero.py); a
-        # single-chip mesh degenerates to replicated, multi-chip runs keep
-        # 1/N of the state per chip
-        from horovod_tpu.optim.zero import zero1_shardings
-
-        opt_sh = zero1_shardings(opt_state, mesh)
-        opt_state = jax.tree_util.tree_map(jax.device_put, opt_state, opt_sh)
-    jitted = jax.jit(_step, out_shardings=(repl, opt_sh, repl),
-                     donate_argnums=(0, 1) if donate else ())
-    step = jitted
-    if on_tpu:
+            opt_state = shard_opt_state(opt_state, mesh)
+        step = spmd.make_train_step(
+            loss_fn, tx, mesh=mesh, donate=donate, zero1=zero1,
+            example_opt_state=opt_state if zero1 else None)
+    if dev.platform == "tpu":
         opts = {"xla_tpu_enable_latency_hiding_scheduler": "true"}
         if os.environ.get("LM_VMEM_KIB"):
             opts["xla_tpu_scoped_vmem_limit_kib"] = os.environ["LM_VMEM_KIB"]
-        try:
-            step = jitted.lower(params, opt_state, tokens, targets).compile(
-                compiler_options=opts)
-        except Exception:
-            step = jitted
+        step = step.lower(params, opt_state, data).compile(
+            compiler_options=opts)
 
     for _ in range(cfg["warmup"]):
-        params, opt_state, loss = step(params, opt_state, tokens, targets)
-    float(loss)
+        params, opt_state, loss = step(params, opt_state, data)
+    jax.block_until_ready(loss)
 
     if os.environ.get("LM_PROFILE"):
         # capture a few steady-state steps; summarize with
         # benchmarks/xplane_summary.py <dir>
         with jax.profiler.trace(os.environ["LM_PROFILE"]):
             for _ in range(3):
-                params, opt_state, loss = step(params, opt_state, tokens,
-                                               targets)
-            float(loss)
+                params, opt_state, loss = step(params, opt_state, data)
+            jax.block_until_ready(loss)
 
     t0 = time.perf_counter()
     for _ in range(cfg["rounds"] * cfg["iters"]):
-        params, opt_state, loss = step(params, opt_state, tokens, targets)
-    float(loss)
+        params, opt_state, loss = step(params, opt_state, data)
+    jax.block_until_ready(loss)
     total = time.perf_counter() - t0
 
     steps = cfg["rounds"] * cfg["iters"]
     n_dev = hvd.num_replicas()
     tok_per_s = batch * seq * steps / total
-    # 6·P·T with non-embedding P only — conservative: excludes the logit
-    # matmul (weight-tied head) and attention-score FLOPs
-    flops_per_s = 6.0 * n_nonemb * tok_per_s
-    mfu = flops_per_s / (n_dev * PEAK_TFLOPS * 1e12)
-    print(f"# backend={jax.default_backend()} devices={n_dev} "
-          f"params={n_params/1e6:.1f}M (non-emb {n_nonemb/1e6:.1f}M) "
-          f"batch={batch} seq={seq} loss={float(loss):.3f}", file=sys.stderr)
-    print(f"# tokens/sec: {tok_per_s:,.0f}; model TFLOP/s: "
-          f"{flops_per_s/1e12:.1f}; MFU/chip: {100*mfu:.1f}%",
-          file=sys.stderr)
+    print(f"# platform={dev.platform} kind={dev.device_kind} "
+          f"devices={n_dev} params={n_params/1e6:.1f}M "
+          f"(non-emb {n_nonemb/1e6:.1f}M) batch={batch} seq={seq} "
+          f"loss={float(loss):.3f}", file=sys.stderr)
+    mfu_pct = None
+    if not smoke:
+        # 6·P·T with non-embedding P only — conservative: excludes the
+        # logit matmul (weight-tied head) and attention-score FLOPs
+        flops_per_s = 6.0 * n_nonemb * tok_per_s
+        mfu_pct = round(100 * flops_per_s / (
+            n_dev * PEAK_BF16_TFLOPS[dev.device_kind] * 1e12), 2)
+        print(f"# tokens/sec: {tok_per_s:,.0f}; model TFLOP/s: "
+              f"{flops_per_s/1e12:.1f}; MFU/chip: {mfu_pct:.1f}%",
+              file=sys.stderr)
     result = {
-        "metric": "transformer_lm_tokens_per_sec",
+        "metric": ("transformer_lm_smoke_tokens_per_sec" if smoke
+                   else "transformer_lm_tokens_per_sec"),
         "value": round(tok_per_s, 1),
         "unit": "tok/s",
-        "mfu_pct": round(100 * mfu, 2) if on_tpu else None,
+        "mfu_pct": mfu_pct,
+        **device_stamp(dev),
     }
     print(json.dumps(result))
 
@@ -511,7 +561,7 @@ def main(argv=None):
             "metric": result["metric"], "value": result["value"],
             "unit": result["unit"], "mfu_pct": result["mfu_pct"],
             "backend": jax.default_backend(), "devices": n_dev,
-            "preset": os.environ.get("LM_PRESET", ""),
+            "preset": preset,
             "batch": batch, "seq": seq,
         })
         print(f"# perf history appended to {args.history}", file=sys.stderr)

@@ -10,14 +10,19 @@ every completion and reports the sustained rate and the latency tail.
 Two modes:
 
 * **in-process** (default): one ``ServingEngine`` replica, submits go
-  straight to the engine. This is the deterministic perf-gate mode.
+  straight to the engine. This is the deterministic perf-gate mode, and
+  the only one that runs on the chip: a chip belongs to one process, and
+  here one process holds the engine.
 * **pod** (``--workers N``): spawns a ``ServingFrontend`` plus N worker
   replica subprocesses (``python -m horovod_tpu.serving.worker``) and
   drives them through a ``ServingClient`` over the hardened control
   plane. ``--kill-one`` SIGKILLs a worker mid-run and asserts ZERO lost
   requests — the killed replica's in-flight work must re-admit onto the
   survivors (exit 4 if anything is lost), which is the ISSUE-11
-  acceptance demonstration.
+  acceptance demonstration. Pod mode and the ``--chaos`` drills are
+  control-plane drills: several replicas cannot share a chip, so their
+  workers run on the CPU, and asking for them without ``JAX_PLATFORMS=cpu``
+  in the environment is an error, not a silent move off the device.
 
 With ``--history PATH`` the run's p99 appends to the schema-versioned
 JSONL store (benchmarks/history.py); ``--check-regression`` compares
@@ -55,7 +60,8 @@ def parse_args(argv=None):
                    help="per-request completion deadline")
     p.add_argument("--workers", type=int, default=0,
                    help="pod mode: spawn a frontend + N worker replica "
-                        "subprocesses (0 = in-process engine)")
+                        "subprocesses, on the CPU (0 = in-process engine, "
+                        "the mode that runs on the chip)")
     p.add_argument("--kill-one", action="store_true",
                    help="pod mode: SIGKILL one worker mid-run and require "
                         "zero lost requests (exit 4 on loss)")
@@ -579,8 +585,27 @@ _CHAOS = {
 }
 
 
+def _require_cpu_drill(what: str) -> None:
+    """Pod mode and the chaos drills run several replicas, each of which
+    would need the chip for itself; their worker subprocesses are started
+    with ``JAX_PLATFORMS=cpu``. The caller has to have said so too."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        sys.exit(f"serving_bench: {what} runs several replicas, and a chip "
+                 f"belongs to one process — it is a control-plane drill "
+                 f"whose workers run on the CPU. Set JAX_PLATFORMS=cpu to "
+                 f"run it; on the chip use the in-process mode (neither "
+                 f"--workers nor --chaos).")
+
+
 def main(argv=None):
     args = parse_args(argv)
+    if args.chaos or args.workers:
+        _require_cpu_drill(f"--chaos {args.chaos}" if args.chaos
+                           else f"--workers {args.workers}")
+    else:
+        from horovod_tpu.utils import compile_cache
+
+        compile_cache.enable()
     if args.chaos:
         return _CHAOS[args.chaos](args)
     if args.kill_one and args.workers < 2:

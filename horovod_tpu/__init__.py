@@ -21,8 +21,6 @@ SPMD fast path (the performance path — everything in one jitted step)::
     step = hvd.spmd.make_train_step(loss_fn, optimizer)
 """
 
-from .utils import compat as _compat  # noqa: F401  (installs jax shims)
-
 from .basics import (  # noqa: F401
     Adasum,
     Average,

@@ -79,8 +79,7 @@ def quantize_blocks(x, block: int | None = None, bits: int = 8):
             f"block {block}")
     x2 = flat.reshape(-1, block)
     pk = _kernels()
-    if (bits == 8 and pk.int8_supported(x2.shape[0], block)
-            and not pk.vma_active(x2)):
+    if bits == 8 and pk.kernel_path("int8_quantize", x2) == "pallas":
         q2, s2 = pk.int8_quantize_2d(x2)
         return q2.reshape(-1), s2[:, 0]
     qmax = 127.0 if bits == 8 else 7.0
@@ -97,7 +96,7 @@ def dequantize_blocks(q, scales, dtype=jnp.float32, block: int | None = None):
     q2 = jnp.ravel(q).reshape(-1, block)
     s2 = jnp.ravel(scales).astype(jnp.float32)[:, None]
     pk = _kernels()
-    if pk.int8_supported(q2.shape[0], block) and not pk.vma_active(q2, s2):
+    if pk.kernel_path("int8_dequantize", q2, s2) == "pallas":
         y2 = pk.int8_dequantize_2d(q2, s2)
     else:
         y2 = q2.astype(jnp.float32) * s2

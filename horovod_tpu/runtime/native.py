@@ -2,15 +2,17 @@
 
 The reference loads its C++ engine the same way — a ctypes wrapper over a C
 ABI (`horovod/common/basics.py:27-31`). The library is built from
-`horovod_tpu/_core/` by `make`; if missing, it is built on first use (the
-toolchain is part of the supported environment) and the engine falls back to
-the pure-Python controller only if compilation is impossible
-(``HVD_TPU_NATIVE=0`` forces the fallback).
+`horovod_tpu/_core/` by `make` (:func:`build`); if missing, it is built on
+first use (the toolchain is part of the supported environment). When it
+cannot be built, :func:`load_library` logs why and the engine takes the
+pure-Python controller (``HVD_TPU_NATIVE=0`` asks for that outright);
+``Engine.native`` says which controller a process got.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -24,6 +26,8 @@ from .messages import RequestType, Response, TensorTableEntry
 _CORE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "_core")
 _LIB_PATH = os.path.join(_CORE_DIR, "libhvd_tpu_core.so")
+
+logger = logging.getLogger("horovod_tpu")
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -40,31 +44,47 @@ def dtype_code(dtype) -> int:
     return _DTYPE_CODES.get(str(dtype), 2)
 
 
-def _build() -> bool:
+def build() -> None:
+    """``make`` the native core from the sources in ``_core/`` (a no-op
+    when the library is newer than all of them). Raises ``RuntimeError``
+    saying why when the library cannot be built — no ``make``, no
+    compiler, or a compile error."""
     try:
         r = subprocess.run(["make", "-C", _CORE_DIR], capture_output=True,
-                           timeout=300)
-        return r.returncode == 0 and os.path.exists(_LIB_PATH)
-    except Exception:
-        return False
+                           text=True, timeout=300)
+    except (OSError, subprocess.TimeoutExpired) as exc:
+        raise RuntimeError(
+            f"cannot run make in {_CORE_DIR}: {exc}") from exc
+    if r.returncode or not os.path.exists(_LIB_PATH):
+        raise RuntimeError(
+            f"make -C {_CORE_DIR} failed (exit {r.returncode}): "
+            f"{(r.stderr or r.stdout).strip()[-2000:]}")
 
 
 def load_library():
-    """Load (building if needed) the native core; returns None on failure."""
+    """Load (building if needed) the native core; returns None, after
+    logging the reason, when it cannot be built or loaded."""
     global _lib
     with _lib_lock:
         if os.environ.get("HVD_TPU_NATIVE", "1") in ("0", "false"):
             return None
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH) and not _build():
-            return None
-        lib = _load_and_bind()
-        if lib is None and _build():
-            # a prebuilt .so can predate newly added C entry points (the
-            # build products are gitignored); one rebuild-and-retry keeps
-            # the returns-None-on-failure contract instead of raising
+        try:
+            if not os.path.exists(_LIB_PATH):
+                build()
             lib = _load_and_bind()
+            if lib is None:
+                # a prebuilt .so can predate newly added C entry points
+                # (the build products are gitignored): rebuild, retry once
+                build()
+                lib = _load_and_bind()
+        except RuntimeError as exc:
+            logger.warning("native core unavailable: %s", exc)
+            return None
+        if lib is None:
+            logger.warning("native core unavailable: %s does not load or "
+                           "lacks a symbol this version binds", _LIB_PATH)
         _lib = lib
         return _lib
 

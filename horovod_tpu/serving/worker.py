@@ -50,6 +50,7 @@ from ..metrics import local_snapshot
 from ..runtime import wire
 from ..runtime.coordinator import (MSG_HEARTBEAT, MSG_METRICS,
                                    _backoff_schedule, _resolve_key)
+from ..utils import compile_cache
 from .engine import ServingConfig, ServingEngine
 from .scheduler import CANCELLED, DONE, QueueFull, Request
 
@@ -356,8 +357,11 @@ def build_replica_engine(vocab_size: int = 251, num_layers: int = 2,
     model = TransformerLM(vocab_size=vocab_size, num_layers=num_layers,
                           num_heads=num_heads, d_model=d_model,
                           max_seq_len=max_seq_len)
-    params = model.init(jax.random.PRNGKey(seed),
-                        jnp.zeros((1, 8), jnp.int32))["params"]
+    # one compiled program, not an op-by-op forward: the init's dummy
+    # forward is dead code under jit, and the persistent cache keeps it
+    params = jax.jit(lambda key: model.init(
+        key, jnp.zeros((1, 8), jnp.int32))["params"])(
+        jax.random.PRNGKey(seed))
     cfg = config or ServingConfig(max_context=max_seq_len)
     if cfg.max_context is None or cfg.max_context > max_seq_len:
         cfg.max_context = max_seq_len
@@ -383,6 +387,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="stall every engine step by SLOW seconds "
                          "(slow-replica chaos drill)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     host, port = args.addr.rsplit(":", 1)
     cfg = ServingConfig(block_size=args.block_size, num_blocks=args.blocks,
                         max_batch=args.max_batch, max_context=args.max_seq)

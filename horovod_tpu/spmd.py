@@ -122,9 +122,9 @@ def adasum(x, axis: str = MESH_AXIS):
         raise ValueError("Adasum requires a power-of-2 replica count "
                          "(parity: torch/mpi_ops.py:104-120)")
     flat = g.reshape(n, -1).astype(jnp.float32)
-    if _pk.mode() != "off" and not _pk.vma_active(flat):
-        pad = (-flat.shape[1]) % 128
-        padded = jnp.pad(flat, ((0, 0), (0, pad))) if pad else flat
+    pad = (-flat.shape[1]) % 128
+    padded = jnp.pad(flat, ((0, 0), (0, pad))) if pad else flat
+    if _pk.kernel_path("adasum_combine", padded, padded) == "pallas":
         while padded.shape[0] > 1:  # one batched launch per tree level
             padded = _pk.adasum_combine_pairs(padded[0::2], padded[1::2])
         return padded[0, :flat.shape[1]].reshape(x.shape).astype(x.dtype)
@@ -805,11 +805,20 @@ def make_train_step(loss_fn: Callable, tx, mesh: Optional[Mesh] = None,
                     algorithm: Optional[str] = None) -> Callable:
     """Build the jitted data-parallel train step (the bench hot loop).
 
-    ``loss_fn(params, batch) -> scalar loss`` computed on the *local* shard;
-    gradient averaging across replicas is inserted automatically by GSPMD
-    because params are replicated while the batch is sharded. ``tx`` is an
-    optax GradientTransformation. Returns
+    ``loss_fn(params, batch) -> scalar loss`` computed on the *local* shard
+    of the batch (dim 0 of every leaf is split over the replica axis); the
+    step averages loss and gradients across replicas. ``tx`` is an optax
+    GradientTransformation. Returns
     ``step(params, opt_state, batch) -> (params, opt_state, loss)``.
+
+    Over more than one device the loss-and-gradient half runs as a
+    ``shard_map`` with an explicit ``pmean``: a Pallas kernel inside
+    ``loss_fn`` (the model's default flash attention) is a custom call the
+    partitioner cannot split ("Mosaic kernels cannot be automatically
+    partitioned"), while under ``shard_map`` every chip runs it on its own
+    batch shard. The optimizer update stays under GSPMD, so ``zero1``'s
+    output shardings apply as before. On a one-device mesh there is
+    nothing to partition and the step is the plain jit it always was.
 
     ``zero1=True`` shards the optimizer state 1/N over the replica axis
     (`optim/zero.py`): pass ``example_opt_state`` (an abstract or concrete
@@ -823,8 +832,8 @@ def make_train_step(loss_fn: Callable, tx, mesh: Optional[Mesh] = None,
     program whose gradient reduction rides the quantized ppermute ring with
     an error-feedback residual carried as an extra optimizer-state leaf —
     build the state with :func:`quantized_opt_state`, and see docs/gspmd.md.
-    With the wire off, this function compiles the exact same program as
-    before the knob existed (the cache-key pin tested in tests/test_gspmd.py).
+    With the wire off, the knob changes nothing in the compiled program
+    (the cache-key pin tested in tests/test_gspmd.py).
 
     ``algorithm`` selects the collective schedule for the quantized wire
     (``"ring"``/``"tree"``/``"hier"``/``"auto"``; ``None`` resolves
@@ -854,8 +863,15 @@ def make_train_step(loss_fn: Callable, tx, mesh: Optional[Mesh] = None,
 
         opt_sh = zero1_shardings(example_opt_state, mesh)
 
+    loss_and_grads = local = jax.value_and_grad(loss_fn)
+    if mesh.shape[MESH_AXIS] > 1:
+        loss_and_grads = _shard_map(
+            lambda params, batch: jax.lax.pmean(local(params, batch),
+                                                MESH_AXIS),
+            mesh, in_specs=(P(), P(MESH_AXIS)), out_specs=P())
+
     def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        loss, grads = loss_and_grads(params, batch)
         updates, opt_state = tx.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         return params, opt_state, loss
@@ -868,22 +884,14 @@ def make_train_step(loss_fn: Callable, tx, mesh: Optional[Mesh] = None,
     )
 
 
-# ------------------------------------------- quantized whole-step builder
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map with the vma/replication checker off (across jax API
-    renames) so the fused quantize+pack kernels stay eligible inside the
-    ring (`pallas_kernels.vma_active`)."""
-    import inspect
-
-    kw = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    params = inspect.signature(jax.shard_map).parameters
-    for flag in ("check_vma", "check_rep"):
-        if flag in params:
-            kw[flag] = False
-            break
-    return jax.shard_map(f, **kw)
+    """shard_map with the vma checker off, so the Pallas kernels stay
+    eligible inside it (`pallas_kernels.vma_active`)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
+# ------------------------------------------- quantized whole-step builder
 def quantized_opt_state(tx, params, mesh: Optional[Mesh] = None,
                         zero1: bool = False, block: Optional[int] = None):
     """Initial ``(inner_state, ef_residual)`` for a quantized train step.

@@ -42,9 +42,24 @@ def test_lm_bench_smoke(capsys, monkeypatch):
     lm_bench.main()
     out = capsys.readouterr().out.strip().splitlines()
     rec = json.loads(out[-1])
-    assert rec["metric"] == "transformer_lm_tokens_per_sec"
+    # the smoke's own name: a CPU rate never goes under the device metric
+    assert rec["metric"] == "transformer_lm_smoke_tokens_per_sec"
     assert rec["value"] > 0
     assert rec["unit"] == "tok/s"
+    assert rec["mfu_pct"] is None
+    assert rec["platform"] == "cpu" and rec["devices"] == 8
+
+
+def test_lm_bench_without_a_chip_is_an_error(monkeypatch):
+    """No preset named: the benchmark wants the medium LM on a TPU, and on
+    the CPU it refuses instead of shrinking under the same metric name."""
+    import pytest
+
+    monkeypatch.delenv("LM_PRESET", raising=False)
+    import lm_bench
+
+    with pytest.raises(SystemExit, match="no TPU"):
+        lm_bench.main()
 
 
 def test_lm_bench_moe_smoke(capsys, monkeypatch):
@@ -53,6 +68,7 @@ def test_lm_bench_moe_smoke(capsys, monkeypatch):
     vs O(C·d) buffers — a large structural gap, safe to assert even on
     noisy CPU timers) and the int4 catalog bytes stay under the 60%
     CI bar vs a bf16 exchange."""
+    monkeypatch.setenv("LM_PRESET", "tiny")
     monkeypatch.setenv("LM_MOE_TOKENS", "1024")
     monkeypatch.setenv("LM_MOE_ITERS", "2")
     monkeypatch.setenv("LM_MOE_WARMUP", "1")
@@ -61,7 +77,7 @@ def test_lm_bench_moe_smoke(capsys, monkeypatch):
     assert lm_bench.main(["--moe"]) == 0
     out = capsys.readouterr().out.strip().splitlines()
     rec = json.loads(out[-1])
-    assert rec["metric"] == "moe_lm_tokens_per_sec"
+    assert rec["metric"] == "moe_lm_smoke_tokens_per_sec"
     assert rec["value"] > 0
     cfgs = rec["configs"]
     assert set(cfgs) == {"exact", "capacity", "capacity-int8",

@@ -405,8 +405,9 @@ def test_instruments_cover_gspmd_ring():
 
 # ------------------------------------------------------------ cache-key pin
 def _golden_plain_step(loss_fn, tx, mesh):
-    """Verbatim copy of make_train_step's pre-knob body (zero1 off): the
-    golden the pin compares against. If spmd.make_train_step's exact path
+    """Verbatim copy of make_train_step's wire-off body on a multi-device
+    mesh (zero1 off; since PR 21 loss and gradients run under shard_map so
+    that Pallas kernels partition): the golden the pin compares against. If spmd.make_train_step's exact path
     drifts, update BOTH on purpose — the test exists to make that drift
     loud, because an accidental change to the wire-off program invalidates
     every user's jit cache."""
@@ -415,9 +416,14 @@ def _golden_plain_step(loss_fn, tx, mesh):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     repl = NamedSharding(mesh, P())
+    local = jax.value_and_grad(loss_fn)
+    loss_and_grads = jax.shard_map(
+        lambda params, batch: jax.lax.pmean(local(params, batch), "hvd"),
+        mesh=mesh, in_specs=(P(), P("hvd")), out_specs=P(),
+        check_vma=False)
 
     def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        loss, grads = loss_and_grads(params, batch)
         updates, opt_state = tx.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         return params, opt_state, loss

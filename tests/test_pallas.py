@@ -660,10 +660,16 @@ def test_vmem_and_fusion_knobs_resolved_per_call(monkeypatch):
     # input fusion: default on, disabled per-call by the env
     monkeypatch.delenv("HVD_PALLAS_VMEM_MB", raising=False)
     monkeypatch.delenv("HVD_PALLAS_INPUT_FUSION", raising=False)
-    p = pk._input_fusion(pk._sem_par2_res(), 6)
+    p = pk._input_fusion(pk._sem_par2_res(), 6, pk._relayout_fusable(8, 16))
     assert list(p.allow_input_fusion) == [False] + [True] * 6
+    # one batch row or one head: the relayout is no 4-D transpose, and
+    # fusing it crashes the TPU compiler (AOT-probed, libtpu 0.0.34)
+    for b, h in ((1, 16), (8, 1)):
+        p = pk._input_fusion(pk._sem_par2_res(), 6,
+                             pk._relayout_fusable(b, h))
+        assert p.allow_input_fusion is None
     monkeypatch.setenv("HVD_PALLAS_INPUT_FUSION", "0")
-    p = pk._input_fusion(pk._sem_par2_res(), 6)
+    p = pk._input_fusion(pk._sem_par2_res(), 6, True)
     assert p.allow_input_fusion is None
 
 
@@ -863,3 +869,69 @@ def test_matmul_reduce_scatter_fallback_when_off(monkeypatch):
     monkeypatch.setenv("HVD_PALLAS", "0")
     out = _ring_mm_run(pk.matmul_reduce_scatter, x, w, m)
     np.testing.assert_array_equal(out, ref)
+
+
+# ------------------------------- pack kernels at the shapes the ring produces
+@pytest.mark.parametrize("rows", [1, 5, 33, 300, 1285])
+@pytest.mark.parametrize("wire", ["int8", "int4"])
+def test_quantize_pack_any_row_count_bit_equal(wire, rows):
+    """A ring chunk has ceil(n / world / block) rows — any count, not a
+    multiple of 8 — so the kernels run a cdiv grid with a partial last
+    tile. Bits must equal the reference's on every row, scale bytes with
+    the high bit set included (a large-magnitude row's f32 scale)."""
+    pack, ref = {
+        "int8": (pk.int8_quantize_pack, pk.int8_quantize_pack_ref),
+        "int4": (pk.int4_quantize_pack, pk.int4_quantize_pack_ref)}[wire]
+    x = np.random.RandomState(rows).randn(rows, 256).astype(np.float32)
+    x[-1] *= 3.0e38 / np.abs(x[-1]).max()   # scale near f32 max
+    x[0] = 0.0                       # the scale > 0 guard
+    x = jnp.asarray(x)
+    assert pk.kernel_path(f"{wire}_quantize_pack", x) == "pallas"
+    np.testing.assert_array_equal(np.asarray(pack(x)), np.asarray(ref(x)))
+
+
+def test_kernel_path_reports_each_hand_over(monkeypatch):
+    x = jnp.zeros((16, 256), jnp.float32)
+    assert pk.kernel_path("int8_quantize_pack", x) == "pallas"
+    # a gate the shape fails
+    assert pk.kernel_path("int8_quantize_pack", x[:, :100]) == "reference"
+    assert pk.kernel_path("int4_quantize_pack", x[:, :128]) == "reference"
+    q = jnp.zeros((1, 100, 2, 64), jnp.float32)
+    assert pk.kernel_path("flash_attention", q, q, q) == "reference"
+    assert pk.kernel_path("matmul_reduce_scatter", x, x.T, 1) == "reference"
+    assert pk.kernel_path("matmul_reduce_scatter", x, x.T, 4) == "pallas"
+    # varying operands under shard_map(check_vma=True)
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:2]), ("x",))
+    paths = []
+    jax.shard_map(lambda v: (paths.append(
+        pk.kernel_path("int8_quantize_pack", v)), v)[1],
+        mesh=mesh, in_specs=P("x"), out_specs=P("x"))(
+        jnp.zeros((32, 256), jnp.float32))
+    assert paths == ["reference"]
+    # kernels off
+    monkeypatch.setenv("HVD_PALLAS", "0")
+    assert pk.kernel_path("int8_quantize_pack", x) == "reference"
+
+
+@pytest.mark.parametrize("wire,digest,scale_hex", [
+    ("int8", "f66fdb1369b85c3df72856db23267f41695f08bbb72ff23eb50478ecc559db65",
+     "552a153fdd846d3a"),
+    ("int4", "863f28f808d53edb19df5b8ed68d0dd284d4be91625459487ecc67cf1e3e7bf4",
+     "9324294130aa863c"),
+])
+def test_packed_wire_rows_golden_bytes(wire, digest, scale_hex):
+    """The wire format is a contract between ranks and between versions:
+    these bytes were produced by the pre-PR-21 reference (whose kernels
+    never compiled for the TPU); kernel and reference must still emit
+    them."""
+    import hashlib
+
+    x = (np.arange(2 * 256, dtype=np.float32).reshape(2, 256)
+         - 200.0) * np.float32(0.37)
+    x[1] *= -1e-3
+    for fn in (f"{wire}_quantize_pack", f"{wire}_quantize_pack_ref"):
+        p = np.asarray(getattr(pk, fn)(jnp.asarray(x)))
+        assert p[:, -4:].astype(np.uint8).tobytes().hex() == scale_hex
+        assert hashlib.sha256(p.tobytes()).hexdigest() == digest

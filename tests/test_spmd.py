@@ -214,3 +214,62 @@ def test_zero1_odd_shapes_replicate():
     assert sh[0].mu["odd"].spec == P()
     assert sh[0].mu["scalar"].spec == P()
     assert sh[0].mu["mat"].spec == P(None, "hvd")
+
+
+def test_multi_device_step_with_pallas_attention_matches_one_device(
+        monkeypatch):
+    """The model's default attention is a Pallas call; over 8 devices the
+    step runs it per shard under shard_map with an explicit pmean. Same
+    global batch, same init: the one-device plain jit and the 8-device step
+    must walk the same losses to the same params."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import Mesh
+
+    from horovod_tpu.models.transformer import TransformerLM, lm_loss
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setenv("HVD_PALLAS", "interpret")
+    hvd.init()
+    model = TransformerLM(vocab_size=64, num_layers=1, num_heads=2,
+                          d_model=128, max_seq_len=32, dtype=jnp.float32)
+    toks = np.random.RandomState(0).randint(0, 64, (8, 33)).astype(np.int32)
+    batch = (jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, 1:]))
+    params0 = jax.jit(lambda key: model.init(key, batch[0][:1])["params"])(
+        jax.random.PRNGKey(0))
+    q = jnp.zeros((1, 32, 2, 64), jnp.float32)
+    assert pk.kernel_path("flash_attention", q, q, q) == "pallas"
+
+    def loss_fn(p, b):
+        return lm_loss(model.apply({"params": p}, b[0]), b[1])
+
+    # plain SGD: the update is linear in the averaged gradient, so a wrong
+    # average cannot hide behind an optimizer that normalizes its scale
+    tx = optax.sgd(0.5)
+
+    def run(mesh):
+        step = spmd.make_train_step(loss_fn, tx, mesh=mesh, donate=False)
+        p = spmd.replicate(params0, mesh)
+        o = spmd.replicate(tx.init(params0), mesh)
+        data = spmd.shard_batch(batch, mesh)
+        losses = []
+        for _ in range(2):
+            p, o, loss = step(p, o, data)
+            losses.append(float(loss))
+        return losses, p
+
+    devices = jax.devices()
+    one_l, one_p = run(Mesh(np.asarray(devices[:1]), ("hvd",)))
+    all_l, all_p = run(hvd.mesh())
+    assert one_l[-1] < one_l[0]
+    np.testing.assert_allclose(all_l, one_l, rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(all_p),
+                    jax.tree_util.tree_leaves(one_p)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+    # and the replicas really are replicas
+    leaf = jax.tree_util.tree_leaves(all_p)[0]
+    first = np.asarray(leaf.addressable_shards[0].data)
+    for s in leaf.addressable_shards[1:]:
+        np.testing.assert_array_equal(first, np.asarray(s.data))

@@ -1,0 +1,160 @@
+"""Every Pallas kernel the tree ships, and the multi-device train step, must
+lower and compile for the TPU — checked from the CPU sandbox, no chip needed.
+
+CPU tests run the kernels through the Pallas interpreter (or not at all:
+off the TPU ``pallas_kernels.mode()`` is ``"off"``), where a kernel is
+ordinary HLO and nothing the TPU toolchain refuses can show. Two checks
+close that gap without a chip:
+
+* ``lower(lowering_platforms=("tpu",))`` runs the real Pallas→Mosaic
+  lowering and the partitioner's checks. It found a width-changing bitcast
+  inside the pack kernels and a Pallas call under a multi-device jit.
+* ``.compile()`` against a v5e 2x2 *topology description* (libtpu builds a
+  compile-only client; skipped where it cannot) runs Mosaic and the XLA TPU
+  compiler. It found an 8-bit vector shift Mosaic cannot legalize and an
+  internal compiler check that input fusion of a degenerate relayout trips.
+
+Neither says the numbers are right — ``chip_smoke.py`` Phase C runs the
+same list of cases (``chip_smoke.kernel_cases``) on the chip against their
+references.
+"""
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+import chip_smoke
+
+CASES = chip_smoke.kernel_cases()
+
+
+@pytest.fixture(autouse=True)
+def _kernels_on(monkeypatch):
+    monkeypatch.setenv("HVD_PALLAS", "on")
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    from jax.experimental import topologies
+
+    try:
+        return list(topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices)
+    except Exception as exc:  # noqa: BLE001 - any libtpu failure: skip
+        pytest.skip(f"no TPU compile-only client here: {exc}")
+
+
+def lower_tpu(fn, *args):
+    """Lower ``fn`` for the TPU. x64 is switched off for the trace: the
+    suite enables it, production does not, and Mosaic has no 64-bit types."""
+    with jax.enable_x64(False):
+        return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+
+
+def on_mesh(args, mesh):
+    """Abstract operands re-placed on ``mesh`` (unsharded ones replicated)."""
+    return [jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=NamedSharding(
+            mesh, a.sharding.spec if a.sharding is not None else P()))
+        for a in args]
+
+
+def _cpu_mesh4():
+    return Mesh(np.array(jax.devices()[:4]), ("hvd",))
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_lowers_for_tpu(name):
+    case = CASES[name]
+    assert lower_tpu(case.fn, *case.args).as_text().count(
+        "tpu_custom_call") == case.calls
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, v5e_devices):
+    case = CASES[name]
+    mesh = Mesh(np.array(v5e_devices[:1]), ("hvd",))
+    lower_tpu(case.fn, *on_mesh(case.args, mesh)).compile()
+
+
+def _four_chip_cases(mesh):
+    fn, _, args = chip_smoke.matmul_reduce_scatter_case(mesh)
+    # one chunk matmul per ring position
+    yield fn, args, 4
+    for wire in ("int8", "int4"):
+        fn, _, arg = chip_smoke.quantized_allreduce_case(mesh, wire)
+        # 3 reduce-scatter hops + the owner's one pack for the all-gather
+        yield fn, [arg], 4
+    fn, _, args = chip_smoke.ring_attention_case(mesh)
+    # forward step, and dq/dkv through the fused backward, per ring pass
+    yield fn, args, None
+
+
+def test_four_chip_cases_lower_for_tpu():
+    for fn, args, calls in _four_chip_cases(_cpu_mesh4()):
+        found = lower_tpu(fn, *args).as_text().count("tpu_custom_call")
+        assert found == calls if calls is not None else found > 0
+
+
+def test_four_chip_cases_compile_for_v5e(v5e_devices):
+    mesh = Mesh(np.array(v5e_devices[:4]), ("hvd",))
+    for fn, args, _ in _four_chip_cases(mesh):
+        lower_tpu(fn, *args).compile()
+
+
+LAYERS = 2
+
+
+def _train_step_lowering(mesh, per_chip_batch):
+    """The data-parallel LM step over ``mesh`` with the model's default
+    (Pallas) attention, lowered for the TPU."""
+    import optax
+
+    from horovod_tpu import spmd
+    from horovod_tpu.models.transformer import TransformerLM, lm_loss
+
+    model = TransformerLM(vocab_size=1024, num_layers=LAYERS, num_heads=4,
+                          d_model=256, max_seq_len=256)
+
+    def loss_fn(p, batch):
+        x, y = batch
+        return lm_loss(model.apply({"params": p}, x), y)
+
+    tx = optax.adamw(3e-4)
+    repl = NamedSharding(mesh, P())
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(lambda l: jax.ShapeDtypeStruct(
+            l.shape, l.dtype, sharding=repl), tree)
+
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 256), jnp.int32))["params"])
+    tok = jax.ShapeDtypeStruct(
+        (per_chip_batch * mesh.devices.size, 256), jnp.int32,
+        sharding=NamedSharding(mesh, P("hvd")))
+    step = spmd.make_train_step(loss_fn, tx, mesh=mesh)
+    with jax.enable_x64(False):
+        return step.trace(
+            abstract(params), abstract(jax.eval_shape(tx.init, params)),
+            (tok, tok)).lower(lowering_platforms=("tpu",))
+
+
+def test_multi_device_train_step_lowers_with_flash_kernel():
+    """Under a plain multi-device jit this raised 'Mosaic kernels cannot be
+    automatically partitioned'."""
+    text = _train_step_lowering(_cpu_mesh4(), 2).as_text()
+    # forward + fused backward per layer
+    assert text.count("tpu_custom_call") == 2 * LAYERS
+
+
+@pytest.mark.parametrize("per_chip_batch", [2, 1])
+def test_multi_device_train_step_compiles_for_v5e(per_chip_batch,
+                                                  v5e_devices):
+    """One sequence a chip is the degenerate relayout input fusion must
+    stay away from (``pallas_kernels._relayout_fusable``)."""
+    mesh = Mesh(np.array(v5e_devices[:4]), ("hvd",))
+    compiled = _train_step_lowering(mesh, per_chip_batch).compile()
+    assert "all-gather" not in compiled.as_text()
