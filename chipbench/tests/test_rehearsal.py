@@ -1,0 +1,123 @@
+"""Both jobs end to end at a toy size on the CPU backend, through the same
+command the driver runs plus ``--rehearse``: interpret-mode kernels, four
+virtual devices for the four-chip cell. The last line has exactly the
+contract's keys, under ``rehearsal_`` metric names.
+
+The AOT proof that each cell's programs compile for a v5e is run by hand
+(it takes minutes and loads libtpu):
+
+    JAX_PLATFORMS=cpu HVD_PALLAS=on python3 -m chipbench.aot_check
+"""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+from chipbench import harness
+
+KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+def rehearse(cell, trace, cwd=harness.ROOT, devices=1, seconds="2"):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", HVD_PALLAS="interpret",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "chipbench.run", "--workload", cell, "--seed",
+         "5", "--seconds", seconds, "--trace", str(trace), "--rehearse"],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1]), proc.stdout
+
+
+CASES = [("gpt2m-train-s1024", 1), ("gpt2m-train-dp4", 4),
+         ("gpt2l-train-s1024", 1), ("gpt2m-serve-c8", 1)]
+
+
+@pytest.mark.parametrize("cell,devices", CASES)
+def test_untraced_rehearsal_prints_the_end_to_end_line(cell, devices):
+    line, out = rehearse(cell, 0, devices=devices)
+    assert set(line) == KEYS
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    declared = harness.declared_metrics(cell)["end_to_end"]
+    assert set(line["metrics"]) == {f"rehearsal_{m['name']}" for m in declared}
+    for m in declared:
+        got = line["metrics"][f"rehearsal_{m['name']}"]
+        assert got["unit"] == m["unit"] and got["value"] > 0
+    assert set(line["device"]) == {"platform", "kind", "count",
+                                   "memory_peak_bytes"}
+    assert line["device"]["count"] == devices
+    assert "sample count" in out
+
+
+@pytest.mark.parametrize("cell,devices", [CASES[1], CASES[3]])
+def test_traced_rehearsal_prints_per_layer_metrics_and_breakdown(cell, devices):
+    line, _ = rehearse(cell, 1, devices=devices)
+    assert set(line) == KEYS | {"breakdown"}
+    assert line["correct"] is True
+    declared = {m["name"] for m in harness.declared_metrics(cell)["per_layer"]}
+    got = {k[len("rehearsal_"):] for k in line["metrics"]}
+    assert all(k.startswith("rehearsal_") for k in line["metrics"])
+    # the CPU backend has no memory statistics; everything else is read
+    assert declared - got <= {"peak_hbm_gib", "serve_peak_hbm_gib",
+                              "flash_attention_roofline"}
+    assert got <= declared
+    assert line["device"]["busy_s"] > 0 and line["device"]["window_s"] > 0
+    b = line["breakdown"]
+    assert 0 < len(b["device_ops"]) <= 10 and 0 < len(b["idle_gaps"]) <= 10
+    if cell == "gpt2m-train-dp4":
+        assert any("[collective]" in n for n, _ in b["device_ops"])
+    else:
+        assert {"kv_gather_host", "decode_call"} & {n for n, _ in b["idle_gaps"]}
+
+
+def test_a_copied_workload_file_is_a_new_cell_with_no_code_edit(tmp_path):
+    shutil.copytree(harness.HERE, tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    os.symlink(os.path.join(harness.ROOT, "horovod_tpu"),
+               tmp_path / "horovod_tpu")
+    spec = harness.load_json("workloads", "gpt2m-train-s1024.json")
+    spec["name"], spec["traffic"] = "copied-cell", "train-b8-s1024-copy"
+    shutil.copy(tmp_path / "chipbench" / "mixes" / "train-b8-s1024.json",
+                tmp_path / "chipbench" / "mixes" / "train-b8-s1024-copy.json")
+    with open(tmp_path / "chipbench" / "workloads" / "copied-cell.json", "w") as f:
+        json.dump(spec, f)
+    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["workloads"].append({**bench["workloads"][0], "name": "copied-cell",
+                               "traffic": "train-b8-s1024-copy"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "gpt2m-train-s1024" in m.get("workloads", []):
+            m["workloads"].append("copied-cell")
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    line, _ = rehearse("copied-cell", 0, cwd=str(tmp_path), seconds="1")
+    assert line["correct"] is True
+    assert "rehearsal_train_tokens_per_s_chip" in line["metrics"]
+
+
+def test_a_directory_with_only_the_benchmark_fails_without_a_result(tmp_path):
+    shutil.copytree(harness.HERE, tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(harness.ROOT, "BENCHMARK.json"), tmp_path)
+    proc = subprocess.run(
+        [sys.executable, "-m", "chipbench.run", "--workload",
+         "gpt2m-train-s1024", "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=tmp_path, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0 and proc.stdout.strip() == ""
+
+
+def test_no_accelerator_is_an_error_not_a_smaller_run():
+    proc = subprocess.run(
+        [sys.executable, "-m", "chipbench.run", "--workload",
+         "gpt2m-train-s1024", "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=harness.ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0 and proc.stdout.strip() == ""
+    assert "no accelerator" in proc.stderr
