@@ -494,8 +494,8 @@ def main(argv=None):
     jax.block_until_ready(loss)
 
     if os.environ.get("LM_PROFILE"):
-        # capture a few steady-state steps; summarize with
-        # benchmarks/xplane_summary.py <dir>
+        # capture a few steady-state steps; reduce with
+        # python3 -m chipbench.op_scopes <file.xplane.pb> 3
         with jax.profiler.trace(os.environ["LM_PROFILE"]):
             for _ in range(3):
                 params, opt_state, loss = step(params, opt_state, data)

@@ -270,6 +270,7 @@ class TransformerLM(nn.Module):
         return out, (jnp.stack(new_ks), jnp.stack(new_vs))
 
 
+@jax.named_scope("loss")
 def lm_loss(logits, targets):
     """Mean next-token cross entropy; with equal-size shards the global loss
     is the pmean of per-shard values (exact)."""
@@ -278,6 +279,7 @@ def lm_loss(logits, targets):
     return -jnp.mean(ll)
 
 
+@jax.named_scope("loss")
 def lm_loss_chunked(hidden, emb_table, targets, chunk_tokens=2048,
                     unroll=1):
     """Weight-tied-head cross entropy WITHOUT materializing [B, T, vocab].

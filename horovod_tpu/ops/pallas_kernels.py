@@ -157,6 +157,22 @@ def _tile_ok(t: int, block: int) -> bool:
     return t % block == 0
 
 
+def _named_call(name: str, kernel, **kwargs):
+    """``pl.pallas_call`` named twice over: ``name=`` is what xprof and a
+    Mosaic dump show, the ``jax.named_scope`` is what reaches the scope path
+    of the call's operation in a device trace (``chipbench/op_scopes.py``
+    tells forward from backward by it; jax 0.9.0 opens a scope for ``name=``
+    itself, so the path reads ``.../flash_fwd/flash_fwd/pallas_call``: the
+    outer one does not depend on that). Compile-time metadata, both."""
+    call = pl.pallas_call(kernel, name=name, **kwargs)
+
+    def named(*operands):
+        with jax.named_scope(name):
+            return call(*operands)
+
+    return named
+
+
 def _struct(shape, dtype, *like):
     """ShapeDtypeStruct carrying the union of the inputs' varying-mesh-axes —
     required for pallas_call outputs inside ``shard_map(check_vma=True)``."""
@@ -380,7 +396,7 @@ def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
              + 2 * block_q * d * 4)
     g = _pick_bh_block(bh, per_g, _BH_VMEM_CAP)
     grid = (bh // g, tq // block_q)
-    return pl.pallas_call(
+    return _named_call("flash_fwd",
         functools.partial(_flash_fwd_once_kernel, causal=causal,
                           scale=scale, block_k=block_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -496,7 +512,7 @@ def _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs, *, causal,
     qtile = pl.BlockSpec((1, block_q, d), lambda i, j, n, offs: (i, j, 0))
     stat = pl.BlockSpec((1, block_q, 1), lambda i, j, n, offs: (i, j, 0))
 
-    return pl.pallas_call(
+    return _named_call("flash_step",
         functools.partial(_flash_step_stream_kernel, causal=causal,
                           scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -563,7 +579,7 @@ def _flash_step_call(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
         ],
     )
     flops = 4 * bh * tq * tk * d  # 2 matmuls
-    return pl.pallas_call(
+    return _named_call("flash_step",
         kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -965,7 +981,7 @@ def _flash_bwd_fused(qt, kt, vt, dot, lset, ddt, offs, d, *, causal, scale,
     _, qmap = _causal_maps(causal, block_q, block_k, tq // block_q)
     ktile = pl.BlockSpec((1, block_k, d), lambda i, j, n, offs: (i, j, 0))
 
-    return pl.pallas_call(
+    return _named_call("flash_bwd",
         functools.partial(_flash_bwd_fused_kernel, causal=causal,
                           scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1030,7 +1046,7 @@ def _flash_bwd_resident(qt, kt, vt, dot, lset, ddt, offs, d, *,
              + 3 * max(block_q, block_k) * d * 4)
     g = _pick_bh_block(bh, per_g, _BH_VMEM_CAP)
 
-    dq = pl.pallas_call(
+    dq = _named_call("flash_bwd_dq",
         functools.partial(_flash_bwd_dq_kernel_res, causal=causal,
                           scale=scale, block_k=block_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1056,7 +1072,7 @@ def _flash_bwd_resident(qt, kt, vt, dot, lset, ddt, offs, d, *,
         interpret=interpret,
     )(offs, lset, ddt, qt, kt, vt, dot)
 
-    dk, dv = pl.pallas_call(
+    dk, dv = _named_call("flash_bwd_dkv",
         functools.partial(_flash_bwd_dkv_kernel_res, causal=causal,
                           scale=scale, block_q=block_q),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1160,7 +1176,7 @@ def _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off=0, k_off=0, *,
 
     kmap, qmap = _causal_maps(causal, block_q, block_k, tq // block_q)
 
-    dq = pl.pallas_call(
+    dq = _named_call("flash_bwd_dq",
         functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -1186,7 +1202,7 @@ def _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off=0, k_off=0, *,
         interpret=interpret,
     )(offs, lset, ddt, qt, kt, vt, dot)
 
-    dk, dv = pl.pallas_call(
+    dk, dv = _named_call("flash_bwd_dkv",
         functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,

@@ -863,17 +863,24 @@ def make_train_step(loss_fn: Callable, tx, mesh: Optional[Mesh] = None,
 
         opt_sh = zero1_shardings(example_opt_state, mesh)
 
+    # The named scopes here, in lm_loss and around the flash kernels are how
+    # a device trace tells the step's parts apart (docs/timeline.md):
+    # compile-time metadata, no run-time cost.
     loss_and_grads = local = jax.value_and_grad(loss_fn)
     if mesh.shape[MESH_AXIS] > 1:
+        def reduced(params, batch):
+            out = local(params, batch)
+            with jax.named_scope("grad_allreduce"):
+                return jax.lax.pmean(out, MESH_AXIS)
+
         loss_and_grads = _shard_map(
-            lambda params, batch: jax.lax.pmean(local(params, batch),
-                                                MESH_AXIS),
-            mesh, in_specs=(P(), P(MESH_AXIS)), out_specs=P())
+            reduced, mesh, in_specs=(P(), P(MESH_AXIS)), out_specs=P())
 
     def step(params, opt_state, batch):
         loss, grads = loss_and_grads(params, batch)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     donate_argnums = (0, 1) if donate else ()
@@ -1045,12 +1052,14 @@ def _make_quantized_step(loss_fn: Callable, tx, mesh: Optional[Mesh],
                 p_flat = jnp.pad(p_flat, (0, pad))
             p = jax.lax.axis_index(MESH_AXIS)
             p_chunk = jax.lax.dynamic_slice_in_dim(p_flat, p * chunk, chunk)
-            upd_chunk, inner = tx.update(g_chunk, inner, p_chunk)
+            with jax.named_scope("optimizer"):
+                upd_chunk, inner = tx.update(g_chunk, inner, p_chunk)
             upd_flat = quantized_all_gather(
                 upd_chunk, MESH_AXIS, wire, block)[:total]
             updates = jax.tree_util.tree_unflatten(
                 treedef, _split_like(upd_flat, g_leaves))
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                params = optax.apply_updates(params, updates)
         else:
             a = resolve_algorithm(total, n, algo)
             if a == "tree":
@@ -1064,8 +1073,9 @@ def _make_quantized_step(loss_fn: Callable, tx, mesh: Optional[Mesh],
                     corrected, Average, MESH_AXIS, wire, block)
             grads = jax.tree_util.tree_unflatten(
                 treedef, _split_like(reduced, g_leaves))
-            updates, inner = tx.update(grads, inner, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, inner = tx.update(grads, inner, params)
+                params = optax.apply_updates(params, updates)
         loss = jax.lax.pmean(loss, MESH_AXIS)
         return params, inner, new_ef, loss
 

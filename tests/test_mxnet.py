@@ -34,6 +34,10 @@ def hvd_mx():
     sys.modules.pop("horovod_tpu.mxnet", None)
     if had_binding is not None:
         sys.modules["horovod_tpu.mxnet"] = had_binding
+        # `import horovod_tpu.mxnet as m` binds the package's attribute, not
+        # the sys.modules entry: leave both on the same module, or a later
+        # test in this process gets the binding built on the fake
+        hvd.mxnet = had_binding
 
 
 def test_mx_allreduce_matrix(hvd_mx):

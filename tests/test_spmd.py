@@ -273,3 +273,82 @@ def test_multi_device_step_with_pallas_attention_matches_one_device(
     first = np.asarray(leaf.addressable_shards[0].data)
     for s in leaf.addressable_shards[1:]:
         np.testing.assert_array_equal(first, np.asarray(s.data))
+
+
+@pytest.fixture(scope="module")
+def step_hlo():
+    """The compiled HLO of a small LM step on one device and on two, and the
+    TPU lowering of flash attention forward and forward + backward."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import Mesh
+
+    from horovod_tpu.models.transformer import TransformerLM, lm_loss
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    model = TransformerLM(vocab_size=64, num_layers=1, num_heads=2,
+                          d_model=128, max_seq_len=32, dtype=jnp.float32)
+    batch = (jnp.zeros((2, 32), jnp.int32), jnp.zeros((2, 32), jnp.int32))
+    params = jax.eval_shape(
+        lambda key: model.init(key, batch[0][:1])["params"],
+        jax.random.PRNGKey(0))
+    tx = optax.adamw(1e-3)
+
+    def loss_fn(p, b):
+        return lm_loss(model.apply({"params": p}, b[0]), b[1])
+
+    def attention(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True)
+
+    def attention_grads(q, k, v):
+        return jax.grad(lambda *qkv: attention(*qkv).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    q = jax.ShapeDtypeStruct((2, 256, 2, 64), jnp.float32)
+    out = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("HVD_PALLAS", "interpret")
+        for name, n in (("one", 1), ("two", 2)):
+            mesh = Mesh(np.asarray(jax.devices()[:n]), ("hvd",))
+            step = spmd.make_train_step(loss_fn, tx, mesh=mesh, donate=False)
+            out[name] = step.lower(
+                params, jax.eval_shape(tx.init, params),
+                batch).compile().as_text()
+        mp.setenv("HVD_PALLAS", "on")
+        with jax.enable_x64(False):   # Mosaic has no 64-bit types
+            for name, fn in (("fwd", attention), ("bwd", attention_grads)):
+                out[name] = jax.jit(fn).trace(q, q, q).lower(
+                    lowering_platforms=("tpu",)).as_text(debug_info=True)
+    return out
+
+
+@pytest.mark.parametrize("program,pattern", [
+    ("one", r'op_name="jit\(step\)/optimizer/'),
+    ("one", r'op_name="jit\(step\)/jvp\(loss\)/'),
+    ("one", r'op_name="jit\(step\)/transpose\(jvp\(loss\)\)/'),
+    ("one", r'op_name="jit\(step\)/jvp\(TransformerLM\)/block_0/qkv/'),
+    ("one", r'op_name="jit\(step\)/transpose\(jvp\(TransformerLM\)\)'
+            r'/block_0/qkv/'),
+    ("one", r'op_name="jit\(step\)/jvp\(TransformerLM\)/tok_emb\.attend/'),
+    ("one", r'op_name="jit\(step\)/jvp\(TransformerLM\)/block_0/flash_fwd/'),
+    ("one", r'op_name="jit\(step\)/transpose\(jvp\(TransformerLM\)\)'
+            r'/block_0/flash_bwd/'),
+    ("two", r'op_name="jit\(step\)/shard_map/grad_allreduce/psum'),
+    ("two", r'op_name="jit\(step\)/optimizer/'),
+    ("two", r'op_name="jit\(step\)/shard_map/jvp\(loss\)/'),
+    ("fwd", r'kernel_name = "flash_fwd"'),
+    ("fwd", r'loc\("jit\(attention\)/flash_fwd/[^"]*pallas_call"'),
+    ("bwd", r'kernel_name = "flash_bwd"'),
+    ("bwd", r'loc\("jit\(attention_grads\)/[^"]*flash_bwd\)?/[^"]*'
+            r'pallas_call"'),
+])
+def test_compiled_step_names_its_parts(step_hlo, program, pattern):
+    """The scopes a device trace is read by (docs/timeline.md): optimizer,
+    loss, grad_allreduce from make_train_step and lm_loss, Flax's module
+    names with jvp( / transpose( for the two passes, and the flash kernels'
+    names on the custom calls the TPU would run."""
+    import re
+
+    assert "grad_allreduce" not in step_hlo["one"]
+    assert re.search(pattern, step_hlo[program]), pattern
