@@ -6,7 +6,8 @@ The three knobs that decide what fits in HBM (measured on a v5e in
 docs/benchmarks.md):
 
   * ``optim.zero``      — AdamW m/v sharded 1/N over the replica axis
-  * ``remat="full"``    — recompute block internals in backward
+  * ``remat="full"``    — recompute block internals in backward, all but
+                          the attention kernel's output
   * ``lm_loss_chunked`` — never materialize the [B, T, vocab] fp32 logits
 
 Run on the 8-device virtual CPU mesh:

@@ -161,16 +161,28 @@ class Block(nn.Module):
         return x if kv is None else (x, new_kv)
 
 
+# the names ``ops.pallas_kernels.flash_attention`` gives its kernel's outputs
+_SAVE_FLASH_OUTPUTS = jax.checkpoint_policies.save_only_these_names(
+    "flash_out", "flash_lse")
+
 #: rematerialization policies for ``TransformerLM(remat=...)``, mapping mode
-#: name -> (wrap_in_remat, jax.checkpoint policy). "full" recomputes
-#: everything inside each block during backward (activation memory = one
-#: [B,T,D] residual per layer — the lever that lets batch 32+ fit at seq
-#: 1024 in 16 GB HBM); "dots" saves matmul outputs and recomputes only
-#: elementwise ops (cheaper backward, more memory).
+#: name -> (wrap_in_remat, jax.checkpoint policy). "full" keeps, per layer,
+#: the block's input and the flash-attention kernel's two outputs (its bf16
+#: [B,T,D] output and f32 [B*H,T] row log-sum-exp, named "flash_out" and
+#: "flash_lse" in ``ops.pallas_kernels.flash_attention``) and recomputes
+#: everything else in the block during backward: activation memory = two
+#: bf16 [B,T,D] a layer. The kernel's outputs are kept because they are the
+#: dearest bytes in the block to rebuild: 0.44 ms of kernel for 10.8 MB a
+#: layer of GPT-2 large at batch 4 x 1024, 40 us a megabyte kept, where the
+#: matmuls' outputs cost 7-10 us a megabyte (PERF.md, PR 25). "dots" saves
+#: matmul outputs too and recomputes only elementwise ops (cheaper backward,
+#: more memory); a Pallas call is not a dot, so it names the same two.
 REMAT_POLICIES = {
     "none": (False, None),
-    "full": (True, None),
-    "dots": (True, jax.checkpoint_policies.dots_with_no_batch_dims_saveable),
+    "full": (True, _SAVE_FLASH_OUTPUTS),
+    "dots": (True, jax.checkpoint_policies.save_from_both_policies(
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        _SAVE_FLASH_OUTPUTS)),
 }
 
 
@@ -182,7 +194,10 @@ class TransformerLM(nn.Module):
     max_seq_len: int = 2048
     dtype: Any = jnp.bfloat16
     attn_fn: Optional[AttnFn] = None  # default: causal flash attention
-    remat: str = "none"  # "none" | "full" | "dots" — see REMAT_POLICIES
+    # "none" | "full" | "dots": recompute each block in backward, keeping
+    # only its input and the attention kernel's outputs ("full") or those
+    # and the matmul outputs ("dots") — see REMAT_POLICIES
+    remat: str = "none"
 
     @nn.compact
     def __call__(self, tokens, pos_offset=0, return_hidden=False,

@@ -37,6 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -1323,6 +1324,14 @@ def _flash_fullattn_vjp(causal: bool, scale: float):
     def fwd(q, k, v):
         b, tq, h, d = q.shape
         qt, kt, vt, out_t, lse_t = fwd_hm(q, k, v)
+        # The kernel's two outputs carry names so that a recomputation
+        # policy can keep them (models.transformer.REMAT_POLICIES): they are
+        # the dearest residuals to rebuild, since that takes the kernel.
+        # Identities outside jax.checkpoint. The statistics are named as
+        # [BH, T]: a kept f32 [BH, T, 1] pads its last dimension to 128
+        # lanes on the TPU, 128x its bytes.
+        out_t = checkpoint_name(out_t, "flash_out")
+        lse_t = checkpoint_name(lse_t[..., 0], "flash_lse")[..., None]
         return (_heads_minor(out_t, b, h, tq, d),
                 (qt, kt, vt, out_t, lse_t))
 

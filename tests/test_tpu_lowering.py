@@ -107,7 +107,7 @@ def test_four_chip_cases_compile_for_v5e(v5e_devices):
 LAYERS = 2
 
 
-def _train_step_lowering(mesh, per_chip_batch):
+def _train_step_lowering(mesh, per_chip_batch, remat="none"):
     """The data-parallel LM step over ``mesh`` with the model's default
     (Pallas) attention, lowered for the TPU."""
     import optax
@@ -116,7 +116,7 @@ def _train_step_lowering(mesh, per_chip_batch):
     from horovod_tpu.models.transformer import TransformerLM, lm_loss
 
     model = TransformerLM(vocab_size=1024, num_layers=LAYERS, num_heads=4,
-                          d_model=256, max_seq_len=256)
+                          d_model=256, max_seq_len=256, remat=remat)
 
     def loss_fn(p, batch):
         x, y = batch
@@ -158,3 +158,15 @@ def test_multi_device_train_step_compiles_for_v5e(per_chip_batch,
     mesh = Mesh(np.array(v5e_devices[:4]), ("hvd",))
     compiled = _train_step_lowering(mesh, per_chip_batch).compile()
     assert "all-gather" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("remat", ["none", "full", "dots"])
+def test_recomputation_does_not_rerun_the_flash_forward(remat, v5e_devices):
+    """Every recomputation policy keeps the forward kernel's two outputs
+    (``models.transformer.REMAT_POLICIES``), so the compiled step runs the
+    kernel once a layer in the forward pass and never again in the backward
+    pass: with a policy that keeps nothing it was ``3 * LAYERS`` calls."""
+    mesh = Mesh(np.array(v5e_devices[:1]), ("hvd",))
+    compiled = _train_step_lowering(mesh, 2, remat=remat).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2 * LAYERS

@@ -6,6 +6,8 @@ concatenated data, `test_torch.py` optimizer tests): here the sharded axes are
 batch AND sequence, and parity is against full-sequence single-device math.
 """
 
+from collections import Counter
+
 import numpy as np
 import pytest
 import jax
@@ -175,29 +177,76 @@ def test_sp_mesh_validation():
 
 
 # ----------------------------------------------- remat + chunked-loss levers
-def test_remat_matches_no_remat():
-    """jax.checkpoint must change memory, never math: grads bit-compare."""
-    from horovod_tpu.models.transformer import lm_loss
+@pytest.mark.parametrize("kernels", ["off", "interpret"])
+@pytest.mark.parametrize("mode", ["full", "dots"])
+def test_remat_matches_no_remat(mode, kernels, monkeypatch):
+    """jax.checkpoint must change memory, never math: the loss and every
+    gradient leaf compare with remat="none"'s. With the kernels in interpret
+    mode attention goes through flash_attention's custom_vjp, whose named
+    outputs the policies keep; off, through the jnp reference."""
+    monkeypatch.setenv("HVD_PALLAS", kernels)
     rng = np.random.RandomState(3)
     tokens, targets = _data(rng, 2, 64)
     base = TransformerLMTiny(vocab_size=VOCAB, dtype=jnp.float32)
     params = base.init(jax.random.PRNGKey(0), tokens)["params"]
 
-    def grads_for(remat):
+    def loss_and_grads_for(remat):
         m = TransformerLMTiny(vocab_size=VOCAB, dtype=jnp.float32,
                               remat=remat)
-        g = jax.grad(lambda p: lm_loss(m.apply({"params": p}, tokens),
-                                       targets))(params)
-        return jax.tree_util.tree_leaves(g)
+        loss, g = jax.value_and_grad(lambda p: lm_loss(
+            m.apply({"params": p}, tokens), targets))(params)
+        return [loss] + jax.tree_util.tree_leaves(g)
 
-    ref = grads_for("none")
-    for mode in ("full", "dots"):
-        got = grads_for(mode)
-        for a, b in zip(ref, got):
-            # remat re-fuses the backward HLO, so low-order fp32 bits may
-            # legitimately differ; the invariant is numerical equality
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-6, atol=1e-6)
+    for a, b in zip(loss_and_grads_for("none"), loss_and_grads_for(mode)):
+        # remat re-fuses the backward HLO, so low-order fp32 bits may
+        # legitimately differ; the invariant is numerical equality
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+
+
+def _saved_intermediates(remat, num_layers, tokens, targets, capsys):
+    """What jax.ad_checkpoint.print_saved_residuals lists for the loss's
+    gradient, arguments (the parameters) left out: a Counter of shapes, and
+    the lines themselves."""
+    m = TransformerLMTiny(vocab_size=VOCAB, dtype=jnp.float32, remat=remat,
+                          num_layers=num_layers)
+    params = m.init(jax.random.PRNGKey(0), tokens)["params"]
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda p: lm_loss(m.apply({"params": p}, tokens), targets), params)
+    lines = [l for l in capsys.readouterr().out.splitlines()
+             if " from the argument " not in l]
+    return Counter(l.split()[0] for l in lines), lines
+
+
+@pytest.mark.parametrize("remat,per_layer", [
+    # B, T, D = 2, 128, 128 and H = 2: the block's input, the kernel's
+    # heads-major output (as large as a [B, T, D]) and its row statistics
+    ("full", {"f32[2,128,128]": 1, "f32[4,128,64]": 1, "f32[4,128]": 1}),
+    # no policy reads the names: every residual of the kernel is kept, q, k
+    # and v heads-major beside its two outputs, the statistics as [BH, T, 1]
+    ("none", {"f32[4,128,64]": 4, "f32[4,128,1]": 1}),
+])
+def test_remat_saved_residuals(remat, per_layer, monkeypatch, capsys):
+    """Under "full" one more layer saves one block input, one flash_out and
+    one flash_lse, and no other tensor. What a layer adds is the difference
+    between three layers and two, so nothing outside the blocks (embedding,
+    final LayerNorm, head, loss) is in it."""
+    monkeypatch.setenv("HVD_PALLAS", "interpret")
+    rng = np.random.RandomState(3)
+    tokens, targets = _data(rng, 2, 128)
+    two, _ = _saved_intermediates(remat, 2, tokens, targets, capsys)
+    three, lines = _saved_intermediates(remat, 3, tokens, targets, capsys)
+    assert not two - three
+    added = three - two
+    assert {shape: added[shape] for shape in per_layer} == per_layer
+    if remat == "full":
+        assert set(added) == set(per_layer)
+        assert sum("named 'flash_lse'" in l for l in lines) == 3
+        # the kept output is the named one: remat puts a reduce_precision
+        # on a residual that the forward pass also uses, at the name's line
+        assert sum(l.startswith("f32[4,128,64]") and "pallas_kernels.py" in l
+                   for l in lines) == 3
 
 
 def test_remat_unknown_mode_raises():
