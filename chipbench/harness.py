@@ -2,9 +2,9 @@
 set-up bookkeeping (first calls, compilations), the profiler slice, and the
 ``Window`` that per-layer metric readers are handed.
 
-Nothing here knows a particular cell, configuration, mix or metric: those
-are files found by name (``workloads/``, ``configs/``, ``mixes/``,
-``jobs/``, ``layer_metrics/``, ``op_classes/``).
+Nothing here knows a particular cell, configuration, architecture, mix or
+metric: those are files found by name (``workloads/``, ``configs/``,
+``families/``, ``mixes/``, ``jobs/``, ``layer_metrics/``, ``op_classes/``).
 """
 
 from __future__ import annotations
@@ -25,11 +25,9 @@ from . import trace_reduce, traffic
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
-#: model sizes of ``--rehearse`` (a CPU walk through the harness; its
-#: numbers carry ``rehearsal_`` names and mean nothing)
-REHEARSAL_MODEL = {"n_layer": 2, "n_embd": 128, "n_head": 2,
-                   "n_positions": 256, "vocab_size": 500}
-REHEARSAL_PADDED_VOCAB = 512
+#: what a family module gives (``families/<model_type>.py``)
+FAMILY_NAMES = ("build_model", "reference_forward", "train_flops_per_token",
+                "attention_train_costs", "expected_first_loss", "REHEARSAL")
 
 
 class BenchmarkError(Exception):
@@ -66,6 +64,28 @@ class Cell:
     def vocab_rows(self) -> int:
         return int(self.config["assumed"]["padded_vocab_size"]["value"])
 
+    @property
+    def family(self):
+        """The module ``families/<model_type>.py``: what of the cell is its
+        architecture's (:data:`FAMILY_NAMES`)."""
+        return load_family(self.config["model_type"])
+
+
+def load_family(model_type: str):
+    name = f"{__package__}.families.{model_type}"
+    try:
+        module = importlib.import_module(name)
+    except ModuleNotFoundError as exc:
+        if exc.name != name:
+            raise
+        raise BenchmarkError(f"no family chipbench/families/{model_type}.py "
+                             f"for model_type {model_type!r}")
+    missing = [n for n in FAMILY_NAMES if not hasattr(module, n)]
+    if missing:
+        raise BenchmarkError(f"chipbench/families/{model_type}.py lacks "
+                             f"{missing}")
+    return module
+
 
 def load_cell(name: str, rehearse: bool = False) -> Cell:
     try:
@@ -74,10 +94,10 @@ def load_cell(name: str, rehearse: bool = False) -> Cell:
         raise BenchmarkError(f"no cell chipbench/workloads/{name}.json")
     config = load_json("configs", f"{spec['config']}.json")
     mix = traffic.load(spec["traffic"])
-    if rehearse:
-        config = {**config, **REHEARSAL_MODEL,
-                  "assumed": {**config["assumed"], "padded_vocab_size":
-                              {"value": REHEARSAL_PADDED_VOCAB}}}
+    if rehearse:   # the family's toy size, its table padded like the real one
+        config = {**config, **load_family(config["model_type"]).REHEARSAL}
+        config["assumed"] = {**config["assumed"], "padded_vocab_size": {
+            "value": -(-config["vocab_size"] // 128) * 128}}
         mix = {**mix, **mix.get("rehearsal", {})}
     return Cell(name, spec, config, mix)
 

@@ -1,10 +1,16 @@
 """The data-parallel LM training job, as a user of the library runs it:
 
 ``hvd.init()`` -> ``hvd.mesh()`` -> ``spmd.make_train_step(loss_fn, tx,
-mesh=...)`` with its defaults, ``models.TransformerLM`` with its defaults
-(bf16 compute, f32 params, flash attention), ``optax.adamw(3e-4,
+mesh=...)`` with its defaults, the model its family builds through the
+library's public constructor with its defaults, ``optax.adamw(3e-4,
 weight_decay=0.01, mu_dtype=bf16)``, the full-logit ``lm_loss``. No
 environment knob of the program is set and no compiler option passed.
+
+What is one architecture's comes from the cell's family,
+``families/<model_type>.py``: the model, its plain reference, its operation
+counts and its attention layers' costs, the first loss its initialisation
+gives. The loss, the optimizer, the step builder, the window and every
+tolerance are the job's, and no family can set them.
 
 Steps are dispatched back to back; every ``chunk_steps`` steps the loss is
 waited for and the chunk's host time recorded, until ``--seconds`` is up.
@@ -12,17 +18,15 @@ waited for and the chunk's host time recorded, until ``--seconds`` is up.
 
 from __future__ import annotations
 
-import math
 import statistics
 import time
 
 import numpy as np
 
-from .. import flops, harness, reference, traffic
+from .. import harness, traffic
 
-#: first loss: |loss - (ln(rows) + sigma^2/2)| with sigma^2 = d * 0.02^2 the
-#: variance of a tied-head logit over N(0, 0.02^2) embeddings on a
-#: unit-variance final LayerNorm (chip_smoke.py's band, its reasoning)
+#: first loss: |loss - family.expected_first_loss(config, rows)|, what the
+#: architecture's initialisation gives (the band is chip_smoke.py's)
 FIRST_LOSS_BAND = 0.5
 
 #: program logits against the float32 reference, as the root-mean-square
@@ -52,13 +56,9 @@ def build(cell: harness.Cell, mesh):
     import optax
 
     from horovod_tpu import spmd
-    from horovod_tpu.models.transformer import TransformerLM, lm_loss
+    from horovod_tpu.models.transformer import lm_loss
 
-    c = cell.config
-    model = TransformerLM(
-        vocab_size=cell.vocab_rows, num_layers=c["n_layer"],
-        num_heads=c["n_head"], d_model=c["n_embd"],
-        max_seq_len=c["n_positions"], remat=cell.mix["remat"])
+    model = cell.family.build_model(cell.config, cell.vocab_rows, cell.mix)
 
     def loss_fn(params, batch):
         tokens, targets = batch
@@ -95,14 +95,14 @@ def check_logits(ctx, model, params, tokens, dev):
     import jax
     import jax.numpy as jnp
 
-    c = ctx.cell.config
+    cell = ctx.cell
     dev0 = on_first_chip(params)
     tokens = jax.device_put(tokens, dev)
     got = ctx.first_call("program_forward", jax.jit(
         lambda p, t: model.apply({"params": p}, t).astype(jnp.float32)),
         dev0, tokens)
-    want = ctx.first_call("reference_forward", reference.forward, dev0,
-                          tokens, c["n_head"], c["layer_norm_epsilon"])
+    want = ctx.first_call("reference_forward", cell.family.reference_forward,
+                          dev0, tokens, cell.config)
 
     @jax.jit
     def compare(got, want):
@@ -161,6 +161,7 @@ def run(ctx: harness.Context) -> harness.Window:
     from horovod_tpu import spmd
 
     cell, mix, c = ctx.cell, ctx.cell.mix, ctx.cell.config
+    family = cell.family
     mesh = cell_mesh(cell.chips)
     chips = mesh.devices.size
     devices = list(mesh.devices.flat)
@@ -243,7 +244,7 @@ def run(ctx: harness.Context) -> harness.Window:
                  f"{rel_max:.5f} of its max")
     expect(rms <= LOGIT_RMS_TOL, f"logit rms error {rms} > {LOGIT_RMS_TOL}")
     rows = cell.vocab_rows
-    want_first = math.log(rows) + c["n_embd"] * 0.02 ** 2 / 2
+    want_first = family.expected_first_loss(c, rows)
     expect(abs(first_loss - want_first) <= FIRST_LOSS_BAND,
            f"first loss {first_loss:.4f} outside {want_first:.3f} +- "
            f"{FIRST_LOSS_BAND}")
@@ -272,7 +273,9 @@ def run(ctx: harness.Context) -> harness.Window:
         measured={"median_chunk_s": median_chunk, "chunk_steps": chunk,
                   "tokens_per_s_chip": tokens_per_s_chip, "chips": chips,
                   "seq": seq, "per_chip_batch": global_batch // chips,
-                  "train_flops_per_token": flops.train_flops_per_token(
-                      c["n_layer"], c["n_embd"], rows, seq)},
+                  "train_flops_per_token": family.train_flops_per_token(
+                      c, rows, seq),
+                  "attention_train_costs": family.attention_train_costs(
+                      c, global_batch // chips, seq)},
         counters={}, first_calls=ctx.first_calls,
         memory_peak_bytes=memory_peak, trace=trace, notes=notes)

@@ -1,7 +1,11 @@
 """The least time the chip could take over a step's attention (forward
-and backward, ``flops.flash_attention_train_cost`` for the per-chip shapes,
-every layer) over the time the ``attention_kernel`` class took. Which limit
-binds is in ``flops.roofline_seconds`` (both are within 6% at head 64)."""
+and backward, the family's ``attention_train_costs`` for the per-chip
+shapes: one ``flops.flash_attention_train_cost`` for each attention layer)
+over the time the ``attention_kernel`` class took. Which limit binds is in
+``flops.roofline_seconds`` (both are within 6% at head 64). A model with no
+attention layer has no such share."""
+
+import math
 
 from .. import flops
 
@@ -13,11 +17,11 @@ JOBS = ("train_lm",)
 
 
 def read(window):
-    t, m, c = window.trace, window.measured, window.cell.config
+    t, costs = window.trace, window.measured["attention_train_costs"]
     took_ms = t and t.ms_per_unit("class_s", "attention_kernel")
-    if not took_ms:
+    if not took_ms or not costs:
         return None
-    cost = flops.flash_attention_train_cost(
-        m["per_chip_batch"], c["n_head"], m["seq"], c["n_embd"] // c["n_head"])
-    least = c["n_layer"] * flops.roofline_seconds(cost, window.peak)["seconds"]
+    # fsum: n equal layers sum to n times one, to the bit
+    least = math.fsum(flops.roofline_seconds(cost, window.peak)["seconds"]
+                      for cost in costs)
     return 100.0 * least / (took_ms * 1e-3)

@@ -4,7 +4,7 @@ JAX's name stack (Flax's module names, ``jax.named_scope``, and ``jvp(`` /
 ``transpose(`` for the forward and the backward pass) goes into every HLO
 instruction's ``op_name``, and the profiler writes it into the trace: on a
 TPU plane as the ``tf_op`` stat of an operation's *event metadata*,
-``jit(step)/transpose(jvp(TransformerLM))/block_1/mlp_in/dot_general:``.
+``jit(step)/transpose(jvp(<model class>))/block_1/mlp_in/dot_general:``.
 ``jax.profiler.ProfileData`` hands out an event's own stats and not its
 metadata's, so :func:`read` takes them from the ``.xplane.pb`` wire format
 itself: a few dozen lines, no protobuf package. The CPU backend writes no

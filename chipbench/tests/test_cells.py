@@ -22,6 +22,9 @@ PENDING = {os.path.basename(p)[:-5]: harness.load_json("pending", os.path.basena
            for p in glob.glob(os.path.join(harness.HERE, "pending", "*.json"))}
 CELLS = sorted(os.path.basename(p)[:-5] for p in glob.glob(
     os.path.join(harness.HERE, "workloads", "*.json")))
+#: a job is a file under jobs/, found by the name a mix gives
+JOBS = {os.path.basename(p)[:-3] for p in glob.glob(
+    os.path.join(harness.HERE, "jobs", "*.py"))} - {"__init__"}
 
 
 def test_benchmark_json_shape():
@@ -118,12 +121,10 @@ def test_layer_metric_files_match_benchmark_json():
     bench = with_pending()
     assert {w["name"] for w in bench["workloads"]} == set(CELLS)
     declared = {m["name"]: m for m in bench["per_layer"]}
-    jobs = {os.path.basename(p)[:-3] for p in glob.glob(
-        os.path.join(harness.HERE, "jobs", "*.py"))} - {"__init__"}
     found = {}
     for mod in harness.layer_metric_modules():
         assert mod.__name__.endswith("." + mod.NAME)
-        assert set(mod.JOBS) <= jobs and callable(mod.read)
+        assert set(mod.JOBS) <= JOBS and callable(mod.read)
         found[mod.NAME] = mod
     assert set(found) == set(declared)
     for name, m in declared.items():
@@ -134,10 +135,40 @@ def test_layer_metric_files_match_benchmark_json():
             assert harness.load_cell(cell).job in mod.JOBS, (name, cell)
 
 
+def test_every_configuration_names_a_family_with_the_six_names():
+    files = sorted(glob.glob(os.path.join(harness.HERE, "configs", "*.json")))
+    assert {f"chipbench/configs/{os.path.basename(p)}" for p in files} == {
+        c["file"] for c in BENCH["configs"]}
+    for path in files:
+        config = harness.load_json("configs", os.path.basename(path))
+        family = harness.load_family(config["model_type"])
+        for name in harness.FAMILY_NAMES:
+            assert name == "REHEARSAL" or callable(getattr(family, name)), \
+                (path, name)
+        # the toy size overrides keys the configuration has, vocab_size among
+        # them (the rehearsal's table is padded from it)
+        assert set(family.REHEARSAL) <= set(config), path
+        assert "vocab_size" in family.REHEARSAL
+    with pytest.raises(harness.BenchmarkError, match="families/no_such.py"):
+        harness.load_family("no_such")
+
+
 def test_op_classes_and_peaks_load():
     classes = trace_reduce.load_classes()
-    assert [c for c, _ in classes] == ["attention_kernel", "collective", "copy"]
-    for name, cls in (("tpu_custom_call %block_6.3", "attention_kernel"),
+    order = [c for c, _ in classes]
+    # a later PR may add class files; these three stay, in priority order
+    assert [c for c in order if c in ("attention_kernel", "collective", "copy")
+            ] == ["attention_kernel", "collective", "copy"]
+    assert len(order) == len(set(order)) and trace_reduce.UNMATCHED not in order
+    for name, cls in (("tpu_custom_call %flash_fwd.47", "attention_kernel"),
+                      ("tpu_custom_call %flash_bwd.36", "attention_kernel"),
+                      ("tpu_custom_call %flash_bwd_dq.2", "attention_kernel"),
+                      ("tpu_custom_call %flash_bwd_dkv", "attention_kernel"),
+                      ("tpu_custom_call %flash_step.1", "attention_kernel"),
+                      # another Pallas kernel, named or not, is not attention
+                      ("tpu_custom_call %ssd_scan.3", "xla_op"),
+                      ("tpu_custom_call %flash_fwd_quantized.3", "xla_op"),
+                      ("tpu_custom_call %block_6.3", "xla_op"),
                       ("custom-call %cholesky.1", "xla_op"),
                       ("all-reduce-start %all-reduce-start.3", "collective"),
                       ("all-reduce all-reduce.1", "collective"),
@@ -153,4 +184,4 @@ def test_op_classes_and_peaks_load():
 def test_traffic_files_load():
     for path in glob.glob(os.path.join(harness.HERE, "mixes", "*.json")):
         mix = traffic.load(os.path.basename(path)[:-5])
-        assert mix["job"] in ("train_lm", "serve_lm") and mix["what"]
+        assert mix["job"] in JOBS and mix["what"]

@@ -197,7 +197,11 @@ def test_every_operation_of_the_recorded_trace_finds_its_metadata():
         return (sum(first.op_s[op] for op in ops if scopes[op])
                 / sum(first.op_s[op] for op in ops))
 
-    assert covered("xla_op") > 0.99 and covered("attention_kernel") == 1.0
+    # PR 22's kernels carry no name: Pallas calls no class file claims
+    assert "attention_kernel" not in first.op_class.values()
+    assert all(scopes[op] for op in first.op_s
+               if op.startswith("tpu_custom_call "))
+    assert covered("xla_op") > 0.99
     assert covered("copy") == pytest.approx(0.206, abs=0.001)
     qkv = {s.rsplit("/", 1)[0] for s in scopes.values()
            if "/block_0/qkv/" in s}

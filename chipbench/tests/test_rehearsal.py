@@ -9,6 +9,7 @@ The AOT proof that each cell's programs compile for a v5e is run by hand
     JAX_PLATFORMS=cpu HVD_PALLAS=on python3 -m chipbench.aot_check
 """
 
+import filecmp
 import json
 import os
 import shutil
@@ -20,6 +21,7 @@ import pytest
 from chipbench import harness
 
 KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+DATA = os.path.join(harness.HERE, "tests", "data")
 
 
 def rehearse(cell, trace, cwd=harness.ROOT, devices=1, seconds="2"):
@@ -76,29 +78,114 @@ def test_traced_rehearsal_prints_per_layer_metrics_and_breakdown(cell, devices):
         assert {"kv_gather_host", "decode_call"} & {n for n, _ in b["idle_gaps"]}
 
 
-def test_a_copied_workload_file_is_a_new_cell_with_no_code_edit(tmp_path):
+def copy_of_the_benchmark(tmp_path):
+    """``chipbench/`` copied beside a link to the program; the copy's
+    ``BENCHMARK.json`` is the caller's to write."""
     shutil.copytree(harness.HERE, tmp_path / "chipbench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     os.symlink(os.path.join(harness.ROOT, "horovod_tpu"),
                tmp_path / "horovod_tpu")
+    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def share_metrics(bench, old_cell, new_cell, but=()):
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if old_cell in m.get("workloads", []) and m["name"] not in but:
+            m["workloads"].append(new_cell)
+
+
+def test_a_copied_workload_file_is_a_new_cell_with_no_code_edit(tmp_path):
+    bench = copy_of_the_benchmark(tmp_path)
     spec = harness.load_json("workloads", "gpt2m-train-s1024.json")
     spec["name"], spec["traffic"] = "copied-cell", "train-b8-s1024-copy"
     shutil.copy(tmp_path / "chipbench" / "mixes" / "train-b8-s1024.json",
                 tmp_path / "chipbench" / "mixes" / "train-b8-s1024-copy.json")
     with open(tmp_path / "chipbench" / "workloads" / "copied-cell.json", "w") as f:
         json.dump(spec, f)
-    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
     bench["workloads"].append({**bench["workloads"][0], "name": "copied-cell",
                                "traffic": "train-b8-s1024-copy"})
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if "gpt2m-train-s1024" in m.get("workloads", []):
-            m["workloads"].append("copied-cell")
+    share_metrics(bench, "gpt2m-train-s1024", "copied-cell")
     with open(tmp_path / "BENCHMARK.json", "w") as f:
         json.dump(bench, f)
     line, _ = rehearse("copied-cell", 0, cwd=str(tmp_path), seconds="1")
     assert line["correct"] is True
     assert "rehearsal_train_tokens_per_s_chip" in line["metrics"]
+
+
+def add_the_second_architecture(tmp_path):
+    """What a ``model_config`` PR brings, as ``tests/data/second_family``
+    holds it for a toy: a family module, its model's reference, a
+    configuration, a mix and a workload file laid over a copy of
+    ``chipbench/``, and entries appended to ``BENCHMARK.json``. Returns the
+    new cell's name."""
+    bench = copy_of_the_benchmark(tmp_path)
+    copy, data = tmp_path / "chipbench", os.path.join(DATA, "second_family")
+    added = {os.path.relpath(os.path.join(path, f), data)
+             for path, _, files in os.walk(data) for f in files}
+    assert not [f for f in added if os.path.exists(copy / f)]
+    shutil.copytree(data, copy, dirs_exist_ok=True)
+    entries = harness.load_json("tests", "data", "second_family",
+                                "benchmark_entries.json")
+    cell = entries["workload"]["name"]
+    bench["configs"].append(entries["config"])
+    bench["workloads"].append(entries["workload"])
+    share_metrics(bench, entries["shares_with"], cell, entries["not_shared"])
+    with open(tmp_path / "BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    return cell
+
+
+def test_a_second_architecture_is_new_files_and_entries_with_no_code_edit(tmp_path):
+    """No file of the copy is edited, and every reader of the training job
+    applies to a model that is not ``TransformerLM``."""
+    cell = add_the_second_architecture(tmp_path)
+    copy = tmp_path / "chipbench"
+    for path, _, files in os.walk(harness.HERE):
+        for f in files if "__pycache__" not in path else ():
+            ours = os.path.join(path, f)
+            theirs = copy / os.path.relpath(ours, harness.HERE)
+            assert filecmp.cmp(ours, theirs, shallow=False), ours
+
+    line, out = rehearse(cell, 0, cwd=str(tmp_path), seconds="1")
+    assert set(line) == KEYS and line["correct"] is True, out
+    assert line["failed"] == 0 and line["attempted"] > 0
+    assert set(line["metrics"]) == {"rehearsal_train_tokens_per_s_chip",
+                                    "rehearsal_setup_s"}
+    line, out = rehearse(cell, 1, cwd=str(tmp_path), seconds="1")
+    assert line["correct"] is True, out
+    got = {k[len("rehearsal_"):] for k in line["metrics"]}
+    assert {"train_mfu", "train_step_ms", "xla_ops_ms", "blocks_fwd_ms",
+            "blocks_bwd_ms", "head_loss_ms", "optimizer_ms", "model_other_ms",
+            "device_idle_share", "compile_s"} <= got
+    assert line["metrics"]["rehearsal_train_mfu"]["value"] > 0
+    assert not got & {"flash_attention_roofline", "attn_kernel_ms",
+                      "attn_fwd_kernel_ms", "attn_bwd_kernel_ms",
+                      "allreduce_ms", "blocks_recompute_ms"}
+    # the family is found by the configuration's model_type, or named missing
+    os.remove(copy / "families" / "toymixer.py")
+    proc = subprocess.run(
+        [sys.executable, "-m", "chipbench.run", "--workload", cell, "--seed",
+         "1", "--seconds", "1", "--trace", "0", "--rehearse"], cwd=tmp_path,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=120)
+    assert proc.returncode != 0 and proc.stdout.strip() == ""
+    assert "chipbench/families/toymixer.py" in proc.stderr
+
+
+def test_a_timed_path_that_computes_something_else_is_not_correct(tmp_path):
+    """The rest of a run with the timed path broken underneath: the model
+    the window trains halves each block's update of the residual stream,
+    its reference does not, and the run says so."""
+    cell = add_the_second_architecture(tmp_path)
+    model = tmp_path / "chipbench" / "toymixer_model.py"
+    sound = model.read_text()
+    assert sound.count("return x + nn.Dense(") == 1
+    model.write_text(sound.replace("return x + nn.Dense(",
+                                   "return x + 0.5 * nn.Dense("))
+    line, out = rehearse(cell, 0, cwd=str(tmp_path), seconds="1")
+    assert line["correct"] is False and line["failed"] == 0
+    assert "CHECK FAILED: logit rms error" in out
 
 
 def test_a_directory_with_only_the_benchmark_fails_without_a_result(tmp_path):

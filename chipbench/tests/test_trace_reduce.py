@@ -30,7 +30,7 @@ def hand_made():
     """One device, a window of 20 s. The op line:
 
         0-4     fusion.1                          xla_op
-        4-6     a Pallas custom call              attention_kernel
+        4-6     flash_fwd, a Pallas custom call   attention_kernel
         6-6.5   all-reduce-start.1                collective
         6.5-8   fusion.2 (the all-reduce is in flight: hidden)
         8-9     all-reduce-done.1 (the wait: exposed)
@@ -41,7 +41,7 @@ def hand_made():
     and beside it all-reduce-start.1 in flight 6-9. Busy union = 0-9,
     12-15, 18-20 = 14; idle = 6 in the gaps 9-12 and 15-18.
     """
-    device = [("fusion %fusion.1", 0, 4), ("tpu_custom_call %block_0.3", 4, 6),
+    device = [("fusion %fusion.1", 0, 4), ("tpu_custom_call %flash_fwd.3", 4, 6),
               ("all-reduce-start %all-reduce-start.1", 6, 6.5),
               ("fusion %fusion.2", 6.5, 8),
               ("all-reduce-done %all-reduce-done.1", 8, 9),
@@ -101,28 +101,49 @@ def test_op_names():
     assert tr.op_name("dot") == "dot dot"
 
 
-def test_recorded_v5e_trace_reduces_to_the_pinned_numbers():
+RECORDED = {
+    # PR 22's chip run: the kernels had no name yet (``%block_0.2``), so
+    # since the class was narrowed to the flash kernels' names they are
+    # ``xla_op``, as any Pallas call is that no class file claims
+    "tiny_train_v5e": dict(
+        window_s=0.013793342, busy_s=0.000901259, idle_share=0.93466,
+        class_s={"copy": 0.00025065, "xla_op": 0.000650609},
+        span_copy=0.000703544, exposed_copy=0.000264553, kernels=[]),
+    # PR 24's chip run of the same model, the kernels named
+    "tiny_train_scoped_v5e": dict(
+        window_s=0.013512116, busy_s=0.000899438, idle_share=0.93343,
+        class_s={"copy": 0.000249539, "xla_op": 0.000506489,
+                 "attention_kernel": 0.00014341},
+        span_copy=None, exposed_copy=None,
+        kernels=["tpu_custom_call %flash_bwd.2", "tpu_custom_call %flash_bwd.3",
+                 "tpu_custom_call %flash_fwd.2", "tpu_custom_call %flash_fwd.3"]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED))
+def test_recorded_v5e_trace_reduces_to_the_pinned_numbers(name):
     """15 steps of the rehearsal model (2 layers, width 128, batch 2 x 128)
-    traced on a TPU v5 lite in PR 22's own chip run: the file's numbers, as
-    this reduction read them then."""
-    trace = tr.read_xplane(os.path.join(DATA, "tiny_train_v5e.xplane.pb.gz"))
+    traced on a TPU v5 lite in a builder's own chip run: the file's
+    numbers, as this reduction reads them."""
+    want = RECORDED[name]
+    trace = tr.read_xplane(os.path.join(DATA, f"{name}.xplane.pb.gz"))
     assert sorted(trace.devices) == ["/device:TPU:0"]
     s = tr.summarize(trace, units=15)
     d = s.first
-    assert s.window_s == pytest.approx(0.013793342, rel=1e-6)
-    assert s.busy_s == pytest.approx(0.000901259, rel=1e-6)
-    assert d.idle_share == pytest.approx(0.93466, rel=1e-4)
-    assert d.class_s["attention_kernel"] == pytest.approx(0.000143424, rel=1e-5)
-    assert d.class_s["copy"] == pytest.approx(0.00025065, rel=1e-5)
-    assert d.class_s["xla_op"] == pytest.approx(0.000507185, rel=1e-5)
+    assert s.window_s == pytest.approx(want["window_s"], rel=1e-6)
+    assert s.busy_s == pytest.approx(want["busy_s"], rel=1e-6)
+    assert d.idle_share == pytest.approx(want["idle_share"], rel=1e-4)
+    assert d.class_s == {k: pytest.approx(v, rel=1e-5)
+                         for k, v in want["class_s"].items()}
     assert sum(d.class_s.values()) == pytest.approx(d.busy_s)
-    assert d.span_s["copy"] == pytest.approx(0.000703544, rel=1e-5)
-    assert d.exposed_s["copy"] == pytest.approx(0.000264553, rel=1e-5)
+    if want["span_copy"]:
+        assert d.span_s["copy"] == pytest.approx(want["span_copy"], rel=1e-5)
+        assert d.exposed_s["copy"] == pytest.approx(want["exposed_copy"],
+                                                    rel=1e-5)
     # 2 layers x (forward, backward) x 15 steps, under four names
-    kernels = [n for n, c in d.op_class.items() if c == "attention_kernel"]
-    assert sorted(kernels) == [
-        "tpu_custom_call %block_0.2", "tpu_custom_call %block_0.3",
-        "tpu_custom_call %block_1.2", "tpu_custom_call %block_1.3"]
+    assert sorted(n for n, c in d.op_class.items()
+                  if c == "attention_kernel") == want["kernels"]
+    assert sum(n.startswith("tpu_custom_call ") for n in d.op_class) == 4
     assert s.idle_by_host_span == {tr.UNATTRIBUTED: pytest.approx(
         d.window_s - d.busy_s)}
 
