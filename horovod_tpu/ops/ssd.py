@@ -1,0 +1,111 @@
+"""Mamba-2's sequence mixer: the state-space scan in its dual (SSD) form,
+and the two small ops on either side of it.
+
+The layer is, per head, the recurrence
+
+    S_t = exp(dt_t * A) * S_{t-1} + dt_t * x_t B_t^T        S: [P, N]
+    y_t = S_t C_t + D * x_t
+
+(Dao & Gu, "Transformers are SSMs", 2024). :func:`ssd_chunked` computes it
+without a loop over time: inside a chunk of ``L`` positions the recurrence
+unrolls to the masked product ``(C B^T * decay) x`` — attention with a decay
+in place of the softmax — and across chunks only the ``[P, N]`` state at each
+chunk's end is carried. Everything is a batched matmul or an elementwise op
+that XLA lays out for the MXU; there is no Pallas kernel here yet, and the
+backward pass is autodiff of the forward.
+
+``causal_conv1d`` and ``gated_rms_norm`` are the depthwise convolution before
+the scan and the gated normalisation after it (``models/hybrid.py``).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+
+def ssd_chunked(x, dt, A, B, C, D, chunk: int = 256):
+    """The scan above for ``x`` ``[b, T, H, P]`` (H heads of width P).
+
+    ``dt`` ``[b, T, H]``: positive step sizes (after the softplus); ``A``
+    ``[H]``: negative decay rates; ``B``, ``C`` ``[b, T, N]``: one group of
+    input and output projections of the N-wide state, shared by the heads;
+    ``D`` ``[H]``: the skip. Returns ``y`` ``[b, T, H, P]`` in ``x.dtype``.
+
+    Matmul operands are in ``x.dtype`` (bf16 in the model) with float32
+    accumulation; ``dt``, ``A``, the cumulative sums of ``dt * A`` and every
+    ``exp`` are float32, and every exponent is <= 0: a decay is taken
+    between two positions of one chunk, or over whole chunks, never as a
+    ratio of two large cumulative products. A ``T`` that ``chunk`` does not
+    divide is padded with steps of ``dt = 0``, which leave the state alone.
+    """
+    b, t, h, p = x.shape
+    pad = -t % chunk
+    if pad:
+        x, dt, B, C = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                       for a in (x, dt, B, C))
+    c, f32 = (t + pad) // chunk, jnp.float32
+    with jax.named_scope("ssd"):
+        xc = x.reshape(b, c, chunk, h, p)
+        Bc = B.reshape(b, c, chunk, -1).astype(x.dtype)
+        Cc = C.reshape(b, c, chunk, -1).astype(x.dtype)
+        # heads before positions: [b, c, H, L], the decay's layout
+        dtc = dt.astype(f32).reshape(b, c, chunk, h).transpose(0, 1, 3, 2)
+        cum = jnp.cumsum(dtc * A.astype(f32)[:, None], axis=-1)
+
+        # inside a chunk: y_l += sum_{s <= l} (C_l . B_s) exp(cum_l - cum_s)
+        # dt_s x_s. The mask goes in before the exp: above the diagonal the
+        # exponent is positive and may overflow.
+        seg = cum[..., :, None] - cum[..., None, :]
+        causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+        decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))      # [b,c,H,L,L]
+        cb = jnp.einsum("bcln,bcsn->bcls", Cc, Bc, preferred_element_type=f32)
+        scores = cb[:, :, None] * decay * dtc[..., None, :]
+        y = jnp.einsum("bchls,bcshp->bclhp", scores.astype(x.dtype), xc,
+                       preferred_element_type=f32)
+
+        # a chunk's own end state: sum_s exp(cum_end - cum_s) dt_s x_s B_s^T
+        to_end = jnp.exp(cum[..., -1:] - cum) * dtc            # [b,c,H,L]
+        xw = xc * to_end.transpose(0, 1, 3, 2)[..., None].astype(x.dtype)
+        states = jnp.einsum("bclhp,bcln->bchpn", xw, Bc,
+                            preferred_element_type=f32)
+
+        # the state entering chunk z: sum_{k < z} states_k decayed over the
+        # whole chunks k+1 .. z-1
+        total = cum[..., -1]                                   # [b,c,H]
+        before = jnp.cumsum(total, axis=1) - total             # exclusive
+        over = before[:, :, None] - before[:, None, :] - total[:, None, :]
+        earlier = jnp.tril(jnp.ones((c, c), bool), -1)[None, :, :, None]
+        carry = jnp.exp(jnp.where(earlier, over, -jnp.inf))    # [b,z,k,H]
+        # (c x c a head: nothing to the MXU, so the float32 decays and states
+        # are multiplied as float32)
+        entering = jnp.einsum("bzkh,bkhpn->bzhpn", carry, states,
+                              precision="highest")
+
+        # ... read by every position of the chunk through its own decay
+        y_in = jnp.einsum("bcln,bchpn->bclhp", Cc, entering.astype(x.dtype),
+                          preferred_element_type=f32)
+        y = y + y_in * jnp.exp(cum).transpose(0, 1, 3, 2)[..., None]
+        y = y + D.astype(f32)[:, None] * xc.astype(f32)
+        return y.reshape(b, t + pad, h, p)[:, :t].astype(x.dtype)
+
+
+def causal_conv1d(x, kernel, bias):
+    """Depthwise causal convolution over time: ``y_t = bias + sum_k
+    kernel[k] * x_{t-(K-1)+k}`` for ``x`` ``[b, T, C]``, ``kernel``
+    ``[K, C]``, positions before the sequence zero. K shifted multiply-adds
+    accumulated in float32 (K is 4: not worth a convolution's layout)."""
+    k, t = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+    y = bias.astype(jnp.float32)
+    for i in range(k):
+        y = y + padded[:, i:i + t] * kernel[i].astype(jnp.float32)
+    return y.astype(x.dtype)
+
+
+def gated_rms_norm(y, gate, scale, eps: float):
+    """``RMSNorm(y * silu(gate)) * scale`` over the last axis (one group),
+    in float32, returned in ``y.dtype``."""
+    h = y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+    h = h * jax.lax.rsqrt(jnp.mean(jnp.square(h), axis=-1, keepdims=True) + eps)
+    return (h * scale.astype(jnp.float32)).astype(y.dtype)
