@@ -401,6 +401,11 @@ def kernel_cases():
 
     qkv = [s((B, T, H, D), jnp.bfloat16)] * 3
     qkv_long = [s((1, LONG_T, H, D), jnp.bfloat16)] * 3
+    # the per-chip calls of the benchmark's other two architectures:
+    # gpt2-large (batch 4 x 20 heads) and granite-4.0-h (1 x 32 x 4096,
+    # four k sweeps with the dq scratch, no input fusion at batch 1)
+    qkv_large = [s((4, T, 20, D), jnp.bfloat16)] * 3
+    qkv_4k = [s((1, 4096, 32, D), jnp.bfloat16)] * 3
     rows = s((CHUNK_ROWS, BLOCK), jnp.float32)
     leaf = (DM, 4 * DM)
     return {
@@ -408,6 +413,10 @@ def kernel_cases():
             flash, qkv, 2, dense, TOL_BF16),
         "flash_attention fwd+bwd 1x8192": KernelCase(
             flash_two_heads, qkv_long, 2, dense_two_heads, TOL_BF16),
+        "flash_attention fwd+bwd 4x20x1024": KernelCase(
+            flash, qkv_large, 2, dense, TOL_BF16),
+        "flash_attention fwd+bwd 1x32x4096": KernelCase(
+            flash_two_heads, qkv_4k, 2, dense_two_heads, TOL_BF16),
         "flash_attention_step (ring hop)": KernelCase(
             lambda q, k, v, m, l, o: pk.flash_attention_step(
                 q, k, v, m, l, o, hop["q_off"], hop["k_off"],

@@ -219,15 +219,15 @@ def _pick_block(t: int, preferred: int = None,
                 side: Optional[str] = None) -> Optional[int]:
     """Largest power-of-2 tile ≤ preferred dividing t (None if none ≥ 8).
 
-    Default tile edges are asymmetric — q-side 512, k-side 1024: bigger
-    tiles mean quadratically fewer grid cells (the per-cell grid overhead,
-    not FLOPs, dominated the attention kernels at 128), and the k side can
-    afford the larger edge because the kernels iterate over k within a
-    cell. lm_bench ladder on a v5e, batch 8 / seq 1024:
-    128/128 → 26.3k, 256/256 → 32.8k, 512/512 → 37.7k,
-    512/1024 → 38.7k tok/s (1024/1024 exceeds scoped VMEM — the f32
-    score tile alone is 4 MB).  ``HVD_PALLAS_BLOCK`` overrides both sides;
-    ``HVD_PALLAS_BLOCK_Q`` / ``HVD_PALLAS_BLOCK_K`` override each
+    Default GRID tile edges are asymmetric — q-side 512, k-side 1024:
+    bigger tiles mean quadratically fewer grid cells, and per-cell overhead,
+    not FLOPs, dominated the attention kernels at 128 (rounds 3-5 of
+    docs/benchmarks.md, on older kernels: 128/128 → 512/1024 was +47% on
+    the lm_bench step; 1024/1024 exceeded scoped VMEM). That ladder moved
+    grid tiles only and never priced the masked scores a large causal tile
+    computes: :func:`_pick_sub_tile` bounds those inside the cell, and
+    PERF.md §6 (PR 28) has its ladder. ``HVD_PALLAS_BLOCK`` overrides both
+    sides; ``HVD_PALLAS_BLOCK_Q`` / ``HVD_PALLAS_BLOCK_K`` override each
     independently for tuning."""
     if preferred is None:
         if side is not None:
@@ -242,6 +242,66 @@ def _pick_block(t: int, preferred: int = None,
             return b
         b //= 2
     return None
+
+
+# Edge of the sub-tiles the CAUSAL fused backward cuts a grid cell into.
+# Read on a v5e (PERF.md §6, PR 28): 512 takes 18.0% off the kernel at 1024
+# positions and 6.5% at 4096; 256 takes 19.9% and 4.4% with four times the
+# bodies (gpt2-medium's compiled step grew from about 59 to 79 MiB) and
+# 1.8 s more of every warm start, for 0.4% of the step; 128 is slower than
+# no cut.
+_SUB_TILE = 512
+
+
+def _pick_sub_tile(causal: bool, block_q: int, block_k: int):
+    """``(sub_q, sub_k)``: the edges at which the fused backward cuts its
+    ``block_q x block_k`` cell. A causal call takes the q tile ``sub_q``
+    rows at a time and gives each row sub-tile a strip of keys as wide as
+    its last unmasked score needs, in steps of ``sub_k``: finer edges skip
+    more of the masked triangle (at 1024 positions a head, 512 x 1024
+    computes the whole square, 512 x 512 three sub-tiles of four, 256 x 256
+    ten of sixteen) against narrower matmuls and one body a width and row
+    sub-tile. A non-causal call has nothing to skip, and a tile no larger
+    than the edge nothing to cut: both keep the whole tile, which is the
+    code without the cut."""
+    if not causal:
+        return block_q, block_k
+    return min(block_q, _SUB_TILE), min(block_k, _SUB_TILE)
+
+
+def _live_sub_tiles(q_lo, k_lo, sub_q, sub_k, n):
+    """Of a key block's ``n`` sub-tiles of ``sub_k`` from global position
+    ``k_lo``, how many hold a score the ``sub_q`` query rows from ``q_lo``
+    may see: the first ``w``; the rest are wholly above the diagonal
+    (exactly zero p). Python ints or traced scalars (ring hops), floor
+    division either way."""
+    w = (q_lo + sub_q - k_lo + sub_k - 1) // sub_k
+    if isinstance(w, (int, np.integer)):
+        return min(max(w, 0), n)
+    return jnp.clip(w, 0, n)
+
+
+def flash_plan(causal: bool, tq: int, tk: int, q_off: int, k_off: int,
+               block_k: int, sub_q: int, sub_k: int) -> dict:
+    """What one head of a flash call computes, counted in sub-tiles of
+    ``sub_q x sub_k`` scores by the bound the kernels themselves run
+    (:func:`_live_sub_tiles` a row sub-tile and key block of ``block_k``):
+    ``computed`` (of them ``masked``: a causal call builds its mask on all
+    it computes), ``skipped``, and ``scores`` = computed x sub_q x sub_k,
+    which the kernels' cost estimates are made of. The fused backward cuts
+    at :func:`_pick_sub_tile`'s edges; the forward runs whole key blocks,
+    ``sub_q, sub_k = block_q, block_k``. The q grid tile does not enter: it
+    is a multiple of ``sub_q``, and a cell the diagonal leaves dead is a
+    cell of skipped sub-tiles. No JAX."""
+    n = block_k // sub_k
+    rows, blocks = tq // sub_q, tk // block_k
+    computed = rows * blocks * n if not causal else sum(
+        _live_sub_tiles(q_off + r * sub_q, k_off + jb * block_k, sub_q,
+                        sub_k, n)
+        for r in range(rows) for jb in range(blocks))
+    return {"computed": computed, "masked": computed if causal else 0,
+            "skipped": rows * blocks * n - computed,
+            "scores": computed * sub_q * sub_k}
 
 
 def _pick_bh_block(bh: int, per_g_bytes: int = 0, cap: int = 0) -> int:
@@ -267,16 +327,35 @@ def _pick_bh_block(bh: int, per_g_bytes: int = 0, cap: int = 0) -> int:
 
 
 # =========================================================== flash attention
-def _flash_accum(q, k_ref, v_ref, g, hi, m, l, o, *, q_off, k_off, causal,
+def _causal_mask(s, q_lo, k_lo):
+    """The scores ``s`` of query rows from global position ``q_lo`` against
+    keys from ``k_lo``, -inf above the diagonal."""
+    delta = (lax.broadcasted_iota(jnp.int32, s.shape, 0)
+             - lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    return jnp.where(delta >= k_lo - q_lo, s, NEG_INF)
+
+
+def _flash_accum(q, k_ref, v_ref, g, m, l, o, *, q_off, k_off, causal,
                  scale, block_k):
-    """Online-softmax accumulation of q against k/v blocks ``[0, hi)`` of
-    slice ``g`` — THE shared inner body of the ring-step and single-shot
-    forward kernels (one copy, so the base-2/masked-row convention cannot
-    drift between them; the backward recompute depends on it). ``m`` is in
-    base-2 units; dot operands stay in the input dtype (bf16 models run
-    the MXU at bf16 rate), accumulation is f32."""
+    """Online-softmax accumulation of the q tile (global first row
+    ``q_off``) against slice ``g``'s resident k/v, a key block of
+    ``block_k`` at a time — THE shared inner body of the ring-step and
+    single-shot forward kernels (one copy, so the base-2/masked-row
+    convention cannot drift between them; the backward recompute depends
+    on it). ``m`` is in base-2 units; dot operands stay in the input dtype
+    (bf16 models run the MXU at bf16 rate), accumulation is f32.
+
+    A causal call skips the key blocks past its last unmasked score, so
+    the loop's trip count is a run-time value — unless the resident k/v is
+    ONE block (every length up to 1024): that block runs as straight-line
+    code, masked. Measured on a v5e at batch·heads 128 x 1024 (PERF.md §6,
+    PR 28): a run-time trip count of one costs the kernel 0.70 ms where the
+    same masked block takes 0.49 without the loop around it, and the mask
+    costs nothing. A ring hop the diagonal leaves wholly dead is then
+    computed to exact zeros (p = 0 on every masked score) and not skipped."""
     bq = q.shape[0]
     in_dt = q.dtype
+    nblk = k_ref.shape[1] // block_k
 
     def body(j, carry):
         m, l, o = carry
@@ -287,24 +366,24 @@ def _flash_accum(q, k_ref, v_ref, g, hi, m, l, o, *, q_off, k_off, causal,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if causal:
-            qpos = q_off + lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            kpos = (k_off + j * block_k
-                    + lax.broadcasted_iota(jnp.int32, (bq, block_k), 1))
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        m_blk = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m, m_blk)
+            s = _causal_mask(s, q_off, k_off + j * block_k)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
         p = jnp.exp2(s - m_safe[:, None])             # exp2(-inf) == 0
         alpha = jnp.exp2(m - m_safe)                  # m=-inf -> 0
         l_new = l * alpha + jnp.sum(p, axis=-1)
-        pv = lax.dot_general(p.astype(in_dt), v,
-                             (((1,), (0,)), ((), ())),
+        pv = lax.dot_general(p.astype(in_dt), v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-        o_new = o * alpha[:, None] + pv
-        return m_new, l_new, o_new
+        return m_new, l_new, o * alpha[:, None] + pv
 
-    return lax.fori_loop(0, hi, body, (m, l, o))
+    if nblk == 1:
+        return body(0, (m, l, o))
+    # k blocks past the last unmasked key for this q tile contribute
+    # nothing — bound the loop (exact: those blocks are fully masked)
+    live = nblk
+    if causal:
+        live = _live_sub_tiles(q_off, k_off, bq, block_k, nblk)
+    return lax.fori_loop(0, live, body, (m, l, o))
 
 
 def _flash_step_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref, o_ref,
@@ -318,19 +397,8 @@ def _flash_step_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref, o_ref,
     origins for causal masking (ring hop offsets) — shared by all G
     sub-problems (they are different batch·head slices of one sequence).
     """
-    iq = pl.program_id(1)
-    bq = q_ref.shape[1]
-    tk = k_ref.shape[1]
-    q_off = offs_ref[0] + iq * bq
+    q_off = offs_ref[0] + pl.program_id(1) * q_ref.shape[1]
     k_off = offs_ref[1]
-
-    nk = tk // block_k
-    if causal:
-        # k blocks past the last unmasked key for this q tile contribute
-        # nothing — bound the loop (exact: those blocks are fully masked)
-        hi = jnp.clip((q_off + bq - k_off + block_k - 1) // block_k, 0, nk)
-    else:
-        hi = nk
 
     for g in range(q_ref.shape[0]):
         q = q_ref[g]                                  # [BQ, D]
@@ -338,7 +406,7 @@ def _flash_step_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref, o_ref,
         m = m_ref[g, :, 0].astype(jnp.float32) * _LOG2E   # [BQ]
         l = l_ref[g, :, 0].astype(jnp.float32)
         o = o_ref[g].astype(jnp.float32)              # [BQ, D]
-        m, l, o = _flash_accum(q, k_ref, v_ref, g, hi, m, l, o,
+        m, l, o = _flash_accum(q, k_ref, v_ref, g, m, l, o,
                                q_off=q_off, k_off=k_off, causal=causal,
                                scale=scale, block_k=block_k)
         mo_ref[g, :, 0] = m * _LN2                    # back to natural units
@@ -356,24 +424,16 @@ def _flash_fwd_once_kernel(offs_ref, q_ref, k_ref, v_ref, oo_ref, lse_ref,
     (~65 MB vs ~130 MB at the GPT-2-medium bench shapes: no f32 o in/out,
     no m/l streams) and retires the separate finalize fusion + zero-init
     copies (measured breakdown in docs/benchmarks.md round 5)."""
-    iq = pl.program_id(1)
     bq = q_ref.shape[1]
-    tk = k_ref.shape[1]
-    q_off = offs_ref[0] + iq * bq
+    q_off = offs_ref[0] + pl.program_id(1) * bq
     k_off = offs_ref[1]
-
-    nk = tk // block_k
-    if causal:
-        hi = jnp.clip((q_off + bq - k_off + block_k - 1) // block_k, 0, nk)
-    else:
-        hi = nk
 
     for g in range(q_ref.shape[0]):
         q = q_ref[g]                                  # [BQ, D]
         m = jnp.full((bq,), NEG_INF, jnp.float32)
         l = jnp.zeros((bq,), jnp.float32)
         o = jnp.zeros((bq, q_ref.shape[2]), jnp.float32)
-        m, l, o = _flash_accum(q, k_ref, v_ref, g, hi, m, l, o,
+        m, l, o = _flash_accum(q, k_ref, v_ref, g, m, l, o,
                                q_off=q_off, k_off=k_off, causal=causal,
                                scale=scale, block_k=block_k)
         # the _masked_row_stats convention, fused into the epilogue:
@@ -397,6 +457,9 @@ def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
              + 2 * block_q * d * 4)
     g = _pick_bh_block(bh, per_g, _BH_VMEM_CAP)
     grid = (bh // g, tq // block_q)
+    # the only caller passes zero offsets: the plan is the call's
+    scores = bh * flash_plan(causal, tq, tk, 0, 0, block_k, block_q,
+                             block_k)["scores"]
     return _named_call("flash_fwd",
         functools.partial(_flash_fwd_once_kernel, causal=causal,
                           scale=scale, block_k=block_k),
@@ -418,9 +481,9 @@ def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
             _struct((bh, tq, 1), jnp.float32, qt, kt, offs),
         ],
         cost_estimate=pl.CostEstimate(
-            flops=4 * bh * tq * tk * d,
+            flops=4 * scores * d,                     # 2 matmuls a score
             bytes_accessed=2 * (2 * bh * tq * d + 2 * bh * tk * d),
-            transcendentals=bh * tq * tk),
+            transcendentals=scores),
         compiler_params=_input_fusion(_sem_par2_res(), 3, fusable),
         interpret=interpret,
     )(offs, qt, kt, vt)
@@ -579,6 +642,7 @@ def _flash_step_call(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
             pl.BlockSpec((g, block_q, d), lambda i, j, offs: (i, j, 0)),
         ],
     )
+    # ring hops pass traced offsets: the whole rectangle, an upper bound
     flops = 4 * bh * tq * tk * d  # 2 matmuls
     return _named_call("flash_step",
         kernel,
@@ -874,29 +938,38 @@ def _flash_bwd_dkv_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
 
 def _flash_bwd_fused_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
                             do_ref, dq_ref, dk_ref, dv_ref, *maybe_acc,
-                            causal, scale):
+                            causal, scale, sub_q, sub_k):
     """ONE-pass FlashAttention-2 backward: grid (bh, k tiles, q tiles) with
     q innermost; each cell recomputes p ONCE and emits all three gradient
     contributions. The legacy pair of kernels (dq pass + dkv pass) each
     streamed the operands and rebuilt p/dp separately — twice the operand
     DMA and 7 matmuls per (q, k) tile pair; this kernel does 5.
 
-    dk/dv accumulate in their revisited output tiles (q innermost, so the
-    visits are consecutive). dq accumulates in a whole-[TQ, D] f32 VMEM
-    scratch that persists across the bh-slice's grid cells (zeroed at the
-    slice's first cell); the current q tile of the scratch is flushed
+    A causal cell is cut at :func:`_pick_sub_tile`'s edges: ``sub_q`` rows
+    at a time, each row sub-tile against the strip of the k tile that holds
+    its unmasked scores — the tile's first ``w`` sub-tiles of ``sub_k``
+    (:func:`_live_sub_tiles`), nothing where ``w`` is 0 — under the mask.
+    One body a width, chosen by ``pl.when``, so every slice is static (a
+    loop over sub-tiles with run-time slices measured slower than the whole
+    tile, PERF.md §6, PR 28). p, dp and ds exist a strip at a time, dk/dv
+    accumulate on the strip's rows of their scratch, and a row sub-tile's
+    dq is written once. A non-causal cell is one strip: the whole tile.
+
+    dk/dv accumulate in f32 VMEM scratch across the q tiles (q innermost,
+    so the visits are consecutive). dq accumulates in a whole-[TQ, D] f32
+    VMEM scratch that persists across the bh-slice's grid cells (zeroed at
+    the slice's first cell); the current q tile of the scratch is flushed
     through the dq output block every visit — tile i's bytes are final
     from its last live k sweep onward, and later sweeps rewrite the same
     final bytes (last-write-wins), so the output is correct for causal
     and non-causal alike at the cost of nk-1 redundant tile writes.
 
-    Single-k-sweep fast path (nk == 1, e.g. the seq-1024 headline config):
-    dq completes within one cell, so the dispatch allocates NO dq scratch
-    and the kernel writes dq directly — skipping a read-modify-write plus
-    a flush copy of the tile per cell.
+    Single-k-sweep form (one k grid tile: every length up to 1024): dq
+    completes within one cell, so the dispatch allocates NO dq scratch and
+    the kernel writes dq directly — skipping a read-modify-write plus a
+    flush copy of the tile per cell.
 
-    Gradients leave the kernel in the INPUT dtype: accumulation stays f32
-    (dk/dv in the per-cell VMEM scratch pair, consecutive iq revisits),
+    Gradients leave the kernel in the INPUT dtype: accumulation stays f32,
     cast once at the final write — a bf16 model never round-trips 3x f32
     gradient tensors through HBM plus three XLA cast fusions (measured
     ladder in docs/benchmarks.md round 5)."""
@@ -921,44 +994,53 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    live = (q_off + bq - 1 >= k_off) if causal else True
-
-    if dq_acc is None and causal:
-        # a fully-masked cell contributes nothing: its dq tile is zero
-        @pl.when(jnp.logical_not(live))
-        def _():
-            dq_ref[0] = jnp.zeros_like(dq_ref[0])
-
-    @pl.when(live)
-    def _():
-        q = q_ref[0]                                  # [BQ, D]
-        do = do_ref[0]
-        lse = lse_ref[0] * _LOG2E                     # [BQ, 1] f32, base-2
-        dd = dd_ref[0]
-        k = k_ref[0]                                  # [BK, D]
-        v = v_ref[0]
+    def strip(rows, w):
+        """One row sub-tile against the k tile's first ``w`` sub-tiles."""
+        q = q_ref[0, rows, :]                         # [SQ, D]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, rows, :] * _LOG2E            # [SQ, 1] f32, base-2
+        dd = dd_ref[0, rows, :]
+        cols = pl.ds(0, w * sub_k)
+        k = k_ref[0, cols, :]                         # [W, D]
+        v = v_ref[0, cols, :]
         s = (scale * _LOG2E) * lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if causal:
-            qpos = q_off + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = k_off + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
+            s = _causal_mask(s, q_off + rows.start, k_off)
         p = jnp.exp2(s - lse)                         # exp2(-inf) == 0
-        dv_acc[...] += lax.dot_general(p.astype(in_dt), do,
-                                       (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
+        dv_acc[cols, :] += lax.dot_general(
+            p.astype(in_dt), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
         ds = (p * (dp - dd) * scale).astype(in_dt)
-        dk_acc[...] += lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-        dq_contrib = lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
+        dk_acc[cols, :] += lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq = lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
         if dq_acc is None:
-            dq_ref[0] = dq_contrib.astype(dq_ref.dtype)
+            dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
         else:
-            dq_acc[pl.ds(iq * bq, bq), :] += dq_contrib
+            dq_acc[pl.ds(iq * bq + rows.start, sub_q), :] += dq
+
+    n = bk // sub_k
+    for r0 in range(0, bq, sub_q):
+        rows = pl.ds(r0, sub_q)
+        if not causal:
+            strip(rows, n)
+            continue
+        live = _live_sub_tiles(q_off + r0, k_off, sub_q, sub_k, n)
+        if dq_acc is None:
+            # nothing of the k tile is under the diagonal for these rows
+            # (every row sub-tile of a dead cell): their dq is zero
+            @pl.when(live == 0)
+            def _(rows=rows):
+                dq_ref[0, rows, :] = jnp.zeros(
+                    (sub_q, dq_ref.shape[2]), dq_ref.dtype)
+        for w in range(1, n + 1):
+            pl.when(live == w)(functools.partial(strip, rows, w))
 
     @pl.when(iq == nq - 1)
     def _():
@@ -970,21 +1052,28 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
 
 
 def _flash_bwd_fused(qt, kt, vt, dot, lset, ddt, offs, d, *, causal, scale,
-                     block_q, block_k, interpret, fusable, out_dtype=None):
+                     block_q, block_k, interpret, fusable, out_dtype=None,
+                     static_offs=None):
     """Dispatch of the one-pass backward (any length: k/v tiles stream
     through the grid, dq rides the VMEM scratch). ``out_dtype`` picks the
     gradient output dtype (default f32); the ring path keeps f32 so its
     cross-hop accumulators never ingest pre-rounded contributions, while
-    the single-device VJP requests the input dtype directly."""
+    the single-device VJP requests the input dtype directly.
+    ``static_offs`` is ``(q_off, k_off)`` where the caller knows them as
+    Python ints: the cost estimate then counts the call's own plan, and
+    the whole rectangle (an upper bound) where they are traced."""
     out_dtype = jnp.float32 if out_dtype is None else out_dtype
     bh, tq = qt.shape[0], qt.shape[1]
     tk = kt.shape[1]
     _, qmap = _causal_maps(causal, block_q, block_k, tq // block_q)
     ktile = pl.BlockSpec((1, block_k, d), lambda i, j, n, offs: (i, j, 0))
+    sub_q, sub_k = _pick_sub_tile(causal, block_q, block_k)
+    scores = bh * (tq * tk if static_offs is None else flash_plan(
+        causal, tq, tk, *static_offs, block_k, sub_q, sub_k)["scores"])
 
     return _named_call("flash_bwd",
         functools.partial(_flash_bwd_fused_kernel, causal=causal,
-                          scale=scale),
+                          scale=scale, sub_q=sub_q, sub_k=sub_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             # q innermost: dk/dv revisits are consecutive; j sweeps
@@ -1015,9 +1104,9 @@ def _flash_bwd_fused(qt, kt, vt, dot, lset, ddt, offs, d, *, causal, scale,
             _struct((bh, tk, d), out_dtype, qt, kt, offs),
         ],
         cost_estimate=pl.CostEstimate(
-            flops=10 * bh * tq * tk * d,  # 5 matmuls per tile pair
+            flops=10 * scores * d,                    # 5 matmuls a score
             bytes_accessed=4 * bh * (4 * tq * d + 4 * tk * d),
-            transcendentals=bh * tq * tk),
+            transcendentals=scores),
         # j and the innermost q dim both accumulate into revisited state;
         # single-sweep (k resident per cell) gets the resident VMEM budget
         # and producer input fusion (the multi-sweep form measured -1.9%
@@ -1139,11 +1228,14 @@ def _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off=0, k_off=0, *,
     way) pays no relayout. Returns (dq, dk, dv) heads-major f32."""
     bh, tq, d = qt.shape
     tk = kt.shape[1]
-    # backward tiles follow the forward defaults unless overridden
+    # backward GRID tiles follow the forward defaults unless overridden
     # independently (HVD_PALLAS_BLOCK_BWD_Q/K) — the fused one-pass kernel
     # has a different VMEM profile (dq scratch + 3 outputs) than the
-    # forward, so its optimum can differ. Measured on the lm_bench step:
-    # BWD_K=512 neutral, BWD_Q=1024 +0.5% (noise) — defaults kept.
+    # forward, so its optimum can differ. A k grid tile of 512 at seq 1024
+    # skips a quarter of the square but leaves the single-sweep form (dq
+    # scratch, no input fusion, no resident budget), and measured neutral
+    # on the lm_bench step (round 5). The masked part is bounded inside the
+    # cell instead (_pick_sub_tile).
     block_q = _pick_block(tq, preferred=_env_block("HVD_PALLAS_BLOCK_BWD_Q"),
                           side="q")
     block_k = _pick_block(tk, preferred=_env_block("HVD_PALLAS_BLOCK_BWD_K"),
@@ -1159,10 +1251,13 @@ def _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off=0, k_off=0, *,
     # legacy two-pass layouts below take over.
     if (os.environ.get("HVD_PALLAS_FUSED_BWD", "1") not in ("0", "false")
             and tq * d * 4 <= _DQ_SCRATCH_CAP):
+        static = all(isinstance(x, (int, np.integer))
+                     for x in (q_off, k_off))
         return _flash_bwd_fused(
             qt, kt, vt, dot, lset, ddt, offs, d, causal=causal, scale=scale,
             block_q=block_q, block_k=block_k, interpret=interpret,
-            fusable=fusable, out_dtype=out_dtype)
+            fusable=fusable, out_dtype=out_dtype,
+            static_offs=(q_off, k_off) if static else None)
 
     # Two legacy kernel layouts: whole-resident (one side of the score
     # matrix stays in VMEM; ~20% faster at short T — no tile re-fetch) and

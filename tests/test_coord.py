@@ -1588,6 +1588,12 @@ class TestStandbyPromotion:
             # promotion itself is a membership reset losing rank 0
             assert sb.server.state.epoch == 2
             assert sb.server.state.members == {1}
+            # _promote sets `promoted`, publishes its address (a KV round
+            # trip), THEN counts the failover: wait for the count
+            deadline = time.monotonic() + 10
+            while (instruments.coord_failovers().value == failovers0
+                   and time.monotonic() < deadline):
+                time.sleep(0.05)
             assert (instruments.coord_failovers().value
                     - failovers0) == 1
             # workers find the promoted address under the failover key

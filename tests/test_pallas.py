@@ -8,6 +8,8 @@ implementations, mirroring how the reference validates its hand kernels
 against NumPy (`test/test_adasum_tensorflow.py:104`).
 """
 
+import functools
+
 import numpy as np
 import pytest
 import jax
@@ -726,6 +728,211 @@ def test_flash_fwd_oneshot_vs_step_path(causal, monkeypatch):
     for a, b in zip(g_once, g_step):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------- causal sub-tiles inside a grid cell
+def _masked_reference(q, k, v, dout, q_off, k_off, causal):
+    """Plain attention of q rows from ``q_off`` against keys from ``k_off``
+    and its three gradients; a row with every key masked gives zeros."""
+    d = q.shape[-1]
+
+    def attn(q, k, v):
+        s = jnp.einsum("bqhd,bkhd->bhqk", q * d ** -0.5, k)
+        mask = jnp.ones(s.shape[-2:], bool)
+        if causal:
+            mask = ((q_off + jnp.arange(q.shape[1]))[:, None]
+                    >= (k_off + jnp.arange(k.shape[1]))[None, :])
+        p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1) * mask
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+    out, vjp = jax.vjp(attn, q, k, v)
+    return (out,) + vjp(dout)
+
+
+def _flash_with_grads(q, k, v, dout, q_off, k_off, causal):
+    """(out, dq, dk, dv) of the kernels: through ``flash_attention`` where
+    the offsets are ``None`` (the single-shot forward, static zero offsets),
+    else the ring hop's pair, the step kernel and ``_flash_bwd``, with the
+    offsets TRACED as a ring passes them."""
+    if q_off is None:
+        out, vjp = jax.vjp(
+            lambda q, k, v: pk.flash_attention(q, k, v, causal=causal),
+            q, k, v)
+        return (out,) + vjp(dout)
+    b, tq, h, d = q.shape
+
+    @jax.jit
+    def hop(q_off, k_off):
+        m, l, o = pk.flash_attention_step(
+            q, k, v, jnp.full((b, h, tq), -jnp.inf, jnp.float32),
+            jnp.zeros((b, h, tq), jnp.float32),
+            jnp.zeros(q.shape, jnp.float32), q_off, k_off, causal=causal,
+            scale=d ** -0.5)
+        out, lse = pk.finalize_attention_stats(m, l, o, jnp.float32)
+        return (out,) + pk._flash_bwd(q, k, v, out, lse, dout, q_off, k_off,
+                                      causal=causal, scale=d ** -0.5)
+
+    return hop(jnp.int32(q_off), jnp.int32(k_off))
+
+
+# name -> (tq, tk, q_off, k_off, causal, block_q, block_k, sub_tile, bh_block)
+# The cells' tiles are 512 x 1024 cut at 512: here 64 x 128 cut at 32, two
+# row sub-tiles a q tile and four widths (sub_tile None: the edge as shipped).
+_SUB_TILE_CASES = {
+    "two_q_tiles_one_k_tile": (128, 128, None, None, True, 64, 128, 32, 1),
+    "eight_q_tiles_four_k_tiles": (512, 512, None, None, True, 64, 128, 32,
+                                   1),
+    "hop_wholly_live": (128, 128, 256, 0, True, 64, 128, 32, 1),
+    "hop_wholly_dead": (128, 128, 0, 256, True, 64, 128, 32, 1),
+    "hop_crossed": (128, 128, 64, 32, True, 64, 128, 32, 1),
+    "hop_crossed_off_the_sub_tile_grid": (128, 128, 48, 16, True, 64, 128,
+                                          32, 1),
+    "hop_tq_below_tk": (64, 256, 128, 0, True, 64, 128, 32, 1),
+    "hop_tq_above_tk": (256, 64, 0, 96, True, 64, 64, 32, 1),
+    "rectangular_sub_tiles": (256, 256, 64, 0, True, 64, 128, 32, 1),
+    "non_causal": (128, 128, None, None, False, 64, 128, 32, 1),
+    "non_causal_hop": (128, 256, 64, 0, False, 64, 128, 32, 1),
+    "edge_over_the_tile": (192, 192, None, None, True, 512, 1024, 128, 1),
+    "grouped_slices": (128, 128, None, None, True, 64, 128, 32, 2),
+    "grouped_slices_hop": (128, 128, 64, 32, True, 64, 128, 32, 2),
+    "a_cell_at_its_real_edges": (1024, 1024, None, None, True, 512, 1024,
+                                 None, 1),
+}
+
+
+def _sub_tile_case(name, monkeypatch):
+    """The case's grid tiles and sub-tile edge set, its operands
+    ``(q, k, v, dout)``, and its offsets as the kernels get them (``None``:
+    through ``flash_attention``) and as the reference does."""
+    tq, tk, q_off, k_off, causal, bq, bk, sub, g = _SUB_TILE_CASES[name]
+    monkeypatch.setenv("HVD_PALLAS_BLOCK_Q", str(bq))
+    monkeypatch.setenv("HVD_PALLAS_BLOCK_K", str(bk))
+    monkeypatch.setenv("HVD_PALLAS_BLOCK_BH", str(g))
+    if sub is not None:
+        monkeypatch.setattr(pk, "_SUB_TILE", sub)
+    pk._flash_fullattn_vjp.cache_clear()
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 4)
+    h = 1 if tq == 1024 else 2
+    q, dout = (jax.random.normal(kk, (1, tq, h, 64)) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (1, tk, h, 64)) for kk in ks[2:])
+    return (q, k, v, dout), (q_off, k_off), (q_off or 0, k_off or 0)
+
+
+@pytest.mark.parametrize("name", sorted(_SUB_TILE_CASES))
+def test_flash_causal_sub_tiles_match_reference(name, monkeypatch):
+    """A causal backward cell computes, a row sub-tile at a time, only the
+    strip of sub-tiles that hold an unmasked score, and the forward runs a
+    lone key block as straight-line code: forward and the three gradients
+    against plain attention, over the geometries they meet (two
+    rectangular q tiles over one k tile as at 1024 positions, eight over
+    four with the dq scratch and the forward's loop as at 4096, ring hops
+    with traced offsets, whole-tile fall-backs, grouped slices)."""
+    operands, offs, ref_offs = _sub_tile_case(name, monkeypatch)
+    tq, tk, _, _, causal, _, _, _, _ = _SUB_TILE_CASES[name]
+    block_q, block_k = pk._pick_block(tq, side="q"), pk._pick_block(
+        tk, side="k")
+    sub = pk._SUB_TILE
+    want = ((min(block_q, sub), min(block_k, sub)) if causal
+            else (block_q, block_k))
+    assert pk._pick_sub_tile(causal, block_q, block_k) == want
+    got = _flash_with_grads(*operands, *offs, causal)
+    ref = _masked_reference(*operands, *ref_offs, causal)
+    for a, b, nm in zip(got, ref, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-4, err_msg=f"{name}: {nm}")
+
+
+@pytest.mark.parametrize("name", ["two_q_tiles_one_k_tile", "hop_crossed",
+                                  "eight_q_tiles_four_k_tiles"])
+def test_flash_causal_sub_tiles_control_one_live_sub_tile_dropped(
+        name, monkeypatch):
+    """The control that must fail: the same comparison with the bound one
+    sub-tile short for the rows from position 64 (the backward's row
+    sub-tile there loses the sub-tile the diagonal crosses; where the
+    forward loops over key blocks, its q tile there loses a block) is far
+    outside the tolerance in every gradient, and in the output where the
+    forward asks the bound — so the test above would see a strip that
+    stops early."""
+    operands, offs, ref_offs = _sub_tile_case(name, monkeypatch)
+    tk, block_k = _SUB_TILE_CASES[name][1], _SUB_TILE_CASES[name][6]
+    real = pk._live_sub_tiles
+
+    def one_short(q_lo, k_lo, sub_q, sub_k, n):
+        w = real(q_lo, k_lo, sub_q, sub_k, n)
+        if isinstance(q_lo, int):                    # the plan's own call
+            return w
+        return jnp.where(q_lo == ref_offs[0] + 64, jnp.maximum(w - 1, 0), w)
+
+    monkeypatch.setattr(pk, "_live_sub_tiles", one_short)
+    got = _flash_with_grads(*operands, *offs, True)
+    ref = _masked_reference(*operands, *ref_offs, True)
+    for a, b, nm in zip(got, ref, ("out", "dq", "dk", "dv")):
+        if nm == "out" and tk == block_k:     # one key block: no bound asked
+            continue
+        err = np.max(np.abs(np.asarray(a) - np.asarray(b)))
+        assert err > 1e-2, f"{name}: {nm} moved only {err}"
+
+
+# cell -> (per-chip batch, heads, positions): its attention layers' calls
+_CELL_ATTENTION = {
+    "gpt2m-train-s1024": (8, 16, 1024),
+    "gpt2m-train-dp4": (8, 16, 1024),
+    "gpt2l-train-s1024": (4, 20, 1024),
+    "granite4hm-train-s4096": (1, 32, 4096),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_ATTENTION))
+def test_flash_plan_at_the_cells_shapes(cell, monkeypatch):
+    """The plan of a cell's causal calls (sub-tiles computed, masked,
+    skipped), and the kernels' cost estimates made of it. The 512 x 1024
+    tiles alone compute the whole square at 1024 positions and five eighths
+    of it at 4096: the forward still does (whole key blocks), the backward
+    computes 3 of 4 and 36 of 64 sub-tiles of 512 x 512 (10 of 16 and 136
+    of 256 were the edge 256)."""
+    b, h, t = _CELL_ATTENTION[cell]
+    block_q, block_k = pk._pick_block(t, side="q"), pk._pick_block(
+        t, side="k")
+    assert (block_q, block_k) == (512, 1024)
+    sub_q, sub_k = pk._pick_sub_tile(True, block_q, block_k)
+    tiles = pk.flash_plan(True, t, t, 0, 0, block_k, block_q, block_k)
+    plan = pk.flash_plan(True, t, t, 0, 0, block_k, sub_q, sub_k)
+    assert tiles["scores"] == {1024: 1.0, 4096: 0.625}[t] * t * t
+    assert plan["scores"] <= {1024: 0.75, 4096: 0.5625}[t] * t * t
+    assert plan["scores"] >= t * (t + 1) // 2        # every needed score
+    assert plan["computed"] + plan["skipped"] == (t // sub_q) * (t // sub_k)
+    assert plan["masked"] == plan["computed"]
+    assert plan["computed"] == {512: {1024: 3, 4096: 36},
+                                256: {1024: 10, 4096: 136}}[sub_q][t]
+    whole = pk.flash_plan(False, t, t, 0, 0, block_k, *pk._pick_sub_tile(
+        False, block_q, block_k))
+    assert (whole["scores"], whole["masked"], whole["skipped"]) == (
+        t * t, 0, 0)
+    # a ring hop: wholly live, wholly dead, crossed off the sub-tile grid
+    hop = functools.partial(pk.flash_plan, True, 256, 256, block_k=256,
+                            sub_q=64, sub_k=64)
+    assert hop(512, 0)["skipped"] == 0 and hop(0, 512)["computed"] == 0
+    assert hop(96, 32)["computed"] == 2 + 3 + 4 + 4
+
+    costs = {}
+    real = pk._named_call
+
+    def spy(name, kernel, **kw):
+        costs[name] = kw["cost_estimate"]
+        return real(name, kernel, **kw)
+
+    monkeypatch.setattr(pk, "_named_call", spy)
+    pk._flash_fullattn_vjp.cache_clear()
+    x = jax.ShapeDtypeStruct((b, t, h, 64), jnp.bfloat16)
+    jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
+        q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2)),
+        x, x, x)
+    pk._flash_fullattn_vjp.cache_clear()
+    fwd, bwd = b * h * tiles["scores"], b * h * plan["scores"]
+    assert costs["flash_fwd"].flops == 4 * fwd * 64
+    assert costs["flash_fwd"].transcendentals == fwd
+    assert costs["flash_bwd"].flops == 10 * bwd * 64
+    assert costs["flash_bwd"].transcendentals == bwd
 
 
 # ------------------------------------------- fused quantize + pack (wire)

@@ -107,7 +107,7 @@ def test_four_chip_cases_compile_for_v5e(v5e_devices):
 LAYERS = 2
 
 
-def _train_step_lowering(mesh, per_chip_batch, remat="none"):
+def _train_step_lowering(mesh, per_chip_batch, remat="none", seq=256):
     """The data-parallel LM step over ``mesh`` with the model's default
     (Pallas) attention, lowered for the TPU."""
     import optax
@@ -116,7 +116,7 @@ def _train_step_lowering(mesh, per_chip_batch, remat="none"):
     from horovod_tpu.models.transformer import TransformerLM, lm_loss
 
     model = TransformerLM(vocab_size=1024, num_layers=LAYERS, num_heads=4,
-                          d_model=256, max_seq_len=256, remat=remat)
+                          d_model=256, max_seq_len=seq, remat=remat)
 
     def loss_fn(p, batch):
         x, y = batch
@@ -131,9 +131,9 @@ def _train_step_lowering(mesh, per_chip_batch, remat="none"):
 
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 256), jnp.int32))["params"])
+                           jnp.zeros((1, seq), jnp.int32))["params"])
     tok = jax.ShapeDtypeStruct(
-        (per_chip_batch * mesh.devices.size, 256), jnp.int32,
+        (per_chip_batch * mesh.devices.size, seq), jnp.int32,
         sharding=NamedSharding(mesh, P("hvd")))
     step = spmd.make_train_step(loss_fn, tx, mesh=mesh)
     with jax.enable_x64(False):
@@ -168,5 +168,21 @@ def test_recomputation_does_not_rerun_the_flash_forward(remat, v5e_devices):
     pass: with a policy that keeps nothing it was ``3 * LAYERS`` calls."""
     mesh = Mesh(np.array(v5e_devices[:1]), ("hvd",))
     compiled = _train_step_lowering(mesh, 2, remat=remat).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2 * LAYERS
+
+
+@pytest.mark.parametrize("remat", ["none", "full"])
+def test_train_step_at_1024_positions_compiles_for_v5e(remat, v5e_devices):
+    """At 1024 positions the causal backward cuts its 512 x 1024 cells into
+    strips of sub-tiles and the forward runs its one key block without a
+    loop, both with the step's heads-major relayouts fused into their
+    reads; the steps above stop at 256 positions, one whole tile. Still one
+    forward and one backward call a layer."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    assert pk._pick_sub_tile(True, 512, 1024) != (512, 1024)
+    mesh = Mesh(np.array(v5e_devices[:1]), ("hvd",))
+    compiled = _train_step_lowering(mesh, 2, remat=remat, seq=1024).compile()
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') == 2 * LAYERS
