@@ -409,21 +409,7 @@ def main(argv=None):
     n_emb = params["tok_emb"]["embedding"].size + params["pos_emb"].size
     n_nonemb = n_params - n_emb
 
-    fused_opt = os.environ.get("LM_FUSED_OPT", "0") == "1"
-    if fused_opt and os.environ.get("LM_ZERO1", "0") == "1":
-        # the Pallas AdamW custom call has no SPMD sharding rule: GSPMD
-        # would all-gather the dp-sharded m/v to replicas inside the step,
-        # silently undoing the ZeRO-1 memory win
-        sys.exit("LM_FUSED_OPT=1 is incompatible with LM_ZERO1=1 "
-                 "(pallas optimizer kernel would force the sharded "
-                 "optimizer state back to replicated)")
-    if fused_opt:
-        # one-pass Pallas AdamW (optim/fused.py) instead of optax's
-        # per-tensor XLA fusions
-        from horovod_tpu.optim import fused_adamw
-        tx = fused_adamw(3e-4, weight_decay=0.01, mu_dtype=mu_dtype)
-    else:
-        tx = optax.adamw(3e-4, weight_decay=0.01, mu_dtype=mu_dtype)
+    tx = optax.adamw(3e-4, weight_decay=0.01, mu_dtype=mu_dtype)
     opt_state = tx.init(params)
     mesh = hvd.mesh()
     params = spmd.replicate(params, mesh)
@@ -456,32 +442,18 @@ def main(argv=None):
             x, y = batch
             return lm_loss(model.apply({"params": p}, x), y)
 
-    if fused_opt:
-        # fused_adamw is an init/apply pair, not an optax transformation,
-        # so it cannot go through make_train_step; its Pallas call sits
-        # outside any shard_map, which a multi-chip jit cannot partition
-        if hvd.num_replicas() > 1:
-            sys.exit("LM_FUSED_OPT=1 runs on one chip only")
+    # the product's builder: on more than one chip it is what makes the
+    # Pallas attention partitionable (spmd.make_train_step)
+    zero1 = os.environ.get("LM_ZERO1", "0") == "1"
+    if zero1:
+        # shard AdamW m/v 1/N over the replica axis (optim/zero.py); a
+        # single-chip mesh degenerates to replicated
+        from horovod_tpu.optim.zero import shard_opt_state
 
-        def _step(p, opt, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(p, batch)
-            p, opt = tx.apply(grads, opt, p)
-            return p, opt, loss
-
-        step = jax.jit(_step, donate_argnums=(0, 1) if donate else ())
-    else:
-        # the product's builder: on more than one chip it is what makes
-        # the Pallas attention partitionable (spmd.make_train_step)
-        zero1 = os.environ.get("LM_ZERO1", "0") == "1"
-        if zero1:
-            # shard AdamW m/v 1/N over the replica axis (optim/zero.py); a
-            # single-chip mesh degenerates to replicated
-            from horovod_tpu.optim.zero import shard_opt_state
-
-            opt_state = shard_opt_state(opt_state, mesh)
-        step = spmd.make_train_step(
-            loss_fn, tx, mesh=mesh, donate=donate, zero1=zero1,
-            example_opt_state=opt_state if zero1 else None)
+        opt_state = shard_opt_state(opt_state, mesh)
+    step = spmd.make_train_step(
+        loss_fn, tx, mesh=mesh, donate=donate, zero1=zero1,
+        example_opt_state=opt_state if zero1 else None)
     if dev.platform == "tpu":
         opts = {"xla_tpu_enable_latency_hiding_scheduler": "true"}
         if os.environ.get("LM_VMEM_KIB"):

@@ -344,7 +344,6 @@ def kernel_cases():
     import jax.numpy as jnp
 
     from horovod_tpu.ops import pallas_kernels as pk
-    from horovod_tpu.optim import fused
     from horovod_tpu.parallel.ring_attention import (_block_attn,
                                                      reference_attention)
 
@@ -379,25 +378,12 @@ def kernel_cases():
     # q rows 1024..3071 against k rows 0..2047: part of the tile masked
     hop = dict(q_off=1024, k_off=0, causal=True, scale=D ** -0.5)
 
-    def ln_grads(ln):
-        def f(x, g, b):
-            def loss(x, g, b):
-                y = ln(x, g, b).astype(jnp.float32)
-                return jnp.sum(y * cotangent(y.shape)), y
-            grads, y = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(
-                x, g, b)
-            return y, grads
-        return f
-
     def adasum_ref(a, b):
         dot, na, nb = jnp.sum(a * b), jnp.sum(a * a), jnp.sum(b * b)
         return (1 - dot / (2 * na)) * a + (1 - dot / (2 * nb)) * b
 
     def unpacked(pack, unpack):
         return lambda x2: unpack(pack(x2))
-
-    adam = dict(b1=0.9, b2=0.999, eps=1e-8, wd=0.01)
-    adam_sc = lambda: jnp.asarray([3e-4, 10.0, 1000.0], jnp.float32)
 
     qkv = [s((B, T, H, D), jnp.bfloat16)] * 3
     qkv_long = [s((1, LONG_T, H, D), jnp.bfloat16)] * 3
@@ -407,7 +393,6 @@ def kernel_cases():
     qkv_large = [s((4, T, 20, D), jnp.bfloat16)] * 3
     qkv_4k = [s((1, 4096, 32, D), jnp.bfloat16)] * 3
     rows = s((CHUNK_ROWS, BLOCK), jnp.float32)
-    leaf = (DM, 4 * DM)
     return {
         "flash_attention fwd+bwd 8x1024": KernelCase(
             flash, qkv, 2, dense, TOL_BF16),
@@ -430,12 +415,6 @@ def kernel_cases():
         "adasum_combine": KernelCase(
             pk.adasum_combine, [s((1 << 20,), jnp.float32)] * 2, 2,
             adasum_ref, TOL_F32),
-        "fused_layer_norm fwd+bwd": KernelCase(
-            ln_grads(lambda x, g, b: pk.fused_layer_norm(x, g, b)),
-            [s((B, T, DM), jnp.bfloat16), s((DM,), jnp.float32),
-             s((DM,), jnp.float32)], 1,
-            ln_grads(lambda x, g, b: pk._ln_reference(x, g, b, 1e-6)),
-            TOL_BF16),
         "int8_quantize_2d": KernelCase(
             pk.int8_quantize_2d, [rows], 1,
             unpacked(pk.int8_quantize_pack_ref, pk.int8_unpack), None),
@@ -456,13 +435,6 @@ def kernel_cases():
              s((DM // 4, VOCAB), jnp.bfloat16)], 1,
             lambda x, w: jnp.dot(x, w, preferred_element_type=jnp.float32
                                  ).astype(x.dtype), TOL_BF16),
-        "fused_adamw leaf": KernelCase(      # |nu|: a second moment
-            lambda g, p, mu, nu: fused._apply_leaf_fused(
-                adam_sc(), g, p, mu, jnp.abs(nu), **adam),
-            [s(leaf, jnp.float32)] * 2
-            + [s(leaf, jnp.bfloat16), s(leaf, jnp.float32)], 1,
-            lambda g, p, mu, nu: fused._apply_leaf_jnp(
-                adam_sc(), g, p, mu, jnp.abs(nu), **adam), TOL_BF16),
     }
 
 

@@ -20,7 +20,6 @@ framework's vehicle for its first-class long-context story. TPU-first design:
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -29,14 +28,6 @@ import jax
 import jax.numpy as jnp
 
 AttnFn = Callable[..., Any]  # (q, k, v) -> out, all [B, T, H, Dh]
-
-
-def _ln_cls():
-    """LayerNorm implementation for the model: XLA's (default) or the
-    Pallas :class:`FusedLayerNorm` when ``HVD_FUSED_LN=1`` — see that
-    class's docstring for the measured trade-off."""
-    return (FusedLayerNorm if os.environ.get("HVD_FUSED_LN") == "1"
-            else nn.LayerNorm)
 
 
 def default_attention(q, k, v):
@@ -81,35 +72,6 @@ def cached_attention(q, k, v, past_mask):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
 
 
-class FusedLayerNorm(nn.Module):
-    """Drop-in ``nn.LayerNorm`` backed by the one-pass Pallas kernels
-    (``ops/pallas_kernels.fused_layer_norm``; identical-contract jnp
-    fallback off-TPU). Same parameter names/shapes as ``nn.LayerNorm``
-    ("scale"/"bias" of [D]), so checkpoints interchange.
-
-    Opt-in (``HVD_FUSED_LN=1``), not the default: measured on a v5e
-    GPT-2-medium step the kernels themselves are fast (~1.4 ms/48 norms)
-    but the custom-call boundary costs XLA its producer/consumer fusions
-    around each norm — end-to-end 38.7k -> 37.3k tok/s. It wins when the
-    norm is NOT surrounded by fusible elementwise ops (e.g. inference
-    prefill) — hence kept as a knob."""
-    epsilon: float = 1e-6
-    dtype: Any = jnp.float32
-    param_dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        from ..ops.pallas_kernels import fused_layer_norm
-
-        d = x.shape[-1]
-        scale = self.param("scale", nn.initializers.ones, (d,),
-                           self.param_dtype)
-        bias = self.param("bias", nn.initializers.zeros, (d,),
-                          self.param_dtype)
-        return fused_layer_norm(x, scale, bias,
-                                eps=self.epsilon).astype(self.dtype)
-
-
 class Block(nn.Module):
     num_heads: int
     dtype: Any
@@ -127,7 +89,7 @@ class Block(nn.Module):
         head_dim = d_model // self.num_heads
         dense = partial(nn.Dense, dtype=self.dtype, param_dtype=jnp.float32,
                         kernel_init=nn.initializers.normal(0.02))
-        ln = partial(_ln_cls(), dtype=self.dtype, param_dtype=jnp.float32)
+        ln = partial(nn.LayerNorm, dtype=self.dtype, param_dtype=jnp.float32)
 
         h = ln(name="ln_attn")(x)
         qkv = dense(3 * d_model, name="qkv")(h)
@@ -273,8 +235,8 @@ class TransformerLM(nn.Module):
                 x, (nk, nv) = block(x, (past_k[i], past_v[i], past_mask))
                 new_ks.append(nk)
                 new_vs.append(nv)
-        x = _ln_cls()(dtype=self.dtype, param_dtype=jnp.float32,
-                      name="ln_f")(x)
+        x = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
+                         name="ln_f")(x)
         if return_hidden:
             out = x
         else:

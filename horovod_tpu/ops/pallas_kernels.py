@@ -62,28 +62,12 @@ _LN2 = 0.6931471805599453
 
 
 def _cparams(*semantics, resident: bool = False):
-    """CompilerParams with the given dimension semantics and the measured
-    per-kernel Mosaic VMEM budget policy: RESIDENT-layout kernels (whole
-    k/v or q/do in VMEM — the short-sequence paths) default to a 96 MB
-    limit, measured +1.4% on the lm_bench step (33.2k vs 32.8k tok/s at
-    seq 1024; the default 16 MB scoped limit leaves double-buffer room
-    unused); STREAMING kernels keep the Mosaic default (96 MB measured
-    −1.5% at seq 8192). ``HVD_PALLAS_VMEM_MB`` overrides both (0 = always
-    Mosaic default). Resolved at pallas_call-build time — the env can be
-    flipped after import, like every other knob (an already-jitted kernel
-    keeps its compiled params until its jax cache entry is evicted)."""
+    """CompilerParams with the given dimension semantics. RESIDENT-layout
+    kernels (whole k/v in VMEM, or one k sweep of the fused backward) get a
+    96 MiB VMEM limit: the default 16 MiB scoped limit leaves double-buffer
+    room unused. STREAMING kernels keep Mosaic's default."""
     kw = {"dimension_semantics": semantics}
-    v = os.environ.get("HVD_PALLAS_VMEM_MB")
-    if v:
-        try:
-            mb = float(v)
-        except ValueError:
-            raise ValueError(
-                f"HVD_PALLAS_VMEM_MB={v!r}: expected a number of MiB "
-                "(0 = Mosaic default)") from None
-        if mb > 0:
-            kw["vmem_limit_bytes"] = int(mb * 2 ** 20)
-    elif resident:
+    if resident:
         kw["vmem_limit_bytes"] = 96 * 2 ** 20
     return pltpu.CompilerParams(**kw)
 
@@ -92,10 +76,10 @@ def _input_fusion(params, n_tensor_inputs: int, fusable: bool):
     """allow_input_fusion on the n tensor inputs (scalar-prefetch operand
     stays unfused): XLA folds cheap producers — the heads-major relayout
     transposes — into the kernel's input reads instead of materializing
-    them in HBM. Measured +3.0% (fwd) and +0.7% (bwd) on the lm_bench
-    step at seq 1024; bit-identical outputs. ``fusable`` is
+    them in HBM; bit-identical outputs. ``fusable`` is
     :func:`_relayout_fusable` of the call's batch and head counts.
-    HVD_PALLAS_INPUT_FUSION=0 disables (escape hatch)."""
+    HVD_PALLAS_INPUT_FUSION=0 disables (the way round a compiler fault,
+    docs/troubleshooting.md)."""
     if not fusable or os.environ.get(
             "HVD_PALLAS_INPUT_FUSION", "1") in ("0", "false"):
         return params
@@ -116,17 +100,12 @@ def _relayout_fusable(b: int, h: int) -> bool:
     return b > 1 and h > 1
 
 
-# Param builders, NOT baked constants: each pallas_call site calls these at
-# build time so HVD_PALLAS_VMEM_MB/HVD_PALLAS_INPUT_FUSION flipped after
-# import behave like every other knob (round-4 verdict weak #4).
 def _sem_par2():
     return _cparams("parallel", "parallel")
 
 
 def _sem_par2_res():
-    # the resident-ATTENTION variant of the 2D-parallel grid (flash forward
-    # / legacy backward with a whole side in VMEM); adasum's streaming apply
-    # pass shares the semantics but not the budget
+    # the flash forward with the whole k/v of a head in VMEM
     return _cparams("parallel", "parallel", resident=True)
 
 
@@ -208,34 +187,19 @@ def kernel_path(name: str, *operands) -> str:
     return "reference"
 
 
-def _env_block(name: str) -> Optional[int]:
-    """Tile-edge env override, clamped to >= 8 (below that the power-of-2
-    divide-search in _pick_block could never terminate on a divisor)."""
-    v = os.environ.get(name)
-    return max(8, int(v)) if v else None
+# GRID tile edges of the flash kernels, forward and backward. A grid cell
+# costs about 1.6 µs whatever it computes (PERF.md §5), so few large cells;
+# 1024 x 1024 exceeds scoped VMEM. :func:`_pick_sub_tile` cuts inside a cell.
+_BLOCK_Q = 512
+_BLOCK_K = 1024
 
 
 def _pick_block(t: int, preferred: int = None,
                 side: Optional[str] = None) -> Optional[int]:
     """Largest power-of-2 tile ≤ preferred dividing t (None if none ≥ 8).
-
-    Default GRID tile edges are asymmetric — q-side 512, k-side 1024:
-    bigger tiles mean quadratically fewer grid cells, and per-cell overhead,
-    not FLOPs, dominated the attention kernels at 128 (rounds 3-5 of
-    docs/benchmarks.md, on older kernels: 128/128 → 512/1024 was +47% on
-    the lm_bench step; 1024/1024 exceeded scoped VMEM). That ladder moved
-    grid tiles only and never priced the masked scores a large causal tile
-    computes: :func:`_pick_sub_tile` bounds those inside the cell, and
-    PERF.md §6 (PR 28) has its ladder. ``HVD_PALLAS_BLOCK`` overrides both
-    sides; ``HVD_PALLAS_BLOCK_Q`` / ``HVD_PALLAS_BLOCK_K`` override each
-    independently for tuning."""
+    The flash kernels name a ``side`` and get its grid tile edge."""
     if preferred is None:
-        if side is not None:
-            preferred = _env_block(f"HVD_PALLAS_BLOCK_{side.upper()}")
-        if preferred is None:
-            preferred = _env_block("HVD_PALLAS_BLOCK")
-        if preferred is None:
-            preferred = 1024 if side == "k" else 512
+        preferred = _BLOCK_K if side == "k" else _BLOCK_Q
     b = preferred
     while b >= 8:
         if t % b == 0:
@@ -304,28 +268,6 @@ def flash_plan(causal: bool, tq: int, tk: int, q_off: int, k_off: int,
             "scores": computed * sub_q * sub_k}
 
 
-def _pick_bh_block(bh: int, per_g_bytes: int = 0, cap: int = 0) -> int:
-    """Rows of the fused batch·head dimension handled per grid cell in the
-    RESIDENT kernels (``HVD_PALLAS_BLOCK_BH``): G sub-problems share one
-    cell (statically unrolled in-kernel), dividing the cell count by G —
-    the grid-geometry lever applied to the third axis. Measured on the
-    lm_bench step: G=2 exactly neutral (38.46k vs 38.45k tok/s), G=4
-    exceeds the 16 MB scoped-VMEM stack (17.98M) at the Q512/K1024 tile
-    defaults — so the default is 1 and the knob exists for parts/configs
-    with different VMEM headroom.
-
-    G is floored to a power of two, then halved until it both divides
-    ``bh`` AND keeps ``G * per_g_bytes`` within ``cap`` (when given) —
-    one loop so neither constraint can be satisfied while silently
-    breaking the other (a non-divisor G would leave trailing bh rows
-    unvisited by the grid)."""
-    g = max(1, int(os.environ.get("HVD_PALLAS_BLOCK_BH", "1")))
-    g = 1 << (g.bit_length() - 1)                     # power-of-two floor
-    while g > 1 and (bh % g or (cap and g * per_g_bytes > cap)):
-        g //= 2
-    return g
-
-
 # =========================================================== flash attention
 def _causal_mask(s, q_lo, k_lo):
     """The scores ``s`` of query rows from global position ``q_lo`` against
@@ -335,10 +277,10 @@ def _causal_mask(s, q_lo, k_lo):
     return jnp.where(delta >= k_lo - q_lo, s, NEG_INF)
 
 
-def _flash_accum(q, k_ref, v_ref, g, m, l, o, *, q_off, k_off, causal,
+def _flash_accum(q, k_ref, v_ref, m, l, o, *, q_off, k_off, causal,
                  scale, block_k):
     """Online-softmax accumulation of the q tile (global first row
-    ``q_off``) against slice ``g``'s resident k/v, a key block of
+    ``q_off``) against the slice's resident k/v, a key block of
     ``block_k`` at a time — THE shared inner body of the ring-step and
     single-shot forward kernels (one copy, so the base-2/masked-row
     convention cannot drift between them; the backward recompute depends
@@ -359,8 +301,8 @@ def _flash_accum(q, k_ref, v_ref, g, m, l, o, *, q_off, k_off, causal,
 
     def body(j, carry):
         m, l, o = carry
-        k = k_ref[g, pl.ds(j * block_k, block_k), :]
-        v = v_ref[g, pl.ds(j * block_k, block_k), :]
+        k = k_ref[0, pl.ds(j * block_k, block_k), :]
+        v = v_ref[0, pl.ds(j * block_k, block_k), :]
         # [BQ, BK] base-2 logits on the MXU; scale on the f32 result
         s = (scale * _LOG2E) * lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -388,30 +330,28 @@ def _flash_accum(q, k_ref, v_ref, g, m, l, o, *, q_off, k_off, causal,
 
 def _flash_step_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref, o_ref,
                        mo_ref, lo_ref, oo_ref, *, causal, scale, block_k):
-    """G q-tiles (G = bh-block, statically unrolled) of flash accumulation,
-    each against its whole resident k/v block.
+    """One q tile of flash accumulation against the whole resident k/v of
+    its batch·head slice.
 
-    Refs (VMEM): q [G, BQ, D], k/v [G, TK, D], m/l [G, BQ, 1] (trailing
+    Refs (VMEM): q [1, BQ, D], k/v [1, TK, D], m/l [1, BQ, 1] (trailing
     singleton keeps the block tile-legal: (BQ, 1) instead of (1, BQ)),
-    o [G, BQ, D]; offs (scalar prefetch): [q_off, k_off] global sequence
-    origins for causal masking (ring hop offsets) — shared by all G
-    sub-problems (they are different batch·head slices of one sequence).
+    o [1, BQ, D]; offs (scalar prefetch): [q_off, k_off] global sequence
+    origins for causal masking (ring hop offsets).
     """
     q_off = offs_ref[0] + pl.program_id(1) * q_ref.shape[1]
     k_off = offs_ref[1]
 
-    for g in range(q_ref.shape[0]):
-        q = q_ref[g]                                  # [BQ, D]
-        # carried m enters in natural units; base-2 inside (_LOG2E note)
-        m = m_ref[g, :, 0].astype(jnp.float32) * _LOG2E   # [BQ]
-        l = l_ref[g, :, 0].astype(jnp.float32)
-        o = o_ref[g].astype(jnp.float32)              # [BQ, D]
-        m, l, o = _flash_accum(q, k_ref, v_ref, g, m, l, o,
-                               q_off=q_off, k_off=k_off, causal=causal,
-                               scale=scale, block_k=block_k)
-        mo_ref[g, :, 0] = m * _LN2                    # back to natural units
-        lo_ref[g, :, 0] = l
-        oo_ref[g] = o
+    q = q_ref[0]                                      # [BQ, D]
+    # carried m enters in natural units; base-2 inside (_LOG2E note)
+    m = m_ref[0, :, 0].astype(jnp.float32) * _LOG2E   # [BQ]
+    l = l_ref[0, :, 0].astype(jnp.float32)
+    o = o_ref[0].astype(jnp.float32)                  # [BQ, D]
+    m, l, o = _flash_accum(q, k_ref, v_ref, m, l, o,
+                           q_off=q_off, k_off=k_off, causal=causal,
+                           scale=scale, block_k=block_k)
+    mo_ref[0, :, 0] = m * _LN2                        # back to natural units
+    lo_ref[0, :, 0] = l
+    oo_ref[0] = o
 
 
 def _flash_fwd_once_kernel(offs_ref, q_ref, k_ref, v_ref, oo_ref, lse_ref,
@@ -421,27 +361,25 @@ def _flash_fwd_once_kernel(offs_ref, q_ref, k_ref, v_ref, oo_ref, lse_ref,
     registers — and the output is NORMALIZED in-kernel (FlashAttention-2
     epilogue) and written in the input dtype beside the f32 row-LSE the
     backward consumes. Per call this halves HBM traffic vs the step kernel
-    (~65 MB vs ~130 MB at the GPT-2-medium bench shapes: no f32 o in/out,
-    no m/l streams) and retires the separate finalize fusion + zero-init
-    copies (measured breakdown in docs/benchmarks.md round 5)."""
+    (no f32 o in/out, no m/l streams) and retires the separate finalize
+    fusion + zero-init copies."""
     bq = q_ref.shape[1]
     q_off = offs_ref[0] + pl.program_id(1) * bq
     k_off = offs_ref[1]
 
-    for g in range(q_ref.shape[0]):
-        q = q_ref[g]                                  # [BQ, D]
-        m = jnp.full((bq,), NEG_INF, jnp.float32)
-        l = jnp.zeros((bq,), jnp.float32)
-        o = jnp.zeros((bq, q_ref.shape[2]), jnp.float32)
-        m, l, o = _flash_accum(q, k_ref, v_ref, g, m, l, o,
-                               q_off=q_off, k_off=k_off, causal=causal,
-                               scale=scale, block_k=block_k)
-        # the _masked_row_stats convention, fused into the epilogue:
-        # l == 0 -> out 0, lse sentinel log(1) on top of a zeroed m
-        l_safe = jnp.where(l == 0, 1.0, l)
-        oo_ref[g] = (o / l_safe[:, None]).astype(oo_ref.dtype)
-        m_nat = jnp.where(m == NEG_INF, 0.0, m * _LN2)
-        lse_ref[g, :, 0] = m_nat + jnp.log(l_safe)
+    q = q_ref[0]                                      # [BQ, D]
+    m = jnp.full((bq,), NEG_INF, jnp.float32)
+    l = jnp.zeros((bq,), jnp.float32)
+    o = jnp.zeros((bq, q_ref.shape[2]), jnp.float32)
+    m, l, o = _flash_accum(q, k_ref, v_ref, m, l, o,
+                           q_off=q_off, k_off=k_off, causal=causal,
+                           scale=scale, block_k=block_k)
+    # the _masked_row_stats convention, fused into the epilogue:
+    # l == 0 -> out 0, lse sentinel log(1) on top of a zeroed m
+    l_safe = jnp.where(l == 0, 1.0, l)
+    oo_ref[0] = (o / l_safe[:, None]).astype(oo_ref.dtype)
+    m_nat = jnp.where(m == NEG_INF, 0.0, m * _LN2)
+    lse_ref[0, :, 0] = m_nat + jnp.log(l_safe)
 
 
 def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
@@ -451,12 +389,6 @@ def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
     lse [BH, TQ, 1] f32). Caller guarantees the resident budget."""
     bh, tq, d = qt.shape
     tk = kt.shape[1]
-    it = kt.dtype.itemsize
-    # same footprint model as the step call, minus the carried f32 o tile
-    per_g = (2 * tk * d * it + block_q * block_k * 4
-             + 2 * block_q * d * 4)
-    g = _pick_bh_block(bh, per_g, _BH_VMEM_CAP)
-    grid = (bh // g, tq // block_q)
     # the only caller passes zero offsets: the plan is the call's
     scores = bh * flash_plan(causal, tq, tk, 0, 0, block_k, block_q,
                              block_k)["scores"]
@@ -465,15 +397,15 @@ def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
                           scale=scale, block_k=block_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(bh, tq // block_q),
             in_specs=[
-                pl.BlockSpec((g, block_q, d), lambda i, j, offs: (i, j, 0)),
-                pl.BlockSpec((g, tk, d), lambda i, j, offs: (i, 0, 0)),
-                pl.BlockSpec((g, tk, d), lambda i, j, offs: (i, 0, 0)),
+                pl.BlockSpec((1, block_q, d), lambda i, j, offs: (i, j, 0)),
+                pl.BlockSpec((1, tk, d), lambda i, j, offs: (i, 0, 0)),
+                pl.BlockSpec((1, tk, d), lambda i, j, offs: (i, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((g, block_q, d), lambda i, j, offs: (i, j, 0)),
-                pl.BlockSpec((g, block_q, 1), lambda i, j, offs: (i, j, 0)),
+                pl.BlockSpec((1, block_q, d), lambda i, j, offs: (i, j, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda i, j, offs: (i, j, 0)),
             ],
         ),
         out_shape=[
@@ -610,37 +542,20 @@ def _flash_step_call(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
     """qt/ot: [BH, T, D]; kt/vt: [BH, TK, D]; mt/lt: [BH, T, 1] f32."""
     bh, tq, d = qt.shape
     tk = kt.shape[1]
-    if tk * d * kt.dtype.itemsize > _KV_VMEM_CAP:
+    if flash_route(tq, tk, d, kt.dtype.itemsize)["step"] == "step_streaming":
         return _flash_step_call_streaming(
             qt, kt, vt, mt, lt, ot, offs, causal=causal, scale=scale,
             block_q=block_q, block_k=block_k, interpret=interpret)
-    # clamp G on an estimate of the full per-slice VMEM footprint — the
-    # f32 score tile (block_q x block_k) dominates, not the resident k/v;
-    # the estimate + _BH_VMEM_CAP reproduce the measured cliff (G=2 fits,
-    # G=4 -> 17.98M > 16M scoped at the Q512/K1024 defaults)
-    it = kt.dtype.itemsize
-    per_g = (2 * tk * d * it + block_q * block_k * 4
-             + 3 * block_q * d * 4)
-    g = _pick_bh_block(bh, per_g, _BH_VMEM_CAP)
-    grid = (bh // g, tq // block_q)
     kernel = functools.partial(_flash_step_kernel, causal=causal, scale=scale,
                                block_k=block_k)
+    qtile = pl.BlockSpec((1, block_q, d), lambda i, j, offs: (i, j, 0))
+    stat = pl.BlockSpec((1, block_q, 1), lambda i, j, offs: (i, j, 0))
+    kv = pl.BlockSpec((1, tk, d), lambda i, j, offs: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((g, block_q, d), lambda i, j, offs: (i, j, 0)),
-            pl.BlockSpec((g, tk, d), lambda i, j, offs: (i, 0, 0)),
-            pl.BlockSpec((g, tk, d), lambda i, j, offs: (i, 0, 0)),
-            pl.BlockSpec((g, block_q, 1), lambda i, j, offs: (i, j, 0)),
-            pl.BlockSpec((g, block_q, 1), lambda i, j, offs: (i, j, 0)),
-            pl.BlockSpec((g, block_q, d), lambda i, j, offs: (i, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((g, block_q, 1), lambda i, j, offs: (i, j, 0)),
-            pl.BlockSpec((g, block_q, 1), lambda i, j, offs: (i, j, 0)),
-            pl.BlockSpec((g, block_q, d), lambda i, j, offs: (i, j, 0)),
-        ],
+        grid=(bh, tq // block_q),
+        in_specs=[qtile, kv, kv, stat, stat, qtile],
+        out_specs=[stat, stat, qtile],
     )
     # ring hops pass traced offsets: the whole rectangle, an upper bound
     flops = 4 * bh * tq * tk * d  # 2 matmuls
@@ -669,28 +584,24 @@ def _flash_step_call(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
 # compiles within the 16 MB scoped-VMEM limit, 2 MB (seq 16384) does not —
 # longer k/v take the streaming forward.
 _KV_VMEM_CAP = 2 ** 20
-# Budget for the backward's whole-resident layout; beyond it _flash_bwd
-# switches to the streaming 3D-grid kernels (any length works there).
-# Tighter than the forward's: the resident dkv pass holds q AND do (plus
-# lse/dd and double-buffered tiles). Re-measured at the Q512/K1024 default
-# tiles: 256 KB/operand (seq 2048 at d=64 bf16) compiles within the 16 MB
-# scoped-VMEM limit, 512 KB (seq 4096) exceeds it by 1.45 MB — the old
-# 512 KB cap dated from the 128-edge-tile era.
-_BWD_RESIDENT_CAP = 256 * 2 ** 10
 # dq-scratch budget for the ONE-pass fused backward: the whole [TQ, D] f32
 # dq accumulator lives in VMEM beside the f32 score/p/dp tiles (~2 MB each
 # at Q512/K1024) and the streamed operand tiles. 4 MB covers seq 16384 at
-# d=64 (or 8192 at d=128); longer falls back to the legacy two-pass
-# streaming layout.
-_DQ_SCRATCH_CAP = int(os.environ.get("HVD_PALLAS_DQ_SCRATCH_CAP",
-                                     4 * 2 ** 20))
-# Per-grid-cell VMEM budget for bh-blocking (G): half the 16 MB scoped
-# limit, leaving the rest for Mosaic's double buffering. With the per-g
-# footprint estimates at the call sites (2.6 MB per slice at the
-# lm_bench shapes) this admits the measured-working G=2
-# (2 x 2.6 = 5.2 MB <= 8 MB) and rejects the measured-failing G=4
-# (10.5 MB) at the Q512/K1024 defaults.
-_BH_VMEM_CAP = 8 * 2 ** 20
+# d=64 (or 8192 at d=128); a longer head takes the streaming pair.
+_DQ_SCRATCH_CAP = 4 * 2 ** 20
+
+
+def flash_route(tq: int, tk: int, d: int, itemsize: int) -> dict:
+    """Which kernels one head of ``tq`` queries against ``tk`` keys of
+    width ``d`` takes; the dispatchers and the tests both read it.
+    ``forward`` (the full-attention call) is ``once`` or ``step_streaming``,
+    ``step`` (a ring hop, carrying m, l, o) ``step`` or ``step_streaming``,
+    ``backward`` ``fused`` or ``streaming`` (the dq / dkv pair). No JAX."""
+    kv_resident = tk * d * itemsize <= _KV_VMEM_CAP
+    return {"forward": "once" if kv_resident else "step_streaming",
+            "step": "step" if kv_resident else "step_streaming",
+            "backward": ("fused" if tq * d * 4 <= _DQ_SCRATCH_CAP
+                         else "streaming")}
 
 
 def step_supported(q, k) -> bool:
@@ -703,11 +614,8 @@ def step_supported(q, k) -> bool:
     tk = k.shape[1]
     if d % 128 != 0 and d not in (64,):  # MXU lane width; 64 still maps
         return False
-    # no length cap: k/v beyond _KV_VMEM_CAP take the streaming forward
     if vma_active(q, k):
         return False
-    # probe with the SAME side= the call sites use, so per-side env
-    # overrides (HVD_PALLAS_BLOCK_Q/K) cannot pass here and fail there
     return (_pick_block(tq, side="q") is not None
             and _pick_block(tk, side="k") is not None)
 
@@ -743,110 +651,7 @@ def flash_attention_step(q, k, v, m, l, o, q_off, k_off, *,
     return m_new, l_new, o_new
 
 
-# (The pre-FA2 "Pallas forward + rematerialized jnp backward" step wrapper
-# lived here; the blockwise backward kernels below cover every supported
-# shape — resident or streaming — so the quadratic-HBM jnp VJP is gone.)
-
-
 # ------------------------------------------------- flash attention backward
-def _flash_bwd_dq_kernel_res(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
-                         do_ref, dq_ref, *, causal, scale, block_k):
-    """dq for one q tile against the whole resident k/v (FlashAttention-2
-    backward, dq pass — VMEM-RESIDENT variant for shapes whose full k/v
-    fits VMEM; the streaming 3D-grid variant covers longer sequences):
-    recompute p = exp(scale*qk^T - LSE) blockwise, then
-    ds = p*(do v^T - D)*scale, dq += ds k.  LSE = m + log l (row logsumexp),
-    D = rowsum(do * out) — both precomputed outside. offs (scalar prefetch):
-    [q_off, k_off] global sequence origins (ring hop offsets)."""
-    iq = pl.program_id(1)
-    bq = q_ref.shape[1]
-    tk = k_ref.shape[1]
-    nk = tk // block_k
-    in_dt = q_ref.dtype  # dot operands in input dtype, f32 accumulation
-    q_off = offs_ref[0] + iq * bq
-    k_off = offs_ref[1]
-    hi = jnp.clip((q_off + bq - k_off + block_k - 1) // block_k, 0, nk) \
-        if causal else nk
-
-    for g in range(q_ref.shape[0]):                   # bh-block unroll
-        q = q_ref[g]                                  # [BQ, D]
-        do = do_ref[g]                                # [BQ, D]
-        lse = lse_ref[g] * _LOG2E                     # [BQ, 1] f32, base-2
-        dd = dd_ref[g]                                # [BQ, 1] f32
-
-        def body(j, acc, q=q, do=do, lse=lse, dd=dd):
-            k = k_ref[g, pl.ds(j * block_k, block_k), :]
-            v = v_ref[g, pl.ds(j * block_k, block_k), :]
-            s = (scale * _LOG2E) * lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if causal:
-                qpos = q_off + lax.broadcasted_iota(
-                    jnp.int32, (bq, block_k), 0)
-                kpos = (k_off + j * block_k
-                        + lax.broadcasted_iota(jnp.int32, (bq, block_k), 1))
-                s = jnp.where(qpos >= kpos, s, NEG_INF)
-            p = jnp.exp2(s - lse)                     # exp2(-inf) == 0
-            dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            ds = (p * (dp - dd) * scale).astype(in_dt)
-            return acc + lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-
-        dq_ref[g] = lax.fori_loop(0, hi, body,
-                                  jnp.zeros(q.shape, jnp.float32))
-
-
-def _flash_bwd_dkv_kernel_res(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
-                          do_ref, dk_ref, dv_ref, *, causal, scale, block_q):
-    """dk/dv for one k/v tile against the whole resident q/do (dkv pass):
-    dv += p^T do; dk += (p*(do v^T - D)*scale)^T q."""
-    jk = pl.program_id(1)
-    bk = k_ref.shape[1]
-    tq = q_ref.shape[1]
-    nq = tq // block_q
-    in_dt = q_ref.dtype  # dot operands in input dtype, f32 accumulation
-    q_off = offs_ref[0]
-    k_off = offs_ref[1] + jk * bk
-    lo = jnp.clip((k_off - q_off) // block_q, 0, nq) if causal else 0
-
-    for g in range(q_ref.shape[0]):                   # bh-block unroll
-        k = k_ref[g]                                  # [BK, D]
-        v = v_ref[g]
-
-        def body(i, carry, k=k, v=v):
-            dk, dv = carry
-            q = q_ref[g, pl.ds(i * block_q, block_q), :]
-            do = do_ref[g, pl.ds(i * block_q, block_q), :]
-            lse = lse_ref[g, pl.ds(i * block_q, block_q), :] * _LOG2E
-            dd = dd_ref[g, pl.ds(i * block_q, block_q), :]
-            s = (scale * _LOG2E) * lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if causal:
-                qpos = (q_off + i * block_q
-                        + lax.broadcasted_iota(jnp.int32, (block_q, bk), 0))
-                kpos = k_off + lax.broadcasted_iota(
-                    jnp.int32, (block_q, bk), 1)
-                s = jnp.where(qpos >= kpos, s, NEG_INF)
-            p = jnp.exp2(s - lse)                     # [BQ, BK] f32
-            pc = p.astype(in_dt)
-            dv = dv + lax.dot_general(pc, do, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            ds = (p * (dp - dd) * scale).astype(in_dt)
-            dk = dk + lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            return dk, dv
-
-        dk, dv = lax.fori_loop(lo, nq, body,
-                               (jnp.zeros(k.shape, jnp.float32),
-                                jnp.zeros(v.shape, jnp.float32)))
-        dk_ref[g] = dk
-        dv_ref[g] = dv
-
-
 def _flash_bwd_dq_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
                          do_ref, dq_ref, *, causal, scale):
     """dq accumulation for one (q tile, k tile) grid cell (FlashAttention-2
@@ -941,8 +746,8 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
                             causal, scale, sub_q, sub_k):
     """ONE-pass FlashAttention-2 backward: grid (bh, k tiles, q tiles) with
     q innermost; each cell recomputes p ONCE and emits all three gradient
-    contributions. The legacy pair of kernels (dq pass + dkv pass) each
-    streamed the operands and rebuilt p/dp separately — twice the operand
+    contributions. The streaming pair of kernels (dq pass + dkv pass) each
+    stream the operands and rebuild p/dp separately — twice the operand
     DMA and 7 matmuls per (q, k) tile pair; this kernel does 5.
 
     A causal cell is cut at :func:`_pick_sub_tile`'s edges: ``sub_q`` rows
@@ -971,8 +776,7 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
 
     Gradients leave the kernel in the INPUT dtype: accumulation stays f32,
     cast once at the final write — a bf16 model never round-trips 3x f32
-    gradient tensors through HBM plus three XLA cast fusions (measured
-    ladder in docs/benchmarks.md round 5)."""
+    gradient tensors through HBM plus three XLA cast fusions."""
     if len(maybe_acc) == 3:
         dq_acc, dk_acc, dv_acc = maybe_acc
     else:
@@ -1121,81 +925,6 @@ def _flash_bwd_fused(qt, kt, vt, dot, lset, ddt, offs, d, *, causal, scale,
     )(offs, lset, ddt, qt, kt, vt, dot)
 
 
-def _flash_bwd_resident(qt, kt, vt, dot, lset, ddt, offs, d, *,
-                        causal, scale, block_q, block_k, interpret):
-    """Whole-resident backward dispatch: dq pass keeps full k/v in VMEM,
-    dkv pass keeps full q/do in VMEM (heads-major [BH, T, D] operands in,
-    heads-major f32 gradients out)."""
-    bh, tq = qt.shape[0], qt.shape[1]
-    tk = kt.shape[1]
-    # clamp G on the fuller of the two passes' per-slice VMEM footprints
-    # (dq holds resident k/v, dkv holds resident q/do; both build the f32
-    # score tile) — same estimate/cap scheme as the forward
-    it = qt.dtype.itemsize
-    per_g = (2 * max(tq, tk) * d * it + block_q * block_k * 4
-             + 3 * max(block_q, block_k) * d * 4)
-    g = _pick_bh_block(bh, per_g, _BH_VMEM_CAP)
-
-    dq = _named_call("flash_bwd_dq",
-        functools.partial(_flash_bwd_dq_kernel_res, causal=causal,
-                          scale=scale, block_k=block_k),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh // g, tq // block_q),
-            in_specs=[
-                pl.BlockSpec((g, block_q, 1), lambda i, j, offs: (i, j, 0)),
-                pl.BlockSpec((g, block_q, 1), lambda i, j, offs: (i, j, 0)),
-                pl.BlockSpec((g, block_q, d), lambda i, j, offs: (i, j, 0)),
-                pl.BlockSpec((g, tk, d), lambda i, j, offs: (i, 0, 0)),
-                pl.BlockSpec((g, tk, d), lambda i, j, offs: (i, 0, 0)),
-                pl.BlockSpec((g, block_q, d), lambda i, j, offs: (i, j, 0)),
-            ],
-            out_specs=pl.BlockSpec((g, block_q, d),
-                                   lambda i, j, offs: (i, j, 0)),
-        ),
-        out_shape=_struct((bh, tq, d), jnp.float32, qt, kt, offs),
-        cost_estimate=pl.CostEstimate(
-            flops=6 * bh * tq * tk * d,
-            bytes_accessed=4 * bh * (3 * tq * d + 2 * tk * d),
-            transcendentals=bh * tq * tk),
-        compiler_params=_sem_par2_res(),
-        interpret=interpret,
-    )(offs, lset, ddt, qt, kt, vt, dot)
-
-    dk, dv = _named_call("flash_bwd_dkv",
-        functools.partial(_flash_bwd_dkv_kernel_res, causal=causal,
-                          scale=scale, block_q=block_q),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh // g, tk // block_k),
-            in_specs=[
-                pl.BlockSpec((g, tq, 1), lambda i, j, offs: (i, 0, 0)),
-                pl.BlockSpec((g, tq, 1), lambda i, j, offs: (i, 0, 0)),
-                pl.BlockSpec((g, tq, d), lambda i, j, offs: (i, 0, 0)),
-                pl.BlockSpec((g, block_k, d), lambda i, j, offs: (i, j, 0)),
-                pl.BlockSpec((g, block_k, d), lambda i, j, offs: (i, j, 0)),
-                pl.BlockSpec((g, tq, d), lambda i, j, offs: (i, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((g, block_k, d), lambda i, j, offs: (i, j, 0)),
-                pl.BlockSpec((g, block_k, d), lambda i, j, offs: (i, j, 0)),
-            ],
-        ),
-        out_shape=[
-            _struct((bh, tk, d), jnp.float32, qt, kt, offs),
-            _struct((bh, tk, d), jnp.float32, qt, kt, offs),
-        ],
-        cost_estimate=pl.CostEstimate(
-            flops=8 * bh * tq * tk * d,
-            bytes_accessed=4 * bh * (3 * tq * d + 3 * tk * d),
-            transcendentals=bh * tq * tk),
-        compiler_params=_sem_par2_res(),
-        interpret=interpret,
-    )(offs, lset, ddt, qt, kt, vt, dot)
-
-    return dq, dk, dv
-
-
 def _flash_bwd(q, k, v, out, lse, dout, q_off=0, k_off=0, *, causal, scale):
     """Blockwise backward for normalized flash attention, [B, T, H, D]
     layout.  ``q_off``/``k_off`` are global sequence origins (traced scalars
@@ -1228,29 +957,15 @@ def _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off=0, k_off=0, *,
     way) pays no relayout. Returns (dq, dk, dv) heads-major f32."""
     bh, tq, d = qt.shape
     tk = kt.shape[1]
-    # backward GRID tiles follow the forward defaults unless overridden
-    # independently (HVD_PALLAS_BLOCK_BWD_Q/K) — the fused one-pass kernel
-    # has a different VMEM profile (dq scratch + 3 outputs) than the
-    # forward, so its optimum can differ. A k grid tile of 512 at seq 1024
-    # skips a quarter of the square but leaves the single-sweep form (dq
-    # scratch, no input fusion, no resident budget), and measured neutral
-    # on the lm_bench step (round 5). The masked part is bounded inside the
-    # cell instead (_pick_sub_tile).
-    block_q = _pick_block(tq, preferred=_env_block("HVD_PALLAS_BLOCK_BWD_Q"),
-                          side="q")
-    block_k = _pick_block(tk, preferred=_env_block("HVD_PALLAS_BLOCK_BWD_K"),
-                          side="k")
+    # the forward's grid tiles: a k tile of 512 at 1024 positions leaves the
+    # single-sweep form; _pick_sub_tile bounds the masked part instead
+    block_q = _pick_block(tq, side="q")
+    block_k = _pick_block(tk, side="k")
     offs = jnp.stack([jnp.asarray(q_off, jnp.int32),
                       jnp.asarray(k_off, jnp.int32)])
     interpret = _interpret()
 
-    # Preferred layout: the ONE-pass fused kernel (dq+dk+dv from a single
-    # streaming of the operands, 5 matmuls per tile pair instead of the
-    # legacy passes' 7). Its dq scratch must fit VMEM alongside the score
-    # tiles; beyond the cap — or with HVD_PALLAS_FUSED_BWD=0 for A/B — the
-    # legacy two-pass layouts below take over.
-    if (os.environ.get("HVD_PALLAS_FUSED_BWD", "1") not in ("0", "false")
-            and tq * d * 4 <= _DQ_SCRATCH_CAP):
+    if flash_route(tq, tk, d, qt.dtype.itemsize)["backward"] == "fused":
         static = all(isinstance(x, (int, np.integer))
                      for x in (q_off, k_off))
         return _flash_bwd_fused(
@@ -1259,17 +974,7 @@ def _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off=0, k_off=0, *,
             fusable=fusable, out_dtype=out_dtype,
             static_offs=(q_off, k_off) if static else None)
 
-    # Two legacy kernel layouts: whole-resident (one side of the score
-    # matrix stays in VMEM; ~20% faster at short T — no tile re-fetch) and
-    # streaming 3D-grid (every operand tiled through the grid; the only
-    # option once a full k/v or q/do side exceeds the VMEM budget).
-    if (tk * d * kt.dtype.itemsize <= _BWD_RESIDENT_CAP
-            and tq * d * qt.dtype.itemsize <= _BWD_RESIDENT_CAP):
-        return _flash_bwd_resident(
-            qt, kt, vt, dot, lset, ddt, offs, d, causal=causal,
-            scale=scale, block_q=block_q, block_k=block_k,
-            interpret=interpret)
-
+    # else the streaming pair: one tile of each operand in VMEM, any length
     kmap, qmap = _causal_maps(causal, block_q, block_k, tq // block_q)
 
     dq = _named_call("flash_bwd_dq",
@@ -1384,12 +1089,9 @@ def _flash_fullattn_vjp(causal: bool, scale: float):
         kt = k.transpose(0, 2, 1, 3).reshape(bh, tk, d)
         vt = v.transpose(0, 2, 1, 3).reshape(bh, tk, d)
         offs = jnp.zeros((2,), jnp.int32)
-        if (tk * d * kt.dtype.itemsize <= _KV_VMEM_CAP
-                and os.environ.get("HVD_PALLAS_ONESHOT_FWD", "1") != "0"):
+        if flash_route(tq, tk, d, kt.dtype.itemsize)["forward"] == "once":
             # resident shapes take the single-shot kernel: no ring-carry
-            # streams, normalized-in-kernel output (measured +6.2% on the
-            # lm_bench step at seq 1024, +4.8% at seq 8192 —
-            # docs/benchmarks.md round 5)
+            # streams, normalized-in-kernel output
             out_t, lse_t = _flash_fwd_once_call(
                 qt, kt, vt, offs, causal=causal, scale=scale,
                 block_q=_pick_block(tq, side="q"),
@@ -1574,133 +1276,6 @@ def adasum_combine(a, b):
     """Fused Adasum pairwise combine of two same-shape arrays (single-pair
     convenience over :func:`adasum_combine_pairs`)."""
     return adasum_combine_pairs(a[None], b[None])[0]
-
-
-# ================================================================ layernorm
-# XLA's LayerNorm on TPU is a multi-pass f32 chain (measured ~28 ms of a
-# 209 ms GPT-2-medium train step across 49 norms — ~14x off the HBM
-# roofline for what is one read + one write of the activation). The fused
-# forward below measured 0.03 ms/norm in-step (vs XLA's 0.25). The
-# backward stays plain jnp ON PURPOSE: a Pallas backward walls off the LN
-# gradient from the backward chain XLA fuses it into, and the all-Pallas
-# variant measured a net end-to-end LOSS (38.7k -> 37.3k tok/s on
-# lm_bench); the hybrid is neutral end-to-end on the training step and
-# wins where the norm is not surrounded by fusible ops (inference).
-# Reference surface being replaced: flax ``nn.LayerNorm``; statistics
-# always f32.
-
-def _ln_fwd_kernel(x_ref, g_ref, b_ref, y_ref, mu_ref, rs_ref, *, eps):
-    x = x_ref[...].astype(jnp.float32)                # [BR, D]
-    d = x.shape[1]
-    mean = jnp.sum(x, axis=1, keepdims=True) / d      # [BR, 1]
-    xc = x - mean
-    var = jnp.sum(xc * xc, axis=1, keepdims=True) / d
-    rstd = lax.rsqrt(var + eps)
-    y = xc * rstd * g_ref[...].astype(jnp.float32) + b_ref[...].astype(
-        jnp.float32)
-    y_ref[...] = y.astype(y_ref.dtype)
-    mu_ref[...] = mean
-    rs_ref[...] = rstd
-
-
-def _ln_rows_block(n: int, d: int) -> Optional[int]:
-    """Row-tile height: largest power of 2 <= 256 dividing n whose f32 tile
-    stays within ~1 MB of VMEM per operand."""
-    cap = max(8, (1 << 20) // (4 * d))
-    b = 256
-    while b >= 8:
-        if b <= cap and n % b == 0:
-            return b
-        b //= 2
-    return None
-
-
-def ln_supported(x) -> bool:
-    """True when the fused kernels take this shape: last dim lane-aligned,
-    row count tileable (the wrapper falls back to plain jnp otherwise)."""
-    n = int(np.prod(x.shape[:-1])) if x.ndim > 1 else 0
-    d = x.shape[-1]
-    return (mode() != "off" and x.ndim >= 2 and d % _LANES == 0
-            and n > 0 and _ln_rows_block(n, d) is not None)
-
-
-def _ln_reference(x, gamma, beta, eps):
-    """jnp fallback with the same math/dtype contract as the kernels
-    (flax ``nn.LayerNorm`` semantics: f32 statistics, output in x.dtype)."""
-    xf = x.astype(jnp.float32)
-    mean = jnp.mean(xf, axis=-1, keepdims=True)
-    xc = xf - mean
-    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
-    y = xc * lax.rsqrt(var + eps) * gamma.astype(jnp.float32) \
-        + beta.astype(jnp.float32)
-    return y.astype(x.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _ln_fused(x2, gamma, beta, eps):
-    y, _, _ = _ln_fused_fwd_call(x2, gamma, beta, eps)
-    return y
-
-
-def _ln_fused_fwd_call(x2, gamma, beta, eps):
-    n, d = x2.shape
-    br = _ln_rows_block(n, d)
-    row = pl.BlockSpec((br, d), lambda i: (i, 0))
-    vec = pl.BlockSpec((1, d), lambda i: (0, 0))
-    col = pl.BlockSpec((br, 1), lambda i: (i, 0))
-    y, mu, rs = pl.pallas_call(
-        functools.partial(_ln_fwd_kernel, eps=eps),
-        grid=(n // br,),
-        in_specs=[row, vec, vec],
-        out_specs=[row, col, col],
-        out_shape=[_struct((n, d), x2.dtype, x2, gamma),
-                   _struct((n, 1), jnp.float32, x2, gamma),
-                   _struct((n, 1), jnp.float32, x2, gamma)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
-        interpret=_interpret(),
-    )(x2, gamma[None], beta[None])
-    return y, mu, rs
-
-
-def _ln_fused_vjp_fwd(x2, gamma, beta, eps):
-    y, mu, rs = _ln_fused_fwd_call(x2, gamma, beta, eps)
-    return y, (x2, mu, rs, gamma)
-
-
-def _ln_fused_vjp_bwd(eps, res, dy):
-    """Backward in plain jnp ON PURPOSE (see section note): fusible into
-    the surrounding gradient chain, off the kernel's saved f32 stats."""
-    x2, mu, rs, gamma = res
-    d = x2.shape[1]
-    xf = x2.astype(jnp.float32)
-    dyf = dy.astype(jnp.float32)
-    xhat = (xf - mu) * rs
-    g = dyf * gamma.astype(jnp.float32)
-    c1 = jnp.sum(g * xhat, axis=1, keepdims=True) / d
-    c2 = jnp.sum(g, axis=1, keepdims=True) / d
-    dx = (rs * (g - xhat * c1 - c2)).astype(x2.dtype)
-    dg = jnp.sum(dyf * xhat, axis=0).astype(gamma.dtype)
-    db = jnp.sum(dyf, axis=0).astype(gamma.dtype)
-    return dx, dg, db
-
-
-_ln_fused.defvjp(_ln_fused_vjp_fwd, _ln_fused_vjp_bwd)
-
-
-def fused_layer_norm(x, gamma, beta, *, eps: float = 1e-6):
-    """LayerNorm over the last axis with a one-pass Pallas forward.
-
-    ``x`` any shape ``[..., D]``; ``gamma``/``beta`` shape ``[D]``.
-    Statistics in f32, output in ``x.dtype``, parameter grads in the
-    parameters' dtype. The identical-contract jnp implementation takes
-    over off-TPU or for non-tileable shapes (:func:`kernel_path`).
-    """
-    if kernel_path("fused_layer_norm", x, gamma, beta) == "reference":
-        return _ln_reference(x, gamma, beta, eps)
-    n = int(np.prod(x.shape[:-1]))
-    y = _ln_fused(x.reshape(n, x.shape[-1]), gamma, beta, eps)
-    return y.reshape(x.shape)
 
 
 # ====================================================== int8 block quantize
@@ -2071,7 +1646,6 @@ def matmul_reduce_scatter(x, w, axis_name):
 # reader is kernel_path above (which adds the mode and vma conditions)
 _GATES = {
     "flash_attention": lambda q, k, v: step_supported(q, k),
-    "fused_layer_norm": lambda x, gamma, beta: ln_supported(x),
     "adasum_combine": lambda a, b: adasum_supported(
         int(np.prod(a.shape[1:])) if a.ndim > 1 else 1),
     "int8_quantize": lambda x2: int8_supported(*x2.shape),

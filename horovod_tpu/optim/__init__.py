@@ -8,5 +8,4 @@ from .distributed import (  # noqa: F401
     DistributedOptimizer,
     allreduce_gradients,
 )
-from .fused import AdamWState, fused_adamw  # noqa: F401
 from .zero import shard_opt_state, zero1_shardings  # noqa: F401

@@ -79,6 +79,38 @@ def test_kernel_compiles_for_v5e(name, v5e_devices):
     lower_tpu(case.fn, *on_mesh(case.args, mesh)).compile()
 
 
+# positions x head width of one bf16 causal call -> what
+# ``pallas_kernels.flash_route`` says of it and the Pallas calls of forward
+# + backward: the shapes on the far side of each boundary, where only the
+# TPU compiler can say that the chosen kernel fits (chip_smoke's 1x8192 case
+# is the longest single-shot forward).
+_ROUTE_EDGES = {
+    (16384, 64): ("step_streaming", "fused", 2),    # the largest dq scratch
+    (32768, 64): ("step_streaming", "streaming", 3),
+    (8192, 128): ("step_streaming", "fused", 2),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_ROUTE_EDGES))
+def test_flash_route_edges_compile_for_v5e(shape, v5e_devices):
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    (t, d), (forward, backward, calls) = shape, _ROUTE_EDGES[shape]
+    route = pk.flash_route(t, t, d, 2)
+    assert (route["forward"], route["backward"]) == (forward, backward)
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2))(
+                q, k, v)
+
+    mesh = Mesh(np.array(v5e_devices[:1]), ("hvd",))
+    x = jax.ShapeDtypeStruct((1, t, 2, d), jnp.bfloat16)
+    lowered = lower_tpu(grads, *on_mesh([x, x, x], mesh))
+    assert lowered.as_text().count("tpu_custom_call") == calls
+    lowered.compile()
+
+
 def _four_chip_cases(mesh):
     fn, _, args = chip_smoke.matmul_reduce_scatter_case(mesh)
     # one chunk matmul per ring position

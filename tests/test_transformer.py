@@ -45,6 +45,39 @@ def test_forward_shapes_and_loss():
     assert abs(float(loss) - np.log(VOCAB)) < 0.5
 
 
+def test_parameter_tree_and_scope_names_are_what_their_readers_expect():
+    """The names a saved checkpoint, the benchmark's plain reference
+    (``chipbench/reference.py``) and its scope classes
+    (``chipbench/scope_classes/``) read: the model's norms are Flax's
+    ``nn.LayerNorm`` under ``ln_attn``, ``ln_mlp`` and ``ln_f``."""
+    from chipbench import reference
+
+    model = TransformerLM(vocab_size=VOCAB, num_layers=2, num_heads=2,
+                          d_model=64, max_seq_len=32, dtype=jnp.float32)
+    tokens, _ = _data(np.random.RandomState(0), 2, 32)
+    params = model.init(jax.random.PRNGKey(0), tokens)["params"]
+    assert set(params) == {"tok_emb", "pos_emb", "block_0", "block_1",
+                           "ln_f"}
+    norm = {"scale": ((64,), jnp.float32), "bias": ((64,), jnp.float32)}
+    for block in (params["block_0"], params["block_1"]):
+        assert set(block) == {"ln_attn", "qkv", "proj", "ln_mlp", "mlp_in",
+                              "mlp_out"}
+        for name in ("ln_attn", "ln_mlp"):
+            assert {k: (v.shape, v.dtype)
+                    for k, v in block[name].items()} == norm
+    assert {k: (v.shape, v.dtype) for k, v in params["ln_f"].items()} == norm
+    # the reference reads the tree by these names and nothing else
+    np.testing.assert_allclose(
+        np.asarray(model.apply({"params": params}, tokens)),
+        np.asarray(reference.forward(params, tokens, n_head=2, eps=1e-6)),
+        rtol=2e-4, atol=2e-4)
+    # the scope paths of the compiled program's operations
+    text = jax.jit(lambda p, x: model.apply({"params": p}, x)).lower(
+        params, tokens).as_text(debug_info=True)
+    for path in ("block_0/ln_attn", "block_1/ln_mlp", "TransformerLM/ln_f"):
+        assert path in text, path
+
+
 def test_sp_forward_matches_single_device():
     """Ring-attention SP forward over (1, 4) == full-sequence forward."""
     mesh = make_dp_sp_mesh(dp=1, sp=4)
