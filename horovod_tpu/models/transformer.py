@@ -130,7 +130,7 @@ _SAVE_FLASH_OUTPUTS = jax.checkpoint_policies.save_only_these_names(
 #: rematerialization policies for ``TransformerLM(remat=...)``, mapping mode
 #: name -> (wrap_in_remat, jax.checkpoint policy). "full" keeps, per layer,
 #: the block's input and the flash-attention kernel's two outputs (its bf16
-#: [B,T,D] output and f32 [B*H,T] row log-sum-exp, named "flash_out" and
+#: [B,T,D] output and f32 [B*H,1,T] row log-sum-exp, named "flash_out" and
 #: "flash_lse" in ``ops.pallas_kernels.flash_attention``) and recomputes
 #: everything else in the block during backward: activation memory = two
 #: bf16 [B,T,D] a layer. The kernel's outputs are kept because they are the

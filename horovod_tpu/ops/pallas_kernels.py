@@ -72,11 +72,18 @@ def _cparams(*semantics, resident: bool = False):
     return pltpu.CompilerParams(**kw)
 
 
-def _input_fusion(params, n_tensor_inputs: int, fusable: bool):
-    """allow_input_fusion on the n tensor inputs (scalar-prefetch operand
-    stays unfused): XLA folds cheap producers — the heads-major relayout
-    transposes — into the kernel's input reads instead of materializing
-    them in HBM; bit-identical outputs. ``fusable`` is
+def _input_fusion(params, tensor_inputs: str, fusable: bool):
+    """allow_input_fusion on the tensor inputs marked ``"t"`` in
+    ``tensor_inputs`` (the scalar-prefetch operand stays unfused): XLA
+    folds cheap producers — the heads-major relayout transposes — into the
+    kernel's input reads instead of materializing them in HBM;
+    bit-identical outputs. The row statistics, marked ``"s"``, stay out:
+    the producer of one (the way from [B, H, T] to [BH, 1, T]) is a
+    reshape XLA does fold in, the call then becomes an XLA ``fusion``, and
+    a device trace names it ``fusion %flash_bwd``, no longer the
+    ``tpu_custom_call %flash_bwd`` that
+    ``chipbench/op_classes/attention_kernel.json`` reads (seen with the
+    row sums as an operand, PERF.md §6, PR 31). ``fusable`` is
     :func:`_relayout_fusable` of the call's batch and head counts.
     HVD_PALLAS_INPUT_FUSION=0 disables (the way round a compiler fault,
     docs/troubleshooting.md)."""
@@ -84,7 +91,8 @@ def _input_fusion(params, n_tensor_inputs: int, fusable: bool):
             "HVD_PALLAS_INPUT_FUSION", "1") in ("0", "false"):
         return params
     return dataclasses.replace(
-        params, allow_input_fusion=[False] + [True] * n_tensor_inputs)
+        params,
+        allow_input_fusion=[False] + [kind == "t" for kind in tensor_inputs])
 
 
 def _relayout_fusable(b: int, h: int) -> bool:
@@ -269,12 +277,54 @@ def flash_plan(causal: bool, tq: int, tk: int, q_off: int, k_off: int,
 
 
 # =========================================================== flash attention
-def _causal_mask(s, q_lo, k_lo):
-    """The scores ``s`` of query rows from global position ``q_lo`` against
-    keys from ``k_lo``, -inf above the diagonal."""
-    delta = (lax.broadcasted_iota(jnp.int32, s.shape, 0)
-             - lax.broadcasted_iota(jnp.int32, s.shape, 1))
+def _causal_mask(s, q_lo, k_lo, q_axis=0):
+    """The scores ``s`` of queries from global position ``q_lo`` (along
+    ``q_axis``; the keys, from ``k_lo``, along the other), -inf above the
+    diagonal."""
+    delta = (lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+             - lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
     return jnp.where(delta >= k_lo - q_lo, s, NEG_INF)
+
+
+# The row statistics (the LSE, a ring hop's m and l) cross HBM as f32 ROWS
+# [BH, 1, T], the positions on the lanes: a trailing dimension of 1 is tiled
+# to 128 lanes on the TPU, 512 bytes a value in HBM and in every DMA, where
+# [BH, 1, T] is laid out (1, 128), dense (PERF.md §6, PR 31). The middle 1
+# is the block rule's: a block's last two dimensions divide by (8, 128) or
+# are the array's own, so one head's (1, BQ) of a [BH, T] is refused and
+# (1, 1, BQ) of a [BH, 1, T] is not. One contract for all six kernels; the
+# fourth statistic, D = rowsum(do * out), is formed inside the backward
+# kernels (:func:`_bwd_scores_t`) and crosses nothing.
+def _stat_spec(block_q, index_map):
+    """BlockSpec of one q tile of a [BH, 1, T] statistic; ``index_map``
+    gives the (head, q tile) of a grid cell as the q-side maps do."""
+    def rows(*grid):
+        i, j, _ = index_map(*grid)
+        return (i, 0, j)
+    return pl.BlockSpec((1, 1, block_q), rows)
+
+
+def _stat_row(x):
+    """A ``[BQ]`` statistic as the kernels compute it (reduced over a
+    tile's lanes: a value a sublane) → the ``[1, BQ]`` row its ref takes,
+    or that broadcasts over key-major scores, by the XLU: lanes filled,
+    transposed, row 0. Once a grid cell at the forward's epilogue, once a
+    strip in the backward. Measured on a v5e (PERF.md §6, PR 31): the
+    forward at batch·heads 128 x 1024 takes 0.457 ms a call this way and
+    0.568 storing the vector through ``ref[0, 0, :]`` (Mosaic's own
+    relayout), 0.486 with the column the parent wrote."""
+    return jnp.broadcast_to(x[:, None], (x.shape[0], _LANES)).T[0:1, :]
+
+
+def _stat_col(row):
+    """The inverse, for the carried m and l of a ring hop: a ``[1, BQ]``
+    row → the ``[BQ]`` vector :func:`_flash_accum` broadcasts over score
+    columns. Through the XLU as well: read as ``ref[0, 0, :]`` the vector
+    keeps the lanes' layout into the key loop, and two hops at 2048
+    positions took 0.836 ms in the kernel where this takes 0.646 and the
+    parent's columns 0.569 (0.717 against 0.723 with the copies around
+    them, PERF.md §6, PR 31)."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T[:, 0]
 
 
 def _flash_accum(q, k_ref, v_ref, m, l, o, *, q_off, k_off, causal,
@@ -333,24 +383,25 @@ def _flash_step_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref, o_ref,
     """One q tile of flash accumulation against the whole resident k/v of
     its batch·head slice.
 
-    Refs (VMEM): q [1, BQ, D], k/v [1, TK, D], m/l [1, BQ, 1] (trailing
-    singleton keeps the block tile-legal: (BQ, 1) instead of (1, BQ)),
-    o [1, BQ, D]; offs (scalar prefetch): [q_off, k_off] global sequence
-    origins for causal masking (ring hop offsets).
+    Refs (VMEM): q [1, BQ, D], k/v [1, TK, D], m/l [1, 1, BQ] (rows: the
+    statistics' lane-dense contract; turned into the [BQ] vectors the
+    accumulation broadcasts over score columns once a cell, and back at
+    the epilogue), o [1, BQ, D]; offs (scalar prefetch): [q_off, k_off]
+    global sequence origins for causal masking (ring hop offsets).
     """
     q_off = offs_ref[0] + pl.program_id(1) * q_ref.shape[1]
     k_off = offs_ref[1]
 
     q = q_ref[0]                                      # [BQ, D]
     # carried m enters in natural units; base-2 inside (_LOG2E note)
-    m = m_ref[0, :, 0].astype(jnp.float32) * _LOG2E   # [BQ]
-    l = l_ref[0, :, 0].astype(jnp.float32)
+    m = _stat_col(m_ref[0]) * _LOG2E                  # f32 [BQ]
+    l = _stat_col(l_ref[0])
     o = o_ref[0].astype(jnp.float32)                  # [BQ, D]
     m, l, o = _flash_accum(q, k_ref, v_ref, m, l, o,
                            q_off=q_off, k_off=k_off, causal=causal,
                            scale=scale, block_k=block_k)
-    mo_ref[0, :, 0] = m * _LN2                        # back to natural units
-    lo_ref[0, :, 0] = l
+    mo_ref[0] = _stat_row(m * _LN2)                   # back to natural units
+    lo_ref[0] = _stat_row(l)
     oo_ref[0] = o
 
 
@@ -379,14 +430,14 @@ def _flash_fwd_once_kernel(offs_ref, q_ref, k_ref, v_ref, oo_ref, lse_ref,
     l_safe = jnp.where(l == 0, 1.0, l)
     oo_ref[0] = (o / l_safe[:, None]).astype(oo_ref.dtype)
     m_nat = jnp.where(m == NEG_INF, 0.0, m * _LN2)
-    lse_ref[0, :, 0] = m_nat + jnp.log(l_safe)
+    lse_ref[0] = _stat_row(m_nat + jnp.log(l_safe))
 
 
 def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
                          block_k, interpret, fusable):
     """Resident-layout dispatch of the single-shot forward.
     qt: [BH, TQ, D]; kt/vt: [BH, TK, D] → (out [BH, TQ, D] in qt.dtype,
-    lse [BH, TQ, 1] f32). Caller guarantees the resident budget."""
+    lse [BH, 1, TQ] f32). Caller guarantees the resident budget."""
     bh, tq, d = qt.shape
     tk = kt.shape[1]
     # the only caller passes zero offsets: the plan is the call's
@@ -405,18 +456,19 @@ def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, d), lambda i, j, offs: (i, j, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda i, j, offs: (i, j, 0)),
+                _stat_spec(block_q, lambda i, j, offs: (i, j, 0)),
             ],
         ),
         out_shape=[
             _struct((bh, tq, d), qt.dtype, qt, kt, offs),
-            _struct((bh, tq, 1), jnp.float32, qt, kt, offs),
+            _struct((bh, 1, tq), jnp.float32, qt, kt, offs),
         ],
         cost_estimate=pl.CostEstimate(
             flops=4 * scores * d,                     # 2 matmuls a score
-            bytes_accessed=2 * (2 * bh * tq * d + 2 * bh * tk * d),
+            bytes_accessed=(2 * (2 * bh * tq * d + 2 * bh * tk * d)
+                            + 4 * bh * tq),           # q, out, k, v; lse
             transcendentals=scores),
-        compiler_params=_input_fusion(_sem_par2_res(), 3, fusable),
+        compiler_params=_input_fusion(_sem_par2_res(), "ttt", fusable),
         interpret=interpret,
     )(offs, qt, kt, vt)
 
@@ -450,8 +502,8 @@ def _flash_step_stream_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref,
         v = v_ref[0]
         # the revisited mo tile stays in natural units (a masked cell's
         # skipped body couldn't convert it back) — base-2 only inside
-        m = mo_ref[0, :, 0] * _LOG2E                  # f32 [BQ]
-        l = lo_ref[0, :, 0]
+        m = _stat_col(mo_ref[0]) * _LOG2E             # f32 [BQ]
+        l = _stat_col(lo_ref[0])
         o = oo_ref[0]                                 # f32 [BQ, D]
         s = (scale * _LOG2E) * lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -467,8 +519,8 @@ def _flash_step_stream_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref,
         alpha = jnp.exp2(m - m_safe)                  # m=-inf -> 0
         pv = lax.dot_general(p.astype(in_dt), v, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-        mo_ref[0, :, 0] = m_new * _LN2
-        lo_ref[0, :, 0] = l * alpha + jnp.sum(p, axis=-1)
+        mo_ref[0] = _stat_row(m_new * _LN2)
+        lo_ref[0] = _stat_row(l * alpha + jnp.sum(p, axis=-1))
         oo_ref[0] = o * alpha[:, None] + pv
 
 
@@ -506,7 +558,7 @@ def _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs, *, causal,
 
     kmap, _ = _causal_maps(causal, block_q, block_k, tq // block_q)
     qtile = pl.BlockSpec((1, block_q, d), lambda i, j, n, offs: (i, j, 0))
-    stat = pl.BlockSpec((1, block_q, 1), lambda i, j, n, offs: (i, j, 0))
+    stat = _stat_spec(block_q, lambda i, j, n, offs: (i, j, 0))
 
     return _named_call("flash_step",
         functools.partial(_flash_step_stream_kernel, causal=causal,
@@ -523,15 +575,16 @@ def _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs, *, causal,
             out_specs=[stat, stat, qtile],
         ),
         out_shape=[
-            _struct((bh, tq, 1), jnp.float32, qt, kt, mt, offs),
-            _struct((bh, tq, 1), jnp.float32, qt, kt, mt, offs),
+            _struct((bh, 1, tq), jnp.float32, qt, kt, mt, offs),
+            _struct((bh, 1, tq), jnp.float32, qt, kt, mt, offs),
             _struct((bh, tq, d), jnp.float32, qt, kt, mt, offs),
         ],
         # k is innermost and ACCUMULATES into the revisited q-side tiles
         compiler_params=_sem_par2_arb(),
         cost_estimate=pl.CostEstimate(
             flops=4 * bh * tq * tk * d,
-            bytes_accessed=4 * (2 * bh * tq * d + 2 * bh * tk * d),
+            bytes_accessed=4 * (2 * bh * tq * d + 2 * bh * tk * d
+                                + 4 * bh * tq),       # m, l in and out
             transcendentals=bh * tq * tk),
         interpret=interpret,
     )(offs, qt, kt, vt, mt, lt, ot)
@@ -539,7 +592,7 @@ def _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs, *, causal,
 
 def _flash_step_call(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
                      block_q, block_k, interpret, fusable):
-    """qt/ot: [BH, T, D]; kt/vt: [BH, TK, D]; mt/lt: [BH, T, 1] f32."""
+    """qt/ot: [BH, T, D]; kt/vt: [BH, TK, D]; mt/lt: [BH, 1, T] f32."""
     bh, tq, d = qt.shape
     tk = kt.shape[1]
     if flash_route(tq, tk, d, kt.dtype.itemsize)["step"] == "step_streaming":
@@ -549,7 +602,7 @@ def _flash_step_call(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
     kernel = functools.partial(_flash_step_kernel, causal=causal, scale=scale,
                                block_k=block_k)
     qtile = pl.BlockSpec((1, block_q, d), lambda i, j, offs: (i, j, 0))
-    stat = pl.BlockSpec((1, block_q, 1), lambda i, j, offs: (i, j, 0))
+    stat = _stat_spec(block_q, lambda i, j, offs: (i, j, 0))
     kv = pl.BlockSpec((1, tk, d), lambda i, j, offs: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -563,17 +616,18 @@ def _flash_step_call(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            _struct((bh, tq, 1), jnp.float32, qt, kt, mt, offs),
-            _struct((bh, tq, 1), jnp.float32, qt, kt, mt, offs),
+            _struct((bh, 1, tq), jnp.float32, qt, kt, mt, offs),
+            _struct((bh, 1, tq), jnp.float32, qt, kt, mt, offs),
             _struct((bh, tq, d), jnp.float32, qt, kt, mt, offs),
         ],
         cost_estimate=pl.CostEstimate(
             flops=flops,
-            bytes_accessed=4 * (2 * bh * tq * d + 2 * bh * tk * d),
+            bytes_accessed=4 * (2 * bh * tq * d + 2 * bh * tk * d
+                                + 4 * bh * tq),       # m, l in and out
             transcendentals=bh * tq * tk),
         # independent grid cells: Mosaic may pipeline across bh and q tiles;
         # producers (the heads-major relayouts) fuse into the input reads
-        compiler_params=_input_fusion(_sem_par2_res(), 6, fusable),
+        compiler_params=_input_fusion(_sem_par2_res(), "tttsst", fusable),
         interpret=interpret,
     )(offs, qt, kt, vt, mt, lt, ot)
 
@@ -636,8 +690,8 @@ def flash_attention_step(q, k, v, m, l, o, q_off, k_off, *,
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
-    mt = m.reshape(b * h, tq, 1)
-    lt = l.reshape(b * h, tq, 1)
+    mt = m.reshape(b * h, 1, tq)
+    lt = l.reshape(b * h, 1, tq)
     ot = o.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
     offs = jnp.stack([jnp.asarray(q_off, jnp.int32),
                       jnp.asarray(k_off, jnp.int32)])
@@ -652,18 +706,55 @@ def flash_attention_step(q, k, v, m, l, o, q_off, k_off, *,
 
 
 # ------------------------------------------------- flash attention backward
-def _flash_bwd_dq_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
+def _dot_tn(a, b):
+    """``a^T b`` with f32 accumulation: dimension 0 of both contracted."""
+    return lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _bwd_scores_t(q, k, v, out, do, lse, q_lo, k_lo, *, causal, scale):
+    """``(p^T, ds^T)`` of q rows ``[SQ, D]`` (from global position
+    ``q_lo``) against keys ``[W, D]`` (from ``k_lo``), both ``[W, SQ]`` in
+    the operands' dtype — THE recompute every backward kernel shares:
+    p = exp(scale*qk^T - LSE), ds = p*(do v^T - D)*scale.
+
+    KEY-MAJOR because the LSE arrives as a row ``[1, SQ]`` (natural
+    units): with the queries on the lanes it broadcasts along sublanes for
+    nothing, where the query-major ``[SQ, W]`` wants a column (a relayout
+    of SQ values a strip; both measured, PERF.md §6, PR 31). Then
+    dv += p^T do and dk += ds^T q are plain products and dq = (ds^T)^T k is
+    the one with a transposed left operand (query-major it was dv and dk,
+    two). D = rowsum(do * out) is formed here from the ``out`` rows, f32,
+    and turned into a row: it never exists in HBM, and XLA runs no
+    reduction and no relayout for it (0.03-0.05 ms a layer, the same
+    probes)."""
+    in_dt = q.dtype     # dot operands in the input dtype, f32 accumulation
+    dd = _stat_row(jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                           axis=-1))                  # [1, SQ]
+    s_t = (scale * _LOG2E) * lax.dot_general(
+        k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    if causal:
+        s_t = _causal_mask(s_t, q_lo, k_lo, q_axis=1)
+    p_t = jnp.exp2(s_t - lse * _LOG2E)                # exp2(-inf) == 0
+    dp_t = lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+    ds_t = p_t * (dp_t - dd) * scale
+    return p_t.astype(in_dt), ds_t.astype(in_dt)
+
+
+def _flash_bwd_dq_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
                          do_ref, dq_ref, *, causal, scale):
     """dq accumulation for one (q tile, k tile) grid cell (FlashAttention-2
     backward, dq pass): recompute p = exp(scale*qk^T - LSE), then
-    ds = p*(do v^T - D)*scale, dq += ds k.  LSE = m + log l (row logsumexp),
-    D = rowsum(do * out) — both precomputed outside. offs (scalar prefetch):
-    [q_off, k_off] global sequence origins (ring hop offsets). The k grid
-    dimension is innermost and revisits the same dq tile, so VMEM holds one
-    tile of each operand regardless of sequence length."""
+    ds = p*(do v^T - D)*scale, dq += ds k.  LSE = m + log l (the forward's
+    row logsumexp, [1, 1, BQ]), D = rowsum(do * out); the scores are
+    computed KEY-MAJOR, as :func:`_bwd_scores_t` says. offs
+    (scalar prefetch): [q_off, k_off] global sequence origins (ring hop
+    offsets). The k grid dimension is innermost and revisits the same dq
+    tile, so VMEM holds one tile of each operand regardless of sequence
+    length."""
     iq, jk = pl.program_id(1), pl.program_id(2)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
-    in_dt = q_ref.dtype  # dot operands in input dtype, f32 accumulation
     q_off = offs_ref[0] + iq * bq
     k_off = offs_ref[1] + jk * bk
 
@@ -676,35 +767,21 @@ def _flash_bwd_dq_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _():
-        q = q_ref[0]                                  # [BQ, D]
-        do = do_ref[0]
-        lse = lse_ref[0] * _LOG2E                     # [BQ, 1] f32, base-2
-        dd = dd_ref[0]
         k = k_ref[0]                                  # [BK, D]
-        v = v_ref[0]
-        s = (scale * _LOG2E) * lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            qpos = q_off + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = k_off + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        p = jnp.exp2(s - lse)                         # exp2(-inf) == 0
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - dd) * scale).astype(in_dt)
-        dq_ref[0] += lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
+        _, ds_t = _bwd_scores_t(
+            q_ref[0], k, v_ref[0], o_ref[0], do_ref[0], lse_ref[0],
+            q_off, k_off, causal=causal, scale=scale)
+        dq_ref[0] += _dot_tn(ds_t, k)
 
 
-def _flash_bwd_dkv_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
+def _flash_bwd_dkv_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
                           do_ref, dk_ref, dv_ref, *, causal, scale):
     """dk/dv accumulation for one (k tile, q tile) grid cell (dkv pass):
-    dv += p^T do; dk += (p*(do v^T - D)*scale)^T q. The q grid dimension is
-    innermost and revisits the same dk/dv tiles."""
+    dv += p^T do; dk += (p*(do v^T - D)*scale)^T q, both plain products of
+    the key-major p^T, ds^T. The q grid dimension is innermost and revisits
+    the same dk/dv tiles."""
     jk, iq = pl.program_id(1), pl.program_id(2)
     bk, bq = k_ref.shape[1], q_ref.shape[1]
-    in_dt = q_ref.dtype  # dot operands in input dtype, f32 accumulation
     q_off = offs_ref[0] + iq * bq
     k_off = offs_ref[1] + jk * bk
 
@@ -717,38 +794,25 @@ def _flash_bwd_dkv_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _():
-        k = k_ref[0]                                  # [BK, D]
-        v = v_ref[0]
         q = q_ref[0]                                  # [BQ, D]
         do = do_ref[0]
-        lse = lse_ref[0] * _LOG2E                     # [BQ, 1], base-2
-        dd = dd_ref[0]
-        s = (scale * _LOG2E) * lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            qpos = q_off + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = k_off + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        p = jnp.exp2(s - lse)                         # [BQ, BK] f32
-        dv_ref[0] += lax.dot_general(p.astype(in_dt), do,
-                                     (((0,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - dd) * scale).astype(in_dt)
-        dk_ref[0] += lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
+        p_t, ds_t = _bwd_scores_t(
+            q, k_ref[0], v_ref[0], o_ref[0], do, lse_ref[0], q_off, k_off,
+            causal=causal, scale=scale)
+        dv_ref[0] += jnp.dot(p_t, do, preferred_element_type=jnp.float32)
+        dk_ref[0] += jnp.dot(ds_t, q, preferred_element_type=jnp.float32)
 
 
-def _flash_bwd_fused_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
+def _flash_bwd_fused_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
                             do_ref, dq_ref, dk_ref, dv_ref, *maybe_acc,
                             causal, scale, sub_q, sub_k):
     """ONE-pass FlashAttention-2 backward: grid (bh, k tiles, q tiles) with
     q innermost; each cell recomputes p ONCE and emits all three gradient
     contributions. The streaming pair of kernels (dq pass + dkv pass) each
     stream the operands and rebuild p/dp separately — twice the operand
-    DMA and 7 matmuls per (q, k) tile pair; this kernel does 5.
+    DMA and 7 matmuls per (q, k) tile pair; this kernel does 5. The
+    LSE is a row [1, 1, BQ] and the scores key-major
+    (:func:`_bwd_scores_t`).
 
     A causal cell is cut at :func:`_pick_sub_tile`'s edges: ``sub_q`` rows
     at a time, each row sub-tile against the strip of the k tile that holds
@@ -784,7 +848,6 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
     jk, iq = pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
-    in_dt = q_ref.dtype  # dot operands in input dtype, f32 accumulation
     q_off = offs_ref[0] + iq * bq
     k_off = offs_ref[1] + jk * bk
 
@@ -802,28 +865,17 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
         """One row sub-tile against the k tile's first ``w`` sub-tiles."""
         q = q_ref[0, rows, :]                         # [SQ, D]
         do = do_ref[0, rows, :]
-        lse = lse_ref[0, rows, :] * _LOG2E            # [SQ, 1] f32, base-2
-        dd = dd_ref[0, rows, :]
         cols = pl.ds(0, w * sub_k)
         k = k_ref[0, cols, :]                         # [W, D]
-        v = v_ref[0, cols, :]
-        s = (scale * _LOG2E) * lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(s, q_off + rows.start, k_off)
-        p = jnp.exp2(s - lse)                         # exp2(-inf) == 0
-        dv_acc[cols, :] += lax.dot_general(
-            p.astype(in_dt), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - dd) * scale).astype(in_dt)
-        dk_acc[cols, :] += lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dq = lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+        p_t, ds_t = _bwd_scores_t(                    # [W, SQ]
+            q, k, v_ref[0, cols, :], o_ref[0, rows, :], do,
+            lse_ref[0, :, rows], q_off + rows.start, k_off, causal=causal,
+            scale=scale)
+        dv_acc[cols, :] += jnp.dot(p_t, do,
+                                   preferred_element_type=jnp.float32)
+        dk_acc[cols, :] += jnp.dot(ds_t, q,
+                                   preferred_element_type=jnp.float32)
+        dq = _dot_tn(ds_t, k)
         if dq_acc is None:
             dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
         else:
@@ -855,7 +907,7 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, dd_ref, q_ref, k_ref, v_ref,
         dq_ref[0] = dq_acc[pl.ds(iq * bq, bq), :].astype(dq_ref.dtype)
 
 
-def _flash_bwd_fused(qt, kt, vt, dot, lset, ddt, offs, d, *, causal, scale,
+def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, d, *, causal, scale,
                      block_q, block_k, interpret, fusable, out_dtype=None,
                      static_offs=None):
     """Dispatch of the one-pass backward (any length: k/v tiles stream
@@ -884,10 +936,10 @@ def _flash_bwd_fused(qt, kt, vt, dot, lset, ddt, offs, d, *, causal, scale,
             # accumulate dq in the persistent scratch
             grid=(bh, tk // block_k, tq // block_q),
             in_specs=[
-                pl.BlockSpec((1, block_q, 1), qmap),
-                pl.BlockSpec((1, block_q, 1), qmap),
+                _stat_spec(block_q, qmap),
                 pl.BlockSpec((1, block_q, d), qmap),
                 ktile, ktile,
+                pl.BlockSpec((1, block_q, d), qmap),
                 pl.BlockSpec((1, block_q, d), qmap),
             ],
             out_specs=[
@@ -909,7 +961,8 @@ def _flash_bwd_fused(qt, kt, vt, dot, lset, ddt, offs, d, *, causal, scale,
         ],
         cost_estimate=pl.CostEstimate(
             flops=10 * scores * d,                    # 5 matmuls a score
-            bytes_accessed=4 * bh * (4 * tq * d + 4 * tk * d),
+            bytes_accessed=4 * bh * (5 * tq * d + 4 * tk * d
+                                     + tq),           # ..., out; lse
             transcendentals=scores),
         # j and the innermost q dim both accumulate into revisited state;
         # single-sweep (k resident per cell) gets the resident VMEM budget
@@ -918,11 +971,11 @@ def _flash_bwd_fused(qt, kt, vt, dot, lset, ddt, offs, d, *, causal, scale,
         # producer recompute, so it stays off there)
         compiler_params=(
             _input_fusion(_cparams("parallel", "arbitrary", "arbitrary",
-                                   resident=True), 6, fusable)
+                                   resident=True), "sttttt", fusable)
             if tk // block_k == 1
             else _cparams("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-    )(offs, lset, ddt, qt, kt, vt, dot)
+    )(offs, lset, qt, kt, vt, ot, dot)
 
 
 def _flash_bwd(q, k, v, out, lse, dout, q_off=0, k_off=0, *, causal, scale):
@@ -936,23 +989,19 @@ def _flash_bwd(q, k, v, out, lse, dout, q_off=0, k_off=0, *, causal, scale):
     def heads_major(x):
         return x.transpose(0, 2, 1, 3).reshape(bh, x.shape[1], d)
 
-    qt, kt, vt, dot = map(heads_major, (q, k, v, dout))
-    # D = rowsum(dout * out) per row — cheap and linear, precomputed in jnp
-    dd = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
-                 axis=-1)                              # [B, T, H]
-    ddt = dd.transpose(0, 2, 1).reshape(bh, tq, 1)
-    lset = lse.reshape(bh, tq, 1)
-    dq, dk, dv = _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off, k_off,
+    qt, kt, vt, ot, dot = map(heads_major, (q, k, v, out, dout))
+    lset = lse.reshape(bh, 1, tq)
+    dq, dk, dv = _flash_bwd_hm(qt, kt, vt, ot, dot, lset, q_off, k_off,
                                causal=causal, scale=scale,
                                fusable=_relayout_fusable(b, h))
     return (_heads_minor(dq, b, h, tq, d), _heads_minor(dk, b, h, tk, d),
             _heads_minor(dv, b, h, tk, d))
 
 
-def _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off=0, k_off=0, *,
+def _flash_bwd_hm(qt, kt, vt, ot, dot, lset, q_off=0, k_off=0, *,
                   causal, scale, fusable, out_dtype=None):
     """Heads-major core of :func:`_flash_bwd`: operands/grads all
-    ``[BH, T, D]`` (lse/dd ``[BH, T, 1]``) so a caller that already holds
+    ``[BH, T, D]`` (lse ``[BH, 1, T]``) so a caller that already holds
     heads-major tensors (the full-attention VJP saves its residuals that
     way) pays no relayout. Returns (dq, dk, dv) heads-major f32."""
     bh, tq, d = qt.shape
@@ -969,7 +1018,7 @@ def _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off=0, k_off=0, *,
         static = all(isinstance(x, (int, np.integer))
                      for x in (q_off, k_off))
         return _flash_bwd_fused(
-            qt, kt, vt, dot, lset, ddt, offs, d, causal=causal, scale=scale,
+            qt, kt, vt, ot, dot, lset, offs, d, causal=causal, scale=scale,
             block_q=block_q, block_k=block_k, interpret=interpret,
             fusable=fusable, out_dtype=out_dtype,
             static_offs=(q_off, k_off) if static else None)
@@ -984,11 +1033,11 @@ def _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off=0, k_off=0, *,
             # k innermost: consecutive grid steps revisit the same dq tile
             grid=(bh, tq // block_q, tk // block_k),
             in_specs=[
-                pl.BlockSpec((1, block_q, 1), lambda i, j, n, offs: (i, j, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda i, j, n, offs: (i, j, 0)),
+                _stat_spec(block_q, lambda i, j, n, offs: (i, j, 0)),
                 pl.BlockSpec((1, block_q, d), lambda i, j, n, offs: (i, j, 0)),
                 pl.BlockSpec((1, block_k, d), kmap),
                 pl.BlockSpec((1, block_k, d), kmap),
+                pl.BlockSpec((1, block_q, d), lambda i, j, n, offs: (i, j, 0)),
                 pl.BlockSpec((1, block_q, d), lambda i, j, n, offs: (i, j, 0)),
             ],
             out_specs=pl.BlockSpec((1, block_q, d),
@@ -997,11 +1046,11 @@ def _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off=0, k_off=0, *,
         out_shape=_struct((bh, tq, d), jnp.float32, qt, kt, offs),
         cost_estimate=pl.CostEstimate(
             flops=6 * bh * tq * tk * d,
-            bytes_accessed=4 * bh * (3 * tq * d + 2 * tk * d),
+            bytes_accessed=4 * bh * (4 * tq * d + 2 * tk * d + tq),
             transcendentals=bh * tq * tk),
         compiler_params=_sem_par2_arb(),
         interpret=interpret,
-    )(offs, lset, ddt, qt, kt, vt, dot)
+    )(offs, lset, qt, kt, vt, ot, dot)
 
     dk, dv = _named_call("flash_bwd_dkv",
         functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale),
@@ -1010,11 +1059,11 @@ def _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off=0, k_off=0, *,
             # q innermost: consecutive grid steps revisit the same dk/dv tiles
             grid=(bh, tk // block_k, tq // block_q),
             in_specs=[
-                pl.BlockSpec((1, block_q, 1), qmap),
-                pl.BlockSpec((1, block_q, 1), qmap),
+                _stat_spec(block_q, qmap),
                 pl.BlockSpec((1, block_q, d), qmap),
                 pl.BlockSpec((1, block_k, d), lambda i, j, n, offs: (i, j, 0)),
                 pl.BlockSpec((1, block_k, d), lambda i, j, n, offs: (i, j, 0)),
+                pl.BlockSpec((1, block_q, d), qmap),
                 pl.BlockSpec((1, block_q, d), qmap),
             ],
             out_specs=[
@@ -1028,11 +1077,11 @@ def _flash_bwd_hm(qt, kt, vt, dot, lset, ddt, q_off=0, k_off=0, *,
         ],
         cost_estimate=pl.CostEstimate(
             flops=8 * bh * tq * tk * d,
-            bytes_accessed=4 * bh * (3 * tq * d + 3 * tk * d),
+            bytes_accessed=4 * bh * (4 * tq * d + 3 * tk * d + tq),
             transcendentals=bh * tq * tk),
         compiler_params=_sem_par2_arb(),
         interpret=interpret,
-    )(offs, lset, ddt, qt, kt, vt, dot)
+    )(offs, lset, qt, kt, vt, ot, dot)
 
     return dq, dk, dv
 
@@ -1098,8 +1147,8 @@ def _flash_fullattn_vjp(causal: bool, scale: float):
                 block_k=_pick_block(tk, side="k"), interpret=_interpret(),
                 fusable=_relayout_fusable(b, h))
             return qt, kt, vt, out_t, lse_t
-        mt = jnp.full((bh, tq, 1), NEG_INF, jnp.float32)
-        lt = jnp.zeros((bh, tq, 1), jnp.float32)
+        mt = jnp.full((bh, 1, tq), NEG_INF, jnp.float32)
+        lt = jnp.zeros((bh, 1, tq), jnp.float32)
         ot = jnp.zeros((bh, tq, d), jnp.float32)
         mt, lt, ot = _flash_step_call(
             qt, kt, vt, mt, lt, ot, offs, causal=causal, scale=scale,
@@ -1108,8 +1157,8 @@ def _flash_fullattn_vjp(causal: bool, scale: float):
             fusable=_relayout_fusable(b, h))
         # heads-major finalize; masked-row convention shared with the ring
         # epilogue via _masked_row_stats (backward recompute relies on it)
-        l_safe, lse_t = _masked_row_stats(mt, lt)            # [BH, T, 1]
-        out_t = (ot / l_safe).astype(q.dtype)
+        l_safe, lse_t = _masked_row_stats(mt, lt)            # [BH, 1, T]
+        out_t = (ot / l_safe[:, 0, :, None]).astype(q.dtype)
         return qt, kt, vt, out_t, lse_t
 
     @jax.custom_vjp
@@ -1125,10 +1174,9 @@ def _flash_fullattn_vjp(causal: bool, scale: float):
         # policy can keep them (models.transformer.REMAT_POLICIES): they are
         # the dearest residuals to rebuild, since that takes the kernel.
         # Identities outside jax.checkpoint. The statistics are named as
-        # [BH, T]: a kept f32 [BH, T, 1] pads its last dimension to 128
-        # lanes on the TPU, 128x its bytes.
+        # the kernels write and read them, [BH, 1, T] rows: kept dense.
         out_t = checkpoint_name(out_t, "flash_out")
-        lse_t = checkpoint_name(lse_t[..., 0], "flash_lse")[..., None]
+        lse_t = checkpoint_name(lse_t, "flash_lse")
         return (_heads_minor(out_t, b, h, tq, d),
                 (qt, kt, vt, out_t, lse_t))
 
@@ -1137,9 +1185,7 @@ def _flash_fullattn_vjp(causal: bool, scale: float):
         b, tq, h, d = dout.shape
         tk = kt.shape[1]
         dot = dout.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
-        ddt = jnp.sum(dot.astype(jnp.float32) * out_t.astype(jnp.float32),
-                      axis=-1, keepdims=True)          # [BH, T, 1]
-        dq, dk, dv = _flash_bwd_hm(qt, kt, vt, dot, lse_t, ddt,
+        dq, dk, dv = _flash_bwd_hm(qt, kt, vt, out_t, dot, lse_t,
                                    causal=causal, scale=scale,
                                    fusable=_relayout_fusable(b, h),
                                    out_dtype=qt.dtype)

@@ -408,16 +408,22 @@ def test_vmem_policy_and_input_fusion(monkeypatch):
 
     # input fusion: default on, disabled per-call by the env
     monkeypatch.delenv("HVD_PALLAS_INPUT_FUSION", raising=False)
-    p = pk._input_fusion(pk._sem_par2_res(), 6, pk._relayout_fusable(8, 16))
+    p = pk._input_fusion(pk._sem_par2_res(), "tttttt",
+                         pk._relayout_fusable(8, 16))
     assert list(p.allow_input_fusion) == [False] + [True] * 6
+    # the row statistics stay out of it: with a producer folded in, the
+    # call is an XLA fusion and a device trace no longer names the kernel
+    p = pk._input_fusion(pk._sem_par2_res(), "tttsst", True)
+    assert list(p.allow_input_fusion) == [False] + [True] * 3 + [
+        False, False, True]
     # one batch row or one head: the relayout is no 4-D transpose, and
     # fusing it crashes the TPU compiler (AOT-probed, libtpu 0.0.34)
     for b, h in ((1, 16), (8, 1)):
-        p = pk._input_fusion(pk._sem_par2_res(), 6,
+        p = pk._input_fusion(pk._sem_par2_res(), "tttttt",
                              pk._relayout_fusable(b, h))
         assert p.allow_input_fusion is None
     monkeypatch.setenv("HVD_PALLAS_INPUT_FUSION", "0")
-    p = pk._input_fusion(pk._sem_par2_res(), 6, True)
+    p = pk._input_fusion(pk._sem_par2_res(), "tttttt", True)
     assert p.allow_input_fusion is None
 
 
@@ -439,13 +445,15 @@ def test_flash_fwd_oneshot_vs_step_path(causal, monkeypatch):
     taken = _spy_kernels(monkeypatch)
     out_once, lse_once = pk._flash_fwd_once_call(qt, kt, vt, offs, **kw)
     mt, lt, ot = pk._flash_step_call(
-        qt, kt, vt, jnp.full((bh, t, 1), -jnp.inf, jnp.float32),
-        jnp.zeros((bh, t, 1), jnp.float32),
+        qt, kt, vt, jnp.full((bh, 1, t), -jnp.inf, jnp.float32),
+        jnp.zeros((bh, 1, t), jnp.float32),
         jnp.zeros((bh, t, d), jnp.float32), offs, **kw)
     assert taken == ["_flash_fwd_once_kernel", "_flash_step_kernel"]
+    assert lse_once.shape == mt.shape == lt.shape == (bh, 1, t)
     l_safe, lse_step = pk._masked_row_stats(mt, lt)
     np.testing.assert_allclose(np.asarray(out_once),
-                               np.asarray(ot / l_safe), rtol=1e-6, atol=1e-6)
+                               np.asarray(ot / l_safe[:, 0, :, None]),
+                               rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(np.asarray(lse_once), np.asarray(lse_step),
                                rtol=1e-6, atol=1e-6)
 
@@ -697,6 +705,82 @@ def test_flash_causal_sub_tiles_control_one_live_sub_tile_dropped(
         assert err > 1e-2, f"{name}: {nm} moved only {err}"
 
 
+# kernel -> the caps that route a 128-position head to it (``None``: as
+# shipped) and which of (out, lse, dq, dk, dv) it produces
+_MASKED_ROW_KERNELS = {
+    "_flash_fwd_once_kernel": ({}, ("out", "lse")),
+    "_flash_step_kernel": ({}, ("out", "lse")),
+    "_flash_step_stream_kernel": ({"_KV_VMEM_CAP": 1}, ("out", "lse")),
+    "_flash_bwd_fused_kernel": ({}, ("dq", "dk", "dv")),
+    "_flash_bwd_dq_kernel": ({"_DQ_SCRATCH_CAP": 1}, ("dq",)),
+    "_flash_bwd_dkv_kernel": ({"_DQ_SCRATCH_CAP": 1}, ("dk", "dv")),
+}
+
+
+@pytest.mark.parametrize("k_off,dead", [(256, 128), (64, 64)],
+                         ids=["hop_wholly_dead", "first_64_rows_dead"])
+@pytest.mark.parametrize("kernel", sorted(_MASKED_ROW_KERNELS))
+def test_flash_fully_masked_rows_in_each_kernel(kernel, k_off, dead,
+                                                monkeypatch):
+    """The fully-masked-row convention (``_masked_row_stats``) with the
+    statistics as rows, in each of the six kernels, reached through
+    ``flash_route``'s caps: 128 queries from position 0 against 128 keys
+    from ``k_off``, so the first ``dead`` query rows see no key. Their
+    output is exactly zero and their LSE the sentinel 0; their dq is
+    exactly zero, and where every row is dead so are dk and dv. What is
+    live matches plain attention."""
+    caps, produces = _MASKED_ROW_KERNELS[kernel]
+    for cap, value in caps.items():
+        monkeypatch.setattr(pk, cap, value)
+    monkeypatch.setattr(pk, "_BLOCK_Q", 64)
+    monkeypatch.setattr(pk, "_BLOCK_K", 64)
+    monkeypatch.setattr(pk, "_SUB_TILE", 32)
+    b, t, h, d = 1, 128, 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(k_off), 4)
+    q, k, v, dout = (jax.random.normal(kk, (b, t, h, d)) for kk in ks)
+    scale = d ** -0.5
+    taken = _spy_kernels(monkeypatch)
+
+    if kernel == "_flash_fwd_once_kernel":
+        # the single-shot forward takes offsets too, though its one caller
+        # passes zeros: heads-major operands, lse as it leaves the kernel
+        hm = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+        out_t, lse_t = pk._flash_fwd_once_call(
+            hm(q), hm(k), hm(v), jnp.array([0, k_off], jnp.int32),
+            causal=True, scale=scale, block_q=64, block_k=64,
+            interpret=True, fusable=True)
+        assert lse_t.shape == (b * h, 1, t) and lse_t.dtype == jnp.float32
+        out = pk._heads_minor(out_t, b, h, t, d)
+        lse = lse_t.reshape(b, h, t)
+    else:
+        m, l, o = pk.flash_attention_step(
+            q, k, v, jnp.full((b, h, t), -jnp.inf, jnp.float32),
+            jnp.zeros((b, h, t), jnp.float32), jnp.zeros(q.shape),
+            jnp.int32(0), jnp.int32(k_off), causal=True, scale=scale)
+        out, lse = pk.finalize_attention_stats(m, l, o, jnp.float32)
+    got = {"out": out, "lse": lse}
+    if "dq" in produces or "dk" in produces:
+        got.update(zip(("dq", "dk", "dv"), pk._flash_bwd(
+            q, k, v, out, lse, dout, jnp.int32(0), jnp.int32(k_off),
+            causal=True, scale=scale)))
+    assert kernel in taken
+
+    ref = dict(zip(("out", "dq", "dk", "dv"),
+                   _masked_reference(q, k, v, dout, 0, k_off, True)))
+    for name in produces:
+        x = np.asarray(got[name])
+        if name == "lse":
+            assert not x[:, :, :dead].any()           # the sentinel
+            assert np.isfinite(x).all()
+            continue
+        if name in ("out", "dq"):
+            assert not x[:, :dead].any(), f"{name}: dead rows not exact 0"
+        elif dead == t:
+            assert not x.any(), f"{name}: not exact 0"
+        np.testing.assert_allclose(x, np.asarray(ref[name]), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)
+
+
 # cell -> (per-chip batch, heads, positions): its attention layers' calls
 _CELL_ATTENTION = {
     "gpt2m-train-s1024": (8, 16, 1024),
@@ -757,6 +841,14 @@ def test_flash_plan_at_the_cells_shapes(cell, monkeypatch):
     assert costs["flash_fwd"].transcendentals == fwd
     assert costs["flash_bwd"].flops == 10 * bwd * 64
     assert costs["flash_bwd"].transcendentals == bwd
+    # the row statistics at their true bytes, 4 a position: the forward
+    # writes lse beside q, k, v, out in bf16; the backward reads lse beside
+    # its nine [T, 64] operands and results (out among them: D is formed in
+    # the kernel), priced at 4 bytes
+    bh = b * h
+    assert costs["flash_fwd"].bytes_accessed == 2 * 4 * bh * t * 64 + 4 * bh * t
+    assert costs["flash_bwd"].bytes_accessed == (
+        4 * 9 * bh * t * 64 + 4 * bh * t)
 
 
 # ------------------------------------------- fused quantize + pack (wire)

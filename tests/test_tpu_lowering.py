@@ -19,6 +19,8 @@ same list of cases (``chip_smoke.kernel_cases``) on the chip against their
 references.
 """
 
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -109,6 +111,85 @@ def test_flash_route_edges_compile_for_v5e(shape, v5e_devices):
     lowered = lower_tpu(grads, *on_mesh([x, x, x], mesh))
     assert lowered.as_text().count("tpu_custom_call") == calls
     lowered.compile()
+
+
+# name -> (batch, heads, positions): the attention calls of the four cells
+# (gpt2-medium's is both its cells'), and one ring hop at chip_smoke's shape
+_STATISTICS_CASES = {
+    "gpt2m_8x16x1024": (8, 16, 1024),
+    "gpt2l_4x20x1024": (4, 20, 1024),
+    "granite4hm_1x32x4096": (1, 32, 4096),
+    "ring_hop_1x16x2048": (1, 16, 2048),
+}
+_FLASH_CALL = re.compile(
+    r'custom_call @tpu_custom_call\(.*kernel_name = "(flash_\w+)".*'
+    r' : \((.*)\) -> \(?(.*?)\)?$')
+_TENSOR = re.compile(r"tensor<([0-9x]+)x(\w+)>")
+
+
+def flash_call_tensors(lowered_text):
+    """``[(kernel name, [(dims, dtype) of every operand and result])]`` of
+    the flash kernels' custom calls in a program lowered for the TPU."""
+    calls = []
+    for line in lowered_text.splitlines():
+        m = _FLASH_CALL.search(line)
+        if m:
+            calls.append((m.group(1), [
+                (tuple(int(n) for n in dims.split("x")), dtype)
+                for dims, dtype in _TENSOR.findall(m.group(2) + m.group(3))]))
+    return calls
+
+
+@pytest.mark.parametrize("name", sorted(_STATISTICS_CASES))
+def test_flash_statistics_cross_hbm_lane_dense(name):
+    """No flash kernel takes or gives an f32 tensor whose last dimension is
+    1 (the TPU tiles it to 128 lanes: 512 bytes a value in HBM and in every
+    DMA): lse and a ring hop's m and l are [BH, 1, T] rows, the residual
+    ``flash_attention`` saves for its backward pass is that row as the
+    forward kernel wrote it, and the row sums of do * out are no operand
+    at all (the backward kernels form them)."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    b, h, t = _STATISTICS_CASES[name]
+    x = jax.ShapeDtypeStruct((b, t, h, 64), jnp.bfloat16)
+    row = (b * h, 1, t)
+    if name.startswith("ring_hop"):
+        stat = jax.ShapeDtypeStruct((b, h, t), jnp.float32)
+        acc = jax.ShapeDtypeStruct(x.shape, jnp.float32)
+
+        def fn(q, k, v, m, l, o, q_off, k_off):
+            m, l, o = pk.flash_attention_step(
+                q, k, v, m, l, o, q_off, k_off, causal=True, scale=0.125)
+            out, lse = pk.finalize_attention_stats(m, l, o, q.dtype)
+            return pk._flash_bwd(q, k, v, out, lse, o, q_off, k_off,
+                                 causal=True, scale=0.125)
+
+        off = jax.ShapeDtypeStruct((), jnp.int32)
+        args = (x, x, x, stat, stat, acc, off, off)
+        want = {"flash_step": 4, "flash_bwd": 1}      # m, l in and out; lse
+    else:
+        def attn(q, k, v):
+            return pk.flash_attention(q, k, v, causal=True)
+
+        def fn(q, k, v):
+            out, vjp = jax.vjp(attn, q, k, v)
+            return vjp(out)
+
+        args = (x, x, x)
+        want = {"flash_fwd": 1, "flash_bwd": 1}
+        # what jax.vjp's backward function closes over: q, k, v and out
+        # heads-major in bf16, and the one f32 row
+        with jax.enable_x64(False):
+            saved = jax.tree_util.tree_leaves(jax.eval_shape(
+                lambda *qkv: jax.vjp(attn, *qkv)[1], *args))
+        assert sorted((a.shape, a.dtype.name) for a in saved) == sorted(
+            [((b * h, t, 64), "bfloat16")] * 4 + [(row, "float32")])
+    calls = flash_call_tensors(lower_tpu(fn, *args).as_text())
+    assert sorted(n for n, _ in calls) == sorted(want)
+    for kernel, tensors in calls:
+        f32 = [dims for dims, dtype in tensors if dtype == "f32"]
+        assert not [dims for dims in f32 if dims[-1] == 1], (kernel, f32)
+        assert f32.count(row) == want[kernel], (kernel, f32)
 
 
 def _four_chip_cases(mesh):

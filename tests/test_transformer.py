@@ -254,11 +254,13 @@ def _saved_intermediates(remat, num_layers, tokens, targets, capsys):
 
 @pytest.mark.parametrize("remat,per_layer", [
     # B, T, D = 2, 128, 128 and H = 2: the block's input, the kernel's
-    # heads-major output (as large as a [B, T, D]) and its row statistics
-    ("full", {"f32[2,128,128]": 1, "f32[4,128,64]": 1, "f32[4,128]": 1}),
+    # heads-major output (as large as a [B, T, D]) and its row statistics,
+    # the [BH, 1, T] rows the kernels write and read: the positions on the
+    # lanes, no trailing 1 for the TPU to pad to 128
+    ("full", {"f32[2,128,128]": 1, "f32[4,128,64]": 1, "f32[4,1,128]": 1}),
     # no policy reads the names: every residual of the kernel is kept, q, k
-    # and v heads-major beside its two outputs, the statistics as [BH, T, 1]
-    ("none", {"f32[4,128,64]": 4, "f32[4,128,1]": 1}),
+    # and v heads-major beside its two outputs, the statistics the same rows
+    ("none", {"f32[4,128,64]": 4, "f32[4,1,128]": 1}),
 ])
 def test_remat_saved_residuals(remat, per_layer, monkeypatch, capsys):
     """Under "full" one more layer saves one block input, one flash_out and
