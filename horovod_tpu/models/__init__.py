@@ -2,7 +2,8 @@
 exercises — ResNets (`examples/tensorflow2_synthetic_benchmark.py:35-40`),
 Inception V3 and VGG-16/19 (the 90%/90%/68% scaling-efficiency trio,
 `README.rst:74-79`) — plus the long-context transformer flagship and the
-Mamba-2 / attention hybrid whose blocks are described by data."""
+hybrid whose blocks are described by data (Mamba-2, attention and gated
+short-conv mixers; SwiGLU and routed-expert feed-forwards)."""
 
 from .hybrid import HybridLM
 from .inception import InceptionV3

@@ -90,14 +90,15 @@ def ssd_chunked(x, dt, A, B, C, D, chunk: int = 256):
         return y.reshape(b, t + pad, h, p)[:, :t].astype(x.dtype)
 
 
-def causal_conv1d(x, kernel, bias):
+def causal_conv1d(x, kernel, bias=None):
     """Depthwise causal convolution over time: ``y_t = bias + sum_k
     kernel[k] * x_{t-(K-1)+k}`` for ``x`` ``[b, T, C]``, ``kernel``
-    ``[K, C]``, positions before the sequence zero. K shifted multiply-adds
-    accumulated in float32 (K is 4: not worth a convolution's layout)."""
+    ``[K, C]``, positions before the sequence zero; no ``bias`` is a bias
+    of zero. K shifted multiply-adds accumulated in float32 (K is 3 or 4:
+    not worth a convolution's layout)."""
     k, t = kernel.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
-    y = bias.astype(jnp.float32)
+    y = 0.0 if bias is None else bias.astype(jnp.float32)
     for i in range(k):
         y = y + padded[:, i:i + t] * kernel[i].astype(jnp.float32)
     return y.astype(x.dtype)

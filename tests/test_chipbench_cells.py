@@ -1,8 +1,9 @@
 """The benchmark's data files in tier-1: ``chipbench/tests/test_cells.py``
 collected here, so that a PR that leaves ``BENCHMARK.json`` and the files
 under ``chipbench/`` inconsistent fails the repo's own tests; and the
-``--rehearse`` walk of the hybrid's cell (CPU, the family's toy size), which
-every reader the cell lists has to survive. Nothing here is a device number.
+``--rehearse`` walk of each ``HybridLM`` cell (CPU, its family's toy size),
+which every reader the cell lists has to survive. Nothing here is a device
+number.
 """
 
 import pytest
@@ -11,11 +12,16 @@ from chipbench import harness
 from chipbench.tests.test_cells import *  # noqa: F401,F403
 from chipbench.tests.test_rehearsal import KEYS, rehearse
 
-HYBRID_CELL = "granite4hm-train-s4096"
+#: cell -> the per-layer metrics that are its architecture's own
+HYBRID_CELLS = {
+    "granite4hm-train-s4096": {"ssd_ms", "ssd_roofline"},
+    "lfm2moe-train-s8192": {"moe_ms", "moe_experts_ms",
+                            "moe_experts_roofline", "short_conv_ms"}}
 
 
-def test_hybrid_cell_rehearsal_prints_the_end_to_end_line():
-    line, out = rehearse(HYBRID_CELL, 0)
+@pytest.mark.parametrize("cell", HYBRID_CELLS)
+def test_hybrid_cell_rehearsal_prints_the_end_to_end_line(cell):
+    line, out = rehearse(cell, 0)
     assert set(line) == KEYS and line["correct"] is True, out
     assert line["failed"] == 0 and line["attempted"] > 0
     assert set(line["metrics"]) == {"rehearsal_train_tokens_per_s_chip",
@@ -23,18 +29,30 @@ def test_hybrid_cell_rehearsal_prints_the_end_to_end_line():
     assert "reference check" in out
 
 
-def test_hybrid_cell_rehearsal_reads_every_per_layer_metric_it_lists():
-    line, out = rehearse(HYBRID_CELL, 1)
+@pytest.mark.parametrize("cell", HYBRID_CELLS)
+def test_hybrid_cell_rehearsal_reads_every_per_layer_metric_it_lists(cell):
+    line, out = rehearse(cell, 1)
     assert line["correct"] is True, out
     declared = {m["name"] for m in
-                harness.declared_metrics(HYBRID_CELL)["per_layer"]}
+                harness.declared_metrics(cell)["per_layer"]}
     got = {k[len("rehearsal_"):] for k in line["metrics"]}
     # the CPU backend has no memory statistics, and the interpreter's
     # kernels are no custom calls, so their roofline share has no time
     assert declared - got <= {"peak_hbm_gib", "flash_attention_roofline"}
-    assert got <= declared and {"ssd_ms", "ssd_roofline"} <= got
-    values = {k: v["value"] for k, v in line["metrics"].items()}
-    assert values["rehearsal_ssd_ms"] > 0
-    # the scan's scope is its own class: its time is not the blocks'
-    assert values["rehearsal_ssd_ms"] < values["rehearsal_xla_ops_ms"]
-    assert values["rehearsal_blocks_recompute_ms"] > 0
+    assert got <= declared and HYBRID_CELLS[cell] <= got
+    values = {k[len("rehearsal_"):]: v["value"]
+              for k, v in line["metrics"].items()}
+    assert values["blocks_recompute_ms"] > 0
+    if cell == "granite4hm-train-s4096":
+        # the scan's scope is its own class: its time is not the blocks'
+        assert 0 < values["ssd_ms"] < values["xla_ops_ms"]
+    else:
+        # overlays: the routed feed-forward's time stays in the blocks'
+        assert 0 < values["moe_experts_ms"] <= values["moe_ms"] \
+            < values["xla_ops_ms"]
+        assert values["moe_experts_roofline"] > 0
+        assert values["short_conv_ms"] > 0
+        parts = ("blocks_fwd_ms", "blocks_bwd_ms", "blocks_recompute_ms",
+                 "head_loss_ms", "optimizer_ms", "model_other_ms")
+        assert sum(values[k] for k in parts) == pytest.approx(
+            values["xla_ops_ms"], rel=1e-9)

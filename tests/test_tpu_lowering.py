@@ -299,3 +299,43 @@ def test_train_step_at_1024_positions_compiles_for_v5e(remat, v5e_devices):
     compiled = _train_step_lowering(mesh, 2, remat=remat, seq=1024).compile()
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') == 2 * LAYERS
+
+
+def test_routed_feed_forward_compiles_for_v5e_at_the_published_widths(
+        v5e_devices):
+    """``ops/moe.routed_ffn`` forward and backward at LFM2-8B-A1B's widths
+    and the cell's 16,384 tokens: the grouped products become the TPU
+    compiler's own kernels (two forward; two recomputed and four gradient
+    products backward) in each of the three row capacities, and nothing of
+    the layer is a dense product over experts x tokens."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.ops import moe
+
+    tokens, d, width, experts, held, top_k = 16384, 2048, 1792, 32, 8, 4
+    one = SingleDeviceSharding(v5e_devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def loss(h, router, bias, w_in, w_out):
+        y = moe.routed_ffn(h, router, bias, w_in, w_out,
+                           held=tuple(range(held)), top_k=top_k)[0]
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4))).trace(
+            shape((tokens, d), jnp.bfloat16), shape((d, experts), jnp.float32),
+            shape((experts,), jnp.float32),
+            shape((held, d, 2 * width), jnp.float32),
+            shape((held, width, d), jnp.float32)).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+    sizes = moe.capacities(tokens * top_k, held, experts)
+    assert sizes == (32768, 65536)
+    products = len(re.findall(r"%ragged-dot-none[.\d]* = ", text))
+    assert products == len(sizes) * (2 + 2 + 4), products
+    for rows in sizes:
+        assert f"bf16[{rows},{2 * width}]" in text
+    # no [experts, tokens, d] or [tokens, experts, d] operand anywhere
+    assert not re.search(rf"\[({held}|{experts}),{tokens},{d}\]", text)
+    assert not re.search(rf"\[{tokens},({held}|{experts}),{d}\]", text)
