@@ -429,12 +429,63 @@ def kernel_cases():
         "int4_quantize_pack": KernelCase(
             pk.int4_quantize_pack, [rows], 1, pk.int4_quantize_pack_ref,
             None),
+        **grouped_cases(),
         "matmul_2d (LM head chunk)": KernelCase(
             pk.matmul_2d,
             [s((B * T // 4, DM // 4), jnp.bfloat16),
              s((DM // 4, VOCAB), jnp.bfloat16)], 1,
             lambda x, w: jnp.dot(x, w, preferred_element_type=jnp.float32
                                  ).astype(x.dtype), TOL_BF16),
+    }
+
+
+def grouped_cases():
+    """The routed feed-forward's three grouped products (``ops/moe.py``) at
+    LFM2-8B-A1B's widths and the cell's smaller row capacity, 22,000 rows
+    in eight uneven groups, against a loop of dense products over the
+    groups. The rows of no group hold whatever was there and are cut off."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import moe
+
+    rows, d, width = 32768, 2048, 1792
+    sizes = (3011, 2467, 3390, 2214, 2905, 2750, 1893, 3370)
+    ends = [sum(sizes[:g + 1]) for g in range(len(sizes))]
+    spans = list(zip([0] + ends[:-1], ends))
+
+    def s(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def product(fn, rows_out=ends[-1]):
+        return lambda lhs, rhs: fn(
+            lhs, rhs, jnp.asarray(sizes, jnp.int32))[:rows_out]
+
+    def dot(a, b, contract):
+        return jax.lax.dot_general(
+            a, b, ((contract, ((), ()))),
+            preferred_element_type=jnp.float32).astype(a.dtype)
+
+    def rowwise(contract):
+        return lambda lhs, rhs: jnp.concatenate([
+            dot(lhs[a:b], rhs[g], contract)
+            for g, (a, b) in enumerate(spans)])
+
+    return {
+        "moe grouped_matmul (x W1)": KernelCase(
+            product(moe.grouped_matmul),
+            [s(rows, d), s(len(sizes), d, 2 * width)], 1,
+            rowwise(((1,), (0,))), TOL_BF16),
+        "moe grouped_matmul_t (dGU W1^T)": KernelCase(
+            product(moe.grouped_matmul_t),
+            [s(rows, 2 * width), s(len(sizes), d, 2 * width)], 1,
+            rowwise(((1,), (1,))), TOL_BF16),
+        "moe grouped_outer (dW1)": KernelCase(
+            product(moe.grouped_outer, None),
+            [s(rows, d), s(rows, 2 * width)], 1,
+            lambda lhs, rhs: jnp.stack([
+                dot(lhs[a:b], rhs[a:b], ((0,), (0,))) for a, b in spans]),
+            TOL_BF16),
     }
 
 

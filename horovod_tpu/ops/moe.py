@@ -15,13 +15,29 @@ exchange.
 No capacity that drops and no one-hot dispatch over experts x tokens: the
 (token, expert) assignments are sorted by expert, the rows of the experts
 held gathered in that order, the two matrix products done as **grouped**
-products over the experts' row ranges (``jax.lax.ragged_dot``, which XLA's
-TPU compiler turns into a tiled kernel that walks only the tiles a group
-has rows in), and the rows summed back into their tokens. Shapes are
-static: the expert stage is compiled at two row counts, up to the worst
-case, every assignment landing here (``tokens * k`` rows), and a step runs
-the smallest that holds its rows (:func:`capacities`), so that the cost
-follows the rows really routed here.
+products over the experts' row ranges, and the rows summed back into their
+tokens. Shapes are static: the expert stage is compiled at two row counts,
+up to the worst case, every assignment landing here (``tokens * k`` rows),
+and a step runs the smallest that holds its rows (:func:`capacities`), so
+that the cost follows the rows really routed here.
+
+**Which kernel runs where.** A grouped product is one of three:
+:func:`grouped_matmul` (rows against their group's matrix),
+:func:`grouped_matmul_t` (against its transpose) and :func:`grouped_outer`
+(a group's rows against a group's rows: the matrices' gradient). On the
+chip they are the Pallas kernels ``pallas_kernels.gmm`` / ``tgmm``, which
+walk only the row tiles a group has rows in, at the tiles
+``pallas_kernels.grouped_route`` gives the shape; off the chip
+(``pallas_kernels.mode() == "off"``) and for a shape the route refuses,
+``jax.lax.ragged_dot``. Nothing is set: the platform and the shape decide,
+and ``pallas_kernels.kernel_path`` answers for a given call. **Seven
+products a layer and step**: two forward (``x W1``, ``act W2``) and five in
+the stage's backward pass, which is written out (:func:`_experts_bwd_at`;
+a Pallas call has no autodiff rule): ``x W1`` again (the stage keeps its
+operands, not the ``[rows, 2 f]`` activations), ``g W2^T``, ``dGU W1^T``
+and the two weight gradients. ``act W2`` is not run again: its one
+consumer there, the routing weights' gradient ``<out, g>``, is
+``<act, g W2^T>``.
 
 ``parallel/expert.py``'s ``MoEMLP`` is the older stand-alone block (top-1,
 capacity with drops, its own train step); this layer lives inside
@@ -36,6 +52,8 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from . import pallas_kernels as pk
 
 #: added to the sum of a token's chosen scores before it divides them
 #: (the published modelling code's ``1e-6``)
@@ -127,31 +145,84 @@ put_rows.defvjp(
     lambda res, g: (take_rows(g, *res), None, None))
 
 
+def _kernel_tiles(name: str, lhs, rhs, n: int, key: str = "tiling"):
+    """The tiles the Pallas kernel of dispatcher ``name`` takes for these
+    operands (``n`` the product's other width), None where the call goes to
+    ``jax.lax.ragged_dot``: ``pallas_kernels.kernel_path`` decides, as for
+    every kernel there."""
+    if pk.kernel_path(name, lhs, rhs) == "reference":
+        return None
+    return pk.grouped_route(*lhs.shape, n, lhs.dtype.itemsize)[key]
+
+
 def grouped_matmul(lhs, rhs, group_sizes):
     """``out[r] = lhs[r] @ rhs[g]`` for row ``r`` in group ``g``: ``lhs``
     ``[R, K]`` with its rows in group order, ``rhs`` ``[G, K, N]``,
     ``group_sizes`` ``[G]`` int32 (a group may be empty). Rows from
     ``sum(group_sizes)`` on are in no group: nothing is computed for them
     and what they hold is unspecified (on the chip: whatever was there).
-    Float32 accumulation, ``lhs.dtype`` out; differentiable in ``lhs`` and
-    ``rhs``."""
+    Float32 accumulation, ``lhs.dtype`` out. On the chip the Pallas kernel
+    ``pallas_kernels.gmm`` (``moe_gmm`` in a trace) at
+    ``pallas_kernels.grouped_route``'s tiles; off it, and for a shape the
+    route refuses, ``jax.lax.ragged_dot``, which alone is differentiable:
+    the expert stage's backward pass is written out below."""
+    tiling = _kernel_tiles("grouped_matmul", lhs, rhs, rhs.shape[2])
+    if tiling:
+        return pk.gmm(lhs, rhs, group_sizes, tiling=tiling)
     return jax.lax.ragged_dot(lhs, rhs, group_sizes,
                               preferred_element_type=lhs.dtype)
+
+
+def grouped_matmul_t(lhs, rhs, group_sizes):
+    """``out[r] = lhs[r] @ rhs[g].T``: :func:`grouped_matmul` against the
+    groups' matrices transposed, ``rhs`` ``[G, N, K]`` as the forward
+    product holds it (the same kernel reading its tiles the other way; no
+    transposed copy)."""
+    tiling = _kernel_tiles("grouped_matmul_t", lhs, rhs, rhs.shape[1])
+    if tiling:
+        return pk.gmm(lhs, rhs, group_sizes, tiling=tiling,
+                      transpose_rhs=True)
+    return jax.lax.ragged_dot(lhs, rhs.swapaxes(1, 2), group_sizes,
+                              preferred_element_type=lhs.dtype)
+
+
+def grouped_outer(lhs, rhs, group_sizes):
+    """``out[g] = lhs[rows of g].T @ rhs[rows of g]``, ``[G, K, N]`` from
+    ``lhs`` ``[R, K]`` and ``rhs`` ``[R, N]``: the gradient of
+    :func:`grouped_matmul` in its matrices. An empty group's is zero; rows
+    in no group are not read. On the chip ``pallas_kernels.tgmm``
+    (``moe_tgmm``), which contracts over the rows inside the kernel."""
+    tiling = _kernel_tiles("grouped_outer", lhs, rhs, rhs.shape[1],
+                           "outer_tiling")
+    if tiling:
+        return pk.tgmm(lhs, rhs, group_sizes, tiling=tiling)
+    return jax.lax.ragged_dot_general(
+        lhs, rhs, group_sizes, jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]),
+        preferred_element_type=lhs.dtype)
+
+
+def _rows_at(rows: int, top_k: int, order, inverse, group_sizes):
+    """Of the first ``rows`` sorted rows: ``(picked, token, slots, valid)``,
+    the assignment and the token each holds, the way back (``put_rows``),
+    and ``[rows, 1]`` whether the row is in a group here."""
+    picked = order[:rows]
+    here = jnp.sum(group_sizes)
+    # an assignment held elsewhere has no row here, whatever its place in
+    # the sorted order: the rows from ``here`` on are in no group
+    slots = jnp.where(inverse < here, inverse, rows).reshape(-1, top_k)
+    valid = (jnp.arange(rows, dtype=jnp.int32) < here)[:, None]
+    return picked, picked // top_k, slots, valid
 
 
 def _experts_at(rows: int, h, w_in, w_out, weights, order, inverse,
                 group_sizes):
     """The expert stage at a static capacity of ``rows`` sorted rows, which
     must hold every row of the experts here (``sum(group_sizes) <= rows``)."""
-    top_k = weights.shape[1]
     with jax.named_scope("dispatch"):
-        picked = order[:rows]
-        token = picked // top_k
-        here = jnp.sum(group_sizes)
-        # an assignment held elsewhere has no row here, whatever its place
-        # in the sorted order: the rows from ``here`` on are in no group
-        slots = jnp.where(inverse < here, inverse, rows).reshape(-1, top_k)
-        valid = (jnp.arange(rows, dtype=jnp.int32) < here)[:, None]
+        picked, token, slots, valid = _rows_at(rows, weights.shape[1], order,
+                                               inverse, group_sizes)
         x = take_rows(h, token, slots)
     with jax.named_scope("experts"):
         gate, up = jnp.split(grouped_matmul(
@@ -160,11 +231,56 @@ def _experts_at(rows: int, h, w_in, w_out, weights, order, inverse,
                              group_sizes)
     with jax.named_scope("combine"):
         # a row of no group holds whatever the product left there. No slot
-        # points at it, so it reaches no token, forward or backward; it is
-        # zeroed so that it reaches no routing weight's gradient either
+        # points at it, so it reaches no token; it is zeroed all the same
         weighted = jnp.where(valid, out, 0).astype(jnp.float32) \
             * weights.reshape(-1)[picked][:, None]
         return put_rows(weighted.astype(h.dtype), token, slots)
+
+
+def _experts_bwd_at(rows: int, h, w_in, w_out, weights, order, inverse,
+                    group_sizes, dy):
+    """The gradients of :func:`_experts_at` in ``h``, ``w_in``, ``w_out``
+    and ``weights`` for the cotangent ``dy`` of its result, at the same
+    capacity: five grouped products. With ``act = silu(G) * U`` and
+    ``out = act W2`` the forward pass gave ``y = put_rows(w * out)``; here
+    ``g = take_rows(dy)``, ``u = g W2^T``, and the routing weight of a row
+    has the gradient ``<out, g> = <act, u>``, so ``out`` is not computed
+    again. Every product leaves the rows of no group as they were: ``u`` and
+    ``G, U`` meet a sum over a row only under ``valid``, the two
+    weight-gradient products read no such row, and no slot points at one of
+    ``dX``."""
+    dtype = h.dtype
+    wide = jnp.promote_types(dtype, jnp.float32)    # between the products
+    with jax.named_scope("dispatch"):
+        picked, token, slots, valid = _rows_at(rows, weights.shape[1], order,
+                                               inverse, group_sizes)
+        x = take_rows(h, token, slots)
+    with jax.named_scope("combine"):
+        g = take_rows(dy, token, slots)
+        w = weights.reshape(-1)[picked][:, None]
+    with jax.named_scope("experts"):
+        w1, w2 = w_in.astype(dtype), w_out.astype(dtype)
+        # (split, then widen: XLA keeps a widened [rows, 2 f] copy in HBM
+        # rather than fuse the convert into both halves' readers)
+        gate, up = (half.astype(wide) for half in jnp.split(
+            grouped_matmul(x, w1, group_sizes), 2, axis=-1))
+        u = grouped_matmul_t(g, w2, group_sizes).astype(wide)
+        sig = jax.nn.sigmoid(gate)
+        act = (gate * sig * up).astype(dtype).astype(wide)  # the operand of W2
+        d_w = jnp.sum(jnp.where(valid, act * u, 0), axis=-1)
+        d_act = w * u
+        d_gu = jnp.concatenate(
+            [d_act * up * sig * (1 + gate * (1 - sig)), d_act * gate * sig],
+            axis=-1).astype(dtype)
+        d_w2 = grouped_outer((w * act).astype(dtype), g, group_sizes)
+        d_x = grouped_matmul_t(d_gu, w1, group_sizes)
+        d_w1 = grouped_outer(x, d_gu, group_sizes)
+    with jax.named_scope("combine"):
+        d_weights = jnp.concatenate([d_w, jnp.zeros((1,), d_w.dtype)])[slots]
+    with jax.named_scope("dispatch"):
+        d_h = put_rows(d_x, token, slots)
+    return (d_h, d_w1.astype(w_in.dtype), d_w2.astype(w_out.dtype),
+            d_weights.astype(weights.dtype))
 
 
 def _smallest_that_holds(sizes: Tuple[int, ...], group_sizes, fn, *operands):
@@ -183,17 +299,13 @@ def _experts(sizes, h, w_in, w_out, weights, order, inverse, group_sizes):
 
 def _experts_fwd(sizes, *operands):
     # the residuals are the operands: which capacity ran is not a shape the
-    # backward pass may depend on, so it runs the stage again at its own
+    # backward pass may depend on, so it picks its own, the same
     return _experts(sizes, *operands), operands
 
 
 def _experts_bwd(sizes, operands, dy):
-    def at(rows, h, w_in, w_out, weights, order, inverse, group_sizes, dy):
-        _, vjp = jax.vjp(lambda *diff: _experts_at(
-            rows, *diff, order, inverse, group_sizes), h, w_in, w_out, weights)
-        return vjp(dy)
-
-    grads = _smallest_that_holds(sizes, operands[-1], at, *operands, dy)
+    grads = _smallest_that_holds(sizes, operands[-1], _experts_bwd_at,
+                                 *operands, dy)
     return (*grads, None, None, None)
 
 
@@ -209,9 +321,11 @@ def routed_ffn(h, router, bias, w_in, w_out, *, held: Tuple[int, ...],
     scores [N, E], load [E])``: ``load`` counts the tokens each of the ``E``
     experts was chosen by (held or not).
 
-    The backward pass runs the expert stage's forward again (it keeps the
-    layer's inputs and the routing, not the ``[rows, 2 f]`` activations),
-    whatever the model's ``remat``."""
+    The expert stage keeps the layer's inputs and the routing, not the
+    ``[rows, 2 f]`` activations, whatever the model's ``remat``: its
+    backward pass runs the first product again and four more, seven grouped
+    products a step with the forward's two (the module's docstring says
+    which, and which kernel each is)."""
     num_experts = router.shape[-1]
     with jax.named_scope("moe"):
         with jax.named_scope("router"):

@@ -1687,6 +1687,259 @@ def matmul_reduce_scatter(x, w, axis_name):
     return acc
 
 
+# -------------------------------------------------------- grouped products
+# The routed feed-forward's products (ops/moe.py): the rows of ``lhs`` are
+# sorted into groups (one an expert held), ``group_sizes`` ``[G]`` says how
+# many each has, and every group meets its own ``[K, N]`` matrix. The design
+# is ``jax.experimental.pallas.ops.tpu.megablox``'s: the walk over row tiles
+# is computed in XLA and scalar-prefetched, so the grid visits only the
+# tiles a group has rows in (a tile that straddles a group's edge once for
+# each group in it, consecutively, so that its output block stays in VMEM
+# between the visits), and the rows of a visit that are not the group's are
+# masked. What differs: only a visit at a group's edge pays for a mask, one
+# K step keeps no accumulator, and the weight-gradient product contracts
+# over the rows inside the kernel instead of taking a transposed copy.
+
+#: row tiles, in the order ``grouped_route`` tries them. A product of 22,000
+#: rows in 8 groups makes 93 visits of 256 rows, 7 of them a tile's second;
+#: at 512 rows it is 7 of 50, and at 128 the grid steps' fixed cost shows:
+#: 1.96 ms for 2.06 and 1.99 (``x W1`` at the cell's shape, PERF.md, PR 33)
+_GROUP_ROW_TILES = (256, 128)
+#: widest K step and N tile of the row-wise products: the whole K of every
+#: product the cell has (one K step keeps no accumulator and is bit-equal to
+#: ``ragged_dot``) and an N tile of 7 or 8 lane widths. (rows, K, N) as in
+#: ``tiling`` everywhere. Wider N tiles run 1-3% quicker (``x W1`` 1.96 ms
+#: at 1792 for 2.02 at 896) but a kernel's code grows with its tile, a step
+#: holds 112 of them, and a compiled step 16 MB larger loads a second
+#: slower at every start (PERF.md, PR 33)
+_GROUP_TILE_CAPS = (3584, 1024)
+#: the same for the weight-gradient product, whose f32 accumulator is K x N
+_GROUP_OUTER_TILE_CAPS = (1024, 1024)
+
+
+def _lane_tile(x: int, cap: int) -> int:
+    """The largest multiple of the lane width that divides ``x`` and is at
+    most ``cap``; ``x`` must be a multiple of the lane width."""
+    return max(t for t in range(_LANES, min(x, cap) + 1, _LANES) if x % t == 0)
+
+
+def grouped_route(rows: int, k: int, n: int, itemsize: int) -> dict:
+    """Which path a grouped product of ``rows`` rows, ``lhs`` width ``k``
+    and other width ``n`` takes, and at which tiles; the dispatchers
+    (``ops/moe.py``) and the tests both read it. ``path`` is ``pallas`` or
+    ``reference`` (``jax.lax.ragged_dot``: rows no row tile divides, a width
+    that is not whole lanes, an element that is not 2 or 4 bytes);
+    ``tiling`` the (rows, K, N) tiles of the row-wise products
+    (:func:`gmm`), ``outer_tiling`` those of the weight-gradient product
+    (:func:`tgmm`). No JAX."""
+    tm = next((t for t in _GROUP_ROW_TILES if rows % t == 0), None)
+    if tm is None or k % _LANES or n % _LANES or itemsize not in (2, 4):
+        return {"path": "reference", "tiling": None, "outer_tiling": None}
+    return {"path": "pallas",
+            "tiling": (tm, _lane_tile(k, _GROUP_TILE_CAPS[0]),
+                       _lane_tile(n, _GROUP_TILE_CAPS[1])),
+            "outer_tiling": (tm, _lane_tile(k, _GROUP_OUTER_TILE_CAPS[0]),
+                             _lane_tile(n, _GROUP_OUTER_TILE_CAPS[1]))}
+
+
+def _grouped_ok(lhs, n: int, rhs) -> bool:
+    return lhs.dtype == rhs.dtype and grouped_route(
+        *lhs.shape, n, lhs.dtype.itemsize)["path"] == "pallas"
+
+
+def _group_visits(group_sizes, rows: int, tm: int, visit_empty: bool):
+    """The walk over row tiles: ``(offsets [G + 1], group [V], tile [V],
+    visits)``. Visit ``i`` is row tile ``tile[i]`` for group ``group[i]``,
+    whose rows are ``offsets[g] .. offsets[g + 1]``; the first ``visits``
+    (a traced count, at most ``V = rows / tm + G - 1``) are real. A group's
+    visits are consecutive and so are a tile's. ``visit_empty`` gives an
+    empty group one visit (of a tile it has no row in), for a kernel that
+    must write the group's output all the same."""
+    groups = group_sizes.shape[0]
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    first = starts // tm
+    tiles = jnp.where(group_sizes == 0, int(visit_empty),
+                      (ends + tm - 1) // tm - first)
+    most = rows // tm + groups - 1
+    group = jnp.repeat(jnp.arange(groups, dtype=jnp.int32), tiles,
+                       total_repeat_length=most)
+    nth = jnp.arange(most, dtype=jnp.int32) - (jnp.cumsum(tiles) - tiles)[group]
+    tile = jnp.clip(first[group] + nth, 0, rows // tm - 1)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return (offsets.astype(jnp.int32), group, tile.astype(jnp.int32),
+            jnp.sum(tiles).astype(jnp.int32))
+
+
+def _visit_rows(offsets_ref, group_ref, tile_ref, visit, tm: int):
+    """Of visit ``visit``: ``(lo, hi, row0)``, its group's row range and the
+    first row of its tile."""
+    g = group_ref[visit]
+    return offsets_ref[g], offsets_ref[g + 1], tile_ref[visit] * tm
+
+
+def _rows_mask(lo, hi, row0, tm: int):
+    rows = row0 + lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    return jnp.logical_and(rows >= lo, rows < hi)
+
+
+def _gmm_kernel(offsets_ref, group_ref, tile_ref, lhs_ref, rhs_ref, out_ref,
+                *acc, tm, k_steps, transpose_rhs):
+    visit, step = pl.program_id(1), pl.program_id(2)
+    part = lax.dot_general(
+        lhs_ref[...], rhs_ref[...],
+        (((1,), (1 if transpose_rhs else 0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if k_steps > 1:
+        acc_ref, = acc
+
+        @pl.when(step == 0)
+        def _first():
+            acc_ref[...] = part
+
+        @pl.when(step > 0)
+        def _add():
+            acc_ref[...] += part
+
+    @pl.when(step == k_steps - 1)
+    def _store():
+        total = acc[0][...] if k_steps > 1 else part
+        lo, hi, row0 = _visit_rows(offsets_ref, group_ref, tile_ref, visit,
+                                   tm)
+        whole = jnp.logical_and(lo <= row0, hi >= row0 + tm)
+
+        @pl.when(whole)
+        def _all_rows():
+            out_ref[...] = total.astype(out_ref.dtype)
+
+        @pl.when(jnp.logical_not(whole))
+        def _the_groups_rows():
+            # the other rows keep what an earlier visit of this tile wrote
+            out_ref[...] = jnp.where(
+                _rows_mask(lo, hi, row0, tm), total,
+                out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+
+
+def gmm(lhs, rhs, group_sizes, *, tiling, transpose_rhs: bool = False):
+    """``out[r] = lhs[r] @ rhs[g]`` (``@ rhs[g].T`` under ``transpose_rhs``)
+    for row ``r`` in group ``g``: ``lhs`` ``[R, K]``, ``rhs`` ``[G, K, N]``
+    (``[G, N, K]``), ``group_sizes`` ``[G]`` int32; f32 accumulation,
+    ``lhs.dtype`` out. Rows past ``sum(group_sizes)`` are not computed: they
+    hold whatever the buffer held. ``tiling`` is ``grouped_route``'s, which
+    also says whether the shape may come here at all."""
+    return _gmm(lhs, rhs, group_sizes, tiling, transpose_rhs, _interpret())
+
+
+# Jitted, here and for ``tgmm``: a step calls each kernel at 8 layers x 2
+# row capacities, and under ``jax.jit`` the kernel's body is traced and
+# lowered to Mosaic once a shape, not once a call site (112 a step: 16 s of
+# every warm start, PERF.md, PR 33). A call site keeps its own scope path.
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _gmm(lhs, rhs, group_sizes, tiling, transpose_rhs, interpret):
+    rows, k = lhs.shape
+    n = rhs.shape[1 if transpose_rhs else 2]
+    tm, tk, tn = tiling
+    k_steps = k // tk
+    offsets, group, tile, visits = _group_visits(group_sizes, rows, tm, False)
+    rhs_block = (None, tn, tk) if transpose_rhs else (None, tk, tn)
+    return _named_call(
+        "moe_gmm",
+        functools.partial(_gmm_kernel, tm=tm, k_steps=k_steps,
+                          transpose_rhs=transpose_rhs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n // tn, visits, k_steps),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda j, i, s, o, g, t: (t[i], s)),
+                pl.BlockSpec(rhs_block, (
+                    lambda j, i, s, o, g, t: (g[i], j, s)) if transpose_rhs
+                    else lambda j, i, s, o, g, t: (g[i], s, j))],
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda j, i, s, o, g, t: (t[i], j)),
+            scratch_shapes=([pltpu.VMEM((tm, tn), jnp.float32)]
+                            if k_steps > 1 else [])),
+        out_shape=_struct((rows, n), lhs.dtype, lhs, rhs),
+        compiler_params=_cparams("parallel", "arbitrary", "arbitrary",
+                                 resident=True),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * rows * k * n, transcendentals=0,
+            bytes_accessed=lhs.dtype.itemsize * (
+                rows * k * (n // tn) + group_sizes.shape[0] * k * n
+                + rows * n)),
+        interpret=interpret,
+    )(offsets, group, tile, lhs, rhs)
+
+
+def _tgmm_kernel(offsets_ref, group_ref, tile_ref, lhs_ref, rhs_ref, out_ref,
+                 acc_ref, *, tm):
+    visit, last = pl.program_id(2), pl.num_programs(2) - 1
+    g = group_ref[visit]
+    lo, hi, row0 = _visit_rows(offsets_ref, group_ref, tile_ref, visit, tm)
+
+    @pl.when(jnp.logical_or(
+        visit == 0, group_ref[jnp.maximum(visit - 1, 0)] != g))
+    def _first_of_group():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    whole = jnp.logical_and(lo <= row0, hi >= row0 + tm)
+
+    @pl.when(whole)
+    def _all_rows():
+        acc_ref[...] += _dot_tn(lhs_ref[...], rhs_ref[...])
+
+    @pl.when(jnp.logical_and(jnp.logical_not(whole), hi > lo))
+    def _the_groups_rows():
+        # both sides: a row of no group may hold anything, NaN too
+        mask = _rows_mask(lo, hi, row0, tm)
+        lhs, rhs = lhs_ref[...], rhs_ref[...]
+        acc_ref[...] += _dot_tn(jnp.where(mask, lhs, jnp.zeros_like(lhs)),
+                                jnp.where(mask, rhs, jnp.zeros_like(rhs)))
+
+    @pl.when(jnp.logical_or(
+        visit == last, group_ref[jnp.minimum(visit + 1, last)] != g))
+    def _last_of_group():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def tgmm(lhs, rhs, group_sizes, *, tiling):
+    """``out[g] = lhs[rows of g].T @ rhs[rows of g]``: ``lhs`` ``[R, K]``,
+    ``rhs`` ``[R, N]``, both with their rows in group order, ``out``
+    ``[G, K, N]`` in ``lhs.dtype`` with f32 accumulation; an empty group's
+    is zero, and rows past ``sum(group_sizes)`` are not read into any."""
+    return _tgmm(lhs, rhs, group_sizes, tiling, _interpret())
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _tgmm(lhs, rhs, group_sizes, tiling, interpret):
+    rows, k = lhs.shape
+    n = rhs.shape[1]
+    tm, tk, tn = tiling
+    groups = group_sizes.shape[0]
+    offsets, group, tile, visits = _group_visits(group_sizes, rows, tm, True)
+    return _named_call(
+        "moe_tgmm",
+        functools.partial(_tgmm_kernel, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n // tn, k // tk, visits),
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda j, s, i, o, g, t: (t[i], s)),
+                pl.BlockSpec((tm, tn), lambda j, s, i, o, g, t: (t[i], j))],
+            out_specs=pl.BlockSpec((None, tk, tn),
+                                   lambda j, s, i, o, g, t: (g[i], s, j)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+        out_shape=_struct((groups, k, n), lhs.dtype, lhs, rhs),
+        compiler_params=_cparams("parallel", "arbitrary", "arbitrary",
+                                 resident=True),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * rows * k * n, transcendentals=0,
+            bytes_accessed=lhs.dtype.itemsize * (
+                rows * k * (n // tn) + rows * n * (k // tk)
+                + groups * k * n)),
+        interpret=interpret,
+    )(offsets, group, tile, lhs, rhs)
+
+
 # ------------------------------------------------------------- path gates
 # dispatcher name -> shape gate over the dispatcher's operands; the only
 # reader is kernel_path above (which adds the mode and vma conditions)
@@ -1701,4 +1954,9 @@ _GATES = {
     "matmul": lambda x2, w2: matmul_tiles(
         x2.shape[0], x2.shape[1], w2.shape[1]) is not None,
     "matmul_reduce_scatter": lambda x, w, m: m > 1 and x.shape[0] % m == 0,
+    # lhs [R, K] and the group matrices [G, K, N] ([G, N, K] transposed) or,
+    # for the weight-gradient product, the other row operand [R, N]
+    "grouped_matmul": lambda lhs, rhs: _grouped_ok(lhs, rhs.shape[2], rhs),
+    "grouped_matmul_t": lambda lhs, rhs: _grouped_ok(lhs, rhs.shape[1], rhs),
+    "grouped_outer": lambda lhs, rhs: _grouped_ok(lhs, rhs.shape[1], rhs),
 }

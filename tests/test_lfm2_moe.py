@@ -23,7 +23,7 @@ from chipbench.families import lfm2_moe as family
 from horovod_tpu import spmd
 from horovod_tpu.models import hybrid
 from horovod_tpu.models.transformer import lm_loss
-from horovod_tpu.ops import moe
+from horovod_tpu.ops import moe, pallas_kernels as pk
 
 #: the configuration keys the family and the reference read, small
 CONFIG = {"num_hidden_layers": 5,
@@ -301,6 +301,198 @@ def test_grouped_matmul_is_a_loop_over_the_experts_with_an_empty_group():
     assert relative(g_got[0][:32], g_want[0][:32]) <= 1e-6
     assert relative(g_got[1], g_want[1]) <= 1e-6
     assert not np.any(np.asarray(g_got[1][1]))          # the empty group's
+
+
+# ------------------------------------- the Pallas grouped products (PR 33)
+#: rows x K x N tiles: one K step and two, one visit a row tile and edges
+_TILINGS = [(128, 256, 128), (256, 128, 384), (512, 128, 128)]
+#: 384 of 512 rows in the groups; an empty group first, in the middle (at a
+#: tile's edge and inside one) and last
+_SIZES = [[0, 100, 0, 156, 28, 0, 100, 0], [128, 0, 0, 256, 0, 0, 0, 0]]
+
+
+def _grouped_operands(sizes, k=256, n=384):
+    """``lhs [512, k]``, the groups' matrices both ways round, a second row
+    operand, and the groups' bounds: the rows of no group are NaN."""
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    here = sum(sizes)
+    poison = (jnp.arange(512) >= here)[:, None]
+    f32 = jnp.float32       # the suite's default is 64 bits: no kernel's
+    lhs = jnp.where(poison, jnp.nan, jax.random.normal(keys[0], (512, k), f32))
+    other = jnp.where(poison, jnp.nan,
+                      jax.random.normal(keys[1], (512, n), f32))
+    return (lhs, jax.random.normal(keys[2], (len(sizes), k, n), f32),
+            jax.random.normal(keys[3], (len(sizes), n, k), f32), other,
+            np.concatenate([[0], np.cumsum(sizes)]))
+
+
+@pytest.mark.parametrize("sizes", _SIZES, ids=["edges", "aligned"])
+@pytest.mark.parametrize("tiling", _TILINGS, ids=str)
+@pytest.mark.parametrize("product", ["gmm", "gmm_transposed", "tgmm"])
+def test_the_grouped_kernels_are_loops_over_the_experts(product, tiling,
+                                                        sizes, monkeypatch):
+    """``pallas_kernels.gmm`` / ``tgmm`` through the interpreter against the
+    loop over the groups, with empty groups and the rows past the groups
+    NaN: none reaches a row of a group or a group's matrix."""
+    monkeypatch.setenv("HVD_PALLAS", "interpret")
+    lhs, rhs, rhs_t, other, bounds = _grouped_operands(sizes)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    spans = list(zip(bounds[:-1], bounds[1:]))
+    with jax.default_matmul_precision("highest"):
+        if product == "tgmm":
+            got = pk.tgmm(lhs, other, group_sizes, tiling=tiling)
+            want = jnp.stack([lhs[a:b].T @ other[a:b] for a, b in spans])
+            assert not np.any(np.asarray(got[2]))       # an empty group's
+        else:
+            flip = product == "gmm_transposed"
+            got = pk.gmm(lhs, rhs_t if flip else rhs, group_sizes,
+                         tiling=tiling, transpose_rhs=flip)[:bounds[-1]]
+            want = jnp.concatenate([
+                lhs[a:b] @ (rhs_t[g].T if flip else rhs[g])
+                for g, (a, b) in enumerate(spans)])
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert relative(got, want) <= 1e-6
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+def test_the_three_dispatchers_agree_with_the_loop_on_either_path(
+        mode, monkeypatch):
+    """``grouped_matmul``, ``grouped_matmul_t`` and ``grouped_outer`` off
+    the chip (``ragged_dot``) and through the kernels, at a shape the route
+    takes."""
+    monkeypatch.setenv("HVD_PALLAS", mode)
+    sizes = _SIZES[0]
+    lhs, rhs, rhs_t, other, bounds = _grouped_operands(sizes)
+    assert pk.kernel_path("grouped_matmul", lhs, rhs) == (
+        "pallas" if mode == "interpret" else "reference")
+    lhs, other = jnp.nan_to_num(lhs), jnp.nan_to_num(other)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    spans = list(zip(bounds[:-1], bounds[1:]))
+    with jax.default_matmul_precision("highest"):
+        for got, want in [
+                (moe.grouped_matmul(lhs, rhs, group_sizes)[:bounds[-1]],
+                 jnp.concatenate([lhs[a:b] @ rhs[g]
+                                  for g, (a, b) in enumerate(spans)])),
+                (moe.grouped_matmul_t(lhs, rhs_t, group_sizes)[:bounds[-1]],
+                 jnp.concatenate([lhs[a:b] @ rhs_t[g].T
+                                  for g, (a, b) in enumerate(spans)])),
+                (moe.grouped_outer(lhs, other, group_sizes),
+                 jnp.stack([lhs[a:b].T @ other[a:b] for a, b in spans]))]:
+            assert relative(got, want) <= 1e-6
+
+
+@pytest.mark.parametrize("rows,k,n,itemsize,path,tiling", [
+    (32768, 2048, 3584, 2, "pallas", (256, 2048, 896)),    # x W1, the cell's
+    (65536, 3584, 2048, 2, "pallas", (256, 3584, 1024)),   # dGU W1^T
+    (384, 256, 128, 4, "pallas", (128, 256, 128)),
+    (144, 256, 128, 4, "reference", None),      # rows no row tile divides
+    (512, 64, 128, 2, "reference", None),       # K not whole lanes
+    (512, 128, 192, 2, "reference", None),      # N not whole lanes
+    (512, 128, 128, 1, "reference", None),      # 8-bit operands
+])
+def test_grouped_route_is_what_the_dispatchers_follow(rows, k, n, itemsize,
+                                                      path, tiling,
+                                                      monkeypatch):
+    route = pk.grouped_route(rows, k, n, itemsize)
+    assert (route["path"], route["tiling"]) == (path, tiling)
+    if path == "pallas":
+        for tile, whole in zip(route["outer_tiling"], (rows, k, n)):
+            assert whole % tile == 0
+    dtype = {1: jnp.int8, 2: jnp.bfloat16, 4: jnp.float32}[itemsize]
+    lhs = jax.ShapeDtypeStruct((rows, k), dtype)
+    operands = {"grouped_matmul": jax.ShapeDtypeStruct((8, k, n), dtype),
+                "grouped_matmul_t": jax.ShapeDtypeStruct((8, n, k), dtype),
+                "grouped_outer": jax.ShapeDtypeStruct((rows, n), dtype)}
+    for name, rhs in operands.items():
+        # off the chip (this run) the reference whatever the shape; with the
+        # kernels on, what the route says
+        assert pk.kernel_path(name, lhs, rhs) == "reference"
+        monkeypatch.setenv("HVD_PALLAS", "interpret")
+        assert pk.kernel_path(name, lhs, rhs) == path
+        monkeypatch.delenv("HVD_PALLAS")
+    # operands of two dtypes: the reference, which promotes them
+    assert pk.kernel_path(
+        "grouped_matmul", jax.ShapeDtypeStruct((512, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((8, 128, 128), jnp.float32)) == "reference"
+
+
+def _stage_operands(held, n=256, d=128, f=128, top_k=2, seed=11):
+    """The operands of ``moe._experts`` for 256 tokens over 8 experts, at
+    widths the kernels' route takes (whole lanes; both capacities whole row
+    tiles), routed as the layer routes them."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    f32 = jnp.float32       # the suite's default is 64 bits: no kernel's
+    h = jax.random.normal(keys[0], (n, d), f32)
+    logits = h @ (0.5 * jax.random.normal(keys[1], (d, 8), f32))
+    # one expert held, and every token sent to it: past the smaller capacity
+    bias = jnp.zeros((8,)).at[jnp.asarray(held)].set(
+        10.0 if len(held) == 1 else 0.0)
+    chosen, weights, _ = moe.route(logits, bias, top_k)
+    order, inverse, group_sizes = moe.dispatch(chosen, held, 8)
+    return (h, 0.2 * jax.random.normal(keys[2], (len(held), d, 2 * f), f32),
+            0.2 * jax.random.normal(keys[3], (len(held), f, d), f32), weights,
+            order, inverse, group_sizes)
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+@pytest.mark.parametrize("held,index", [((1, 4), 0), ((4,), 1)],
+                         ids=["smaller", "worst-case"])
+def test_the_hand_written_backward_pass_is_autodiff_of_the_ragged_dot_path(
+        held, index, mode, monkeypatch):
+    """``_experts``' gradients (the layer's ``custom_vjp``: five grouped
+    products, the second forward product not run again) in ``h``, ``w_in``,
+    ``w_out`` and the routing weights against ``jax.vjp`` of the forward
+    stage on the ``ragged_dot`` path, at each of the two capacities, off the
+    chip and through the kernels."""
+    operands = _stage_operands(held)
+    sizes = moe.capacities(256 * 2, len(held), 8)
+    assert sizes == ((256, 512), (128, 512))[index]
+    here = int(jnp.sum(operands[-1]))
+    assert sum(here > s for s in sizes[:-1]) == index, (here, sizes)
+    rows = sizes[index]
+    dy = jnp.cos(jnp.arange(256 * 128, dtype=jnp.float32).reshape(256, 128))
+    with jax.default_matmul_precision("highest"):
+        want_y, vjp = jax.vjp(lambda *diff: moe._experts_at(
+            rows, *diff, *operands[4:]), *operands[:4])
+        want = vjp(dy)
+        monkeypatch.setenv("HVD_PALLAS", mode)
+        assert pk.kernel_path("grouped_matmul", operands[0][:1].repeat(
+            rows, 0), operands[1]) == ("pallas" if mode == "interpret"
+                                       else "reference")
+        got_y, vjp = jax.vjp(lambda *diff: moe._experts(
+            sizes, *diff, *operands[4:]), *operands[:4])
+        got = vjp(dy)
+    assert relative(got_y, want_y) <= 1e-6
+    for name, a, b in zip(("h", "w_in", "w_out", "weights"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert float(jnp.max(jnp.abs(b))) > 0, name
+        assert relative(a, b) <= 2e-6, (name, relative(a, b))
+
+
+def test_the_backward_pass_reads_no_row_of_no_group(monkeypatch):
+    """Every one of the stage's seven products leaves the rows past its
+    groups as they were; here each returns them NaN (and the two
+    weight-gradient products are handed NaN there), at a capacity with rows
+    to spare: no gradient sees one."""
+    sound = {name: getattr(moe, name) for name in
+             ("grouped_matmul", "grouped_matmul_t", "grouped_outer")}
+
+    def spoil(out, sizes):
+        rows = jnp.arange(out.shape[0])[:, None]
+        return jnp.where(rows < jnp.sum(sizes), out, jnp.nan)
+
+    ops, held = layer_operands(), (1, 4, 6)
+    want = jax.grad(lambda o: jnp.sum(jnp.sin(reference_layer(o, held))))(ops)
+    for name in ("grouped_matmul", "grouped_matmul_t"):
+        monkeypatch.setattr(moe, name, lambda lhs, rhs, sizes, name=name:
+                            spoil(sound[name](lhs, rhs, sizes), sizes))
+    monkeypatch.setattr(moe, "grouped_outer", lambda lhs, rhs, sizes: sound[
+        "grouped_outer"](spoil(lhs, sizes), spoil(rhs, sizes), sizes))
+    got = jax.grad(lambda o: jnp.sum(jnp.sin(routed(o, held)[0])))(ops)
+    for name in ("h", "router", "w_in", "w_out", "bias"):
+        assert bool(jnp.all(jnp.isfinite(got[name]))), name
+        assert float(jnp.max(jnp.abs(got[name] - want[name]))) <= 1e-5, name
 
 
 def test_take_and_put_rows_are_each_others_transposes():

@@ -304,13 +304,15 @@ def test_train_step_at_1024_positions_compiles_for_v5e(remat, v5e_devices):
 def test_routed_feed_forward_compiles_for_v5e_at_the_published_widths(
         v5e_devices):
     """``ops/moe.routed_ffn`` forward and backward at LFM2-8B-A1B's widths
-    and the cell's 16,384 tokens: the grouped products become the TPU
-    compiler's own kernels (two forward; two recomputed and four gradient
-    products backward) in each of the three row capacities, and nothing of
-    the layer is a dense product over experts x tokens."""
+    and the cell's 16,384 tokens: the grouped products are the Pallas
+    kernels ``moe_gmm`` / ``moe_tgmm``, seven a row capacity (two forward;
+    one recomputed, two against the transposed matrices and two
+    weight-gradient products in the hand-written backward pass), none is
+    XLA's ragged-dot kernel, and nothing of the layer is a dense product
+    over experts x tokens."""
     from jax.sharding import SingleDeviceSharding
 
-    from horovod_tpu.ops import moe
+    from horovod_tpu.ops import moe, pallas_kernels as pk
 
     tokens, d, width, experts, held, top_k = 16384, 2048, 1792, 32, 8, 4
     one = SingleDeviceSharding(v5e_devices[0])
@@ -323,6 +325,11 @@ def test_routed_feed_forward_compiles_for_v5e_at_the_published_widths(
                            held=tuple(range(held)), top_k=top_k)[0]
         return jnp.sum(y.astype(jnp.float32) ** 2)
 
+    sizes = moe.capacities(tokens * top_k, held, experts)
+    assert sizes == (32768, 65536)
+    for rows in sizes:
+        for k, n in ((d, 2 * width), (width, d), (2 * width, d)):
+            assert pk.grouped_route(rows, k, n, 2)["path"] == "pallas"
     with jax.enable_x64(False):
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4))).trace(
             shape((tokens, d), jnp.bfloat16), shape((d, experts), jnp.float32),
@@ -330,10 +337,11 @@ def test_routed_feed_forward_compiles_for_v5e_at_the_published_widths(
             shape((held, d, 2 * width), jnp.float32),
             shape((held, width, d), jnp.float32)).lower(
                 lowering_platforms=("tpu",)).compile().as_text()
-    sizes = moe.capacities(tokens * top_k, held, experts)
-    assert sizes == (32768, 65536)
-    products = len(re.findall(r"%ragged-dot-none[.\d]* = ", text))
-    assert products == len(sizes) * (2 + 2 + 4), products
+    assert "ragged-dot" not in text
+    calls = re.findall(r"%(moe_t?gmm)[.\d]* = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert calls.count("moe_gmm") == len(sizes) * (2 + 3), calls
+    assert calls.count("moe_tgmm") == len(sizes) * 2, calls
     for rows in sizes:
         assert f"bf16[{rows},{2 * width}]" in text
     # no [experts, tokens, d] or [tokens, experts, d] operand anywhere
