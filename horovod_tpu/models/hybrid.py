@@ -3,25 +3,32 @@ mixers (Mamba-2, attention, gated short convolution) and feed-forwards
 (SwiGLU, routed experts).
 
 ``TransformerLM`` is one recipe. Here a model is a tuple of per-layer mixer
-kinds (``"mamba"`` | ``"attention"`` | ``"short_conv"``), a tuple of
-per-layer feed-forward kinds (``"swiglu"`` | ``"moe"``) and the widths of
-each; every block is
+kinds (``"mamba"`` | ``"attention"`` | ``"short_conv"`` | ``"none"``), a
+tuple of per-layer feed-forward kinds (``"swiglu"`` | ``"moe"`` |
+``"none"``) and the widths of each; every block is
 
     x = x + r * mixer(RMSNorm(x));    x = x + r * ffn(RMSNorm(x))
 
-and the model is ``tok_emb[t] * e`` -> blocks -> RMSNorm -> tied head ``/ s``
-(``r``, ``e``, ``s`` and the attention scale are Granite 4.0-H's four
-multipliers; each is 1, and the scale ``head_dim ** -0.5``, unless given).
+or, where one of its two kinds is ``"none"``, the other sublayer alone
+under its own norm (Nemotron-H: every block is one sublayer), and the model
+is ``tok_emb[t] * e`` -> blocks -> RMSNorm -> head ``/ s``, the head the
+table itself or, untied, a matrix of its own (``r``, ``e``, ``s`` and the
+attention scale are Granite 4.0-H's four multipliers; each is 1, and the
+scale ``head_dim ** -0.5``, unless given).
 
 * The attention mixer has grouped KV heads and runs the same flash kernels
   as ``TransformerLM``. Its position kind is ``"none"`` (Granite 4.0-H: the
   state-space layers carry the order) or ``"rope"`` (``ops/rope.py``), and
   it can normalise q and k per head before that (LFM2).
-* The Mamba-2 mixer is ``ops/ssd.py``; the gated short convolution (LFM2's
+* The Mamba-2 mixer is ``ops/ssd.py``, with one group of ``B`` and ``C``
+  or several; the gated short convolution (LFM2's
   ``conv`` layer) is ``W_out (C * conv(B * u))`` over ``[B, C, u] = W_in h``
   with the same depthwise causal conv.
 * The routed feed-forward is ``ops/moe.py``: sigmoid top-k routing that
-  drops no token, told which experts it holds.
+  drops no token, told which experts it holds. Its experts are SwiGLU or
+  squared-ReLU, read the block's input or a latent of it (projected down
+  before them and up after their sum), and may stand beside a shared
+  expert that every token passes.
 
 bf16 compute and f32 parameters, ``remat=`` with ``TransformerLM``'s three
 names and policies, and the module names the trace's scope classes read
@@ -50,12 +57,13 @@ def _dense(features: int, dtype, name: str) -> nn.Dense:
 
 class GatedRMSNorm(nn.Module):
     eps: float
+    groups: int = 1
 
     @nn.compact
     def __call__(self, y, gate):
         scale = self.param("scale", nn.initializers.ones, (y.shape[-1],),
                            jnp.float32)
-        return ssd.gated_rms_norm(y, gate, scale, self.eps)
+        return ssd.gated_rms_norm(y, gate, scale, self.eps, self.groups)
 
 
 def _conv_kernel_init(width: int):
@@ -86,7 +94,9 @@ class CausalConv(nn.Module):
 class MambaMixer(nn.Module):
     """Mamba-2: one projection to gate ``z``, ``[x, B, C]`` and ``dt``; a
     causal conv and SiLU over ``[x, B, C]``; the scan; the gated norm; the
-    output projection. One group of ``B`` and ``C``, shared by the heads."""
+    output projection. ``groups`` groups of ``B`` and ``C``, each shared by
+    ``heads / groups`` consecutive heads, and the gated norm over as many
+    groups of channels."""
     heads: int
     head_dim: int
     state: int
@@ -94,17 +104,19 @@ class MambaMixer(nn.Module):
     chunk: int
     eps: float
     dtype: Any
+    groups: int = 1
 
     @nn.compact
     def __call__(self, h):
         b, t, d_model = h.shape
-        inner = self.heads * self.head_dim
+        inner, bc = self.heads * self.head_dim, self.groups * self.state
         z, xbc, dt = jnp.split(
-            _dense(2 * inner + 2 * self.state + self.heads, self.dtype,
-                   "in_proj")(h),
-            [inner, 2 * inner + 2 * self.state], axis=-1)
+            _dense(2 * inner + 2 * bc + self.heads, self.dtype, "in_proj")(h),
+            [inner, 2 * inner + 2 * bc], axis=-1)
         xbc = nn.silu(CausalConv(self.conv_width, name="conv")(xbc))
-        x, B, C = jnp.split(xbc, [inner, inner + self.state], axis=-1)
+        x, B, C = jnp.split(xbc, [inner, inner + bc], axis=-1)
+        if self.groups > 1:
+            B, C = (a.reshape(b, t, self.groups, self.state) for a in (B, C))
 
         def per_head(name, init):
             return self.param(name, lambda *_: init, (self.heads,))
@@ -116,7 +128,8 @@ class MambaMixer(nn.Module):
         y = ssd.ssd_chunked(x.reshape(b, t, self.heads, self.head_dim), dt,
                             -jnp.exp(a_log), B, C, per_head("D", ones),
                             chunk=self.chunk)
-        y = GatedRMSNorm(self.eps, name="gate_norm")(y.reshape(b, t, inner), z)
+        y = GatedRMSNorm(self.eps, self.groups, name="gate_norm")(
+            y.reshape(b, t, inner), z)
         return _dense(d_model, self.dtype, "out_proj")(y)
 
 
@@ -200,52 +213,94 @@ class AttentionMixer(nn.Module):
             out.astype(self.dtype).reshape(b, t, self.heads * self.head_dim))
 
 
+def _activate(kind: str, pre):
+    """``"swiglu"``: ``silu(a) * b`` of ``pre = [a, b]``; ``"relu2"``:
+    ``relu(pre) ** 2``."""
+    if kind == "relu2":
+        return jnp.square(nn.relu(pre))
+    gate, up = jnp.split(pre, 2, axis=-1)
+    return nn.silu(gate) * up
+
+
 class RoutedFeedForward(nn.Module):
     """``ops/moe.routed_ffn`` with its parameters: the router over all
-    ``experts`` and its selection bias (float32), and the SwiGLU weights of
-    the experts ``held`` here, ``width`` wide. Sows the experts each token
-    chose, their scores and the tokens an expert (``intermediates``: free
-    unless asked for)."""
+    ``experts`` and its selection bias (float32), and the weights of the
+    experts ``held`` here, ``width`` wide, of kind ``activation``
+    (``"swiglu"`` | ``"relu2"``: ``W2 relu(W1 u) ** 2``, no gate). With
+    ``latent`` the experts read ``latent_in(h)``, that wide, and their
+    weighted sum goes through ``latent_out`` back to the model's width; the
+    router reads ``h`` all the same. With ``shared_width`` a shared expert
+    of that width and the same kind (``shared_in``, ``shared_out``), which
+    every token passes, is added. A routed token's weights sum to
+    ``scale``, their sum taking ``norm_eps`` before it divides. Sows the
+    experts each token chose, their scores and the tokens an expert
+    (``intermediates``: free unless asked for)."""
     experts: int
     held: Tuple[int, ...]
     top_k: int
     width: int
     dtype: Any
+    activation: str = "swiglu"
+    latent: int = 0
+    shared_width: int = 0
+    scale: float = 1.0
+    norm_eps: float = moe.NORM_EPS
 
     @nn.compact
     def __call__(self, h):
         b, t, d = h.shape
         init, n_held = nn.initializers.normal(0.02), len(self.held)
+        sides = 2 if self.activation == "swiglu" else 1
+        inner = self.latent or d
+        x = _dense(inner, self.dtype, "latent_in")(h).reshape(b * t, inner) \
+            if self.latent else None
         y, chosen, scores, load = moe.routed_ffn(
             h.reshape(b * t, d),
             self.param("router", init, (d, self.experts), jnp.float32),
             self.param("expert_bias", nn.initializers.zeros, (self.experts,),
                        jnp.float32),
-            self.param("w_in", init, (n_held, d, 2 * self.width), jnp.float32),
-            self.param("w_out", init, (n_held, self.width, d), jnp.float32),
-            held=self.held, top_k=self.top_k)
+            self.param("w_in", init, (n_held, inner, sides * self.width),
+                       jnp.float32),
+            self.param("w_out", init, (n_held, self.width, inner),
+                       jnp.float32),
+            held=self.held, top_k=self.top_k, x=x,
+            activation=self.activation, scale=self.scale,
+            norm_eps=self.norm_eps)
         self.sow("intermediates", "chosen", chosen.reshape(b, t, self.top_k))
         self.sow("intermediates", "scores", scores.reshape(b, t, self.experts))
         self.sow("intermediates", "load", load)
-        return y.reshape(b, t, d)
+        y = y.reshape(b, t, inner)
+        if self.latent:
+            y = _dense(d, self.dtype, "latent_out")(y)
+        if self.shared_width:
+            y = y + _dense(d, self.dtype, "shared_out")(_activate(
+                self.activation, _dense(sides * self.shared_width, self.dtype,
+                                        "shared_in")(h)))
+        return y
 
 
 class HybridBlock(nn.Module):
-    mixer: Callable[..., nn.Module]     # makes the block's mixer, given name=
+    """A mixer and a feed-forward, each a residual sublayer under its own
+    norm, or one of the two alone."""
+    mixer: Optional[Callable[..., nn.Module]]   # makes it, given name=
+    ffn: str                            # "swiglu" | "moe" | "none"
     ffn_width: int                      # of the SwiGLU feed-forward
     residual_multiplier: float
     eps: float
     dtype: Any
-    routed_ffn: Optional[Callable[..., nn.Module]] = None   # in its place
+    routed_ffn: Optional[Callable[..., nn.Module]] = None   # makes the "moe"
 
     @nn.compact
     def __call__(self, x):
         norm = partial(nn.RMSNorm, epsilon=self.eps, dtype=self.dtype,
                        param_dtype=jnp.float32)
-        h = self.mixer(name="mixer")(norm(name="norm_mixer")(x))
-        x = x + self.residual_multiplier * h
+        if self.mixer is not None:
+            h = self.mixer(name="mixer")(norm(name="norm_mixer")(x))
+            x = x + self.residual_multiplier * h
+        if self.ffn == "none":
+            return x
         h = norm(name="norm_ffn")(x)
-        if self.routed_ffn is not None:
+        if self.ffn == "moe":
             h = self.routed_ffn(name="ffn")(h)
         else:
             gate, up = jnp.split(
@@ -258,6 +313,7 @@ class HybridLM(nn.Module):
     """Tokens ``[B, T]`` -> float32 logits ``[B, T, vocab_size]``."""
     vocab_size: int
     layer_kinds: Tuple[str, ...]        # "mamba" | "attention" | "short_conv"
+                                        # | "none": a feed-forward block
     d_model: int
     ffn_width: int                      # of a "swiglu" feed-forward
     attn_heads: int
@@ -279,11 +335,19 @@ class HybridLM(nn.Module):
     attn_rope_theta: float = 1e4
     attn_qk_norm: bool = False          # RMSNorm on q and k, per head
     conv_width: int = 3                 # the "short_conv" layers' kernel
-    ffn_kinds: Tuple[str, ...] = ()     # "swiglu" | "moe"; () = all "swiglu"
+    ffn_kinds: Tuple[str, ...] = ()     # "swiglu" | "moe" | "none" (a mixer
+                                        # block); () = all "swiglu"
     moe_experts: int = 0                # the router's width
     moe_held: Tuple[int, ...] = ()      # ids of the experts held here
     moe_top_k: int = 0
-    moe_width: int = 0                  # of one expert's SwiGLU
+    moe_width: int = 0                  # of one expert
+    ssm_groups: int = 1                 # groups of B and C, and of the norm
+    moe_activation: str = "swiglu"      # | "relu2": W2 relu(W1 u)^2
+    moe_latent: int = 0                 # the experts' input width; 0: d_model
+    moe_shared_width: int = 0           # of the shared expert; 0: none
+    moe_scale: float = 1.0              # what a token's weights sum to
+    moe_norm_eps: float = moe.NORM_EPS  # added to their sum before it divides
+    tied_head: bool = True              # the head is the table
 
     @nn.compact
     def __call__(self, tokens):
@@ -298,23 +362,27 @@ class HybridLM(nn.Module):
         mixers = {
             "mamba": partial(MambaMixer, self.ssm_heads, self.ssm_head_dim,
                              self.ssm_state, self.ssm_conv_width,
-                             self.ssm_chunk, self.norm_eps, self.dtype),
+                             self.ssm_chunk, self.norm_eps, self.dtype,
+                             self.ssm_groups),
             "attention": partial(
                 AttentionMixer, self.attn_heads, self.attn_kv_heads,
                 self.attn_head_dim, scale, self.dtype, self.attn_position,
                 self.attn_rope_theta,
                 self.norm_eps if self.attn_qk_norm else None),
             "short_conv": partial(ShortConvMixer, self.conv_width,
-                                  self.dtype)}
+                                  self.dtype),
+            "none": None}
         unknown = set(self.layer_kinds) - set(mixers)
         if unknown:
             raise ValueError(f"layer_kinds has {sorted(unknown)}; expected "
                              f"each of {sorted(mixers)}")
         ffn_kinds = self.ffn_kinds or ("swiglu",) * len(self.layer_kinds)
         if len(ffn_kinds) != len(self.layer_kinds) \
-                or set(ffn_kinds) - {"swiglu", "moe"}:
-            raise ValueError(f"ffn_kinds={ffn_kinds!r}; expected 'swiglu' or "
-                             f"'moe' for each of {len(self.layer_kinds)} layers")
+                or set(ffn_kinds) - {"swiglu", "moe", "none"} \
+                or ("none", "none") in zip(self.layer_kinds, ffn_kinds):
+            raise ValueError(f"ffn_kinds={ffn_kinds!r}; expected 'swiglu', "
+                             f"'moe' or (beside a mixer) 'none' for each of "
+                             f"{len(self.layer_kinds)} layers")
         routed = None
         if "moe" in ffn_kinds:
             held = tuple(self.moe_held)
@@ -325,8 +393,14 @@ class HybridLM(nn.Module):
                     f"moe_held={held!r} must be distinct ids below "
                     f"moe_experts={self.moe_experts}, and moe_top_k="
                     f"{self.moe_top_k} at most that")
+            if self.moe_activation not in ("swiglu", "relu2"):
+                raise ValueError(f"moe_activation={self.moe_activation!r}; "
+                                 f"expected 'swiglu' or 'relu2'")
             routed = partial(RoutedFeedForward, self.moe_experts, held,
-                             self.moe_top_k, self.moe_width, self.dtype)
+                             self.moe_top_k, self.moe_width, self.dtype,
+                             self.moe_activation, self.moe_latent,
+                             self.moe_shared_width, self.moe_scale,
+                             self.moe_norm_eps)
         use_remat, policy = REMAT_POLICIES[self.remat]
         block_cls = nn.remat(HybridBlock, policy=policy) if use_remat \
             else HybridBlock
@@ -337,11 +411,14 @@ class HybridLM(nn.Module):
                        name="tok_emb")
         x = emb(tokens) * self.embedding_multiplier
         for i, (kind, ffn) in enumerate(zip(self.layer_kinds, ffn_kinds)):
-            x = block_cls(mixers[kind], self.ffn_width,
+            x = block_cls(mixers[kind], ffn, self.ffn_width,
                           self.residual_multiplier, self.norm_eps, self.dtype,
                           routed if ffn == "moe" else None,
                           name=f"block_{i}")(x)
         x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                        param_dtype=jnp.float32, name="norm_f")(x)
-        # weight-tied head, in the model's dtype as TransformerLM's
-        return emb.attend(x).astype(jnp.float32) / self.logits_scaling
+        # the head in the model's dtype as TransformerLM's: the table, or a
+        # matrix of its own
+        logits = emb.attend(x) if self.tied_head else _dense(
+            self.vocab_size, self.dtype, "lm_head")(x)
+        return logits.astype(jnp.float32) / self.logits_scaling

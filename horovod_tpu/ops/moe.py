@@ -7,7 +7,11 @@ parallelism) and computes those experts' part of the result,
 
     y_t = sum over e chosen by t and held here of  w_te * expert_e(h_t)
 
-with ``expert_e(h) = W2_e (silu(a) * b)``, ``[a, b] = W1_e h``. What the
+with ``expert_e(h) = W2_e (silu(a) * b)``, ``[a, b] = W1_e h`` (SwiGLU) or
+``W2_e relu(W1_e h)^2`` (squared ReLU, no gate), the weights of a token
+summing to a scale. The experts may read something other than what the
+router reads: a latent of ``h`` the caller projects down before them and
+back up after their sum (``routed_ffn``'s ``x``). What the
 experts held elsewhere add is their chips' to compute and to send: nothing
 here stands in for them, and on one chip the layer runs without its
 exchange.
@@ -35,9 +39,12 @@ products a layer and step**: two forward (``x W1``, ``act W2``) and five in
 the stage's backward pass, which is written out (:func:`_experts_bwd_at`;
 a Pallas call has no autodiff rule): ``x W1`` again (the stage keeps its
 operands, not the ``[rows, 2 f]`` activations), ``g W2^T``, ``dGU W1^T``
-and the two weight gradients. ``act W2`` is not run again: its one
-consumer there, the routing weights' gradient ``<out, g>``, is
-``<act, g W2^T>``.
+and the two weight gradients, for either kind of expert. ``act W2`` is not
+run again: its one consumer there, the routing weights' gradient
+``<out, g>``, is ``<act, g W2^T>``. (A model that recomputes its blocks
+runs the two forward products again only where something after the layer
+keeps their result for its own gradient, as a latent's up-projection does:
+nine products then.)
 
 ``parallel/expert.py``'s ``MoEMLP`` is the older stand-alone block (top-1,
 capacity with drops, its own train step); this layer lives inside
@@ -60,13 +67,15 @@ from . import pallas_kernels as pk
 NORM_EPS = 1e-6
 
 
-def route(logits, bias, top_k: int):
+def route(logits, bias, top_k: int, scale: float = 1.0,
+          norm_eps: float = NORM_EPS):
     """Sigmoid routing with a selection bias: ``scores = sigmoid(logits)``
     ``[N, E]`` in float32; each token's ``top_k`` experts are those with the
     largest ``scores + bias`` (the bias steers the choice only: it is under
     ``stop_gradient`` and not in the weights); the weights are the chosen
-    experts' scores renormalised to sum to one. Returns ``(chosen [N, k]
-    int32, weights [N, k] float32, scores [N, E])``."""
+    experts' scores renormalised to sum to ``scale`` (their sum takes
+    ``norm_eps`` before it divides). Returns ``(chosen [N, k] int32,
+    weights [N, k] float32, scores [N, E])``."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, chosen = jax.lax.top_k(
         scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
@@ -75,8 +84,8 @@ def route(logits, bias, top_k: int):
     picked = jnp.sum(jax.nn.one_hot(chosen, scores.shape[-1],
                                     dtype=scores.dtype)
                      * scores[..., None, :], axis=-1)
-    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + NORM_EPS)
-    return chosen, weights, scores
+    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + norm_eps)
+    return chosen, weights if scale == 1.0 else scale * weights, scores
 
 
 def dispatch(chosen, held: Sequence[int], num_experts: int):
@@ -103,16 +112,20 @@ def dispatch(chosen, held: Sequence[int], num_experts: int):
 def capacities(assignments: int, held: int, num_experts: int) -> Tuple[int, ...]:
     """The static row counts the expert stage is compiled at, ascending:
     twice the rows a balanced router sends here (``assignments * held /
-    num_experts``, rounded up to whole sublane tiles) and the worst case,
-    every assignment. A step runs the smallest that holds its rows, so the
-    cost of moving and activating rows follows the routing and no row is
-    ever dropped. (Not finer: a router trained on a share of the experts
-    learns to prefer them, 1.5 times the balanced rows within 50 steps, and
-    a count inside the range the rows wander through makes the step's time
-    jump as each layer crosses it: PERF.md, PR 32.)"""
+    num_experts``) or an eighth of the worst case, whichever is more
+    (rounded up to whole sublane tiles), and the worst case, every
+    assignment. A step runs the smallest that holds its rows, so the cost of
+    moving and activating rows follows the routing and no row is ever
+    dropped. (Not finer: a router trained on a share of the experts learns
+    to prefer them, 1.5 times the balanced rows within 50 steps where a
+    quarter is held and 2 to 5 times within 70 where a 64th is, and a count
+    inside the range the rows wander through makes the step's time jump as
+    each layer crosses it, by 20 ms a layer from 2,816 rows to 90,112:
+    PERF.md, PR 32 and PR 34. Under an eighth of the worst case a smaller
+    count saves little and is crossed.)"""
     balanced = assignments * held / num_experts
-    return tuple(sorted({min(assignments, -(-int(2 * balanced) // 8) * 8),
-                         assignments}))
+    smaller = max(int(2 * balanced), assignments // 8)
+    return tuple(sorted({min(assignments, -(-smaller // 8) * 8), assignments}))
 
 
 # Rows move between token order [N, d] and sorted-row order [R, d] by
@@ -217,7 +230,7 @@ def _rows_at(rows: int, top_k: int, order, inverse, group_sizes):
 
 
 def _experts_at(rows: int, h, w_in, w_out, weights, order, inverse,
-                group_sizes):
+                group_sizes, activation: str = "swiglu"):
     """The expert stage at a static capacity of ``rows`` sorted rows, which
     must hold every row of the experts here (``sum(group_sizes) <= rows``)."""
     with jax.named_scope("dispatch"):
@@ -225,10 +238,13 @@ def _experts_at(rows: int, h, w_in, w_out, weights, order, inverse,
                                                inverse, group_sizes)
         x = take_rows(h, token, slots)
     with jax.named_scope("experts"):
-        gate, up = jnp.split(grouped_matmul(
-            x, w_in.astype(h.dtype), group_sizes), 2, axis=-1)
-        out = grouped_matmul(jax.nn.silu(gate) * up, w_out.astype(h.dtype),
-                             group_sizes)
+        first = grouped_matmul(x, w_in.astype(h.dtype), group_sizes)
+        if activation == "swiglu":
+            gate, up = jnp.split(first, 2, axis=-1)
+            act = jax.nn.silu(gate) * up
+        else:
+            act = jnp.square(jax.nn.relu(first))
+        out = grouped_matmul(act, w_out.astype(h.dtype), group_sizes)
     with jax.named_scope("combine"):
         # a row of no group holds whatever the product left there. No slot
         # points at it, so it reaches no token; it is zeroed all the same
@@ -238,10 +254,11 @@ def _experts_at(rows: int, h, w_in, w_out, weights, order, inverse,
 
 
 def _experts_bwd_at(rows: int, h, w_in, w_out, weights, order, inverse,
-                    group_sizes, dy):
+                    group_sizes, dy, activation: str = "swiglu"):
     """The gradients of :func:`_experts_at` in ``h``, ``w_in``, ``w_out``
     and ``weights`` for the cotangent ``dy`` of its result, at the same
-    capacity: five grouped products. With ``act = silu(G) * U`` and
+    capacity: five grouped products. With ``act = silu(G) * U`` (or
+    ``relu(A) ** 2``) and
     ``out = act W2`` the forward pass gave ``y = put_rows(w * out)``; here
     ``g = take_rows(dy)``, ``u = g W2^T``, and the routing weight of a row
     has the gradient ``<out, g> = <act, u>``, so ``out`` is not computed
@@ -249,6 +266,7 @@ def _experts_bwd_at(rows: int, h, w_in, w_out, weights, order, inverse,
     ``G, U`` meet a sum over a row only under ``valid``, the two
     weight-gradient products read no such row, and no slot points at one of
     ``dX``."""
+    swiglu = activation == "swiglu"
     dtype = h.dtype
     wide = jnp.promote_types(dtype, jnp.float32)    # between the products
     with jax.named_scope("dispatch"):
@@ -260,21 +278,32 @@ def _experts_bwd_at(rows: int, h, w_in, w_out, weights, order, inverse,
         w = weights.reshape(-1)[picked][:, None]
     with jax.named_scope("experts"):
         w1, w2 = w_in.astype(dtype), w_out.astype(dtype)
-        # (split, then widen: XLA keeps a widened [rows, 2 f] copy in HBM
-        # rather than fuse the convert into both halves' readers)
-        gate, up = (half.astype(wide) for half in jnp.split(
-            grouped_matmul(x, w1, group_sizes), 2, axis=-1))
+        first = grouped_matmul(x, w1, group_sizes)
+        if swiglu:
+            # (split, then widen: XLA keeps a widened [rows, 2 f] copy in
+            # HBM rather than fuse the convert into both halves' readers)
+            gate, up = (half.astype(wide)
+                        for half in jnp.split(first, 2, axis=-1))
         u = grouped_matmul_t(g, w2, group_sizes).astype(wide)
-        sig = jax.nn.sigmoid(gate)
-        act = (gate * sig * up).astype(dtype).astype(wide)  # the operand of W2
+        if swiglu:
+            sig = jax.nn.sigmoid(gate)
+            act = gate * sig * up
+        else:
+            positive = jax.nn.relu(first.astype(wide))
+            act = positive * positive
+        act = act.astype(dtype).astype(wide)    # the operand of W2
         d_w = jnp.sum(jnp.where(valid, act * u, 0), axis=-1)
         d_act = w * u
-        d_gu = jnp.concatenate(
-            [d_act * up * sig * (1 + gate * (1 - sig)), d_act * gate * sig],
-            axis=-1).astype(dtype)
+        if swiglu:
+            d_first = jnp.concatenate(
+                [d_act * up * sig * (1 + gate * (1 - sig)),
+                 d_act * gate * sig], axis=-1)
+        else:
+            d_first = 2 * d_act * positive
+        d_first = d_first.astype(dtype)
         d_w2 = grouped_outer((w * act).astype(dtype), g, group_sizes)
-        d_x = grouped_matmul_t(d_gu, w1, group_sizes)
-        d_w1 = grouped_outer(x, d_gu, group_sizes)
+        d_x = grouped_matmul_t(d_first, w1, group_sizes)
+        d_w1 = grouped_outer(x, d_first, group_sizes)
     with jax.named_scope("combine"):
         d_weights = jnp.concatenate([d_w, jnp.zeros((1,), d_w.dtype)])[slots]
     with jax.named_scope("dispatch"):
@@ -291,21 +320,25 @@ def _smallest_that_holds(sizes: Tuple[int, ...], group_sizes, fn, *operands):
                           *operands)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _experts(sizes, h, w_in, w_out, weights, order, inverse, group_sizes):
-    return _smallest_that_holds(sizes, group_sizes, _experts_at, h, w_in,
-                                w_out, weights, order, inverse, group_sizes)
+@partial(jax.custom_vjp, nondiff_argnums=(0, 8))
+def _experts(sizes, h, w_in, w_out, weights, order, inverse, group_sizes,
+             activation="swiglu"):
+    return _smallest_that_holds(
+        sizes, group_sizes, partial(_experts_at, activation=activation), h,
+        w_in, w_out, weights, order, inverse, group_sizes)
 
 
-def _experts_fwd(sizes, *operands):
+def _experts_fwd(sizes, *operands_and_activation):
     # the residuals are the operands: which capacity ran is not a shape the
     # backward pass may depend on, so it picks its own, the same
-    return _experts(sizes, *operands), operands
+    return (_experts(sizes, *operands_and_activation),
+            operands_and_activation[:-1])
 
 
-def _experts_bwd(sizes, operands, dy):
-    grads = _smallest_that_holds(sizes, operands[-1], _experts_bwd_at,
-                                 *operands, dy)
+def _experts_bwd(sizes, activation, operands, dy):
+    grads = _smallest_that_holds(
+        sizes, operands[-1], partial(_experts_bwd_at, activation=activation),
+        *operands, dy)
     return (*grads, None, None, None)
 
 
@@ -313,13 +346,18 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
 
 
 def routed_ffn(h, router, bias, w_in, w_out, *, held: Tuple[int, ...],
-               top_k: int):
+               top_k: int, x=None, activation: str = "swiglu",
+               scale: float = 1.0, norm_eps: float = NORM_EPS):
     """The layer above for ``h`` ``[N, d]``: ``router`` ``[d, E]`` and
     ``bias`` ``[E]`` (float32), ``w_in`` ``[H, d, 2 f]`` (gate and up side
-    by side) and ``w_out`` ``[H, f, d]`` for the ``H = len(held)`` experts
-    held (cast to ``h.dtype`` here). Returns ``(y [N, d], chosen [N, k],
-    scores [N, E], load [E])``: ``load`` counts the tokens each of the ``E``
-    experts was chosen by (held or not).
+    by side; ``[H, d, f]`` for ``activation="relu2"``) and ``w_out``
+    ``[H, f, d]`` for the ``H = len(held)`` experts held (cast to
+    ``h.dtype`` here). The router always reads ``h``; the experts read
+    ``x`` ``[N, l]`` where one is given (a latent of ``h``: their ``d`` is
+    then ``l``, and so is ``y``'s). ``scale`` and ``norm_eps`` are
+    :func:`route`'s. Returns ``(y [N, d], chosen [N, k], scores [N, E],
+    load [E])``: ``load`` counts the tokens each of the ``E`` experts was
+    chosen by (held or not).
 
     The expert stage keeps the layer's inputs and the routing, not the
     ``[rows, 2 f]`` activations, whatever the model's ``remat``: its
@@ -333,13 +371,18 @@ def routed_ffn(h, router, bias, w_in, w_out, *, held: Tuple[int, ...],
             # between near-equal scores should not hang on them
             logits = jnp.dot(h.astype(jnp.float32), router.astype(jnp.float32),
                              precision="highest")
-            chosen, weights, scores = route(logits, bias, top_k)
+            # (the defaults are left to ``route``: tests put one of three
+            # arguments in its place)
+            renorm = () if (scale, norm_eps) == (1.0, NORM_EPS) \
+                else (scale, norm_eps)
+            chosen, weights, scores = route(logits, bias, top_k, *renorm)
             load = jnp.sum(jax.nn.one_hot(chosen, num_experts,
                                           dtype=jnp.int32), axis=(0, 1))
         with jax.named_scope("dispatch"):
             order, inverse, group_sizes = dispatch(chosen, held, num_experts)
-        y = _experts(capacities(order.shape[0], len(held), num_experts), h,
-                     w_in, w_out, weights, order, inverse, group_sizes)
+        y = _experts(capacities(order.shape[0], len(held), num_experts),
+                     h if x is None else x, w_in, w_out, weights, order,
+                     inverse, group_sizes, activation)
     return y, chosen, scores, load
 
 

@@ -20,6 +20,8 @@ the scan and the gated normalisation after it (``models/hybrid.py``).
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
@@ -28,9 +30,11 @@ def ssd_chunked(x, dt, A, B, C, D, chunk: int = 256):
     """The scan above for ``x`` ``[b, T, H, P]`` (H heads of width P).
 
     ``dt`` ``[b, T, H]``: positive step sizes (after the softplus); ``A``
-    ``[H]``: negative decay rates; ``B``, ``C`` ``[b, T, N]``: one group of
-    input and output projections of the N-wide state, shared by the heads;
-    ``D`` ``[H]``: the skip. Returns ``y`` ``[b, T, H, P]`` in ``x.dtype``.
+    ``[H]``: negative decay rates; ``B``, ``C``: the input and output
+    projections of the N-wide state, ``[b, T, N]`` for one group shared by
+    every head or ``[b, T, G, N]`` for ``G`` groups, head ``h`` reading
+    group ``h // (H / G)``; ``D`` ``[H]``: the skip. Returns ``y``
+    ``[b, T, H, P]`` in ``x.dtype``.
 
     Matmul operands are in ``x.dtype`` (bf16 in the model) with float32
     accumulation; ``dt``, ``A``, the cumulative sums of ``dt * A`` and every
@@ -40,6 +44,15 @@ def ssd_chunked(x, dt, A, B, C, D, chunk: int = 256):
     divide is padded with steps of ``dt = 0``, which leave the state alone.
     """
     b, t, h, p = x.shape
+    if B.ndim == 4:
+        # G groups are G scans, each of H / G heads over one group
+        g = B.shape[2]
+        with jax.named_scope("ssd"):
+            return jax.vmap(partial(ssd_chunked, chunk=chunk),
+                            in_axes=(2, 2, 0, 2, 2, 0), out_axes=2)(
+                x.reshape(b, t, g, h // g, p), dt.reshape(b, t, g, h // g),
+                A.reshape(g, h // g), B, C,
+                D.reshape(g, h // g)).reshape(x.shape)
     pad = -t % chunk
     if pad:
         x, dt, B, C = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
@@ -104,9 +117,12 @@ def causal_conv1d(x, kernel, bias=None):
     return y.astype(x.dtype)
 
 
-def gated_rms_norm(y, gate, scale, eps: float):
-    """``RMSNorm(y * silu(gate)) * scale`` over the last axis (one group),
-    in float32, returned in ``y.dtype``."""
+def gated_rms_norm(y, gate, scale, eps: float, groups: int = 1):
+    """``RMSNorm(y * silu(gate)) * scale``, the mean square taken over each
+    of ``groups`` equal runs of the last axis, in float32, returned in
+    ``y.dtype``."""
     h = y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+    if groups > 1:
+        h = h.reshape(h.shape[:-1] + (groups, -1))
     h = h * jax.lax.rsqrt(jnp.mean(jnp.square(h), axis=-1, keepdims=True) + eps)
-    return (h * scale.astype(jnp.float32)).astype(y.dtype)
+    return (h.reshape(y.shape) * scale.astype(jnp.float32)).astype(y.dtype)

@@ -16,7 +16,11 @@ from chipbench.tests.test_rehearsal import KEYS, rehearse
 HYBRID_CELLS = {
     "granite4hm-train-s4096": {"ssd_ms", "ssd_roofline"},
     "lfm2moe-train-s8192": {"moe_ms", "moe_experts_ms",
-                            "moe_experts_roofline", "short_conv_ms"}}
+                            "moe_experts_roofline", "short_conv_ms"},
+    "nemotron3s-train-s4096": {"ssd_ms", "ssd_roofline", "moe_ms",
+                               "moe_experts_ms", "moe_experts_roofline",
+                               "moe_route_ms", "moe_shared_ms",
+                               "moe_latent_ms", "lm_head_ms"}}
 
 
 @pytest.mark.parametrize("cell", HYBRID_CELLS)
@@ -43,15 +47,22 @@ def test_hybrid_cell_rehearsal_reads_every_per_layer_metric_it_lists(cell):
     values = {k[len("rehearsal_"):]: v["value"]
               for k, v in line["metrics"].items()}
     assert values["blocks_recompute_ms"] > 0
-    if cell == "granite4hm-train-s4096":
+    assert all(values[name] > 0 for name in HYBRID_CELLS[cell])
+    if "ssd_ms" in HYBRID_CELLS[cell]:
         # the scan's scope is its own class: its time is not the blocks'
         assert 0 < values["ssd_ms"] < values["xla_ops_ms"]
-    else:
+    if "moe_ms" in HYBRID_CELLS[cell]:
         # overlays: the routed feed-forward's time stays in the blocks'
         assert 0 < values["moe_experts_ms"] <= values["moe_ms"] \
             < values["xla_ops_ms"]
-        assert values["moe_experts_roofline"] > 0
-        assert values["short_conv_ms"] > 0
+        if "moe_route_ms" in HYBRID_CELLS[cell]:
+            # what is under ``moe`` and not under ``experts``; the latent's
+            # and the shared expert's products lie beside ``moe``, the
+            # untied head outside every block
+            assert values["moe_route_ms"] == pytest.approx(
+                values["moe_ms"] - values["moe_experts_ms"])
+            for name in ("moe_shared_ms", "moe_latent_ms", "lm_head_ms"):
+                assert values[name] < values["xla_ops_ms"], name
         parts = ("blocks_fwd_ms", "blocks_bwd_ms", "blocks_recompute_ms",
                  "head_loss_ms", "optimizer_ms", "model_other_ms")
         assert sum(values[k] for k in parts) == pytest.approx(

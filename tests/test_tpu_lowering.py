@@ -90,6 +90,8 @@ _ROUTE_EDGES = {
     (16384, 64): ("step_streaming", "fused", 2),    # the largest dq scratch
     (32768, 64): ("step_streaming", "streaming", 3),
     (8192, 128): ("step_streaming", "fused", 2),
+    # nemotron3s-train-s4096's: the widest head whose keys still stay resident
+    (4096, 128): ("once", "fused", 2),
 }
 
 
@@ -347,3 +349,49 @@ def test_routed_feed_forward_compiles_for_v5e_at_the_published_widths(
     # no [experts, tokens, d] or [tokens, experts, d] operand anywhere
     assert not re.search(rf"\[({held}|{experts}),{tokens},{d}\]", text)
     assert not re.search(rf"\[{tokens},({held}|{experts}),{d}\]", text)
+
+
+def test_latent_routed_feed_forward_compiles_for_v5e_at_the_published_widths(
+        v5e_devices):
+    """``ops/moe.routed_ffn`` forward and backward as
+    NVIDIA-Nemotron-3-Super-120B-A12B's LatentMoE layer calls it, at the
+    cell's 4,096 tokens: top-22 of 512 with 8 held, squared-ReLU experts
+    2688 wide reading a latent of 1024 beside the router's 4096-wide input.
+    The grouped products are the Pallas kernels at both row capacities, the
+    same seven a capacity as the SwiGLU stage's, and ``w_in`` is one expert
+    width wide, not two."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.ops import moe
+
+    tokens, d, latent, width, experts, held, top_k = \
+        4096, 4096, 1024, 2688, 512, 8, 22
+    one = SingleDeviceSharding(v5e_devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def loss(h, x, router, bias, w_in, w_out):
+        y = moe.routed_ffn(h, router, bias, w_in, w_out,
+                           held=tuple(range(held)), top_k=top_k, x=x,
+                           activation="relu2", scale=5.0, norm_eps=1e-20)[0]
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    sizes = moe.capacities(tokens * top_k, held, experts)
+    assert sizes == (11264, 90112)
+    with jax.enable_x64(False):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 4, 5))).trace(
+            shape((tokens, d), jnp.bfloat16),
+            shape((tokens, latent), jnp.bfloat16),
+            shape((d, experts), jnp.float32), shape((experts,), jnp.float32),
+            shape((held, latent, width), jnp.float32),
+            shape((held, width, latent), jnp.float32)).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+    assert "ragged-dot" not in text
+    calls = re.findall(r"%(moe_t?gmm)[.\d]* = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert calls.count("moe_gmm") == len(sizes) * (2 + 3), calls
+    assert calls.count("moe_tgmm") == len(sizes) * 2, calls
+    for rows in sizes:
+        assert f"bf16[{rows},{width}]" in text
+        assert f"bf16[{rows},{2 * width}]" not in text
