@@ -430,6 +430,7 @@ def kernel_cases():
             pk.int4_quantize_pack, [rows], 1, pk.int4_quantize_pack_ref,
             None),
         **grouped_cases(),
+        **scan_cases(),
         "matmul_2d (LM head chunk)": KernelCase(
             pk.matmul_2d,
             [s((B * T // 4, DM // 4), jnp.bfloat16),
@@ -487,6 +488,54 @@ def grouped_cases():
                 dot(lhs[a:b], rhs[a:b], ((0,), (0,))) for a, b in spans]),
             TOL_BF16),
     }
+
+
+def scan_cases():
+    """The state-space scan (``ops/ssd.ssd_chunked``) and its six gradients
+    at the Mamba-2 layers of the benchmark's two hybrids, one sequence of
+    4096 positions: the Pallas pair against the dual form in XLA, which is
+    what the same call runs with kernels off. The operands are drawn as
+    every case's are and brought into the scan's ranges here: steps
+    ``softplus`` of a normal, rates ``-exp`` of one."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import ssd
+
+    def scan(chunk):
+        def f(x, dt, A, B, C, D):
+            dt = jax.nn.softplus(dt - 3.0)
+            A = -jnp.exp(A / 6.0)
+
+            def loss(*a):
+                y = ssd.ssd_chunked(*a, chunk=chunk).astype(jnp.float32)
+                return jnp.sum(y * cotangent(y.shape)), y
+            grads, y = jax.grad(loss, argnums=range(6), has_aux=True)(
+                x, dt, A, B / 3.0, C / 3.0, D)
+            return y, grads
+        return f
+
+    def kernels_off(fn):
+        def traced_off(*operands):
+            with mock.patch.dict(os.environ, HVD_PALLAS="0"):
+                return fn(*operands)
+        return traced_off
+
+    def case(heads, groups, chunk):
+        t, p, n = 4096, 64, 128
+        bc = (1, t, n) if groups is None else (1, t, groups, n)
+        s = jax.ShapeDtypeStruct
+        return KernelCase(
+            scan(chunk),
+            [s((1, t, heads, p), jnp.bfloat16), s((1, t, heads), jnp.float32),
+             s((heads,), jnp.float32), s(bc, jnp.bfloat16),
+             s(bc, jnp.bfloat16), s((heads,), jnp.float32)], 2,
+            kernels_off(scan(chunk)), TOL_BF16)
+
+    return {"ssd_scan fwd+bwd 64 heads, one group": case(64, None, 256),
+            "ssd_scan fwd+bwd 128 heads, 8 groups": case(128, 8, 128)}
 
 
 def matmul_reduce_scatter_case(mesh):

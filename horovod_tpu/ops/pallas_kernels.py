@@ -1940,6 +1940,430 @@ def _tgmm(lhs, rhs, group_sizes, tiling, interpret):
     )(offsets, group, tile, lhs, rhs)
 
 
+# ------------------------------------------------------- state-space scan
+# Mamba-2's scan (ops/ssd.py has the recurrence and the dual form this is
+# measured against) as two kernels whose grid walks the tiles of a sequence
+# in order, every head's ``[P, N]`` float32 state in VMEM from one tile to
+# the next: the forward never writes a state it does not have to (the
+# variant a backward pass follows saves each tile's entering state, which
+# its backward kernel reads walking the tiles in reverse with the state's
+# gradient in VMEM). Grid ``(batch, tile, head block)``: the head blocks of
+# one group of B and C are consecutive, so the group's ``C B^T`` is formed
+# once a tile and its dB and dC are summed in VMEM.
+#
+# Layouts. x, y and their gradients are ``[b, T, H P]`` as the model has
+# them: a cell takes ``heads`` heads' lanes and works a lane width (128 / P
+# heads) at a time, one head's ``[tile, tile]`` scores at a time inside it.
+# The per-position scalars (dt, and the cumulative sum of dt A inside a
+# tile, both float32, formed in XLA where autodiff takes them back to dt
+# and A) come as ``rows`` ``[b, 2, H, T]``, positions on the lanes, for
+# what varies along a score's columns; what varies along its rows is their
+# transpose, taken once a cell by the XLU (:func:`_ssd_cols`).
+# The state of a lane width of heads is ``[N, 128]``: state ``[P, N]`` of
+# head ``u`` transposed into lanes ``u P .. (u + 1) P``.
+
+#: the kernel tile of a sequence longer than a lane width, and the heads a
+#: grid cell takes, in the order ``ssd_route`` tries them (a multiple of 8:
+#: the rows' sublanes). Read on a v5e, one layer and sequence of 4096
+#: positions, forward / backward kernel in ms (PERF.md §6, PR 35), 64 heads
+#: and one group: tile 128 and 8 heads 0.332 / 0.821, 128 and 16 0.237 /
+#: 0.853, 256 and 8 0.266 / 0.699, **256 and 16 0.222 / 0.675**, 256 and 32
+#: 0.200 / 0.677, 512 and 16 0.296 / 0.889; 128 heads in 8 groups: 0.692 /
+#: 1.721, 0.443 / 1.693, 0.555 / 1.447, **0.444 / 1.361**, (a group has 16
+#: heads), 0.564 / 1.647. A head's ``[tile, tile]`` work grows with the
+#: tile, but what a cell does once a lane width of heads (the per-position
+#: scalars spread over lanes, the products with the ``[N, 128]`` state)
+#: does not, and outweighs it at 128.
+_SSD_TILE = 256
+_SSD_HEADS = (16, 8)
+_SSD_VMEM = 64 * 2 ** 20
+
+
+def ssd_route(t: int, h: int, p: int, n: int, g: int) -> dict:
+    """Which path the scan of ``t`` positions, ``h`` heads of width ``p``,
+    state ``n`` and ``g`` groups takes, and at which kernel tile and heads
+    a grid cell: ``{"path", "tile", "heads"}``, ``path`` ``"pallas"`` or
+    ``"reference"`` (the dual form in XLA: a head width that does not
+    divide a lane width, a state that is not whole lane widths, a group of
+    fewer heads than a cell takes). The dispatcher (``ops/ssd.py``) and
+    the tests both read it; a ``t`` the tile does not divide is padded. No
+    JAX."""
+    refused = {"path": "reference", "tile": None, "heads": None}
+    if p < 16 or _LANES % p or n % _LANES or g < 1 or h % g:
+        return refused
+    heads = next((k for k in _SSD_HEADS if (h // g) % k == 0), None)
+    if heads is None:
+        return refused
+    return {"path": "pallas", "tile": _SSD_TILE if t > _LANES else _LANES,
+            "heads": heads}
+
+
+def ssd_supported(x, B) -> bool:
+    """``x`` ``[b, T, H, P]`` and ``B`` ``[b, T, N]`` or ``[b, T, G, N]``."""
+    _, t, h, p = x.shape
+    g = B.shape[2] if B.ndim == 4 else 1
+    return x.dtype.itemsize in (2, 4) and ssd_route(
+        t, h, p, B.shape[-1], g)["path"] == "pallas"
+
+
+def _dot_nt(a, b):
+    """``a b^T`` with f32 accumulation: dimension 1 of both contracted."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _ssd_lanes(parts, p: int, rows: int):
+    """``[rows, 128]`` whose lanes ``u p .. (u + 1) p`` are ``parts[u]``
+    (each ``[rows, 1]`` or ``[rows, 128]``): what is one number a head,
+    spread over the lanes its head has in a lane width of heads. (One head
+    a lane width goes through a select as well: left a bare broadcast, a
+    row cut from it and spread over sublanes becomes one broadcast of a
+    ``[1, 1]`` along both axes, which Mosaic does not lower.)"""
+    lane = lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1) // p
+    out = jnp.where(lane < len(parts), parts[-1], 0.0)
+    for u in range(len(parts) - 2, -1, -1):
+        out = jnp.where(lane == u, parts[u], out)
+    return out
+
+
+def _ssd_decay(cum_c, cum_r, h: int, causal):
+    """``exp(cum_l - cum_s)`` for ``s <= l`` and 0 above the diagonal, head
+    ``h`` of the cell: the mask goes in before the ``exp`` (above the
+    diagonal the exponent is positive and may overflow)."""
+    seg = cum_c[:, h:h + 1] - cum_r[h:h + 1, :]
+    return jnp.exp(jnp.where(causal, seg, NEG_INF))
+
+
+def _ssd_causal(tile: int):
+    return (lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+            >= lax.broadcasted_iota(jnp.int32, (tile, tile), 1))
+
+
+def _ssd_cols(rows_ref, heads: int, tile: int):
+    """``(dt, cum)`` of a cell's heads as columns, ``[tile, heads]`` each,
+    from the ``[2, heads, tile]`` rows: one transpose by the XLU of the
+    rows laid over a lane width of sublanes."""
+    rows = jnp.concatenate(
+        [rows_ref[0, 0], rows_ref[0, 1],
+         jnp.zeros((_LANES - 2 * heads, tile), jnp.float32)], axis=0)
+    cols = rows.T                                           # [tile, 128]
+    return cols[:, :heads], cols[:, heads:2 * heads]
+
+
+def _ssd_fwd_kernel(x_ref, rows_ref, b_ref, c_ref, d_ref, y_ref,
+                    *rest, tile, heads, p, cells, save):
+    state_ref, cb_ref = rest[-2:]
+    i, j = pl.program_id(1), pl.program_id(2)
+    q = _LANES // p
+    widths = heads // q
+    dtype, f32 = x_ref.dtype, jnp.float32
+
+    @pl.when(i == 0)
+    def _a_sequence_starts():
+        state_ref[pl.ds(j * widths, widths)] = jnp.zeros(
+            (widths,) + state_ref.shape[1:], f32)
+
+    B, C = b_ref[0], c_ref[0]
+
+    @pl.when(j % cells == 0)
+    def _a_group_starts():
+        cb_ref[...] = _dot_nt(C, B)
+
+    cb, causal = cb_ref[...], _ssd_causal(tile)
+    dt_r, cum_r = rows_ref[0, 0], rows_ref[0, 1]            # [heads, tile]
+    dt_c, cum_c = _ssd_cols(rows_ref, heads, tile)          # [tile, heads]
+    for k in range(widths):
+        own = range(k * q, (k + 1) * q)
+        at = slice(k * _LANES, (k + 1) * _LANES)
+        x2 = x_ref[0, :, at]
+        x2f = x2.astype(f32)
+        cum2 = _ssd_lanes([cum_c[:, h:h + 1] for h in own], p, tile)
+        dt2 = _ssd_lanes([dt_c[:, h:h + 1] for h in own], p, tile)
+        total2 = cum2[tile - 1:tile, :]     # a whole tile's decay, [1, 128]
+        state = state_ref[j * widths + k]                   # [N, 128]
+        if save:
+            rest[0][0, 0, k] = state
+        # what the entering state gives every position, and the skip
+        y2 = jnp.dot(C, state.astype(dtype), preferred_element_type=f32) \
+            * jnp.exp(cum2) + d_ref[0, :, at] * x2f
+        within = []
+        for h in own:
+            scores = cb * _ssd_decay(cum_c, cum_r, h, causal) \
+                * dt_r[h:h + 1, :]
+            # (every head of the lane width's x: a head keeps its lanes)
+            within.append(jnp.dot(scores.astype(dtype), x2,
+                                  preferred_element_type=f32))
+        y_ref[0, :, at] = (y2 + _ssd_lanes(within, p, tile)).astype(dtype)
+        to_end = jnp.exp(total2 - cum2) * dt2
+        state_ref[j * widths + k] = jnp.exp(total2) * state + _dot_tn(
+            B, (x2f * to_end).astype(dtype))
+
+
+def _ssd_bwd_kernel(x_ref, rows_ref, b_ref, c_ref, d_ref, dy_ref, s_ref,
+                    dx_ref, drows_ref, db_ref, dc_ref, dd_ref, dstate_ref,
+                    cb_ref, dm_ref, dbc_ref, *, tile, heads, p, cells):
+    i, j = pl.program_id(1), pl.program_id(2)
+    q = _LANES // p
+    widths = heads // q
+    dtype, f32 = x_ref.dtype, jnp.float32
+
+    @pl.when(i == 0)
+    def _a_sequence_ends():
+        dstate_ref[pl.ds(j * widths, widths)] = jnp.zeros(
+            (widths,) + dstate_ref.shape[1:], f32)
+
+    B, C = b_ref[0], c_ref[0]
+
+    @pl.when(j % cells == 0)
+    def _a_group_starts():
+        cb_ref[...] = _dot_nt(C, B)
+        dm_ref[...] = jnp.zeros_like(dm_ref)
+        dbc_ref[...] = jnp.zeros_like(dbc_ref)
+
+    cb, causal = cb_ref[...], _ssd_causal(tile)
+    cum_r = rows_ref[0, 1]                                  # [heads, tile]
+    dt_c, cum_c = _ssd_cols(rows_ref, heads, tile)
+    # d dt and d cum a head and position, as columns (lanes ``h`` and
+    # ``heads + h``) where a sum over a head's lanes forms them
+    head_of = lax.broadcasted_iota(jnp.int32, (tile, _LANES), 1)
+    lane = head_of // p
+    lane1 = lane[0:1, :]
+
+    def of_head(a, u, mask=lane):
+        return jnp.sum(a if q == 1 else jnp.where(mask == u, a, 0.0),
+                       axis=1, keepdims=True)
+
+    head_at = lax.broadcasted_iota(jnp.int32, (heads, tile), 0)
+    dcols = jnp.zeros((tile, _LANES), f32)
+    dtotal = jnp.zeros((1, _LANES), f32)
+    dcum_r = jnp.zeros((heads, tile), f32)
+    for k in range(widths):
+        own = range(k * q, (k + 1) * q)
+        at = slice(k * _LANES, (k + 1) * _LANES)
+        x2, dy2 = x_ref[0, :, at], dy_ref[0, :, at]
+        x2f, dy2f = x2.astype(f32), dy2.astype(f32)
+        skip = d_ref[0, :, at]
+        cum2 = _ssd_lanes([cum_c[:, h:h + 1] for h in own], p, tile)
+        dt2 = _ssd_lanes([dt_c[:, h:h + 1] for h in own], p, tile)
+        total2 = cum2[tile - 1:tile, :]
+        to_end, from_start = jnp.exp(total2 - cum2), jnp.exp(cum2)
+        state = s_ref[0, 0, k]                  # entered the tile
+        dstate = dstate_ref[j * widths + k]     # gradient of what left it
+        state_low, dstate_low = state.astype(dtype), dstate.astype(dtype)
+        xdt = (x2f * dt2).astype(dtype)
+        # d(x dt): through the state the tile leaves ...
+        late = to_end * jnp.dot(B, dstate_low, preferred_element_type=f32)
+        early, by_rows = [], []
+        for u, h in enumerate(own):
+            decay = _ssd_decay(cum_c, cum_r, h, causal)
+            # ... and through the tile's own later positions
+            early.append(_dot_tn((cb * decay).astype(dtype), dy2))
+            dy_h = dy2 if q == 1 else jnp.where(lane == u, dy2f, 0.0).astype(
+                dtype)
+            d_cb = _dot_nt(dy_h, xdt) * decay
+            dm_ref[...] += d_cb
+            # d cum of the decays: the row sums of d scores * scores less
+            # its column sums, of ONE product: summed back over positions
+            # they cancel but for what crosses a position, which two
+            # roundings of the product would bury
+            by_decay = d_cb * cb
+            by_rows.append(jnp.sum(by_decay, axis=1, keepdims=True))
+            dcum_r = jnp.where(head_at == h, -jnp.sum(
+                by_decay, axis=0, keepdims=True), dcum_r)
+        dxdt = late + _ssd_lanes(early, p, tile)
+        dx_ref[0, :, at] = (dt2 * dxdt + skip * dy2f).astype(dtype)
+        # per head and position: d dt directly; d cum, which is those row
+        # sums (the column sums leave in the rows' layout), the entering
+        # state's share and less to_end's; d total, through to_end and
+        # through the entering state's decay
+        dy_in = from_start * dy2f
+        by_dt = x2f * dxdt
+        by_late = late * x2f * dt2
+        by_cum = dy_in * jnp.dot(C, state_low, preferred_element_type=f32) \
+            - by_late
+        by_total = jnp.sum(by_late, axis=0, keepdims=True) \
+            + jnp.exp(total2) * jnp.sum(dstate * state, axis=0, keepdims=True)
+        for u, h in enumerate(own):
+            dcols = jnp.where(head_of == h, of_head(by_dt, u), dcols)
+            dcols = jnp.where(head_of == heads + h,
+                              by_rows[u] + of_head(by_cum, u), dcols)
+            dtotal = jnp.where(head_of[0:1, :] == heads + h,
+                               of_head(by_total, u, lane1), dtotal)
+        dy_in = dy_in.astype(dtype)
+        dbc_ref[0] += _dot_nt((x2f * to_end * dt2).astype(dtype), dstate_low)
+        dbc_ref[1] += _dot_nt(dy_in, state_low)
+        dstate_ref[j * widths + k] = jnp.exp(total2) * dstate + _dot_tn(
+            C, dy_in)
+        dd_ref[0, 0, 0, :, at] = jnp.sum(dy2f * x2f, axis=0, keepdims=True)
+    # total is cum at the tile's last position
+    last = lax.broadcasted_iota(jnp.int32, (tile, _LANES), 0) == tile - 1
+    drows = jnp.where(last, dcols + dtotal, dcols).T        # [128, tile]
+    drows_ref[0, 0] = drows[:heads]
+    drows_ref[0, 1] = drows[heads:2 * heads] + dcum_r
+
+    @pl.when(j % cells == cells - 1)
+    def _a_group_ends():
+        dm = dm_ref[...].astype(dtype)
+        db_ref[0] = (dbc_ref[0] + _dot_tn(dm, C)).astype(db_ref.dtype)
+        dc_ref[0] = (dbc_ref[1] + jnp.dot(
+            dm, B, preferred_element_type=f32)).astype(dc_ref.dtype)
+
+
+def _ssd_specs(cfg, nt: int, reverse: bool):
+    """The block specs every scan kernel shares, by operand name; a
+    backward kernel walks the tiles from the last."""
+    tile, heads, p, n, cells = cfg
+    wide = heads * p
+
+    def at(i):
+        return nt - 1 - i if reverse else i
+
+    return {
+        "x": pl.BlockSpec((1, tile, wide), lambda b, i, j: (b, at(i), j)),
+        "rows": pl.BlockSpec((1, 2, heads, tile),
+                             lambda b, i, j: (b, 0, j, at(i))),
+        "bc": pl.BlockSpec((1, tile, n),
+                           lambda b, i, j: (b, at(i), j // cells)),
+        "skip": pl.BlockSpec((1, 1, wide), lambda b, i, j: (j, 0, 0)),
+        "states": pl.BlockSpec((1, 1, wide // _LANES, n, _LANES),
+                               lambda b, i, j: (b, at(i), j, 0, 0)),
+        "dskip": pl.BlockSpec((1, 1, 1, 1, wide),
+                              lambda b, i, j: (b, at(i), j, 0, 0)),
+    }
+
+
+def _ssd_sizes(x2, cfg):
+    tile, heads, p, n, _ = cfg
+    b, t, hp = x2.shape
+    return b, t, hp, t // tile, hp // (heads * p), hp // _LANES
+
+
+# Jitted, as the grouped products are and for their reason: a step calls the
+# scan a Mamba-2 layer and pass, and under ``jax.jit`` a kernel's body is
+# traced and lowered to Mosaic once a shape, not once a call site.
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _ssd_fwd(x2, rows, B2, C2, skip, cfg, save, interpret):
+    tile, heads, p, n, cells = cfg
+    b, t, hp, nt, nj, widths = _ssd_sizes(x2, cfg)
+    spec = _ssd_specs(cfg, nt, False)
+    states = _struct((b, nt, widths, n, _LANES), jnp.float32, x2, rows, B2)
+    y = _struct(x2.shape, x2.dtype, x2, rows, B2)
+    hn = hp // p * n
+    return _named_call(
+        "ssd_fwd",
+        functools.partial(_ssd_fwd_kernel, tile=tile, heads=heads, p=p,
+                          cells=cells, save=save),
+        grid=(b, nt, nj),
+        in_specs=[spec["x"], spec["rows"], spec["bc"], spec["bc"],
+                  spec["skip"]],
+        out_specs=[spec["x"], spec["states"]] if save else spec["x"],
+        out_shape=[y, states] if save else y,
+        scratch_shapes=[pltpu.VMEM((widths, n, _LANES), jnp.float32),
+                        pltpu.VMEM((tile, tile), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_SSD_VMEM),
+        cost_estimate=pl.CostEstimate(
+            flops=b * t * (2 * tile * hp + 4 * hn),
+            transcendentals=b * t * tile * hp // p,
+            bytes_accessed=2 * x2.size * x2.dtype.itemsize
+            + (states.size * 4 if save else 0)),
+        interpret=interpret,
+    )(x2, rows, B2, C2, skip)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def _ssd_bwd(x2, rows, B2, C2, skip, dy2, states, cfg, interpret):
+    tile, heads, p, n, cells = cfg
+    b, t, hp, nt, nj, widths = _ssd_sizes(x2, cfg)
+    spec = _ssd_specs(cfg, nt, True)
+    like = (x2, rows, B2, dy2)
+    hn = hp // p * n
+    return _named_call(
+        "ssd_bwd",
+        functools.partial(_ssd_bwd_kernel, tile=tile, heads=heads, p=p,
+                          cells=cells),
+        grid=(b, nt, nj),
+        in_specs=[spec["x"], spec["rows"], spec["bc"], spec["bc"],
+                  spec["skip"], spec["x"], spec["states"]],
+        out_specs=[spec["x"], spec["rows"], spec["bc"], spec["bc"],
+                   spec["dskip"]],
+        out_shape=[_struct(x2.shape, x2.dtype, *like),
+                   _struct(rows.shape, jnp.float32, *like),
+                   _struct(B2.shape, B2.dtype, *like),
+                   _struct(C2.shape, C2.dtype, *like),
+                   _struct((b, nt, nj, 1, heads * p), jnp.float32, *like)],
+        scratch_shapes=[pltpu.VMEM((widths, n, _LANES), jnp.float32),
+                        pltpu.VMEM((tile, tile), jnp.float32),
+                        pltpu.VMEM((tile, tile), jnp.float32),
+                        pltpu.VMEM((2, tile, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_SSD_VMEM),
+        cost_estimate=pl.CostEstimate(
+            flops=b * t * (4 * tile * hp + 10 * hn),
+            transcendentals=b * t * tile * hp // p,
+            bytes_accessed=3 * x2.size * x2.dtype.itemsize + states.size * 4),
+        interpret=interpret,
+    )(x2, rows, B2, C2, skip, dy2, states)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _ssd_core(x2, rows, B2, C2, skip, cfg):
+    return _ssd_fwd(x2, rows, B2, C2, skip, cfg, False, _interpret())
+
+
+def _ssd_core_fwd(x2, rows, B2, C2, skip, cfg):
+    y2, states = _ssd_fwd(x2, rows, B2, C2, skip, cfg, True, _interpret())
+    return y2, (x2, rows, B2, C2, skip, states)
+
+
+def _ssd_core_bwd(cfg, saved, dy2):
+    # (the backward pass's operations carry the name stack of the call
+    # site, ``.../mixer/ssd`` as ``ops/ssd.py`` calls this: ``ssd_ms``
+    # reads the scope, and tests/test_tpu_lowering.py holds the path)
+    dx2, drows, dB2, dC2, dskip = _ssd_bwd(*saved[:-1], dy2, saved[-1], cfg,
+                                           _interpret())
+    return dx2, drows, dB2, dC2, jnp.sum(dskip, axis=(0, 1))
+
+
+_ssd_core.defvjp(_ssd_core_fwd, _ssd_core_bwd)
+
+
+def ssd_scan(x, dt, A, B, C, D):
+    """``ops/ssd.ssd_chunked``'s scan on the kernels above, for operands
+    ``ssd_supported`` admits: the same mathematics at the same precision
+    (matmul operands in ``x.dtype`` with float32 accumulation; dt, A, the
+    cumulative sums and every ``exp`` float32, every exponent <= 0), the
+    state between tiles float32 and cast at the MXU's operand only. The
+    caller opens the ``ssd`` scope."""
+    b, t, h, p = x.shape
+    g = B.shape[2] if B.ndim == 4 else 1
+    n, f32 = B.shape[-1], jnp.float32
+    route = ssd_route(t, h, p, n, g)
+    tile, heads = route["tile"], route["heads"]
+    pad = -t % tile
+    dt = dt.astype(f32)
+    B, C = (a.reshape(b, t, g * n).astype(x.dtype) for a in (B, C))
+    x2 = x.reshape(b, t, h * p)
+    if pad:
+        # steps of dt = 0 leave the state alone
+        x2, dt, B, C = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+                        for a in (x2, dt, B, C))
+    nt = (t + pad) // tile
+    # the cumulative sum inside a tile as a product with a triangle of
+    # ones (exact to float32's rounding at "highest"; XLA's own cumsum is a
+    # reduce-window that takes as long as the forward kernel)
+    sums = jnp.tril(jnp.ones((tile, tile), f32))
+    cum = jnp.einsum("ls,bish->bilh", sums,
+                     (dt * A.astype(f32)).reshape(b, nt, tile, h),
+                     precision="highest").reshape(dt.shape)
+    rows = jnp.stack([dt, cum], axis=1).transpose(0, 1, 3, 2)  # [b, 2, H, T]
+    skip = jnp.repeat(D.astype(f32), p).reshape(h // heads, 1, heads * p)
+    y2 = _ssd_core(x2, rows, B, C, skip, (tile, heads, p, n, h // g // heads))
+    return y2[:, :t].reshape(x.shape)
+
+
 # ------------------------------------------------------------- path gates
 # dispatcher name -> shape gate over the dispatcher's operands; the only
 # reader is kernel_path above (which adds the mode and vma conditions)
@@ -1959,4 +2383,6 @@ _GATES = {
     "grouped_matmul": lambda lhs, rhs: _grouped_ok(lhs, rhs.shape[2], rhs),
     "grouped_matmul_t": lambda lhs, rhs: _grouped_ok(lhs, rhs.shape[1], rhs),
     "grouped_outer": lambda lhs, rhs: _grouped_ok(lhs, rhs.shape[1], rhs),
+    # x [b, T, H, P] and B [b, T, N] or [b, T, G, N]
+    "ssd_scan": ssd_supported,
 }

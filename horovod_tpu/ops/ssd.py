@@ -10,9 +10,15 @@ The layer is, per head, the recurrence
 without a loop over time: inside a chunk of ``L`` positions the recurrence
 unrolls to the masked product ``(C B^T * decay) x`` — attention with a decay
 in place of the softmax — and across chunks only the ``[P, N]`` state at each
-chunk's end is carried. Everything is a batched matmul or an elementwise op
-that XLA lays out for the MXU; there is no Pallas kernel here yet, and the
-backward pass is autodiff of the forward.
+chunk's end is carried. That dual form, batched matmuls and elementwise ops
+that XLA lays out and autodiff takes back, is the reference path: off the
+chip, with kernels off, and for a shape ``pallas_kernels.ssd_route``
+refuses. On the chip the scan runs on a Pallas kernel pair
+(``pallas_kernels.ssd_scan``: ``ssd_fwd``, and ``ssd_bwd`` under a
+hand-written backward pass) that walks a sequence's tiles in order with
+every head's state in VMEM, at its own tile edge, the groups of ``B`` and
+``C`` an index map; ``pallas_kernels.kernel_path("ssd_scan", x, B)`` says
+which path a call takes.
 
 ``causal_conv1d`` and ``gated_rms_norm`` are the depthwise convolution before
 the scan and the gated normalisation after it (``models/hybrid.py``).
@@ -24,6 +30,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from . import pallas_kernels as pk
 
 
 def ssd_chunked(x, dt, A, B, C, D, chunk: int = 256):
@@ -42,8 +50,13 @@ def ssd_chunked(x, dt, A, B, C, D, chunk: int = 256):
     between two positions of one chunk, or over whole chunks, never as a
     ratio of two large cumulative products. A ``T`` that ``chunk`` does not
     divide is padded with steps of ``dt = 0``, which leave the state alone.
+    On the kernel path ``chunk`` decides nothing: the tile is the kernel's
+    own, and so is the padding.
     """
     b, t, h, p = x.shape
+    if pk.kernel_path("ssd_scan", x, B) == "pallas":
+        with jax.named_scope("ssd"):
+            return pk.ssd_scan(x, dt, A, B, C, D)
     if B.ndim == 4:
         # G groups are G scans, each of H / G heads over one group
         g = B.shape[2]

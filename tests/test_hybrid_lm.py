@@ -173,10 +173,65 @@ def test_ssd_chunked_is_the_recurrence(seq, chunk):
         assert relative(a, b) <= 10 * SCAN_TOL, name
 
 
-def test_control_bf16_decay_exponents_fail_the_scan_tolerance():
+def kernel_operands(seq, seed=5):
+    """Operands the scan's Pallas kernels take (``pallas_kernels.ssd_route``:
+    8 heads a grid cell, a head width that divides a lane width, a state of
+    whole lane widths), small: four heads share a lane width."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    b, h, p, n = 2, 8, 32, 128
+    x = jax.random.normal(keys[0], (b, seq, h, p), jnp.float32)
+    dt = 0.2 * jax.nn.softplus(
+        jax.random.normal(keys[1], (b, seq, h), jnp.float32))
+    A = -jnp.exp(jax.random.normal(keys[2], (h,), jnp.float32))
+    B = 0.3 * jax.random.normal(keys[3], (b, seq, n), jnp.float32)
+    C = 0.3 * jax.random.normal(keys[4], (b, seq, n), jnp.float32)
+    D = jax.random.normal(keys[5], (h,), jnp.float32)
+    return x, dt, A, B, C, D
+
+
+@pytest.mark.parametrize("seq,chunk", [(512, 256), (512, 512), (300, 64)],
+                         ids=["a_tile_a_chunk", "two_tiles_a_chunk", "padded"])
+def test_the_scan_kernels_are_the_recurrence(seq, chunk, monkeypatch):
+    """The kernel path (``ssd_fwd`` and the hand-written ``ssd_bwd``, through
+    the Pallas interpreter) against the recurrence at the tolerance the
+    dual form is held to: measured 2e-7 in ``y`` and 7e-6 in the worst
+    gradient (A's). Its tile is its own, 256, whatever ``chunk`` says: two
+    tiles, of which 300 positions fill the second to 44."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setenv("HVD_PALLAS", "interpret")
+    ops = kernel_operands(seq)
+    assert pk.kernel_path("ssd_scan", ops[0], ops[3]) == "pallas"
+    assert pk.ssd_route(seq, 8, 32, 128, 1) == {
+        "path": "pallas", "tile": 256, "heads": 8}
+    want = jax.jit(reference.recurrence)(*ops)
+    got = ssd_chunked(*ops, chunk=chunk)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    assert relative(got, want) <= SCAN_TOL
+
+    def loss(fn, **kw):
+        return lambda *a: jnp.sum(fn(*a, **kw) * jnp.cos(want))
+
+    g_got = jax.grad(loss(ssd_chunked, chunk=chunk), argnums=range(6))(*ops)
+    g_want = jax.jit(jax.grad(loss(reference.recurrence),
+                              argnums=range(6)))(*ops)
+    for name, a, b in zip("x dt A B C D".split(), g_got, g_want):
+        assert relative(a, b) <= 10 * SCAN_TOL, name
+
+
+@pytest.mark.parametrize("path", ["reference", "pallas"])
+def test_control_bf16_decay_exponents_fail_the_scan_tolerance(path,
+                                                              monkeypatch):
     """``dt`` and ``A`` rounded to bf16 before the scan: every decay
-    exponent one precision lower, everything else float32."""
-    x, dt, A, B, C, D = scan_operands(32)
+    exponent one precision lower, everything else float32. On the dual
+    form, and on the kernels."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    if path == "pallas":
+        monkeypatch.setenv("HVD_PALLAS", "interpret")
+    x, dt, A, B, C, D = scan_operands(32) if path == "reference" \
+        else kernel_operands(300)
+    assert pk.kernel_path("ssd_scan", x, B) == path
     want = reference.recurrence(x, dt, A, B, C, D)
 
     def low(a):
@@ -184,6 +239,7 @@ def test_control_bf16_decay_exponents_fail_the_scan_tolerance():
 
     got = ssd_chunked(x, low(dt), low(A), B, C, D, chunk=8)
     assert relative(got, want) > 100 * SCAN_TOL
+    assert relative(ssd_chunked(x, dt, A, B, C, D, chunk=8), want) <= SCAN_TOL
 
 
 def test_remat_modes_agree():

@@ -338,6 +338,147 @@ def test_the_chunked_scan_with_groups_is_the_recurrence(groups):
         assert relative(ssd.ssd_chunked(*first, chunk=16), want) > 0.1
 
 
+def kernel_operands(groups, h, p, t, seed=0, b=1, n=128, dtype=jnp.float32):
+    """Operands the scan's Pallas kernels take (``pk.ssd_route``): a group
+    of at least 8 heads, a state of one lane width."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 7)
+    bc = (b, t, n) if groups is None else (b, t, groups, n)
+
+    def normal(key, shape):
+        return jax.random.normal(key, shape, jnp.float32)
+
+    return (normal(keys[0], (b, t, h, p)).astype(dtype),
+            0.3 * jax.nn.softplus(normal(keys[1], (b, t, h))),
+            -jnp.exp(0.3 * normal(keys[2], (h,))),
+            (0.3 * normal(keys[3], bc)).astype(dtype),
+            (0.3 * normal(keys[4], bc)).astype(dtype), normal(keys[5], (h,)))
+
+
+#: groups, heads, head width, positions, chunk: the published head width
+#: (two heads a lane width) under one, two and no axis of groups (16 heads
+#: a grid cell, 8 and 8); eight groups of eight narrow heads (a lane width
+#: is a cell); a head a lane width. The kernel's tile is 256 (128 for a
+#: sequence no longer): 300 and 400 positions fill the second tile in
+#: part; a chunk of 512 is two kernel tiles, 256 one, 64 a quarter of one.
+KERNEL_CASES = {"one_group": (1, 16, 64, 512, 256),
+                "two_groups": (2, 16, 64, 300, 512),
+                "no_group_axis": (None, 8, 64, 128, 64),
+                "eight_groups": (8, 64, 16, 400, 256),
+                "a_head_a_lane_width": (2, 16, 128, 300, 512)}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_the_scan_kernels_with_groups_are_the_recurrence(case, monkeypatch):
+    """``y`` and all six gradients of the kernel path (``ssd_fwd`` and the
+    hand-written ``ssd_bwd`` through the Pallas interpreter, the groups an
+    index map) against the float32 recurrence, at the dual form's
+    tolerances: measured 2e-7 and, in A's gradient, 7e-6."""
+    monkeypatch.setenv("HVD_PALLAS", "interpret")
+    groups, h, p, t, chunk = KERNEL_CASES[case]
+    operands = kernel_operands(groups, h, p, t)
+    assert pk.kernel_path("ssd_scan", operands[0], operands[3]) == "pallas"
+    with jax.default_matmul_precision("highest"):
+        got = ssd.ssd_chunked(*operands, chunk=chunk)
+        want = reference.recurrence(*(
+            a[:, :, None] if groups is None and i in (3, 4) else a
+            for i, a in enumerate(operands)))
+        assert got.shape == want.shape == (1, t, h, p)
+        assert relative(got, want) <= 1e-5
+        weight = jnp.cos(jnp.arange(want.size, dtype=jnp.float32)).reshape(
+            want.shape)
+        g_got = jax.grad(lambda *a: jnp.sum(ssd.ssd_chunked(
+            *a, chunk=chunk) * weight), range(6))(*operands)
+        g_want = jax.grad(lambda *a: jnp.sum(reference.recurrence(*(
+            x[:, :, None] if groups is None and i in (3, 4) else x
+            for i, x in enumerate(a))) * weight), range(6))(*operands)
+    for name, a, b in zip("x dt A B C D".split(), g_got, g_want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert relative(a, b) <= 2e-5, (name, relative(a, b))
+    if groups and groups > 1:
+        first = tuple(a[:, :, :1] if i in (3, 4) else a
+                      for i, a in enumerate(operands))
+        assert relative(ssd.ssd_chunked(*first, chunk=chunk), want) > 0.1
+
+
+#: bf16 operands, either path against the float32 recurrence and the two
+#: against each other: measured 2.4e-3 to 3.4e-3 in ``y`` and up to 6e-3 in
+#: a gradient (each path rounds its scores and its state once to bf16,
+#: 2^-9, at different places: the kernels fold dt into the scores' columns
+#: forward and into x backward); 2e-2 is what the chip's check allows a
+#: bf16 program (``test_hybrid_lm``'s control)
+BF16_SCAN_TOL = 2e-2
+
+
+@pytest.mark.parametrize("case", ["one_group", "two_groups"])
+def test_the_scan_kernels_at_bf16_stay_by_the_dual_form(case, monkeypatch):
+    groups, h, p, t, chunk = KERNEL_CASES[case]
+    operands = kernel_operands(groups, h, p, t, dtype=jnp.bfloat16)
+    exact = tuple(a.astype(jnp.float32) for a in operands)
+    weight = jnp.cos(jnp.arange(operands[0].size, dtype=jnp.float32)
+                     ).reshape(operands[0].shape)
+
+    def both(*a):
+        def loss(*a):
+            y = ssd.ssd_chunked(*a, chunk=chunk)
+            return jnp.sum(y.astype(jnp.float32) * weight), y
+        grads, y = jax.grad(loss, range(6), has_aux=True)(*a)
+        return (y,) + grads
+
+    assert pk.kernel_path("ssd_scan", operands[0], operands[3]) == "reference"
+    dual = both(*operands)
+    monkeypatch.setenv("HVD_PALLAS", "interpret")
+    assert pk.kernel_path("ssd_scan", operands[0], operands[3]) == "pallas"
+    kernels = both(*operands)
+    want = (reference.recurrence(*exact),) + jax.grad(
+        lambda *a: jnp.sum(reference.recurrence(*a) * weight),
+        range(6))(*exact)
+    for name, k, d, w in zip("y x dt A B C D".split(), kernels, dual, want):
+        assert k.dtype == d.dtype and k.shape == d.shape, name
+        assert relative(k, d) <= BF16_SCAN_TOL, (name, relative(k, d))
+        assert relative(k, w) <= BF16_SCAN_TOL, (name, relative(k, w))
+        assert relative(k, w) <= 1.5 * relative(d, w) + 1e-3, name
+
+
+def scan_shapes(h, p, n, groups, t=4096, dtype=jnp.bfloat16):
+    bc = (1, t, n) if groups is None else (1, t, groups, n)
+    return (jax.ShapeDtypeStruct((1, t, h, p), dtype),
+            jax.ShapeDtypeStruct(bc, dtype))
+
+
+#: name -> heads, head width, state, groups, HVD_PALLAS, the path: the two
+#: published shapes (granite-4.0-h-micro, Nemotron-3-Super) on and off the
+#: chip's mode, and what the gate refuses
+PATH_CASES = {
+    "granite": (64, 64, 128, None, "on", "pallas"),
+    "nemotron_h": (128, 64, 128, 8, "on", "pallas"),
+    "eight_heads_a_group": (64, 64, 128, 8, "on", "pallas"),
+    "granite_interpreted": (64, 64, 128, None, "interpret", "pallas"),
+    "granite_kernels_off": (64, 64, 128, None, "0", "reference"),
+    "nemotron_h_kernels_off": (128, 64, 128, 8, "0", "reference"),
+    "granite_off_the_chip": (64, 64, 128, None, "", "reference"),
+    "four_heads_a_group": (32, 64, 128, 8, "on", "reference"),
+    "a_state_of_16": (64, 64, 16, None, "on", "reference"),
+    "a_head_of_48": (64, 48, 128, None, "on", "reference"),
+    "a_head_of_8": (64, 8, 128, None, "on", "reference"),
+    "heads_the_groups_do_not_divide": (64, 64, 128, 3, "on", "reference"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PATH_CASES))
+def test_kernel_path_says_which_scan_runs(case, monkeypatch):
+    h, p, n, groups, env, path = PATH_CASES[case]
+    monkeypatch.setenv("HVD_PALLAS", env)
+    assert pk.kernel_path("ssd_scan", *scan_shapes(h, p, n, groups)) == path
+    route = pk.ssd_route(4096, h, p, n, groups or 1)
+    assert (route["path"] == "pallas") == (path == "pallas" or env in ("0", ""))
+    if route["path"] == "pallas":
+        assert route["tile"] == 256
+        assert route["heads"] == (8 if case == "eight_heads_a_group" else 16)
+    # float64 operands (the suite enables x64) are nobody's kernel
+    assert pk.kernel_path("ssd_scan", *scan_shapes(
+        h, p, n, groups, dtype=jnp.float64)) == "reference"
+
+
 def test_one_group_is_todays_scan_to_the_bit():
     """``B`` and ``C`` ``[b, T, N]`` take the path they always took, whose
     code did not move; one group given as ``[b, T, 1, N]`` is the same

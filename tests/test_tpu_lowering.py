@@ -19,6 +19,7 @@ same list of cases (``chip_smoke.kernel_cases``) on the chip against their
 references.
 """
 
+import functools
 import re
 
 import numpy as np
@@ -395,3 +396,121 @@ def test_latent_routed_feed_forward_compiles_for_v5e_at_the_published_widths(
     for rows in sizes:
         assert f"bf16[{rows},{width}]" in text
         assert f"bf16[{rows},{2 * width}]" not in text
+
+
+# name -> heads, groups (None: B and C [b, T, N]), chunk, head width,
+# state, positions: the Mamba-2 layer of granite-4.0-h-micro and of
+# NVIDIA-Nemotron-3-Super-120B-A12B at the cells' one sequence of 4096
+# positions; and the gate's other corners at a padded length: a head a
+# lane width (nothing to pick between heads) with a state of two, eight
+# heads a lane width
+_SCAN_CASES = {"granite": (64, None, 256, 64, 128, 4096),
+               "nemotron_h": (128, 8, 128, 64, 128, 4096),
+               "a_head_a_lane_width": (16, 2, 128, 128, 256, 700),
+               "narrow_heads": (64, 8, 64, 16, 128, 100)}
+
+
+@pytest.mark.parametrize("name", sorted(_SCAN_CASES))
+def test_scan_kernels_compile_for_v5e_at_the_published_widths(name,
+                                                               v5e_devices):
+    """``ops/ssd.ssd_chunked`` forward and backward at the two cells'
+    shapes: the scan is the Pallas pair ``ssd_fwd`` (the variant that saves
+    the tiles' entering states) and ``ssd_bwd``, nothing of it is the dual
+    form's ``[b, c, H, L, L]`` decay or ``[b, c, H, P, N]`` float32
+    states, and what is saved is one ``[N, 128]`` state a tile and lane
+    width of heads."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.ops import pallas_kernels as pk, ssd
+
+    (h, groups, chunk, p, n, t), b = _SCAN_CASES[name], 1
+    one = SingleDeviceSharding(v5e_devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    bc = (b, t, n) if groups is None else (b, t, groups, n)
+    operands = (shape((b, t, h, p), jnp.bfloat16), shape((b, t, h), jnp.float32),
+                shape((h,), jnp.float32), shape(bc, jnp.bfloat16),
+                shape(bc, jnp.bfloat16), shape((h,), jnp.float32))
+    assert pk.kernel_path("ssd_scan", operands[0], operands[3]) == "pallas"
+    route = pk.ssd_route(t, h, p, n, groups or 1)
+
+    def loss(*a):
+        return jnp.sum(ssd.ssd_chunked(*a, chunk=chunk).astype(jnp.float32) ** 2)
+
+    with jax.enable_x64(False):
+        forward = jax.jit(functools.partial(ssd.ssd_chunked, chunk=chunk)).trace(
+            *operands).lower(lowering_platforms=("tpu",)).compile().as_text()
+        text = jax.jit(jax.grad(loss, argnums=range(6))).trace(
+            *operands).lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert [name for name, _ in _scan_calls(forward)] == ["ssd_fwd"]
+    assert sorted(name for name, _ in _scan_calls(text)) == \
+        ["ssd_bwd", "ssd_fwd"]
+    tiles, widths = -(-t // route["tile"]), h * p // 128
+    assert f"f32[{b},{tiles},{widths},{n},128]" in text
+    assert f"f32[{b},{tiles},{widths},{n},128]" not in forward
+    for c, edge in ((-(-t // chunk), chunk), (tiles, route["tile"])):
+        for dims in ((b, c, h, p, n), (b, c, h, edge, edge)):
+            assert "[" + ",".join(map(str, dims)) + "]" not in text, dims
+
+
+def _scan_calls(hlo_text):
+    """``(kernel, op_name path)`` of every call of a scan kernel."""
+    return re.findall(
+        r"%(ssd_fwd|ssd_bwd)[.\d]* = [^\n]*custom_call_target="
+        r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', hlo_text)
+
+
+def _hybrid_grad_text(v5e_devices, remat):
+    """The compiled gradient of a two-layer Mamba-2 model whose scan the
+    kernels take (8 heads of 16, state 128, 256 positions), for a v5e."""
+    from jax.sharding import SingleDeviceSharding
+
+    from horovod_tpu.models.hybrid import HybridLM
+
+    model = HybridLM(
+        vocab_size=512, layer_kinds=("mamba", "mamba"), d_model=128,
+        ffn_width=256, attn_heads=4, attn_kv_heads=2, attn_head_dim=32,
+        ssm_heads=8, ssm_head_dim=16, ssm_state=128, ssm_chunk=64,
+        remat=remat)
+    one = SingleDeviceSharding(v5e_devices[0])
+    toks = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one)
+    params = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 256), jnp.int32))["params"]))
+    with jax.enable_x64(False):
+        return jax.jit(jax.grad(lambda p, x: jnp.sum(
+            model.apply({"params": p}, x).astype(jnp.float32)))).trace(
+                params, toks).lower(
+                    lowering_platforms=("tpu",)).compile().as_text()
+
+
+@pytest.mark.parametrize("remat", ["none", "full"])
+def test_hybrid_step_runs_the_scan_kernels_under_the_ssd_scope(remat,
+                                                                v5e_devices):
+    """A Mamba-2 layer's scan is ``ssd_fwd`` in the forward pass, again in
+    a recomputed block (``remat="full"`` keeps a block's input, not the
+    tiles' states), and ``ssd_bwd``; every one of them sits under
+    ``block_<i>/mixer/ssd``, the backward kernel too (``ssd_ms`` reads the
+    scope, and a backward kernel outside it would read as a gain)."""
+    text = _hybrid_grad_text(v5e_devices, remat)
+    calls = _scan_calls(text)
+    layers = 2
+    assert sorted(name for name, _ in calls) == \
+        ["ssd_bwd"] * layers + ["ssd_fwd"] * layers * (2 if remat == "full"
+                                                       else 1)
+    for name, path in calls:
+        block, = re.findall(
+            r"block_(\d)/mixer/ssd/jit\(_" + name + r"\)/" + name, path)
+        assert ("transpose(" in path) == (name == "ssd_bwd"
+                                          or "rematted_computation" in path)
+    for block in range(layers):
+        paths = [path for _, path in calls if f"block_{block}/" in path]
+        assert len(paths) == len(calls) // layers
+    if remat == "full":
+        assert sum("rematted_computation" in path for _, path in calls) \
+            == layers
+    # nothing of the dual form: no state of a chunk crosses HBM
+    assert not re.search(r"f32\[1,\d+,8,16,128\]", text)
