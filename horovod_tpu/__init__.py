@@ -21,7 +21,11 @@ SPMD fast path (the performance path — everything in one jitted step)::
     step = hvd.spmd.make_train_step(loss_fn, optimizer)
 """
 
-from .basics import (  # noqa: F401
+import time as _time
+
+_IMPORT_START = _time.perf_counter()   # the `import` span: here to the end
+
+from .basics import (  # noqa: F401,E402
     Adasum,
     Average,
     Sum,
@@ -106,3 +110,7 @@ from . import tracing  # noqa: F401
 from .run.api import run  # noqa: F401
 
 __version__ = "0.1.0"
+
+from .metrics import phases as _phases  # noqa: E402
+
+_phases.record("import", _IMPORT_START, _time.perf_counter())

@@ -29,6 +29,7 @@ from typing import Any, Optional, Sequence
 import numpy as np
 
 from .exceptions import NotInitializedError
+from .metrics import phases
 
 logger = logging.getLogger("horovod_tpu")
 
@@ -132,6 +133,14 @@ def init(
     ``ranks`` (subset init, `basics.py:33-65` in the reference) is accepted for
     API parity; subsetting is only meaningful in multiprocess mode.
     """
+    if _state.initialized:
+        return
+    phases.install_jax_listeners()
+    with phases.phase("init"):
+        _init(_cluster_size, _devices)
+
+
+def _init(_cluster_size, _devices) -> None:
     import jax
 
     global _state
@@ -244,10 +253,11 @@ def init(
                 mesh=_build_mesh(devices),
                 rank_mesh=_build_mesh(devices[:1]),
             )
-        from .runtime.engine import Engine
+        with phases.phase("init/engine"):
+            from .runtime.engine import Engine
 
-        st.engine = Engine(st)
-        st.engine.start()
+            st.engine = Engine(st)
+            st.engine.start()
         _state = st
         if st.rank0 == 0:
             # aggregating process: serve /metrics when HOROVOD_METRICS_PORT
@@ -281,6 +291,13 @@ def register_shutdown_hook(fn) -> None:
 
 def shutdown() -> None:
     """Stop the background engine and reset state (`operations.cc:636-640`)."""
+    if not _state.initialized:
+        return
+    with phases.phase("shutdown"):
+        _shutdown()
+
+
+def _shutdown() -> None:
     global _state
     with _init_lock:
         if not _state.initialized:

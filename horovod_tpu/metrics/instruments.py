@@ -567,3 +567,59 @@ def snapshot_unix_seconds():
         "Unix time the engine loop last refreshed this registry (NOT the "
         "scrape time — a stale value under a live /metrics endpoint means "
         "the process is wedged).", agg="max")
+
+
+# -- set-up of the compiled path (metrics/phases.py; docs/observability.md) --
+
+def phase_seconds():
+    return get_registry().counter(
+        "hvd_phase_seconds_total",
+        "Host seconds by phase of the compiled path (import, init, "
+        "shutdown, compile/trace, compile/lower, compile/backend, "
+        "compile/cache_read): each span's self time, its interval less "
+        "what spans nested in it on the same thread cover, so a thread's "
+        "phases add up to its wall time (metrics/phases.py).",
+        labels=("phase",))
+
+
+def phase_count():
+    return get_registry().counter(
+        "hvd_phase_total", "Spans closed, by phase.", labels=("phase",))
+
+
+def phase_spans_dropped():
+    return get_registry().counter(
+        "hvd_phase_spans_dropped_total",
+        "Spans counted in hvd_phase_* and left out of the in-memory span "
+        "list because it was at its cap (phases.MAX_SPANS).")
+
+
+def compiles():
+    return get_registry().counter(
+        "hvd_compiles_total",
+        "Programs JAX handed to the backend (compiled, or loaded from the "
+        "persistent cache), by jitted function; names past the first few "
+        "dozen are _other. An increment after warm-up is a recompile.",
+        labels=("program",))
+
+
+def compile_cache_requests():
+    return get_registry().counter(
+        "hvd_compile_cache_requests_total",
+        "Programs looked up in JAX's persistent compilation cache.")
+
+
+def compile_cache():
+    return get_registry().counter(
+        "hvd_compile_cache_total",
+        "Persistent compilation cache outcomes: hit = loaded, miss = "
+        "compiled and written. A request that is neither was compiled and "
+        "not kept (under JAX's compile-time or entry-size threshold).",
+        labels=("outcome",))
+
+
+def compile_seconds_saved():
+    return get_registry().counter(
+        "hvd_compile_seconds_saved_total",
+        "Compile seconds the persistent cache saved: each hit's recorded "
+        "compile time less the time its load took, where positive.")

@@ -23,9 +23,14 @@ def enable() -> str:
 
     With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already uses that directory
     and this function sets no other. Unset, the cache goes to the fixed
-    in-checkout path. Returns the directory in use."""
+    in-checkout path. Returns the directory in use. From here on every
+    lookup is a ``compile/*`` span and counted by outcome
+    (``metrics/phases.py``)."""
     import jax
 
+    from ..metrics import phases
+
+    phases.install_jax_listeners()
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -6,11 +6,14 @@ which every reader the cell lists has to survive. Nothing here is a device
 number.
 """
 
+import functools
+
 import pytest
 
 from chipbench import harness
 from chipbench.tests.test_cells import *  # noqa: F401,F403
 from chipbench.tests.test_rehearsal import KEYS, rehearse
+from chipbench.tests.test_setup_phases import *  # noqa: F401,F403
 
 #: cell -> the per-layer metrics that are its architecture's own
 HYBRID_CELLS = {
@@ -21,6 +24,49 @@ HYBRID_CELLS = {
                                "moe_experts_ms", "moe_experts_roofline",
                                "moe_route_ms", "moe_shared_ms",
                                "moe_latent_ms", "lm_head_ms"}}
+
+
+#: the eight metrics that split ``setup_s`` (``chipbench/setup_phases.py``)
+SETUP = {"setup_import_s", "setup_init_s", "setup_trace_s", "setup_lower_s",
+         "setup_cache_read_s", "setup_backend_compile_s",
+         "setup_cache_hit_share", "setup_unattributed_s"}
+
+
+@functools.lru_cache(maxsize=None)
+def traced(cell):
+    """One ``--trace 1`` rehearsal a cell, shared by the tests that read it."""
+    return rehearse(cell, 1)
+
+
+@pytest.mark.parametrize("cell", ["gpt2m-train-s1024",
+                                  "granite4hm-train-s4096"])
+def test_traced_rehearsal_splits_setup_s_into_its_phases(cell):
+    line, out = traced(cell)
+    assert line["correct"] is True, out
+    values = {k[len("rehearsal_"):]: v["value"]
+              for k, v in line["metrics"].items()}
+    assert SETUP <= set(values)
+    assert all(values[name] >= 0 for name in SETUP)
+    seconds = SETUP - {"setup_cache_hit_share"}
+    assert {line["metrics"][f"rehearsal_{name}"]["unit"]
+            for name in seconds} == {"s"}
+    assert 0 <= values["setup_cache_hit_share"] <= 100
+    # the program's own phases are under set-up's length, and with what no
+    # span covers they are all of it
+    setup_s = float(out.split('"setup_s": ')[1].split("}")[0])
+    spans = sum(values[name] for name in seconds - {"setup_unattributed_s"})
+    assert 0 < spans <= setup_s
+    assert spans + values["setup_unattributed_s"] == pytest.approx(setup_s)
+    # the package import and the step's trace and lowering are never free
+    assert values["setup_import_s"] > 0.05 and values["setup_trace_s"] > 0.05
+    assert values["setup_lower_s"] > 0.05
+    # the reference check compiles its two programs after the window: what
+    # compiled in set-up is inside the first calls the harness timed there
+    # (eager operations between them aside)
+    compiled = sum(values[name] for name in (
+        "setup_trace_s", "setup_lower_s", "setup_cache_read_s",
+        "setup_backend_compile_s"))
+    assert compiled < values["compile_s"] + 1.0
 
 
 @pytest.mark.parametrize("cell", HYBRID_CELLS)
@@ -35,7 +81,7 @@ def test_hybrid_cell_rehearsal_prints_the_end_to_end_line(cell):
 
 @pytest.mark.parametrize("cell", HYBRID_CELLS)
 def test_hybrid_cell_rehearsal_reads_every_per_layer_metric_it_lists(cell):
-    line, out = rehearse(cell, 1)
+    line, out = traced(cell)
     assert line["correct"] is True, out
     declared = {m["name"] for m in
                 harness.declared_metrics(cell)["per_layer"]}
