@@ -161,6 +161,27 @@ def _named_call(name: str, kernel, **kwargs):
     return named
 
 
+# THE RULE for a dispatcher that ends in ``_named_call(...)``: it sits under
+# ``jax.jit``, the arrays its operands and everything else that picks its
+# kernel (tiles, flags, a scale, ``interpret``) a static argument. A step
+# calls a kernel at every layer and pass; under ``jax.jit`` the kernel's body
+# is traced by ``pallas_call`` and lowered to Mosaic once a distinct (shapes,
+# dtypes, static arguments), and every further call site is a cached ``pjit``
+# equation and a ``call`` of the one lowered function. Outside it each site
+# was traced and lowered on its own: 48 flash sites were 11.8 s of a
+# four-chip warm start's tracing, 112 grouped products 16 s of a one-chip
+# one (PERF.md §6, PR 36, PR 33). XLA inlines the calls before it fuses, so
+# the compiled step is the same, input fusion included, and a call site
+# keeps its own scope path: ``.../block_3/jit(_flash_fwd_once_call)/
+# flash_fwd``. What decides WHICH dispatcher a shape takes (``flash_route``,
+# ``_pick_block``, ``kernel_path``, ``grouped_route``, ``ssd_route``) stays
+# outside the boundary; what a dispatcher reads of the module or of the
+# environment inside it (``_SUB_TILE``, HVD_PALLAS_INPUT_FUSION) is read
+# when a shape is first traced, so a test that patches one forgets the
+# traces first (``dispatcher.clear_cache()``).
+_FLASH_STATIC = ("causal", "scale", "block_q", "block_k", "interpret")
+
+
 def _struct(shape, dtype, *like):
     """ShapeDtypeStruct carrying the union of the inputs' varying-mesh-axes —
     required for pallas_call outputs inside ``shard_map(check_vma=True)``."""
@@ -433,9 +454,11 @@ def _flash_fwd_once_kernel(offs_ref, q_ref, k_ref, v_ref, oo_ref, lse_ref,
     lse_ref[0] = _stat_row(m_nat + jnp.log(l_safe))
 
 
+@functools.partial(jax.jit, static_argnames=_FLASH_STATIC + ("fusable",))
 def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
                          block_k, interpret, fusable):
-    """Resident-layout dispatch of the single-shot forward.
+    """Resident-layout dispatch of the single-shot forward (jitted: the
+    rule below :func:`_named_call`).
     qt: [BH, TQ, D]; kt/vt: [BH, TK, D] → (out [BH, TQ, D] in qt.dtype,
     lse [BH, 1, TQ] f32). Caller guarantees the resident budget."""
     bh, tq, d = qt.shape
@@ -549,6 +572,7 @@ def _causal_maps(causal, block_q, block_k, nq):
     return kmap, qmap
 
 
+@functools.partial(jax.jit, static_argnames=_FLASH_STATIC)
 def _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs, *, causal,
                                scale, block_q, block_k, interpret):
     """Streaming-layout dispatch of the forward step (k/v too long to keep
@@ -590,15 +614,25 @@ def _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs, *, causal,
     )(offs, qt, kt, vt, mt, lt, ot)
 
 
-def _flash_step_call(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
-                     block_q, block_k, interpret, fusable):
-    """qt/ot: [BH, T, D]; kt/vt: [BH, TK, D]; mt/lt: [BH, 1, T] f32."""
+def _flash_step_call(qt, kt, vt, mt, lt, ot, offs, *, fusable, **static):
+    """qt/ot: [BH, T, D]; kt/vt: [BH, TK, D]; mt/lt: [BH, 1, T] f32.
+    ``flash_route`` picks the layout here, outside the two dispatchers."""
     bh, tq, d = qt.shape
     tk = kt.shape[1]
     if flash_route(tq, tk, d, kt.dtype.itemsize)["step"] == "step_streaming":
-        return _flash_step_call_streaming(
-            qt, kt, vt, mt, lt, ot, offs, causal=causal, scale=scale,
-            block_q=block_q, block_k=block_k, interpret=interpret)
+        return _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs,
+                                          **static)
+    return _flash_step_call_resident(qt, kt, vt, mt, lt, ot, offs,
+                                     fusable=fusable, **static)
+
+
+@functools.partial(jax.jit, static_argnames=_FLASH_STATIC + ("fusable",))
+def _flash_step_call_resident(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
+                              block_q, block_k, interpret, fusable):
+    """Resident-layout dispatch of the forward step (the whole k/v of a
+    head in VMEM)."""
+    bh, tq, d = qt.shape
+    tk = kt.shape[1]
     kernel = functools.partial(_flash_step_kernel, causal=causal, scale=scale,
                                block_k=block_k)
     qtile = pl.BlockSpec((1, block_q, d), lambda i, j, offs: (i, j, 0))
@@ -907,7 +941,9 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
         dq_ref[0] = dq_acc[pl.ds(iq * bq, bq), :].astype(dq_ref.dtype)
 
 
-def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, d, *, causal, scale,
+@functools.partial(jax.jit, static_argnames=_FLASH_STATIC + (
+    "fusable", "out_dtype", "static_offs"))
+def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
                      block_q, block_k, interpret, fusable, out_dtype=None,
                      static_offs=None):
     """Dispatch of the one-pass backward (any length: k/v tiles stream
@@ -919,7 +955,7 @@ def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, d, *, causal, scale,
     Python ints: the cost estimate then counts the call's own plan, and
     the whole rectangle (an upper bound) where they are traced."""
     out_dtype = jnp.float32 if out_dtype is None else out_dtype
-    bh, tq = qt.shape[0], qt.shape[1]
+    bh, tq, d = qt.shape
     tk = kt.shape[1]
     _, qmap = _causal_maps(causal, block_q, block_k, tq // block_q)
     ktile = pl.BlockSpec((1, block_k, d), lambda i, j, n, offs: (i, j, 0))
@@ -1018,12 +1054,23 @@ def _flash_bwd_hm(qt, kt, vt, ot, dot, lset, q_off=0, k_off=0, *,
         static = all(isinstance(x, (int, np.integer))
                      for x in (q_off, k_off))
         return _flash_bwd_fused(
-            qt, kt, vt, ot, dot, lset, offs, d, causal=causal, scale=scale,
+            qt, kt, vt, ot, dot, lset, offs, causal=causal, scale=scale,
             block_q=block_q, block_k=block_k, interpret=interpret,
             fusable=fusable, out_dtype=out_dtype,
             static_offs=(q_off, k_off) if static else None)
+    return _flash_bwd_streaming(
+        qt, kt, vt, ot, dot, lset, offs, causal=causal, scale=scale,
+        block_q=block_q, block_k=block_k, interpret=interpret)
 
-    # else the streaming pair: one tile of each operand in VMEM, any length
+
+@functools.partial(jax.jit, static_argnames=_FLASH_STATIC)
+def _flash_bwd_streaming(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
+                         block_q, block_k, interpret):
+    """Dispatch of the streaming pair, ``flash_bwd_dq`` and
+    ``flash_bwd_dkv``: one tile of each operand in VMEM, any length.
+    Returns (dq, dk, dv) heads-major f32."""
+    bh, tq, d = qt.shape
+    tk = kt.shape[1]
     kmap, qmap = _causal_maps(causal, block_q, block_k, tq // block_q)
 
     dq = _named_call("flash_bwd_dq",
@@ -1830,10 +1877,8 @@ def gmm(lhs, rhs, group_sizes, *, tiling, transpose_rhs: bool = False):
     return _gmm(lhs, rhs, group_sizes, tiling, transpose_rhs, _interpret())
 
 
-# Jitted, here and for ``tgmm``: a step calls each kernel at 8 layers x 2
-# row capacities, and under ``jax.jit`` the kernel's body is traced and
-# lowered to Mosaic once a shape, not once a call site (112 a step: 16 s of
-# every warm start, PERF.md, PR 33). A call site keeps its own scope path.
+# Jitted, here and for ``tgmm``, by the rule below :func:`_named_call`: a
+# step calls each kernel at 8 layers x 2 row capacities.
 @functools.partial(jax.jit, static_argnums=(3, 4, 5))
 def _gmm(lhs, rhs, group_sizes, tiling, transpose_rhs, interpret):
     rows, k = lhs.shape
@@ -2238,9 +2283,8 @@ def _ssd_sizes(x2, cfg):
     return b, t, hp, t // tile, hp // (heads * p), hp // _LANES
 
 
-# Jitted, as the grouped products are and for their reason: a step calls the
-# scan a Mamba-2 layer and pass, and under ``jax.jit`` a kernel's body is
-# traced and lowered to Mosaic once a shape, not once a call site.
+# Jitted by the rule below :func:`_named_call`: a step calls the scan a
+# Mamba-2 layer and pass.
 @functools.partial(jax.jit, static_argnums=(5, 6, 7))
 def _ssd_fwd(x2, rows, B2, C2, skip, cfg, save, interpret):
     tile, heads, p, n, cells = cfg

@@ -27,6 +27,34 @@ def _interpret_mode(monkeypatch):
     yield
 
 
+# the flash kernels' jitted dispatchers (the rule below ``pk._named_call``)
+_FLASH_DISPATCHERS = ("_flash_fwd_once_call", "_flash_step_call_resident",
+                      "_flash_step_call_streaming", "_flash_bwd_fused",
+                      "_flash_bwd_streaming")
+
+
+def _forget_flash_traces():
+    """A dispatcher's body runs once a (shapes, static arguments): what a
+    test patches inside it (``_named_call``, ``_SUB_TILE``,
+    ``_live_sub_tiles``) is seen by the next trace only."""
+    for name in _FLASH_DISPATCHERS:
+        getattr(pk, name).clear_cache()
+
+
+@pytest.fixture(autouse=True)
+def _fresh_flash_traces():
+    _forget_flash_traces()
+    yield
+    _forget_flash_traces()
+
+
+def _at_every_call_site(monkeypatch):
+    """The dispatchers without their ``jax.jit``: each call site traces the
+    kernel's body itself, which is the program before the boundary."""
+    for name in _FLASH_DISPATCHERS:
+        monkeypatch.setattr(pk, name, getattr(pk, name).__wrapped__)
+
+
 def _rand_qkv(rng, b, t, h, d, dtype=jnp.float32):
     ks = jax.random.split(rng, 3)
     shape = (b, t, h, d)
@@ -458,6 +486,47 @@ def test_flash_fwd_oneshot_vs_step_path(causal, monkeypatch):
                                rtol=1e-6, atol=1e-6)
 
 
+# name -> the call that shares nothing with ``causal=True, scale=0.125``
+_OTHER_STATIC = {"causal": dict(causal=False, scale=0.125),
+                 "scale": dict(causal=True, scale=0.25)}
+
+
+@pytest.mark.parametrize("differs", sorted(_OTHER_STATIC))
+def test_flash_kernel_is_traced_once_a_shape_not_once_a_call_site(
+        differs, monkeypatch):
+    """Three attention layers of one shape under one ``jax.grad`` trace the
+    forward and the backward kernel once each (six times before the
+    dispatchers were jitted), a second program of the same shape traces
+    nothing, and a call that differs only in ``causal`` or in ``scale``
+    shares no trace with it. One batch row, as a ring shard's or granite's
+    call has: its gradients match a kernel traced at every site to the
+    bit."""
+    taken = _spy_kernels(monkeypatch)
+    q, k, v = _rand_qkv(jax.random.PRNGKey(41), 1, 128, 2, 64)
+
+    def grads(**kw):
+        def loss(q, k, v):
+            x = q
+            for _ in range(3):
+                x = pk.flash_attention(x, k, v, **kw)
+            return jnp.sum(x ** 2)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+    pair = ["_flash_fwd_once_kernel", "_flash_bwd_fused_kernel"]
+    first = grads(causal=True, scale=0.125)
+    assert taken == pair
+    grads(causal=True, scale=0.125)
+    assert taken == pair
+    grads(**_OTHER_STATIC[differs])
+    assert taken == pair + pair
+
+    del taken[:]
+    _at_every_call_site(monkeypatch)
+    for a, b in zip(first, grads(causal=True, scale=0.125)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert sorted(taken) == sorted(pair * 3)
+
+
 # ------------------------------------------------ which kernels a shape takes
 # name -> (tq, tk, d, itemsize), then what ``flash_route`` must say: the
 # forward, the step (a ring hop) and the backward. The four cells' calls, and
@@ -517,6 +586,7 @@ def test_flash_route(name, monkeypatch):
     assert taken == _ROUTE_KERNELS[forward] + _ROUTE_KERNELS[backward]
 
     del taken[:]
+    _forget_flash_traces()    # the streamed forward above is this step's call
     stat = jax.ShapeDtypeStruct((2, 2, tq), jnp.float32)
     jax.eval_shape(
         lambda q, k, v, m, l, o: pk.flash_attention_step(
@@ -741,29 +811,41 @@ def test_flash_fully_masked_rows_in_each_kernel(kernel, k_off, dead,
     scale = d ** -0.5
     taken = _spy_kernels(monkeypatch)
 
-    if kernel == "_flash_fwd_once_kernel":
-        # the single-shot forward takes offsets too, though its one caller
-        # passes zeros: heads-major operands, lse as it leaves the kernel
-        hm = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-        out_t, lse_t = pk._flash_fwd_once_call(
-            hm(q), hm(k), hm(v), jnp.array([0, k_off], jnp.int32),
-            causal=True, scale=scale, block_q=64, block_k=64,
-            interpret=True, fusable=True)
-        assert lse_t.shape == (b * h, 1, t) and lse_t.dtype == jnp.float32
-        out = pk._heads_minor(out_t, b, h, t, d)
-        lse = lse_t.reshape(b, h, t)
-    else:
-        m, l, o = pk.flash_attention_step(
-            q, k, v, jnp.full((b, h, t), -jnp.inf, jnp.float32),
-            jnp.zeros((b, h, t), jnp.float32), jnp.zeros(q.shape),
-            jnp.int32(0), jnp.int32(k_off), causal=True, scale=scale)
-        out, lse = pk.finalize_attention_stats(m, l, o, jnp.float32)
-    got = {"out": out, "lse": lse}
-    if "dq" in produces or "dk" in produces:
-        got.update(zip(("dq", "dk", "dv"), pk._flash_bwd(
-            q, k, v, out, lse, dout, jnp.int32(0), jnp.int32(k_off),
-            causal=True, scale=scale)))
+    def run():
+        if kernel == "_flash_fwd_once_kernel":
+            # the single-shot forward takes offsets too, though its one
+            # caller passes zeros: heads-major operands, lse as it leaves
+            # the kernel
+            hm = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+            out_t, lse_t = pk._flash_fwd_once_call(
+                hm(q), hm(k), hm(v), jnp.array([0, k_off], jnp.int32),
+                causal=True, scale=scale, block_q=64, block_k=64,
+                interpret=True, fusable=True)
+            assert lse_t.shape == (b * h, 1, t)
+            assert lse_t.dtype == jnp.float32
+            out = pk._heads_minor(out_t, b, h, t, d)
+            lse = lse_t.reshape(b, h, t)
+        else:
+            m, l, o = pk.flash_attention_step(
+                q, k, v, jnp.full((b, h, t), -jnp.inf, jnp.float32),
+                jnp.zeros((b, h, t), jnp.float32), jnp.zeros(q.shape),
+                jnp.int32(0), jnp.int32(k_off), causal=True, scale=scale)
+            out, lse = pk.finalize_attention_stats(m, l, o, jnp.float32)
+        got = {"out": out, "lse": lse}
+        if "dq" in produces or "dk" in produces:
+            got.update(zip(("dq", "dk", "dv"), pk._flash_bwd(
+                q, k, v, out, lse, dout, jnp.int32(0), jnp.int32(k_off),
+                causal=True, scale=scale)))
+        return got
+
+    got = run()
     assert kernel in taken
+    # through the dispatchers' jax.jit, the bits of a kernel traced at its
+    # call site (a ring hop's run-time offsets are operands of both)
+    _at_every_call_site(monkeypatch)
+    for name, x in run().items():
+        np.testing.assert_array_equal(np.asarray(got[name]), np.asarray(x),
+                                      err_msg=name)
 
     ref = dict(zip(("out", "dq", "dk", "dv"),
                    _masked_reference(q, k, v, dout, 0, k_off, True)))

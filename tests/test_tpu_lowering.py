@@ -223,7 +223,8 @@ def test_four_chip_cases_compile_for_v5e(v5e_devices):
 LAYERS = 2
 
 
-def _train_step_lowering(mesh, per_chip_batch, remat="none", seq=256):
+def _train_step_lowering(mesh, per_chip_batch, remat="none", seq=256,
+                         layers=LAYERS):
     """The data-parallel LM step over ``mesh`` with the model's default
     (Pallas) attention, lowered for the TPU."""
     import optax
@@ -231,7 +232,7 @@ def _train_step_lowering(mesh, per_chip_batch, remat="none", seq=256):
     from horovod_tpu import spmd
     from horovod_tpu.models.transformer import TransformerLM, lm_loss
 
-    model = TransformerLM(vocab_size=1024, num_layers=LAYERS, num_heads=4,
+    model = TransformerLM(vocab_size=1024, num_layers=layers, num_heads=4,
                           d_model=256, max_seq_len=seq, remat=remat)
 
     def loss_fn(p, batch):
@@ -262,8 +263,11 @@ def test_multi_device_train_step_lowers_with_flash_kernel():
     """Under a plain multi-device jit this raised 'Mosaic kernels cannot be
     automatically partitioned'."""
     text = _train_step_lowering(_cpu_mesh4(), 2).as_text()
-    # forward + fused backward per layer
-    assert text.count("tpu_custom_call") == 2 * LAYERS
+    # one Mosaic body a kernel, forward and fused backward, whatever the
+    # depth (the dispatchers are jitted), and a call of each a layer
+    assert text.count("tpu_custom_call") == 2
+    for dispatcher in ("_flash_fwd_once_call", "_flash_bwd_fused"):
+        assert len(re.findall(rf"call @{dispatcher}\(", text)) == LAYERS
 
 
 @pytest.mark.parametrize("per_chip_batch", [2, 1])
@@ -286,6 +290,90 @@ def test_recomputation_does_not_rerun_the_flash_forward(remat, v5e_devices):
     compiled = _train_step_lowering(mesh, 2, remat=remat).compile()
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') == 2 * LAYERS
+
+
+def _kernel_calls(hlo_text, kernels):
+    """``(kernel, instruction name, op_name path)`` of every Pallas call of
+    a compiled program whose kernel's name matches the regex ``kernels``."""
+    return [(kernel, kernel + suffix, path) for kernel, suffix, path in
+            re.findall(rf"%({kernels})([.\d]*) = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', hlo_text)]
+
+
+def _kernel_body_traces(spans, program):
+    """The ``compile/trace`` spans of ``pallas_call``'s inner function
+    (``wrapped``: one trace of a kernel's body each) nested in the trace of
+    ``program``; a span's ``parent`` is the listed span around it."""
+    by_id = {s.id: s for s in spans}
+
+    def under(s):
+        while s.parent is not None:
+            s = by_id[s.parent]
+            if s.name == "compile/trace" and s.program == program:
+                return True
+        return False
+
+    return [s for s in spans if s.name == "compile/trace"
+            and s.program == "wrapped" and under(s)]
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+@pytest.mark.parametrize("remat", ["none", "full"])
+def test_flash_kernels_are_traced_and_lowered_once_a_shape(remat, chips,
+                                                           v5e_devices):
+    """The flash dispatchers are under ``jax.jit`` (the rule below
+    ``pallas_kernels._named_call``): a four-layer step traces each kernel's
+    body once, forward and backward, where it traced one a call site
+    (eight), and a fifth layer adds none; the lowered module holds one
+    Mosaic body a kernel (a recomputed block on one chip traces the forward
+    a second time and lowers it once: ``jax.checkpoint`` traces the block
+    with no abstract mesh set, the backward pass replays it under the empty
+    one, and jit's cache keys on the difference; ``shard_map`` sets its own
+    for both); and in the compiled step every site is still a
+    ``tpu_custom_call`` named for its kernel, under its own ``block_<i>``
+    and, in the backward pass, ``transpose(``: what the benchmark's op
+    class ``attention_kernel`` and scope classes ``attn_fwd`` /
+    ``attn_bwd`` read. On four chips the calls sit inside ``shard_map``."""
+    from chipbench import op_scopes, trace_reduce
+    from horovod_tpu.metrics import phases
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    op_classes = trace_reduce.load_classes()
+    scope_classes = trace_reduce.load_classes(op_scopes.SCOPE_CLASSES)
+    phases.install_jax_listeners()
+    mesh = Mesh(np.array(v5e_devices[:chips]), ("hvd",))
+    for layers in (4, 5):
+        for dispatcher in (pk._flash_fwd_once_call, pk._flash_bwd_fused):
+            dispatcher.clear_cache()    # an earlier test's trace of the shape
+        recorder = phases.reset()
+        lowered = _train_step_lowering(mesh, 2, remat=remat, layers=layers)
+        bodies = _kernel_body_traces(recorder.spans(), "step")
+        assert len(bodies) == 2 + (remat == "full" and chips == 1), (
+            layers, len(bodies))
+        text = lowered.as_text()
+        assert sorted(re.findall(r'kernel_name = "(\w+)"', text)) == [
+            "flash_bwd", "flash_fwd"]
+        assert text.count("tpu_custom_call") == 2
+        if layers == 5:
+            continue
+        calls = _kernel_calls(lowered.compile().as_text(), r"flash_\w+?")
+        assert sorted(name for name, _, _ in calls) == \
+            ["flash_bwd"] * layers + ["flash_fwd"] * layers
+        for name, instruction, path in calls:
+            dispatcher, scope = {
+                "flash_fwd": ("_flash_fwd_once_call", "attn_fwd"),
+                "flash_bwd": ("_flash_bwd_fused", "attn_bwd")}[name]
+            assert re.search(rf"/block_\d/jit\({dispatcher}\)/{name}/", path)
+            assert ("transpose(" in path) == (name == "flash_bwd"), path
+            assert ("shard_map" in path) == (chips == 4), path
+            assert trace_reduce.classify(path, scope_classes) == scope
+            assert trace_reduce.classify(
+                f"tpu_custom_call %{instruction}", op_classes) \
+                == "attention_kernel"
+        for name in ("flash_fwd", "flash_bwd"):
+            assert sorted(re.search(r"/block_(\d)/", path).group(1)
+                          for kernel, _, path in calls if kernel == name) \
+                == [str(i) for i in range(layers)]
 
 
 @pytest.mark.parametrize("remat", ["none", "full"])
@@ -457,9 +545,8 @@ def test_scan_kernels_compile_for_v5e_at_the_published_widths(name,
 
 def _scan_calls(hlo_text):
     """``(kernel, op_name path)`` of every call of a scan kernel."""
-    return re.findall(
-        r"%(ssd_fwd|ssd_bwd)[.\d]* = [^\n]*custom_call_target="
-        r'"tpu_custom_call"[^\n]*op_name="([^"]*)"', hlo_text)
+    return [(kernel, path) for kernel, _, path in
+            _kernel_calls(hlo_text, "ssd_fwd|ssd_bwd")]
 
 
 def _hybrid_grad_text(v5e_devices, remat):
