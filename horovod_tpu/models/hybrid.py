@@ -19,13 +19,20 @@ scale ``head_dim ** -0.5``, unless given).
 * The attention mixer has grouped KV heads and runs the same flash kernels
   as ``TransformerLM``. Its position kind is ``"none"`` (Granite 4.0-H: the
   state-space layers carry the order) or ``"rope"`` (``ops/rope.py``), and
-  it can normalise q and k per head before that (LFM2).
+  it can normalise q and k per head before that (LFM2). A model may have
+  attention layers of several kinds (``attn_kinds``: a further mixer kind
+  by name, and what of the attention mixer differs in it): Laguna's window
+  layers have more query heads than its full ones, see their last 512
+  positions only (``window``, a band the flash kernels skip by) and turn
+  by another rotary scheme (a base over the whole head against given
+  frequencies over half of it, scaled), and every layer gates each head's
+  output (``gate``).
 * The Mamba-2 mixer is ``ops/ssd.py``, with one group of ``B`` and ``C``
   or several; the gated short convolution (LFM2's
   ``conv`` layer) is ``W_out (C * conv(B * u))`` over ``[B, C, u] = W_in h``
   with the same depthwise causal conv.
-* The routed feed-forward is ``ops/moe.py``: sigmoid top-k routing that
-  drops no token, told which experts it holds. Its experts are SwiGLU or
+* The routed feed-forward is ``ops/moe.py``: top-k routing over sigmoid or
+  softmax scores that drops no token, told which experts it holds. Its experts are SwiGLU or
   squared-ReLU, read the block's input or a latent of it (projected down
   before them and up after their sum), and may stand beside a shared
   expert that every token passes.
@@ -37,8 +44,10 @@ names and policies, and the module names the trace's scope classes read
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 from functools import partial
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -174,7 +183,14 @@ class AttentionMixer(nn.Module):
     ``rope_theta``, on q and k); with ``qk_norm_eps`` q and k are RMS-normed
     per head first, each under its own ``[head_dim]`` weight. The flash
     kernels take as many KV heads as query heads, so each is handed to them
-    ``heads // kv_heads`` times."""
+    ``heads // kv_heads`` times. The rotary scheme's further fields are
+    ``ops/rope.apply_rope``'s: ``rotary_dim`` elements of a head turn (all
+    unless given), by ``rope_inv_freq`` where given and not by the base,
+    cos and sin times ``rope_factor``. With ``window`` a query sees its
+    last ``window`` positions only (``flash_attention``'s band, under the
+    scope ``window``); with ``gate`` each head's output is multiplied by
+    the sigmoid of a projection of the mixer's input (``gate``: one scalar
+    a head and position) before ``o``."""
     heads: int
     kv_heads: int
     head_dim: int
@@ -183,6 +199,11 @@ class AttentionMixer(nn.Module):
     position: str = "none"
     rope_theta: float = 1e4
     qk_norm_eps: Optional[float] = None
+    rotary_dim: Optional[int] = None
+    rope_inv_freq: Optional[Tuple[float, ...]] = None
+    rope_factor: float = 1.0
+    window: Optional[int] = None
+    gate: bool = False
 
     @nn.compact
     def __call__(self, h):
@@ -203,14 +224,24 @@ class AttentionMixer(nn.Module):
                 q = _head_rms_norm(q, weight("q_norm"), self.qk_norm_eps)
                 k = _head_rms_norm(k, weight("k_norm"), self.qk_norm_eps)
         if self.position == "rope":
+            turn = partial(apply_rope, theta=self.rope_theta,
+                           rotary_dim=self.rotary_dim,
+                           inv_freq=self.rope_inv_freq,
+                           factor=self.rope_factor)
             with jax.named_scope("rope"):
-                q, k = apply_rope(q, self.rope_theta), \
-                    apply_rope(k, self.rope_theta)
+                q, k = turn(q), turn(k)
         k, v = (jnp.repeat(a, self.heads // self.kv_heads, axis=2)
                 for a in (k, v))
-        out = flash_attention(q, k, v, causal=True, scale=self.scale)
+        with jax.named_scope("window") if self.window is not None \
+                else contextlib.nullcontext():
+            out = flash_attention(q, k, v, causal=True, scale=self.scale,
+                                  window=self.window)
+        out = out.astype(self.dtype)
+        if self.gate:
+            out = out * nn.sigmoid(
+                _dense(self.heads, self.dtype, "gate")(h))[..., None]
         return _dense(d_model, self.dtype, "o")(
-            out.astype(self.dtype).reshape(b, t, self.heads * self.head_dim))
+            out.reshape(b, t, self.heads * self.head_dim))
 
 
 def _activate(kind: str, pre):
@@ -232,7 +263,9 @@ class RoutedFeedForward(nn.Module):
     router reads ``h`` all the same. With ``shared_width`` a shared expert
     of that width and the same kind (``shared_in``, ``shared_out``), which
     every token passes, is added. A routed token's weights sum to
-    ``scale``, their sum taking ``norm_eps`` before it divides. Sows the
+    ``scale``, their sum taking ``norm_eps`` before it divides; the scores
+    they are made of are ``scoring`` of the router's logits (``"sigmoid"``
+    | ``"softmax"`` over all the experts). Sows the
     experts each token chose, their scores and the tokens an expert
     (``intermediates``: free unless asked for)."""
     experts: int
@@ -245,6 +278,7 @@ class RoutedFeedForward(nn.Module):
     shared_width: int = 0
     scale: float = 1.0
     norm_eps: float = moe.NORM_EPS
+    scoring: str = "sigmoid"
 
     @nn.compact
     def __call__(self, h):
@@ -265,7 +299,7 @@ class RoutedFeedForward(nn.Module):
                        jnp.float32),
             held=self.held, top_k=self.top_k, x=x,
             activation=self.activation, scale=self.scale,
-            norm_eps=self.norm_eps)
+            norm_eps=self.norm_eps, scoring=self.scoring)
         self.sow("intermediates", "chosen", chosen.reshape(b, t, self.top_k))
         self.sow("intermediates", "scores", scores.reshape(b, t, self.experts))
         self.sow("intermediates", "load", load)
@@ -314,6 +348,7 @@ class HybridLM(nn.Module):
     vocab_size: int
     layer_kinds: Tuple[str, ...]        # "mamba" | "attention" | "short_conv"
                                         # | "none": a feed-forward block
+                                        # | a name of ``attn_kinds``
     d_model: int
     ffn_width: int                      # of a "swiglu" feed-forward
     attn_heads: int
@@ -348,6 +383,12 @@ class HybridLM(nn.Module):
     moe_scale: float = 1.0              # what a token's weights sum to
     moe_norm_eps: float = moe.NORM_EPS  # added to their sum before it divides
     tied_head: bool = True              # the head is the table
+    moe_scoring: str = "sigmoid"        # | "softmax", over all the experts
+    attn_gate: bool = False             # a sigmoid gate on each head's output
+    # further attention kinds by name, for ``layer_kinds``: what of
+    # ``AttentionMixer``'s fields differs from the "attention" kind's
+    attn_kinds: Dict[str, Dict[str, Any]] = dataclasses.field(
+        default_factory=dict)
 
     @nn.compact
     def __call__(self, tokens):
@@ -365,13 +406,23 @@ class HybridLM(nn.Module):
                              self.ssm_chunk, self.norm_eps, self.dtype,
                              self.ssm_groups),
             "attention": partial(
-                AttentionMixer, self.attn_heads, self.attn_kv_heads,
-                self.attn_head_dim, scale, self.dtype, self.attn_position,
-                self.attn_rope_theta,
-                self.norm_eps if self.attn_qk_norm else None),
+                AttentionMixer, heads=self.attn_heads,
+                kv_heads=self.attn_kv_heads, head_dim=self.attn_head_dim,
+                scale=scale, dtype=self.dtype, position=self.attn_position,
+                rope_theta=self.attn_rope_theta,
+                qk_norm_eps=self.norm_eps if self.attn_qk_norm else None,
+                gate=self.attn_gate),
             "short_conv": partial(ShortConvMixer, self.conv_width,
                                   self.dtype),
             "none": None}
+        fields = {f.name for f in dataclasses.fields(AttentionMixer)} - {
+            "parent", "name"}
+        for kind, differs in self.attn_kinds.items():
+            if kind in mixers or set(differs) - fields:
+                raise ValueError(
+                    f"attn_kinds[{kind!r}]={dict(differs)!r}; expected a "
+                    f"new kind's name and fields of AttentionMixer")
+            mixers[kind] = partial(mixers["attention"], **differs)
         unknown = set(self.layer_kinds) - set(mixers)
         if unknown:
             raise ValueError(f"layer_kinds has {sorted(unknown)}; expected "
@@ -400,7 +451,7 @@ class HybridLM(nn.Module):
                              self.moe_top_k, self.moe_width, self.dtype,
                              self.moe_activation, self.moe_latent,
                              self.moe_shared_width, self.moe_scale,
-                             self.moe_norm_eps)
+                             self.moe_norm_eps, self.moe_scoring)
         use_remat, policy = REMAT_POLICIES[self.remat]
         block_cls = nn.remat(HybridBlock, policy=policy) if use_remat \
             else HybridBlock

@@ -53,6 +53,7 @@ capacity with drops, its own train step); this layer lives inside
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Sequence, Tuple
 
@@ -68,15 +69,21 @@ NORM_EPS = 1e-6
 
 
 def route(logits, bias, top_k: int, scale: float = 1.0,
-          norm_eps: float = NORM_EPS):
-    """Sigmoid routing with a selection bias: ``scores = sigmoid(logits)``
-    ``[N, E]`` in float32; each token's ``top_k`` experts are those with the
-    largest ``scores + bias`` (the bias steers the choice only: it is under
-    ``stop_gradient`` and not in the weights); the weights are the chosen
-    experts' scores renormalised to sum to ``scale`` (their sum takes
-    ``norm_eps`` before it divides). Returns ``(chosen [N, k] int32,
-    weights [N, k] float32, scores [N, E])``."""
-    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+          norm_eps: float = NORM_EPS, scoring: str = "sigmoid"):
+    """Top-k routing with a selection bias: ``scores`` ``[N, E]`` in
+    float32 are ``sigmoid(logits)`` or, with ``scoring="softmax"``, the
+    softmax of a token's logits over all ``E``; each token's ``top_k``
+    experts are those with the largest ``scores + bias`` (the bias steers
+    the choice only: it is under ``stop_gradient`` and not in the weights);
+    the weights are the chosen experts' scores renormalised to sum to
+    ``scale`` (their sum takes ``norm_eps`` before it divides). Returns
+    ``(chosen [N, k] int32, weights [N, k] float32, scores [N, E])``."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"scoring={scoring!r}; expected 'sigmoid' or "
+                         f"'softmax'")
+    logits = logits.astype(jnp.float32)
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
     _, chosen = jax.lax.top_k(
         scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
     # (a masked sum, not take_along_axis: its gather is the slowest thing in
@@ -111,20 +118,24 @@ def dispatch(chosen, held: Sequence[int], num_experts: int):
 
 def capacities(assignments: int, held: int, num_experts: int) -> Tuple[int, ...]:
     """The static row counts the expert stage is compiled at, ascending:
-    twice the rows a balanced router sends here (``assignments * held /
-    num_experts``) or an eighth of the worst case, whichever is more
-    (rounded up to whole sublane tiles), and the worst case, every
-    assignment. A step runs the smallest that holds its rows, so the cost of
-    moving and activating rows follows the routing and no row is ever
-    dropped. (Not finer: a router trained on a share of the experts learns
-    to prefer them, 1.5 times the balanced rows within 50 steps where a
-    quarter is held and 2 to 5 times within 70 where a 64th is, and a count
-    inside the range the rows wander through makes the step's time jump as
-    each layer crosses it, by 20 ms a layer from 2,816 rows to 90,112:
-    PERF.md, PR 32 and PR 34. Under an eighth of the worst case a smaller
-    count saves little and is crossed.)"""
+    ``log2(num_experts / held)`` times the rows a balanced router sends
+    here (``assignments * held / num_experts``; twice at least) or an
+    eighth of the worst case, whichever is more (rounded up to whole
+    sublane tiles), and the worst case, every assignment. A step runs the
+    smallest that holds its rows, so the cost of moving and activating rows
+    follows the routing and no row is ever dropped. (Not finer, and the
+    margin wider the smaller the share: a router trained on a share of the
+    experts learns to prefer them, 1.5 times the balanced rows within 50
+    steps where a quarter is held, 2.1 times within 70 where a 16th is and
+    2 to 5 times where a 64th is, and a count inside the range the rows
+    wander through makes the step's time jump as each layer crosses it, by
+    20 ms a layer from 2,816 rows to 90,112 and by the whole spread of a
+    cell's runs at 10,240 of 81,920: PERF.md, PR 32, PR 34 and PR 39. Under
+    an eighth of the worst case a smaller count saves little and is
+    crossed.)"""
     balanced = assignments * held / num_experts
-    smaller = max(int(2 * balanced), assignments // 8)
+    margin = max(2.0, math.log2(num_experts / held))
+    smaller = max(int(margin * balanced), assignments // 8)
     return tuple(sorted({min(assignments, -(-smaller // 8) * 8), assignments}))
 
 
@@ -347,15 +358,16 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
 
 def routed_ffn(h, router, bias, w_in, w_out, *, held: Tuple[int, ...],
                top_k: int, x=None, activation: str = "swiglu",
-               scale: float = 1.0, norm_eps: float = NORM_EPS):
+               scale: float = 1.0, norm_eps: float = NORM_EPS,
+               scoring: str = "sigmoid"):
     """The layer above for ``h`` ``[N, d]``: ``router`` ``[d, E]`` and
     ``bias`` ``[E]`` (float32), ``w_in`` ``[H, d, 2 f]`` (gate and up side
     by side; ``[H, d, f]`` for ``activation="relu2"``) and ``w_out``
     ``[H, f, d]`` for the ``H = len(held)`` experts held (cast to
     ``h.dtype`` here). The router always reads ``h``; the experts read
     ``x`` ``[N, l]`` where one is given (a latent of ``h``: their ``d`` is
-    then ``l``, and so is ``y``'s). ``scale`` and ``norm_eps`` are
-    :func:`route`'s. Returns ``(y [N, d], chosen [N, k], scores [N, E],
+    then ``l``, and so is ``y``'s). ``scale``, ``norm_eps`` and ``scoring``
+    are :func:`route`'s. Returns ``(y [N, d], chosen [N, k], scores [N, E],
     load [E])``: ``load`` counts the tokens each of the ``E`` experts was
     chosen by (held or not).
 
@@ -375,7 +387,9 @@ def routed_ffn(h, router, bias, w_in, w_out, *, held: Tuple[int, ...],
             # arguments in its place)
             renorm = () if (scale, norm_eps) == (1.0, NORM_EPS) \
                 else (scale, norm_eps)
-            chosen, weights, scores = route(logits, bias, top_k, *renorm)
+            kind = {} if scoring == "sigmoid" else {"scoring": scoring}
+            chosen, weights, scores = route(logits, bias, top_k, *renorm,
+                                            **kind)
             load = jnp.sum(jax.nn.one_hot(chosen, num_experts,
                                           dtype=jnp.int32), axis=(0, 1))
         with jax.named_scope("dispatch"):

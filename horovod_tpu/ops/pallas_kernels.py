@@ -179,7 +179,8 @@ def _named_call(name: str, kernel, **kwargs):
 # environment inside it (``_SUB_TILE``, HVD_PALLAS_INPUT_FUSION) is read
 # when a shape is first traced, so a test that patches one forgets the
 # traces first (``dispatcher.clear_cache()``).
-_FLASH_STATIC = ("causal", "scale", "block_q", "block_k", "interpret")
+_FLASH_STATIC = ("causal", "scale", "block_q", "block_k", "interpret",
+                 "window")
 
 
 def _struct(shape, dtype, *like):
@@ -237,6 +238,19 @@ def _pick_block(t: int, preferred: int = None,
     return None
 
 
+def flash_tiles(tq: int, tk: int, window: Optional[int] = None):
+    """``(block_q, block_k)``: the grid tile of a flash call. A windowed
+    call takes key tiles no wider than its window (the largest power of two
+    up to it, 8 at least): a q tile's band then touches the key tiles it
+    needs and no more. At 8192 positions and a window of 512, 512 x 512
+    tiles compute 2.0 times the needed scores forward and backward, where
+    512 x 1024 would compute 3.0 and 4.0 on the tiles an edge crosses."""
+    if window is None:
+        return _pick_block(tq, side="q"), _pick_block(tk, side="k")
+    widest = max(8, 1 << (int(window).bit_length() - 1))
+    return _pick_block(tq, side="q"), _pick_block(tk, min(_BLOCK_K, widest))
+
+
 # Edge of the sub-tiles the CAUSAL fused backward cuts a grid cell into.
 # Read on a v5e (PERF.md §6, PR 28): 512 takes 18.0% off the kernel at 1024
 # positions and 6.5% at 4096; 256 takes 19.9% and 4.4% with four times the
@@ -274,8 +288,59 @@ def _live_sub_tiles(q_lo, k_lo, sub_q, sub_k, n):
     return jnp.clip(w, 0, n)
 
 
+def _clamp(x, lo, hi):
+    """``x`` held to ``[lo, hi]``: Python ints or traced scalars."""
+    if isinstance(x, (int, np.integer)):
+        return min(max(x, lo), hi)
+    return jnp.clip(x, lo, hi)
+
+
+# THE BAND. ``window=W`` on a causal call keeps the scores with
+# ``q - W < k <= q``: the causal bound and a second one behind it. Wherever
+# the kernels skip by the first (a key loop's trip count, a streaming
+# grid's extent and its index maps, the fused backward's strips) they skip
+# by the second too, through the three functions below; wherever they mask
+# by the first, :func:`_causal_mask` takes both. A windowed call comes from
+# :func:`flash_attention` alone: as many keys as queries, both from
+# position 0 (a ring hop refuses ``window``).
+def _band_first(q_lo, k_lo, sub_k, n, window):
+    """Of a key block's ``n`` sub-tiles of ``sub_k`` from global position
+    ``k_lo``, the first that holds a score the query at ``q_lo`` may see
+    through a window of ``window``; the ones before it lie wholly behind
+    the band for that query and for every later one."""
+    return _clamp((q_lo - window + 1 - k_lo) // sub_k, 0, n)
+
+
+def _band_k(q_lo, k0, block_q, block_k, nk, window):
+    """``(first, last)`` of the ``nk`` key blocks (from position ``k0``)
+    that hold a score of the q tile from ``q_lo``."""
+    return (_band_first(q_lo, k0, block_k, nk - 1, window),
+            _clamp((q_lo + block_q - 1 - k0) // block_k, 0, nk - 1))
+
+
+def _band_q(k_lo, q0, block_q, block_k, nq, window):
+    """``(first, last)`` of the ``nq`` q tiles (from position ``q0``) that
+    hold a score of the key block from ``k_lo``."""
+    return (_clamp((k_lo - q0) // block_q, 0, nq - 1),
+            _clamp((k_lo + block_k + window - 2 - q0) // block_q, 0, nq - 1))
+
+
+def _band_spans(window, block_q, block_k, nq, nk):
+    """``(key blocks, q tiles)``: the most a q tile's band touches, and the
+    most that touch a key block: the innermost extents of a windowed
+    call's streaming grids. Python ints."""
+    def widest(ends):
+        return max(last - first + 1 for first, last in ends)
+
+    return (widest(_band_k(j * block_q, 0, block_q, block_k, nk, window)
+                   for j in range(nq)),
+            widest(_band_q(j * block_k, 0, block_q, block_k, nq, window)
+                   for j in range(nk)))
+
+
 def flash_plan(causal: bool, tq: int, tk: int, q_off: int, k_off: int,
-               block_k: int, sub_q: int, sub_k: int) -> dict:
+               block_k: int, sub_q: int, sub_k: int,
+               window: Optional[int] = None) -> dict:
     """What one head of a flash call computes, counted in sub-tiles of
     ``sub_q x sub_k`` scores by the bound the kernels themselves run
     (:func:`_live_sub_tiles` a row sub-tile and key block of ``block_k``):
@@ -285,26 +350,43 @@ def flash_plan(causal: bool, tq: int, tk: int, q_off: int, k_off: int,
     at :func:`_pick_sub_tile`'s edges; the forward runs whole key blocks,
     ``sub_q, sub_k = block_q, block_k``. The q grid tile does not enter: it
     is a multiple of ``sub_q``, and a cell the diagonal leaves dead is a
-    cell of skipped sub-tiles. No JAX."""
+    cell of skipped sub-tiles. With ``window`` (a causal call) the band's
+    second bound counts as the kernels run it (:func:`_band_first`).
+    ``needed`` is the scores the mathematics asks for, one by one: what
+    ``scores`` is measured against. No JAX."""
     n = block_k // sub_k
     rows, blocks = tq // sub_q, tk // block_k
+
+    def live(q_lo, k_lo):
+        first = 0 if window is None else _band_first(q_lo, k_lo, sub_k, n,
+                                                     window)
+        return max(_live_sub_tiles(q_lo, k_lo, sub_q, sub_k, n) - first, 0)
+
     computed = rows * blocks * n if not causal else sum(
-        _live_sub_tiles(q_off + r * sub_q, k_off + jb * block_k, sub_q,
-                        sub_k, n)
+        live(q_off + r * sub_q, k_off + jb * block_k)
         for r in range(rows) for jb in range(blocks))
+    needed = tq * tk
+    if causal:
+        q = q_off + np.arange(tq, dtype=np.int64)
+        first = k_off if window is None else np.maximum(k_off, q - window + 1)
+        needed = int(np.maximum(
+            np.minimum(q, k_off + tk - 1) - first + 1, 0).sum())
     return {"computed": computed, "masked": computed if causal else 0,
             "skipped": rows * blocks * n - computed,
-            "scores": computed * sub_q * sub_k}
+            "scores": computed * sub_q * sub_k, "needed": needed}
 
 
 # =========================================================== flash attention
-def _causal_mask(s, q_lo, k_lo, q_axis=0):
+def _causal_mask(s, q_lo, k_lo, q_axis=0, window=None):
     """The scores ``s`` of queries from global position ``q_lo`` (along
     ``q_axis``; the keys, from ``k_lo``, along the other), -inf above the
-    diagonal."""
+    diagonal and, with ``window``, ``window`` positions or more below it."""
     delta = (lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
              - lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
-    return jnp.where(delta >= k_lo - q_lo, s, NEG_INF)
+    keep = delta >= k_lo - q_lo
+    if window is not None:
+        keep = keep & (delta < k_lo - q_lo + window)
+    return jnp.where(keep, s, NEG_INF)
 
 
 # The row statistics (the LSE, a ring hop's m and l) cross HBM as f32 ROWS
@@ -349,7 +431,7 @@ def _stat_col(row):
 
 
 def _flash_accum(q, k_ref, v_ref, m, l, o, *, q_off, k_off, causal,
-                 scale, block_k):
+                 scale, block_k, window=None):
     """Online-softmax accumulation of the q tile (global first row
     ``q_off``) against the slice's resident k/v, a key block of
     ``block_k`` at a time — THE shared inner body of the ring-step and
@@ -365,7 +447,9 @@ def _flash_accum(q, k_ref, v_ref, m, l, o, *, q_off, k_off, causal,
     PR 28): a run-time trip count of one costs the kernel 0.70 ms where the
     same masked block takes 0.49 without the loop around it, and the mask
     costs nothing. A ring hop the diagonal leaves wholly dead is then
-    computed to exact zeros (p = 0 on every masked score) and not skipped."""
+    computed to exact zeros (p = 0 on every masked score) and not skipped.
+    With ``window`` the loop also starts at the first key block of the
+    band (:func:`_band_first`)."""
     bq = q.shape[0]
     in_dt = q.dtype
     nblk = k_ref.shape[1] // block_k
@@ -379,7 +463,7 @@ def _flash_accum(q, k_ref, v_ref, m, l, o, *, q_off, k_off, causal,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if causal:
-            s = _causal_mask(s, q_off, k_off + j * block_k)
+            s = _causal_mask(s, q_off, k_off + j * block_k, window=window)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
         p = jnp.exp2(s - m_safe[:, None])             # exp2(-inf) == 0
@@ -393,10 +477,12 @@ def _flash_accum(q, k_ref, v_ref, m, l, o, *, q_off, k_off, causal,
         return body(0, (m, l, o))
     # k blocks past the last unmasked key for this q tile contribute
     # nothing — bound the loop (exact: those blocks are fully masked)
-    live = nblk
+    first, live = 0, nblk
     if causal:
         live = _live_sub_tiles(q_off, k_off, bq, block_k, nblk)
-    return lax.fori_loop(0, live, body, (m, l, o))
+        if window is not None:
+            first = _band_first(q_off, k_off, block_k, nblk, window)
+    return lax.fori_loop(first, live, body, (m, l, o))
 
 
 def _flash_step_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref, o_ref,
@@ -427,7 +513,7 @@ def _flash_step_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref, o_ref,
 
 
 def _flash_fwd_once_kernel(offs_ref, q_ref, k_ref, v_ref, oo_ref, lse_ref,
-                           *, causal, scale, block_k):
+                           *, causal, scale, block_k, window=None):
     """Single-shot forward: the resident step kernel minus the ring-carry
     plumbing. No (m, l, o) stream in — the statistics initialize in
     registers — and the output is NORMALIZED in-kernel (FlashAttention-2
@@ -445,7 +531,7 @@ def _flash_fwd_once_kernel(offs_ref, q_ref, k_ref, v_ref, oo_ref, lse_ref,
     o = jnp.zeros((bq, q_ref.shape[2]), jnp.float32)
     m, l, o = _flash_accum(q, k_ref, v_ref, m, l, o,
                            q_off=q_off, k_off=k_off, causal=causal,
-                           scale=scale, block_k=block_k)
+                           scale=scale, block_k=block_k, window=window)
     # the _masked_row_stats convention, fused into the epilogue:
     # l == 0 -> out 0, lse sentinel log(1) on top of a zeroed m
     l_safe = jnp.where(l == 0, 1.0, l)
@@ -456,7 +542,7 @@ def _flash_fwd_once_kernel(offs_ref, q_ref, k_ref, v_ref, oo_ref, lse_ref,
 
 @functools.partial(jax.jit, static_argnames=_FLASH_STATIC + ("fusable",))
 def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
-                         block_k, interpret, fusable):
+                         block_k, interpret, fusable, window=None):
     """Resident-layout dispatch of the single-shot forward (jitted: the
     rule below :func:`_named_call`).
     qt: [BH, TQ, D]; kt/vt: [BH, TK, D] → (out [BH, TQ, D] in qt.dtype,
@@ -465,10 +551,10 @@ def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
     tk = kt.shape[1]
     # the only caller passes zero offsets: the plan is the call's
     scores = bh * flash_plan(causal, tq, tk, 0, 0, block_k, block_q,
-                             block_k)["scores"]
+                             block_k, window)["scores"]
     return _named_call("flash_fwd",
         functools.partial(_flash_fwd_once_kernel, causal=causal,
-                          scale=scale, block_k=block_k),
+                          scale=scale, block_k=block_k, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, tq // block_q),
@@ -498,25 +584,33 @@ def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
 
 def _flash_step_stream_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref,
                               o_ref, mo_ref, lo_ref, oo_ref, *, causal,
-                              scale):
+                              scale, window=None, nk=None):
     """Streaming forward: one (q tile, k tile) grid cell of flash
     accumulation. The k grid dimension is innermost and revisits the same
     (m, l, o) output tiles, so VMEM holds single tiles regardless of
     sequence length; the carried-in statistics seed the outputs on the
-    first k step (ring hops carry (m, l, o) across calls)."""
-    iq, jk = pl.program_id(1), pl.program_id(2)
+    first k step (ring hops carry (m, l, o) across calls). A windowed
+    call's k dimension is the band's extent (:func:`_band_spans`), its
+    steps counted from the q tile's first key block of the ``nk``."""
+    iq, step = pl.program_id(1), pl.program_id(2)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
     in_dt = q_ref.dtype
     q_off = offs_ref[0] + iq * bq
+    jk = step
+    if window is not None:
+        first, last = _band_k(q_off, offs_ref[1], bq, bk, nk, window)
+        jk = first + step
     k_off = offs_ref[1] + jk * bk
 
-    @pl.when(jk == 0)
+    @pl.when(step == 0)
     def _():
         mo_ref[0] = m_ref[0]
         lo_ref[0] = l_ref[0]
         oo_ref[0] = o_ref[0].astype(jnp.float32)
 
     live = (q_off + bq - 1 >= k_off) if causal else True
+    if window is not None:
+        live = jnp.logical_and(live, jk <= last)
 
     @pl.when(live)
     def _():
@@ -534,7 +628,10 @@ def _flash_step_stream_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref,
         if causal:
             qpos = q_off + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             kpos = k_off + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
+            keep = qpos >= kpos
+            if window is not None:
+                keep = keep & (qpos - kpos < window)
+            s = jnp.where(keep, s, NEG_INF)
         m_blk = jnp.max(s, axis=-1)
         m_new = jnp.maximum(m, m_blk)
         m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
@@ -547,17 +644,32 @@ def _flash_step_stream_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref,
         oo_ref[0] = o * alpha[:, None] + pv
 
 
-def _causal_maps(causal, block_q, block_k, nq):
+def _causal_maps(causal, block_q, block_k, nq, window=None, nk=None):
     """Index maps for streaming grids with causal DMA elision: a fully-
     masked cell's kernel body is skipped by pl.when, but its input tiles
     would still be fetched — clamping the dead cell's map onto the nearest
     LIVE tile makes consecutive steps request the same index, which the
     Mosaic pipeline elides. Returns (kmap, qmap): the k/v-side map for
     (bh, iq, jk-innermost) grids and the q/do-side map for
-    (bh, jk, iq-innermost) grids."""
+    (bh, jk, iq-innermost) grids. With ``window`` the innermost dimension
+    counts from the band's first tile (:func:`_band_k`, :func:`_band_q`,
+    as the kernels do) and clamps onto its last."""
     if not causal:
         passthrough = lambda i, j, n, offs: (i, n, 0)
         return passthrough, passthrough
+
+    if window is not None:
+        def kmap(i, j, n, offs):
+            first, last = _band_k(offs[0] + j * block_q, offs[1], block_q,
+                                  block_k, nk, window)
+            return (i, jnp.minimum(first + n, last), 0)
+
+        def qmap(i, j, n, offs):
+            first, last = _band_q(offs[1] + j * block_k, offs[0], block_q,
+                                  block_k, nq, window)
+            return (i, jnp.minimum(first + n, last), 0)
+
+        return kmap, qmap
 
     def kmap(i, j, n, offs):
         n_max = jnp.maximum(
@@ -574,22 +686,29 @@ def _causal_maps(causal, block_q, block_k, nq):
 
 @functools.partial(jax.jit, static_argnames=_FLASH_STATIC)
 def _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs, *, causal,
-                               scale, block_q, block_k, interpret):
+                               scale, block_q, block_k, interpret,
+                               window=None):
     """Streaming-layout dispatch of the forward step (k/v too long to keep
     resident)."""
     bh, tq, d = qt.shape
     tk = kt.shape[1]
+    nq, nk = tq // block_q, tk // block_k
+    kspan, scores = nk, bh * tq * tk
+    if window is not None:
+        kspan = _band_spans(window, block_q, block_k, nq, nk)[0]
+        scores = bh * flash_plan(causal, tq, tk, 0, 0, block_k, block_q,
+                                 block_k, window)["scores"]
 
-    kmap, _ = _causal_maps(causal, block_q, block_k, tq // block_q)
+    kmap, _ = _causal_maps(causal, block_q, block_k, nq, window, nk)
     qtile = pl.BlockSpec((1, block_q, d), lambda i, j, n, offs: (i, j, 0))
     stat = _stat_spec(block_q, lambda i, j, n, offs: (i, j, 0))
 
     return _named_call("flash_step",
         functools.partial(_flash_step_stream_kernel, causal=causal,
-                          scale=scale),
+                          scale=scale, window=window, nk=nk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, tq // block_q, tk // block_k),
+            grid=(bh, nq, kspan),
             in_specs=[
                 qtile,
                 pl.BlockSpec((1, block_k, d), kmap),
@@ -606,10 +725,10 @@ def _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs, *, causal,
         # k is innermost and ACCUMULATES into the revisited q-side tiles
         compiler_params=_sem_par2_arb(),
         cost_estimate=pl.CostEstimate(
-            flops=4 * bh * tq * tk * d,
+            flops=4 * scores * d,
             bytes_accessed=4 * (2 * bh * tq * d + 2 * bh * tk * d
                                 + 4 * bh * tq),       # m, l in and out
-            transcendentals=bh * tq * tk),
+            transcendentals=scores),
         interpret=interpret,
     )(offs, qt, kt, vt, mt, lt, ot)
 
@@ -628,9 +747,12 @@ def _flash_step_call(qt, kt, vt, mt, lt, ot, offs, *, fusable, **static):
 
 @functools.partial(jax.jit, static_argnames=_FLASH_STATIC + ("fusable",))
 def _flash_step_call_resident(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
-                              block_q, block_k, interpret, fusable):
+                              block_q, block_k, interpret, fusable,
+                              window=None):
     """Resident-layout dispatch of the forward step (the whole k/v of a
-    head in VMEM)."""
+    head in VMEM). A ring hop's: no ``window`` (a windowed forward whose
+    k/v is resident takes ``flash_fwd``)."""
+    assert window is None
     bh, tq, d = qt.shape
     tk = kt.shape[1]
     kernel = functools.partial(_flash_step_kernel, causal=causal, scale=scale,
@@ -679,12 +801,16 @@ _KV_VMEM_CAP = 2 ** 20
 _DQ_SCRATCH_CAP = 4 * 2 ** 20
 
 
-def flash_route(tq: int, tk: int, d: int, itemsize: int) -> dict:
+def flash_route(tq: int, tk: int, d: int, itemsize: int,
+                window: Optional[int] = None) -> dict:
     """Which kernels one head of ``tq`` queries against ``tk`` keys of
     width ``d`` takes; the dispatchers and the tests both read it.
     ``forward`` (the full-attention call) is ``once`` or ``step_streaming``,
     ``step`` (a ring hop, carrying m, l, o) ``step`` or ``step_streaming``,
-    ``backward`` ``fused`` or ``streaming`` (the dq / dkv pair). No JAX."""
+    ``backward`` ``fused`` or ``streaming`` (the dq / dkv pair). A
+    ``window`` changes none of the three (the resident kernels hold a
+    head's whole k/v and dq whatever the band; its tiles are
+    :func:`flash_tiles`'s). No JAX."""
     kv_resident = tk * d * itemsize <= _KV_VMEM_CAP
     return {"forward": "once" if kv_resident else "step_streaming",
             "step": "step" if kv_resident else "step_streaming",
@@ -709,14 +835,19 @@ def step_supported(q, k) -> bool:
 
 
 def flash_attention_step(q, k, v, m, l, o, q_off, k_off, *,
-                         causal: bool = False, scale: float = 1.0):
+                         causal: bool = False, scale: float = 1.0,
+                         window: Optional[int] = None):
     """Flash-accumulate ``q`` against one resident ``(k, v)`` block.
 
     Same contract as the ring-attention inner step: shapes
     q/o ``[B, T, H, D]``, k/v ``[B, TK, H, D]``, m/l ``[B, H, T]`` (f32 running
     max / normalizer), ``q_off``/``k_off`` global sequence origins (traced
-    scalars OK). Returns updated ``(m, l, o)``.
+    scalars OK). Returns updated ``(m, l, o)``. No ``window`` under a ring
+    hop yet: the band's grids count from offsets that are 0.
     """
+    if window is not None:
+        raise ValueError("flash_attention_step takes no window: a band "
+                         "under a ring hop is not written (ROADMAP V5)")
     b, tq, h, d = q.shape
     tk = k.shape[1]
     block_q = _pick_block(tq, side="q")
@@ -746,7 +877,8 @@ def _dot_tn(a, b):
                            preferred_element_type=jnp.float32)
 
 
-def _bwd_scores_t(q, k, v, out, do, lse, q_lo, k_lo, *, causal, scale):
+def _bwd_scores_t(q, k, v, out, do, lse, q_lo, k_lo, *, causal, scale,
+                  window=None):
     """``(p^T, ds^T)`` of q rows ``[SQ, D]`` (from global position
     ``q_lo``) against keys ``[W, D]`` (from ``k_lo``), both ``[W, SQ]`` in
     the operands' dtype — THE recompute every backward kernel shares:
@@ -768,7 +900,7 @@ def _bwd_scores_t(q, k, v, out, do, lse, q_lo, k_lo, *, causal, scale):
     s_t = (scale * _LOG2E) * lax.dot_general(
         k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     if causal:
-        s_t = _causal_mask(s_t, q_lo, k_lo, q_axis=1)
+        s_t = _causal_mask(s_t, q_lo, k_lo, q_axis=1, window=window)
     p_t = jnp.exp2(s_t - lse * _LOG2E)                # exp2(-inf) == 0
     dp_t = lax.dot_general(v, do, (((1,), (1,)), ((), ())),
                            preferred_element_type=jnp.float32)
@@ -777,7 +909,8 @@ def _bwd_scores_t(q, k, v, out, do, lse, q_lo, k_lo, *, causal, scale):
 
 
 def _flash_bwd_dq_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
-                         do_ref, dq_ref, *, causal, scale):
+                         do_ref, dq_ref, *, causal, scale, window=None,
+                         nk=None):
     """dq accumulation for one (q tile, k tile) grid cell (FlashAttention-2
     backward, dq pass): recompute p = exp(scale*qk^T - LSE), then
     ds = p*(do v^T - D)*scale, dq += ds k.  LSE = m + log l (the forward's
@@ -786,45 +919,61 @@ def _flash_bwd_dq_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
     (scalar prefetch): [q_off, k_off] global sequence origins (ring hop
     offsets). The k grid dimension is innermost and revisits the same dq
     tile, so VMEM holds one tile of each operand regardless of sequence
-    length."""
-    iq, jk = pl.program_id(1), pl.program_id(2)
+    length; with ``window`` it is the band's extent, as the streaming
+    forward's."""
+    iq, step = pl.program_id(1), pl.program_id(2)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
     q_off = offs_ref[0] + iq * bq
+    jk = step
+    if window is not None:
+        first, last = _band_k(q_off, offs_ref[1], bq, bk, nk, window)
+        jk = first + step
     k_off = offs_ref[1] + jk * bk
 
-    @pl.when(jk == 0)
+    @pl.when(step == 0)
     def _():
         dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
     # causal: a block with every pair masked contributes nothing
     live = (q_off + bq - 1 >= k_off) if causal else True
+    if window is not None:
+        live = jnp.logical_and(live, jk <= last)
 
     @pl.when(live)
     def _():
         k = k_ref[0]                                  # [BK, D]
         _, ds_t = _bwd_scores_t(
             q_ref[0], k, v_ref[0], o_ref[0], do_ref[0], lse_ref[0],
-            q_off, k_off, causal=causal, scale=scale)
+            q_off, k_off, causal=causal, scale=scale, window=window)
         dq_ref[0] += _dot_tn(ds_t, k)
 
 
 def _flash_bwd_dkv_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
-                          do_ref, dk_ref, dv_ref, *, causal, scale):
+                          do_ref, dk_ref, dv_ref, *, causal, scale,
+                          window=None, nq=None):
     """dk/dv accumulation for one (k tile, q tile) grid cell (dkv pass):
     dv += p^T do; dk += (p*(do v^T - D)*scale)^T q, both plain products of
     the key-major p^T, ds^T. The q grid dimension is innermost and revisits
-    the same dk/dv tiles."""
-    jk, iq = pl.program_id(1), pl.program_id(2)
+    the same dk/dv tiles; with ``window`` it is the band's extent, its
+    steps counted from the key block's first q tile of the ``nq``."""
+    jk, step = pl.program_id(1), pl.program_id(2)
     bk, bq = k_ref.shape[1], q_ref.shape[1]
+    iq = step
+    if window is not None:
+        first, last = _band_q(offs_ref[1] + jk * bk, offs_ref[0], bq, bk,
+                              nq, window)
+        iq = first + step
     q_off = offs_ref[0] + iq * bq
     k_off = offs_ref[1] + jk * bk
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _():
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
 
     live = (q_off + bq - 1 >= k_off) if causal else True
+    if window is not None:
+        live = jnp.logical_and(live, iq <= last)
 
     @pl.when(live)
     def _():
@@ -832,14 +981,15 @@ def _flash_bwd_dkv_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
         do = do_ref[0]
         p_t, ds_t = _bwd_scores_t(
             q, k_ref[0], v_ref[0], o_ref[0], do, lse_ref[0], q_off, k_off,
-            causal=causal, scale=scale)
+            causal=causal, scale=scale, window=window)
         dv_ref[0] += jnp.dot(p_t, do, preferred_element_type=jnp.float32)
         dk_ref[0] += jnp.dot(ds_t, q, preferred_element_type=jnp.float32)
 
 
 def _flash_bwd_fused_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
                             do_ref, dq_ref, dk_ref, dv_ref, *maybe_acc,
-                            causal, scale, sub_q, sub_k):
+                            causal, scale, sub_q, sub_k, window=None,
+                            nq=None):
     """ONE-pass FlashAttention-2 backward: grid (bh, k tiles, q tiles) with
     q innermost; each cell recomputes p ONCE and emits all three gradient
     contributions. The streaming pair of kernels (dq pass + dkv pass) each
@@ -874,37 +1024,53 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
 
     Gradients leave the kernel in the INPUT dtype: accumulation stays f32,
     cast once at the final write — a bf16 model never round-trips 3x f32
-    gradient tensors through HBM plus three XLA cast fusions."""
+    gradient tensors through HBM plus three XLA cast fusions.
+
+    With ``window`` (more than one k sweep) the q dimension is the band's
+    extent, its steps counted from the key block's first q tile of the
+    ``nq`` (:func:`_band_q`) and the steps past its last held on that
+    tile, dead; a strip starts at the band's first sub-tile
+    (:func:`_band_first`) as it ends at the diagonal's last. Every q tile
+    is in the sweep of its own diagonal's key block, which is the last
+    that adds to it: its dq is final when it is last written."""
     if len(maybe_acc) == 3:
         dq_acc, dk_acc, dv_acc = maybe_acc
     else:
         dq_acc, (dk_acc, dv_acc) = None, maybe_acc
-    jk, iq = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    jk, step = pl.program_id(1), pl.program_id(2)
+    steps = pl.num_programs(2)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
+    iq = tile = step
+    if nq is not None:
+        first, last = _band_q(offs_ref[1] + jk * bk, offs_ref[0], bq, bk,
+                              nq, window)
+        iq = first + step
+        tile = jnp.minimum(iq, last)      # the q-side index maps' clamp
     q_off = offs_ref[0] + iq * bq
     k_off = offs_ref[1] + jk * bk
 
     if dq_acc is not None:
-        @pl.when(jnp.logical_and(jk == 0, iq == 0))
+        @pl.when(jnp.logical_and(jk == 0, step == 0))
         def _():
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def strip(rows, w):
-        """One row sub-tile against the k tile's first ``w`` sub-tiles."""
+    def strip(rows, lo, w):
+        """One row sub-tile against the k tile's sub-tiles ``lo`` to
+        ``w``."""
         q = q_ref[0, rows, :]                         # [SQ, D]
         do = do_ref[0, rows, :]
-        cols = pl.ds(0, w * sub_k)
+        cols = pl.ds(lo * sub_k, (w - lo) * sub_k)
         k = k_ref[0, cols, :]                         # [W, D]
         p_t, ds_t = _bwd_scores_t(                    # [W, SQ]
             q, k, v_ref[0, cols, :], o_ref[0, rows, :], do,
-            lse_ref[0, :, rows], q_off + rows.start, k_off, causal=causal,
-            scale=scale)
+            lse_ref[0, :, rows], q_off + rows.start,
+            k_off + lo * sub_k if lo else k_off, causal=causal,
+            scale=scale, window=window)
         dv_acc[cols, :] += jnp.dot(p_t, do,
                                    preferred_element_type=jnp.float32)
         dk_acc[cols, :] += jnp.dot(ds_t, q,
@@ -919,33 +1085,42 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
     for r0 in range(0, bq, sub_q):
         rows = pl.ds(r0, sub_q)
         if not causal:
-            strip(rows, n)
+            strip(rows, 0, n)
             continue
         live = _live_sub_tiles(q_off + r0, k_off, sub_q, sub_k, n)
+        lo = 0
+        if window is not None:
+            lo = _band_first(q_off + r0, k_off, sub_k, n, window)
+            if nq is not None:        # a step past the band's last q tile
+                live = jnp.where(iq <= last, live, 0)
         if dq_acc is None:
-            # nothing of the k tile is under the diagonal for these rows
-            # (every row sub-tile of a dead cell): their dq is zero
-            @pl.when(live == 0)
+            # nothing of the k tile is in the band for these rows (every
+            # row sub-tile of a dead cell): their dq is zero
+            @pl.when(live == 0 if window is None else live <= lo)
             def _(rows=rows):
                 dq_ref[0, rows, :] = jnp.zeros(
                     (sub_q, dq_ref.shape[2]), dq_ref.dtype)
-        for w in range(1, n + 1):
-            pl.when(live == w)(functools.partial(strip, rows, w))
+        # one body a strip: from sub-tile ``a`` (0 without a window) to ``w``
+        for a in range(n if window is not None else 1):
+            for w in range(a + 1, n + 1):
+                pl.when(live == w if window is None
+                        else jnp.logical_and(lo == a, live == w))(
+                    functools.partial(strip, rows, a, w))
 
-    @pl.when(iq == nq - 1)
+    @pl.when(step == steps - 1)
     def _():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
     if dq_acc is not None:
-        dq_ref[0] = dq_acc[pl.ds(iq * bq, bq), :].astype(dq_ref.dtype)
+        dq_ref[0] = dq_acc[pl.ds(tile * bq, bq), :].astype(dq_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=_FLASH_STATIC + (
     "fusable", "out_dtype", "static_offs"))
 def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
                      block_q, block_k, interpret, fusable, out_dtype=None,
-                     static_offs=None):
+                     static_offs=None, window=None):
     """Dispatch of the one-pass backward (any length: k/v tiles stream
     through the grid, dq rides the VMEM scratch). ``out_dtype`` picks the
     gradient output dtype (default f32); the ring path keeps f32 so its
@@ -957,20 +1132,28 @@ def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
     out_dtype = jnp.float32 if out_dtype is None else out_dtype
     bh, tq, d = qt.shape
     tk = kt.shape[1]
-    _, qmap = _causal_maps(causal, block_q, block_k, tq // block_q)
+    nq, nk = tq // block_q, tk // block_k
+    # a single k sweep writes each q tile's dq in its own cell: every q
+    # tile is visited, and the band bounds the strips alone
+    banded = window is not None and nk > 1
+    band = (window, nk) if banded else ()
+    _, qmap = _causal_maps(causal, block_q, block_k, nq, *band)
     ktile = pl.BlockSpec((1, block_k, d), lambda i, j, n, offs: (i, j, 0))
     sub_q, sub_k = _pick_sub_tile(causal, block_q, block_k)
     scores = bh * (tq * tk if static_offs is None else flash_plan(
-        causal, tq, tk, *static_offs, block_k, sub_q, sub_k)["scores"])
+        causal, tq, tk, *static_offs, block_k, sub_q, sub_k,
+        window)["scores"])
 
     return _named_call("flash_bwd",
         functools.partial(_flash_bwd_fused_kernel, causal=causal,
-                          scale=scale, sub_q=sub_q, sub_k=sub_k),
+                          scale=scale, sub_q=sub_q, sub_k=sub_k,
+                          window=window, nq=nq if banded else None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             # q innermost: dk/dv revisits are consecutive; j sweeps
             # accumulate dq in the persistent scratch
-            grid=(bh, tk // block_k, tq // block_q),
+            grid=(bh, nk, _band_spans(window, block_q, block_k, nq, nk)[1]
+                  if banded else nq),
             in_specs=[
                 _stat_spec(block_q, qmap),
                 pl.BlockSpec((1, block_q, d), qmap),
@@ -979,7 +1162,8 @@ def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
                 pl.BlockSpec((1, block_q, d), qmap),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda i, j, n, offs: (i, n, 0)),
+                pl.BlockSpec((1, block_q, d), qmap if banded else
+                             lambda i, j, n, offs: (i, n, 0)),
                 ktile, ktile,
             ],
             # single k sweep: dq finishes inside its cell — no dq scratch;
@@ -1035,7 +1219,7 @@ def _flash_bwd(q, k, v, out, lse, dout, q_off=0, k_off=0, *, causal, scale):
 
 
 def _flash_bwd_hm(qt, kt, vt, ot, dot, lset, q_off=0, k_off=0, *,
-                  causal, scale, fusable, out_dtype=None):
+                  causal, scale, fusable, out_dtype=None, window=None):
     """Heads-major core of :func:`_flash_bwd`: operands/grads all
     ``[BH, T, D]`` (lse ``[BH, 1, T]``) so a caller that already holds
     heads-major tensors (the full-attention VJP saves its residuals that
@@ -1044,8 +1228,7 @@ def _flash_bwd_hm(qt, kt, vt, ot, dot, lset, q_off=0, k_off=0, *,
     tk = kt.shape[1]
     # the forward's grid tiles: a k tile of 512 at 1024 positions leaves the
     # single-sweep form; _pick_sub_tile bounds the masked part instead
-    block_q = _pick_block(tq, side="q")
-    block_k = _pick_block(tk, side="k")
+    block_q, block_k = flash_tiles(tq, tk, window)
     offs = jnp.stack([jnp.asarray(q_off, jnp.int32),
                       jnp.asarray(k_off, jnp.int32)])
     interpret = _interpret()
@@ -1057,28 +1240,35 @@ def _flash_bwd_hm(qt, kt, vt, ot, dot, lset, q_off=0, k_off=0, *,
             qt, kt, vt, ot, dot, lset, offs, causal=causal, scale=scale,
             block_q=block_q, block_k=block_k, interpret=interpret,
             fusable=fusable, out_dtype=out_dtype,
-            static_offs=(q_off, k_off) if static else None)
+            static_offs=(q_off, k_off) if static else None, window=window)
     return _flash_bwd_streaming(
         qt, kt, vt, ot, dot, lset, offs, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interpret)
+        block_q=block_q, block_k=block_k, interpret=interpret, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=_FLASH_STATIC)
 def _flash_bwd_streaming(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
-                         block_q, block_k, interpret):
+                         block_q, block_k, interpret, window=None):
     """Dispatch of the streaming pair, ``flash_bwd_dq`` and
     ``flash_bwd_dkv``: one tile of each operand in VMEM, any length.
     Returns (dq, dk, dv) heads-major f32."""
     bh, tq, d = qt.shape
     tk = kt.shape[1]
-    kmap, qmap = _causal_maps(causal, block_q, block_k, tq // block_q)
+    nq, nk = tq // block_q, tk // block_k
+    kspan, qspan, scores = nk, nq, bh * tq * tk
+    if window is not None:
+        kspan, qspan = _band_spans(window, block_q, block_k, nq, nk)
+        scores = bh * flash_plan(causal, tq, tk, 0, 0, block_k, block_q,
+                                 block_k, window)["scores"]
+    kmap, qmap = _causal_maps(causal, block_q, block_k, nq, window, nk)
 
     dq = _named_call("flash_bwd_dq",
-        functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale),
+        functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale,
+                          window=window, nk=nk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             # k innermost: consecutive grid steps revisit the same dq tile
-            grid=(bh, tq // block_q, tk // block_k),
+            grid=(bh, nq, kspan),
             in_specs=[
                 _stat_spec(block_q, lambda i, j, n, offs: (i, j, 0)),
                 pl.BlockSpec((1, block_q, d), lambda i, j, n, offs: (i, j, 0)),
@@ -1092,19 +1282,20 @@ def _flash_bwd_streaming(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
         ),
         out_shape=_struct((bh, tq, d), jnp.float32, qt, kt, offs),
         cost_estimate=pl.CostEstimate(
-            flops=6 * bh * tq * tk * d,
+            flops=6 * scores * d,
             bytes_accessed=4 * bh * (4 * tq * d + 2 * tk * d + tq),
-            transcendentals=bh * tq * tk),
+            transcendentals=scores),
         compiler_params=_sem_par2_arb(),
         interpret=interpret,
     )(offs, lset, qt, kt, vt, ot, dot)
 
     dk, dv = _named_call("flash_bwd_dkv",
-        functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale),
+        functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale,
+                          window=window, nq=nq),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             # q innermost: consecutive grid steps revisit the same dk/dv tiles
-            grid=(bh, tk // block_k, tq // block_q),
+            grid=(bh, nk, qspan),
             in_specs=[
                 _stat_spec(block_q, qmap),
                 pl.BlockSpec((1, block_q, d), qmap),
@@ -1123,9 +1314,9 @@ def _flash_bwd_streaming(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
             _struct((bh, tk, d), jnp.float32, qt, kt, offs),
         ],
         cost_estimate=pl.CostEstimate(
-            flops=8 * bh * tq * tk * d,
+            flops=8 * scores * d,
             bytes_accessed=4 * bh * (4 * tq * d + 3 * tk * d + tq),
-            transcendentals=bh * tq * tk),
+            transcendentals=scores),
         compiler_params=_sem_par2_arb(),
         interpret=interpret,
     )(offs, lset, qt, kt, vt, ot, dot)
@@ -1163,7 +1354,8 @@ def finalize_attention_stats(m, l, o, out_dtype):
 
 
 @functools.lru_cache(maxsize=None)
-def _flash_fullattn_vjp(causal: bool, scale: float):
+def _flash_fullattn_vjp(causal: bool, scale: float,
+                        window: Optional[int] = None):
     """Normalized flash attention with a full Pallas backward
     (FlashAttention-2): forward saves only (q, k, v, out, LSE) — O(T)
     residuals — and the backward recomputes p blockwise on the MXU instead
@@ -1185,23 +1377,22 @@ def _flash_fullattn_vjp(causal: bool, scale: float):
         kt = k.transpose(0, 2, 1, 3).reshape(bh, tk, d)
         vt = v.transpose(0, 2, 1, 3).reshape(bh, tk, d)
         offs = jnp.zeros((2,), jnp.int32)
+        block_q, block_k = flash_tiles(tq, tk, window)
         if flash_route(tq, tk, d, kt.dtype.itemsize)["forward"] == "once":
             # resident shapes take the single-shot kernel: no ring-carry
             # streams, normalized-in-kernel output
             out_t, lse_t = _flash_fwd_once_call(
                 qt, kt, vt, offs, causal=causal, scale=scale,
-                block_q=_pick_block(tq, side="q"),
-                block_k=_pick_block(tk, side="k"), interpret=_interpret(),
-                fusable=_relayout_fusable(b, h))
+                block_q=block_q, block_k=block_k, interpret=_interpret(),
+                fusable=_relayout_fusable(b, h), window=window)
             return qt, kt, vt, out_t, lse_t
         mt = jnp.full((bh, 1, tq), NEG_INF, jnp.float32)
         lt = jnp.zeros((bh, 1, tq), jnp.float32)
         ot = jnp.zeros((bh, tq, d), jnp.float32)
         mt, lt, ot = _flash_step_call(
             qt, kt, vt, mt, lt, ot, offs, causal=causal, scale=scale,
-            block_q=_pick_block(tq, side="q"),
-            block_k=_pick_block(tk, side="k"), interpret=_interpret(),
-            fusable=_relayout_fusable(b, h))
+            block_q=block_q, block_k=block_k, interpret=_interpret(),
+            fusable=_relayout_fusable(b, h), window=window)
         # heads-major finalize; masked-row convention shared with the ring
         # epilogue via _masked_row_stats (backward recompute relies on it)
         l_safe, lse_t = _masked_row_stats(mt, lt)            # [BH, 1, T]
@@ -1235,7 +1426,7 @@ def _flash_fullattn_vjp(causal: bool, scale: float):
         dq, dk, dv = _flash_bwd_hm(qt, kt, vt, out_t, dot, lse_t,
                                    causal=causal, scale=scale,
                                    fusable=_relayout_fusable(b, h),
-                                   out_dtype=qt.dtype)
+                                   out_dtype=qt.dtype, window=window)
         return (_heads_minor(dq, b, h, tq, d).astype(qt.dtype),
                 _heads_minor(dk, b, h, tk, d).astype(kt.dtype),
                 _heads_minor(dv, b, h, tk, d).astype(vt.dtype))
@@ -1245,21 +1436,36 @@ def _flash_fullattn_vjp(causal: bool, scale: float):
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None,
+                    window: Optional[int] = None):
     """Single-device flash attention, ``[B, T, H, D]`` layout.
 
     The full-sequence special case of the ring step (one hop, offsets 0),
     with the Pallas FlashAttention-2 backward when shapes allow. Plain jnp
     attention when ``kernel_path("flash_attention", q, k, v)`` says
     ``"reference"`` (kernels off, or shapes not tile-aligned).
+
+    ``window=W`` on a causal call of as many keys as queries keeps, for the
+    query at ``i``, the keys ``i - W < j <= i``: its last ``W`` positions,
+    itself among them. The kernels skip what lies behind the band as they
+    skip what lies above the diagonal (the note at :func:`_band_first`);
+    a window that reaches the first key from the last query is no window.
     """
     d = q.shape[-1]
     if scale is None:
         scale = d ** -0.5
+    if window is not None:
+        if not causal or window < 1 or q.shape[1] != k.shape[1]:
+            raise ValueError(
+                f"window={window!r} needs causal=True, a positive width "
+                f"and as many keys as queries; got causal={causal}, "
+                f"{q.shape[1]} queries, {k.shape[1]} keys")
+        window = None if window >= k.shape[1] else int(window)
     if kernel_path("flash_attention", q, k, v) == "reference":
         from ..parallel.ring_attention import reference_attention
-        return reference_attention(q, k, v, causal=causal, scale=scale)
-    return _flash_fullattn_vjp(causal, float(scale))(q, k, v)
+        return reference_attention(q, k, v, causal=causal, scale=scale,
+                                   window=window)
+    return _flash_fullattn_vjp(causal, float(scale), window)(q, k, v)
 
 
 # ==================================================================== adasum

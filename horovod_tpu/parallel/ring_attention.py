@@ -208,8 +208,10 @@ def make_ring_attention(mesh, axis_name: str = "sp", causal: bool = False):
 
 
 def reference_attention(q, k, v, causal: bool = False,
-                        scale: Optional[float] = None):
-    """Plain full attention (for tests / single-device fallback)."""
+                        scale: Optional[float] = None,
+                        window: Optional[int] = None):
+    """Plain full attention (for tests / single-device fallback); with
+    ``window`` (causal) a query sees its last ``window`` positions only."""
     d = q.shape[-1]
     if scale is None:
         scale = d ** -0.5
@@ -217,7 +219,8 @@ def reference_attention(q, k, v, causal: bool = False,
                    k.astype(jnp.float32))
     if causal:
         tq, tk = q.shape[1], k.shape[1]
-        mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        delta = jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
+        mask = delta >= 0 if window is None else (delta >= 0) & (delta < window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p,

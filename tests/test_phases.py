@@ -23,6 +23,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def fresh(monkeypatch):
     reset_registry()
     phases.reset()
+    # an earlier test file of this worker that called ``hvd.init()`` and
+    # jitted a model has filled the process's label set to its cap
+    # (MAX_PROGRAM_LABELS): every program here would count as "_other"
+    monkeypatch.setattr(phases, "_program_labels", set())
     phases.install_jax_listeners()
     # the toy functions here trace in microseconds: list them all the same
     monkeypatch.setattr(phases, "MIN_TRACE_SPAN_S", 0.0)

@@ -84,10 +84,12 @@ def test_spark_run_longer_than_start_timeout_succeeds():
     (regression: total-runtime cap masquerading as a start timeout)."""
 
     def fn():
-        time.sleep(1.5)
+        time.sleep(4.5)
         return "done"
 
-    assert hvd_spark.run(fn, num_proc=2, start_timeout=0.5) == ["done", "done"]
+    # (3 s to start two tasks: on a machine busy with the rest of the suite
+    # half a second was not enough in two whole runs of three)
+    assert hvd_spark.run(fn, num_proc=2, start_timeout=3.0) == ["done", "done"]
 
 
 def test_spark_num_proc_defaults_to_parallelism():

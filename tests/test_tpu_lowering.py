@@ -601,3 +601,72 @@ def test_hybrid_step_runs_the_scan_kernels_under_the_ssd_scope(remat,
             == layers
     # nothing of the dual form: no state of a chunk crosses HBM
     assert not re.search(r"f32\[1,\d+,8,16,128\]", text)
+
+
+@pytest.mark.parametrize("remat", ["none", "full"])
+def test_window_and_full_attention_sites_are_named_and_classed(remat,
+                                                               v5e_devices):
+    """A model with window and full attention layers of two head counts (the
+    Laguna family's toy: 6 and 4 query heads of 64 over 2 KV heads, a window
+    of 128 under 512 positions): two shapes of flash call, each kernel
+    traced and lowered once a shape; in the compiled step every site is a
+    ``tpu_custom_call %flash_fwd`` / ``%flash_bwd`` under its own
+    ``block_<i>/mixer``, a window layer's under ``mixer/window`` and the
+    backward's under ``transpose(``: what the benchmark's op class
+    ``attention_kernel``, its scope classes ``attn_fwd`` / ``attn_bwd`` and
+    ``attn_window_kernel_ms``'s own pattern read."""
+    from jax.sharding import SingleDeviceSharding
+
+    from chipbench import op_scopes, trace_reduce
+    from chipbench.families import laguna
+    from chipbench.layer_metrics import attn_gate_ms, attn_window_kernel_ms
+    from tests.test_laguna import CONFIG
+
+    op_classes = trace_reduce.load_classes()
+    scope_classes = trace_reduce.load_classes(op_scopes.SCOPE_CLASSES)
+    config = {**CONFIG, "head_dim": 64, "sliding_window": 128}
+    model = laguna.build_model(config, 512, {"remat": remat})
+    one = SingleDeviceSharding(v5e_devices[0])
+    toks = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one)
+    params = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 512), jnp.int32))["params"])
+    with jax.enable_x64(False):
+        lowered = jax.jit(jax.grad(lambda p, x: jnp.sum(
+            model.apply({"params": p}, x).astype(jnp.float32)))).trace(
+                params, toks).lower(lowering_platforms=("tpu",))
+    # two head counts, one of them windowed: two bodies a kernel
+    assert sorted(re.findall(r'kernel_name = "(flash_\w+)"',
+                             lowered.as_text())) == \
+        ["flash_bwd"] * 2 + ["flash_fwd"] * 2
+    text = lowered.compile().as_text()
+    calls = _kernel_calls(text, r"flash_\w+?")
+    assert sorted(name for name, _, _ in calls) == \
+        ["flash_bwd"] * 5 + ["flash_fwd"] * 5
+    windowed = set()
+    for name, instruction, path in calls:
+        dispatcher, scope = {
+            "flash_fwd": ("_flash_fwd_once_call", "attn_fwd"),
+            "flash_bwd": ("_flash_bwd_fused", "attn_bwd")}[name]
+        block, window = re.search(
+            rf"/block_(\d)/mixer/(window/)?jit\({dispatcher}\)/{name}/",
+            path).groups()
+        assert bool(window) == (config["layer_types"][int(block)]
+                                == "sliding_attention"), path
+        assert bool(attn_window_kernel_ms.PATTERN.search(path)) \
+            == bool(window)
+        windowed.add((name, block)) if window else None
+        assert ("transpose(" in path) == (name == "flash_bwd"), path
+        assert trace_reduce.classify(path, scope_classes) == scope
+        assert trace_reduce.classify(
+            f"tpu_custom_call %{instruction}", op_classes) \
+            == "attention_kernel"
+    assert windowed == {(name, str(block)) for name in
+                        ("flash_fwd", "flash_bwd") for block in (1, 2, 3)}
+    # the gate's projection carries its own scope, in every layer
+    gates = set(re.findall(r'op_name="[^"]*/block_(\d)/mixer/gate/[^"]*"',
+                           text))
+    assert gates == set("01234")
+    assert re.search(attn_gate_ms.PATTERN, "x/block_1/mixer/gate/dot_general")
+    assert not re.search(attn_gate_ms.PATTERN, "x/block_1/mixer/gate_norm/mul")
