@@ -445,9 +445,14 @@ def grouped_cases():
     """The routed feed-forward's three grouped products (``ops/moe.py``) at
     LFM2-8B-A1B's widths and the cell's smaller row capacity, 22,000 rows
     in eight uneven groups, against a loop of dense products over the
-    groups. The rows of no group hold whatever was there and are cut off."""
+    groups. The rows of no group hold whatever was there and are cut off.
+    And the way back to token order, ``put_rows``' segment sum, at
+    Laguna-S-2.1's shape (8,192 tokens, ten assignments each, 16 of 256
+    experts held and preferred; 20,480 rows 3072 wide) against the plain
+    sum over a token's slots."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from horovod_tpu.ops import moe
 
@@ -473,7 +478,31 @@ def grouped_cases():
             dot(lhs[a:b], rhs[g], contract)
             for g, (a, b) in enumerate(spans)])
 
+    tokens, top_k, experts, held, capacity, wide = 8192, 10, 256, 16, 20480, 3072
+
+    def way_back(rows):
+        scores = np.random.RandomState(0).gumbel(size=(tokens, experts))
+        scores[:, :held] += 0.75
+        chosen = np.argsort(-scores, axis=1)[:, :top_k]
+        assert 5120 < (chosen < held).sum() <= capacity
+        _, token, slots, valid, back = moe._rows_at(
+            capacity, top_k, *moe.dispatch(jnp.asarray(chosen, jnp.int32),
+                                           tuple(range(held)), experts))
+        return jnp.where(valid, rows, 0), token, slots, back
+
+    def segment_sum(rows):
+        rows, token, _, back = way_back(rows)
+        return moe.put_rows(rows, token, back)
+
+    def slot_sum(rows):
+        rows, _, slots, _ = way_back(rows)
+        padded = jnp.concatenate([rows, jnp.zeros((1, wide), rows.dtype)])
+        return sum(padded[slots[:, j]].astype(jnp.float32)
+                   for j in range(top_k)).astype(rows.dtype)
+
     return {
+        "moe put_rows (rows summed back into tokens)": KernelCase(
+            segment_sum, [s(capacity, wide)], 1, slot_sum, TOL_BF16),
         "moe grouped_matmul (x W1)": KernelCase(
             product(moe.grouped_matmul),
             [s(rows, d), s(len(sizes), d, 2 * width)], 1,

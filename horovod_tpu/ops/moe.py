@@ -20,10 +20,14 @@ No capacity that drops and no one-hot dispatch over experts x tokens: the
 (token, expert) assignments are sorted by expert, the rows of the experts
 held gathered in that order, the two matrix products done as **grouped**
 products over the experts' row ranges, and the rows summed back into their
-tokens. Shapes are static: the expert stage is compiled at two row counts,
-up to the worst case, every assignment landing here (``tokens * k`` rows),
-and a step runs the smallest that holds its rows (:func:`capacities`), so
-that the cost follows the rows really routed here.
+tokens: put in token order (a sort of the stage's rows, not of the
+assignments) and summed tile of tokens by tile as one more grouped product,
+a one-hot of each row's place in its tile against the rows
+(:func:`put_rows`), so that the way back costs by the rows held here and not
+by every assignment. Shapes are static: the expert stage is compiled at two
+row counts, up to the worst case, every assignment landing here
+(``tokens * k`` rows), and a step runs the smallest that holds its rows
+(:func:`capacities`), so that the cost follows the rows really routed here.
 
 **Which kernel runs where.** A grouped product is one of three:
 :func:`grouped_matmul` (rows against their group's matrix),
@@ -34,7 +38,10 @@ walk only the row tiles a group has rows in, at the tiles
 ``pallas_kernels.grouped_route`` gives the shape; off the chip
 (``pallas_kernels.mode() == "off"``) and for a shape the route refuses,
 ``jax.lax.ragged_dot``. Nothing is set: the platform and the shape decide,
-and ``pallas_kernels.kernel_path`` answers for a given call. **Seven
+and ``pallas_kernels.kernel_path`` answers for a given call.
+:func:`put_rows`' segment sum is a :func:`grouped_outer` too (its groups
+the tiles of 128 tokens), under the scopes ``combine`` and ``dispatch``; the
+products of the experts themselves are under ``experts``. **Seven
 products a layer and step**: two forward (``x W1``, ``act W2``) and five in
 the stage's backward pass, which is written out (:func:`_experts_bwd_at`;
 a Pallas call has no autodiff rule): ``x W1`` again (the stage keeps its
@@ -139,36 +146,6 @@ def capacities(assignments: int, held: int, num_experts: int) -> Tuple[int, ...]
     return tuple(sorted({min(assignments, -(-smaller // 8) * 8), assignments}))
 
 
-# Rows move between token order [N, d] and sorted-row order [R, d] by
-# gathers in both directions: ``take_rows`` and ``put_rows`` are each
-# other's transposes (autodiff's would be a scatter-add of R rows, which
-# the chip runs at a third of the speed: PERF.md, PR 32).
-@jax.custom_vjp
-def take_rows(x, token, slots):
-    """``x[token]``: the row of each sorted row's token (``token`` ``[R]``).
-    ``slots`` ``[N, k]`` is the way back: the sorted row of each of a
-    token's assignments, ``R`` where it has none here."""
-    return x[token]
-
-
-@jax.custom_vjp
-def put_rows(rows, token, slots):
-    """``y[n] = sum_j rows[slots[n, j]]``, a slot of ``R`` adding nothing:
-    each token's rows summed in float32, in ``rows.dtype``."""
-    padded = jnp.concatenate([rows, jnp.zeros((1, rows.shape[1]), rows.dtype)])
-    y = sum(padded[slots[:, j]].astype(jnp.float32)
-            for j in range(slots.shape[1]))
-    return y.astype(rows.dtype)
-
-
-take_rows.defvjp(
-    lambda x, token, slots: (x[token], (token, slots)),
-    lambda res, g: (put_rows(g, *res), None, None))
-put_rows.defvjp(
-    lambda rows, token, slots: (put_rows(rows, token, slots), (token, slots)),
-    lambda res, g: (take_rows(g, *res), None, None))
-
-
 def _kernel_tiles(name: str, lhs, rhs, n: int, key: str = "tiling"):
     """The tiles the Pallas kernel of dispatcher ``name`` takes for these
     operands (``n`` the product's other width), None where the call goes to
@@ -227,17 +204,84 @@ def grouped_outer(lhs, rhs, group_sizes):
         preferred_element_type=lhs.dtype)
 
 
+# Rows move between token order [N, d] and sorted-row order [R, d] by one
+# gather of R rows in either direction, and ``take_rows`` and ``put_rows``
+# are each other's transposes (autodiff's transpose of the gather would be
+# a scatter-add of R rows). The way back to tokens is a sum, and what it
+# costs is how it finds a token's rows. One call, ms on a v5e (PERF.md §6,
+# PR 40), as k gathers of N rows (before PR 40) and as the segment sum with
+# its sort and regather: [20480, 3072] into 8,192 tokens at k 10 5.70-5.76
+# and 1.81-1.92; [11264, 1024] into 4,096 at k 22 0.68-0.69 and 0.21-0.22;
+# [32768, 2048] into 16,384 at k 4 3.69-3.72 and 2.23-2.36 (half of the
+# segment sum's time is the regather); the layer held whole ([65536, 2048],
+# R = N k) 4.34 and 4.27. A scatter-add of the R rows read 2.16-2.69 where
+# the k gathers read 1.25 ([20480, 2048], k 4; PR 32).
+
+#: tokens a segment of :func:`put_rows`' sum: a lane width, so that a row's
+#: place in its segment is one K tile of ``pallas_kernels.tgmm``
+_TOKEN_TILE = 128
+
+
+@jax.custom_vjp
+def take_rows(x, token, back):
+    """``x[token]``: the row of each sorted row's token (``token`` ``[R]``).
+    ``back`` is the way back, :func:`put_rows`' (``_rows_at`` makes it)."""
+    return x[token]
+
+
+@jax.custom_vjp
+def put_rows(rows, token, back):
+    """``y[n]`` = the sum of the sorted rows whose token is ``n``, in
+    float32, in ``rows.dtype``; ``[N, d]`` from ``rows`` ``[R, d]``.
+    ``back = (by_token [R], held [N])``: the permutation that puts the rows
+    in token order with those of no group last, and the rows each token has
+    here. A **segment sum over the rows in token order**: the rows are
+    regathered in that order (a gather of R rows) and each tile of
+    ``_TOKEN_TILE`` tokens is ``one_hot.T @ rows`` over its own rows, the
+    one-hot ``[R, _TOKEN_TILE]`` of ``token mod _TOKEN_TILE``:
+    :func:`grouped_outer` with the tiles as its groups, so on the chip
+    ``pallas_kernels.tgmm`` (rows times 1.0 accumulated in float32 on the
+    MXU; a row past the rows held is not read, a tile with none is zero)
+    and off it, or for a shape ``grouped_route`` refuses,
+    ``jax.lax.ragged_dot_general``. The cost follows ``R`` and, inside the
+    kernel, the rows really here, not the ``N k`` assignments."""
+    by_token, held = back
+    tokens = held.shape[0]
+    tiles = -(-tokens // _TOKEN_TILE)
+    one_hot = jax.nn.one_hot(token[by_token] % _TOKEN_TILE, _TOKEN_TILE,
+                             dtype=rows.dtype)
+    counts = jnp.sum(jnp.pad(held, (0, tiles * _TOKEN_TILE - tokens)).reshape(
+        tiles, _TOKEN_TILE), axis=1)
+    y = grouped_outer(one_hot, rows[by_token], counts)
+    return y.reshape(tiles * _TOKEN_TILE, rows.shape[1])[:tokens]
+
+
+take_rows.defvjp(
+    lambda x, token, back: (x[token], (token, back)),
+    lambda res, g: (put_rows(g, *res), None, None))
+put_rows.defvjp(
+    lambda rows, token, back: (put_rows(rows, token, back), (token, back)),
+    lambda res, g: (take_rows(g, *res), None, None))
+
+
 def _rows_at(rows: int, top_k: int, order, inverse, group_sizes):
-    """Of the first ``rows`` sorted rows: ``(picked, token, slots, valid)``,
-    the assignment and the token each holds, the way back (``put_rows``),
-    and ``[rows, 1]`` whether the row is in a group here."""
+    """Of the first ``rows`` sorted rows: ``(picked, token, slots, valid,
+    back)``, the assignment and the token each holds, the sorted row of
+    each of a token's assignments (``[N, k]``, ``rows`` where it has none
+    here), ``[rows, 1]`` whether the row is in a group here, and the way
+    back to token order (:func:`put_rows`' ``back``)."""
     picked = order[:rows]
+    token = picked // top_k
     here = jnp.sum(group_sizes)
     # an assignment held elsewhere has no row here, whatever its place in
     # the sorted order: the rows from ``here`` on are in no group
-    slots = jnp.where(inverse < here, inverse, rows).reshape(-1, top_k)
-    valid = (jnp.arange(rows, dtype=jnp.int32) < here)[:, None]
-    return picked, picked // top_k, slots, valid
+    mine = (inverse < here).reshape(-1, top_k)
+    slots = jnp.where(mine, inverse.reshape(-1, top_k), rows)
+    valid = jnp.arange(rows, dtype=jnp.int32) < here
+    # (a sort of ``rows`` keys, not of the N k assignments again)
+    by_token = jnp.argsort(jnp.where(valid, token, mine.shape[0]))
+    back = (by_token.astype(jnp.int32), jnp.sum(mine, axis=1, dtype=jnp.int32))
+    return picked, token, slots, valid[:, None], back
 
 
 def _experts_at(rows: int, h, w_in, w_out, weights, order, inverse,
@@ -245,9 +289,9 @@ def _experts_at(rows: int, h, w_in, w_out, weights, order, inverse,
     """The expert stage at a static capacity of ``rows`` sorted rows, which
     must hold every row of the experts here (``sum(group_sizes) <= rows``)."""
     with jax.named_scope("dispatch"):
-        picked, token, slots, valid = _rows_at(rows, weights.shape[1], order,
-                                               inverse, group_sizes)
-        x = take_rows(h, token, slots)
+        picked, token, _, valid, back = _rows_at(
+            rows, weights.shape[1], order, inverse, group_sizes)
+        x = take_rows(h, token, back)
     with jax.named_scope("experts"):
         first = grouped_matmul(x, w_in.astype(h.dtype), group_sizes)
         if activation == "swiglu":
@@ -257,11 +301,11 @@ def _experts_at(rows: int, h, w_in, w_out, weights, order, inverse,
             act = jnp.square(jax.nn.relu(first))
         out = grouped_matmul(act, w_out.astype(h.dtype), group_sizes)
     with jax.named_scope("combine"):
-        # a row of no group holds whatever the product left there. No slot
-        # points at it, so it reaches no token; it is zeroed all the same
+        # a row of no group holds whatever the product left there. It is in
+        # no token's segment, so it reaches none; it is zeroed all the same
         weighted = jnp.where(valid, out, 0).astype(jnp.float32) \
             * weights.reshape(-1)[picked][:, None]
-        return put_rows(weighted.astype(h.dtype), token, slots)
+        return put_rows(weighted.astype(h.dtype), token, back)
 
 
 def _experts_bwd_at(rows: int, h, w_in, w_out, weights, order, inverse,
@@ -275,17 +319,17 @@ def _experts_bwd_at(rows: int, h, w_in, w_out, weights, order, inverse,
     has the gradient ``<out, g> = <act, u>``, so ``out`` is not computed
     again. Every product leaves the rows of no group as they were: ``u`` and
     ``G, U`` meet a sum over a row only under ``valid``, the two
-    weight-gradient products read no such row, and no slot points at one of
-    ``dX``."""
+    weight-gradient products read no such row, and ``put_rows`` reads none
+    of ``dX``."""
     swiglu = activation == "swiglu"
     dtype = h.dtype
     wide = jnp.promote_types(dtype, jnp.float32)    # between the products
     with jax.named_scope("dispatch"):
-        picked, token, slots, valid = _rows_at(rows, weights.shape[1], order,
-                                               inverse, group_sizes)
-        x = take_rows(h, token, slots)
+        picked, token, slots, valid, back = _rows_at(
+            rows, weights.shape[1], order, inverse, group_sizes)
+        x = take_rows(h, token, back)
     with jax.named_scope("combine"):
-        g = take_rows(dy, token, slots)
+        g = take_rows(dy, token, back)
         w = weights.reshape(-1)[picked][:, None]
     with jax.named_scope("experts"):
         w1, w2 = w_in.astype(dtype), w_out.astype(dtype)
@@ -318,7 +362,7 @@ def _experts_bwd_at(rows: int, h, w_in, w_out, weights, order, inverse,
     with jax.named_scope("combine"):
         d_weights = jnp.concatenate([d_w, jnp.zeros((1,), d_w.dtype)])[slots]
     with jax.named_scope("dispatch"):
-        d_h = put_rows(d_x, token, slots)
+        d_h = put_rows(d_x, token, back)
     return (d_h, d_w1.astype(w_in.dtype), d_w2.astype(w_out.dtype),
             d_weights.astype(weights.dtype))
 

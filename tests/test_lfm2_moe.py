@@ -496,25 +496,121 @@ def test_the_backward_pass_reads_no_row_of_no_group(monkeypatch):
         assert float(jnp.max(jnp.abs(got[name] - want[name]))) <= 1e-5, name
 
 
-def test_take_and_put_rows_are_each_others_transposes():
-    order = jnp.asarray(np.random.RandomState(0).permutation(24), jnp.int32)
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(24, dtype=jnp.int32))
-    rows, top_k = 10, 2
-    token = order[:rows] // top_k
-    slots = jnp.minimum(inverse, rows).reshape(-1, top_k)
-    x = jax.random.normal(jax.random.PRNGKey(0), (12, 8))
-    r = jax.random.normal(jax.random.PRNGKey(1), (rows, 8))
-    taken, put = moe.take_rows(x, token, slots), moe.put_rows(r, token, slots)
+def _chosen(tokens, experts, top_k, seed=0):
+    """``[tokens, top_k]`` distinct experts a token, drawn evenly."""
+    rs = np.random.RandomState(seed)
+    return np.stack([rs.permutation(experts)[:top_k] for _ in range(tokens)])
+
+
+def _every_token_elsewhere_but(tokens, experts, top_k, held, here):
+    """Tokens ``here`` choose ``held`` first (all ``top_k`` of theirs where
+    there are that many), every other token none of them."""
+    away = [e for e in range(experts) if e not in held]
+    chosen = np.stack([np.random.RandomState(t).permutation(away)[:top_k]
+                       for t in range(tokens)])
+    for t in here:
+        chosen[t, :min(top_k, len(held))] = held[:top_k]
+    return chosen
+
+
+# name -> (chosen [N, k], experts, held, row capacity or None for N k)
+_ROWS_CASES = {
+    "k1": (_chosen(300, 8, 1), 8, (1, 4), 128),
+    "k4": (_chosen(300, 32, 4), 32, tuple(range(8)), 512),
+    "k10": (_chosen(256, 64, 10), 64, (0, 1, 2, 3), 256),
+    "k22": (_chosen(128, 64, 22), 64, tuple(range(8)), 512),
+    # tokens 5-9 have no row here, token 3 all four of its
+    "a_token_with_none_and_one_with_all_k": (
+        _every_token_elsewhere_but(40, 16, 4, (2, 3, 5, 7), [3, 11, 12]),
+        16, (2, 3, 5, 7), 16),
+    # 384 tokens are three tiles of 128: every row in the second; the
+    # first and the third are empty
+    "every_row_in_one_token_tile": (
+        _every_token_elsewhere_but(384, 16, 2, (0, 9), range(130, 250)),
+        16, (0, 9), 256),
+    "an_empty_token_tile": (
+        _every_token_elsewhere_but(
+            512, 16, 2, (4,), [*range(0, 128, 3), *range(300, 512, 2)]),
+        16, (4,), 256),
+    "no_row_at_all": (
+        _every_token_elsewhere_but(256, 16, 2, (4,), []), 16, (4,), 128),
+    # the layer held whole: R = N k, every row in a group
+    "layer_held_whole": (_chosen(256, 8, 2), 8, tuple(range(8)), None),
+    "worst_case_capacity_with_rows_to_spare": (
+        _chosen(200, 16, 4), 16, (1, 2), None),
+    # a token count no tile divides, and a capacity no row tile divides
+    "tokens_no_tile_divides": (_chosen(203, 16, 3), 16, (0, 5, 6), 200),
+}
+
+
+@pytest.mark.parametrize("mode", ["off", "interpret"])
+@pytest.mark.parametrize("case", sorted(_ROWS_CASES))
+def test_take_and_put_rows_are_each_others_transposes(case, mode,
+                                                      monkeypatch):
+    """``put_rows`` (the segment sum over the rows in token order) against
+    its plain definition, ``sum_j padded[slots[:, j]]`` in float32, with the
+    rows past the groups NaN; ``take_rows`` against ``x[token]``; each the
+    other's transpose, and their gradients autodiff's of the plain
+    definitions. Off the chip (``ragged_dot_general``) and through the
+    kernel where the route takes the shape."""
+    monkeypatch.setenv("HVD_PALLAS", mode)
+    chosen, experts, held, capacity = _ROWS_CASES[case]
+    (tokens, top_k), width = chosen.shape, 128
+    order, inverse, group_sizes = moe.dispatch(
+        jnp.asarray(chosen, jnp.int32), held, experts)
+    rows = capacity or tokens * top_k
+    here = int(jnp.sum(group_sizes))
+    assert here <= rows
+    _, token, slots, valid, back = moe._rows_at(rows, top_k, order, inverse,
+                                                group_sizes)
+    held_by = np.asarray(back[1])
+    assert held_by.sum() == here and held_by.shape == (tokens,)
+    if case == "a_token_with_none_and_one_with_all_k":
+        assert held_by[3] == top_k and not held_by[5:10].any()
+    if case in ("every_row_in_one_token_tile", "an_empty_token_tile"):
+        assert 0 in held_by.reshape(-1, 128).sum(axis=1)
+    if case == "layer_held_whole":
+        assert here == rows == tokens * top_k
+    x = jax.random.normal(jax.random.PRNGKey(0), (tokens, width), jnp.float32)
+    r = jax.random.normal(jax.random.PRNGKey(1), (rows, width), jnp.float32)
+    path = pk.kernel_path("grouped_outer", jnp.zeros((rows, 128), r.dtype), r)
+    assert path == ("pallas" if mode == "interpret" and rows % 128 == 0
+                    else "reference")
+
+    def plain_put(r):
+        padded = jnp.concatenate([jnp.where(valid, r, 0),
+                                  jnp.zeros((1, width), r.dtype)])
+        return sum(padded[slots[:, j]].astype(jnp.float32)
+                   for j in range(top_k)).astype(r.dtype)
+
+    def plain_take(x):
+        return jnp.where(valid, x[token], 0)
+
+    poisoned = jnp.where(valid, r, jnp.nan)
+    taken = moe.take_rows(x, token, back)
+    put = moe.put_rows(poisoned, token, back)
     assert np.array_equal(taken, x[token])
-    want = jnp.zeros((12, 8)).at[token].add(r)
-    assert relative(put, want) <= 1e-6
-    # <take(x), r> = <x, put(r)>, and autodiff gives the same
-    assert abs(float(jnp.vdot(taken, r) - jnp.vdot(x, put))) <= 1e-4
-    assert relative(jax.grad(lambda x: jnp.vdot(
-        moe.take_rows(x, token, slots), r))(x), want) <= 1e-6
-    assert relative(jax.grad(lambda r: jnp.vdot(
-        moe.put_rows(r, token, slots), x))(r), x[token]) <= 1e-6
+    assert put.shape == x.shape and put.dtype == r.dtype
+    assert bool(jnp.all(jnp.isfinite(put)))
+    want = plain_put(r)
+    assert float(jnp.max(jnp.abs(put - want))) <= 1e-5
+    assert not np.any(np.asarray(put)[held_by == 0])
+    # <take(x), r> = <x, put(r)> over the rows here, and autodiff of the
+    # plain definitions gives the same as the pair's own rules
+    wide = [np.asarray(a, np.float64) for a in (plain_take(x), r, x, put)]
+    assert abs(np.vdot(*wide[:2]) - np.vdot(*wide[2:])) <= 1e-4
+    d_x = jax.grad(lambda x: jnp.vdot(moe.take_rows(x, token, back),
+                                      poisoned))(x)
+    assert float(jnp.max(jnp.abs(d_x - jax.grad(
+        lambda x: jnp.vdot(plain_take(x), r))(x)))) <= 1e-5
+    d_r = jax.grad(lambda r: jnp.vdot(moe.put_rows(
+        jnp.where(valid, r, 0), token, back), x))(r)
+    assert float(jnp.max(jnp.abs(d_r - jax.grad(
+        lambda r: jnp.vdot(plain_put(r), x))(r)))) <= 1e-5
+    # in bfloat16: float32 sums rounded once, as the plain definition's
+    low = moe.put_rows(poisoned.astype(jnp.bfloat16), token, back)
+    assert low.dtype == jnp.bfloat16
+    assert np.array_equal(low, plain_put(r.astype(jnp.bfloat16)))
 
 
 # -------------------------------------------------- the two controls

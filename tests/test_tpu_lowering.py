@@ -392,20 +392,57 @@ def test_train_step_at_1024_positions_compiles_for_v5e(remat, v5e_devices):
         'custom_call_target="tpu_custom_call"') == 2 * LAYERS
 
 
+def _tgmm_scopes(hlo_text):
+    """The innermost of the routed layer's scopes around every ``moe_tgmm``
+    call of a compiled program, sorted: ``experts`` for a weight-gradient
+    product, ``combine`` / ``dispatch`` for ``put_rows``' segment sum
+    (``moe_experts_ms`` reads the scope ``experts``, and ``put_rows`` must
+    stay outside it)."""
+    return sorted(re.search(
+        r"[/(]moe[/)].*/(experts|combine|dispatch)/jit\(_tgmm\)/moe_tgmm/",
+        path).group(1) for _, _, path in _kernel_calls(hlo_text, "moe_tgmm"))
+
+
+def _put_rows_takes_the_kernel(rows, d):
+    """``put_rows`` of ``[rows, d]`` bfloat16 rows goes to ``tgmm``: what
+    ``grouped_outer`` asks of ``kernel_path`` for its one-hot and rows."""
+    from horovod_tpu.ops import moe, pallas_kernels as pk
+
+    return pk.kernel_path(
+        "grouped_outer",
+        jax.ShapeDtypeStruct((rows, moe._TOKEN_TILE), jnp.bfloat16),
+        jax.ShapeDtypeStruct((rows, d), jnp.bfloat16)) == "pallas"
+
+
+# family -> tokens, d, expert width, experts, held, top_k, the row
+# capacities, and what the family's layer passes ``routed_ffn`` by keyword:
+# LFM2-8B-A1B at its cell's 16,384 tokens, and Laguna-S-2.1's sparse layers
+# (softmax scores, weights 2.5 x p / sum) at its cell's 8,192
+_SWIGLU_LAYERS = {
+    "lfm2_moe": (16384, 2048, 1792, 32, 8, 4, (32768, 65536), {}),
+    "laguna": (8192, 3072, 1024, 256, 16, 10, (20480, 81920),
+               {"scale": 2.5, "scoring": "softmax"}),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_SWIGLU_LAYERS))
 def test_routed_feed_forward_compiles_for_v5e_at_the_published_widths(
-        v5e_devices):
-    """``ops/moe.routed_ffn`` forward and backward at LFM2-8B-A1B's widths
-    and the cell's 16,384 tokens: the grouped products are the Pallas
-    kernels ``moe_gmm`` / ``moe_tgmm``, seven a row capacity (two forward;
-    one recomputed, two against the transposed matrices and two
-    weight-gradient products in the hand-written backward pass), none is
-    XLA's ragged-dot kernel, and nothing of the layer is a dense product
-    over experts x tokens."""
+        family, v5e_devices):
+    """``ops/moe.routed_ffn`` forward and backward at a family's widths and
+    its cell's tokens: the grouped products are the Pallas kernels
+    ``moe_gmm`` / ``moe_tgmm``, seven a row capacity (two forward; one
+    recomputed, two against the transposed matrices and two weight-gradient
+    products in the hand-written backward pass), none is XLA's ragged-dot
+    kernel, and nothing of the layer is a dense product over experts x
+    tokens. ``put_rows`` is two more ``moe_tgmm`` a capacity, the forward's
+    under ``combine`` and ``d_h``'s under ``dispatch``, whose operand is the
+    ``[rows, 128]`` one-hot of a row's place in its tile of tokens."""
     from jax.sharding import SingleDeviceSharding
 
     from horovod_tpu.ops import moe, pallas_kernels as pk
 
-    tokens, d, width, experts, held, top_k = 16384, 2048, 1792, 32, 8, 4
+    tokens, d, width, experts, held, top_k, capacities, keywords = \
+        _SWIGLU_LAYERS[family]
     one = SingleDeviceSharding(v5e_devices[0])
 
     def shape(dims, dtype):
@@ -413,11 +450,12 @@ def test_routed_feed_forward_compiles_for_v5e_at_the_published_widths(
 
     def loss(h, router, bias, w_in, w_out):
         y = moe.routed_ffn(h, router, bias, w_in, w_out,
-                           held=tuple(range(held)), top_k=top_k)[0]
+                           held=tuple(range(held)), top_k=top_k,
+                           **keywords)[0]
         return jnp.sum(y.astype(jnp.float32) ** 2)
 
     sizes = moe.capacities(tokens * top_k, held, experts)
-    assert sizes == (32768, 65536)
+    assert sizes == capacities
     for rows in sizes:
         for k, n in ((d, 2 * width), (width, d), (2 * width, d)):
             assert pk.grouped_route(rows, k, n, 2)["path"] == "pallas"
@@ -432,8 +470,11 @@ def test_routed_feed_forward_compiles_for_v5e_at_the_published_widths(
     calls = re.findall(r"%(moe_t?gmm)[.\d]* = [^\n]*custom_call_target="
                        r'"tpu_custom_call"', text)
     assert calls.count("moe_gmm") == len(sizes) * (2 + 3), calls
-    assert calls.count("moe_tgmm") == len(sizes) * 2, calls
+    assert _tgmm_scopes(text) == sorted(
+        len(sizes) * ["experts", "experts", "combine", "dispatch"])
     for rows in sizes:
+        assert _put_rows_takes_the_kernel(rows, d)
+        assert f"bf16[{rows},{moe._TOKEN_TILE}]" in text
         assert f"bf16[{rows},{2 * width}]" in text
     # no [experts, tokens, d] or [tokens, experts, d] operand anywhere
     assert not re.search(rf"\[({held}|{experts}),{tokens},{d}\]", text)
@@ -447,8 +488,8 @@ def test_latent_routed_feed_forward_compiles_for_v5e_at_the_published_widths(
     cell's 4,096 tokens: top-22 of 512 with 8 held, squared-ReLU experts
     2688 wide reading a latent of 1024 beside the router's 4096-wide input.
     The grouped products are the Pallas kernels at both row capacities, the
-    same seven a capacity as the SwiGLU stage's, and ``w_in`` is one expert
-    width wide, not two."""
+    same seven a capacity as the SwiGLU stage's with ``put_rows``' two
+    beside them, and ``w_in`` is one expert width wide, not two."""
     from jax.sharding import SingleDeviceSharding
 
     from horovod_tpu.ops import moe
@@ -480,10 +521,47 @@ def test_latent_routed_feed_forward_compiles_for_v5e_at_the_published_widths(
     calls = re.findall(r"%(moe_t?gmm)[.\d]* = [^\n]*custom_call_target="
                        r'"tpu_custom_call"', text)
     assert calls.count("moe_gmm") == len(sizes) * (2 + 3), calls
-    assert calls.count("moe_tgmm") == len(sizes) * 2, calls
+    assert _tgmm_scopes(text) == sorted(
+        len(sizes) * ["experts", "experts", "combine", "dispatch"])
     for rows in sizes:
+        assert _put_rows_takes_the_kernel(rows, latent)
         assert f"bf16[{rows},{width}]" in text
         assert f"bf16[{rows},{2 * width}]" not in text
+
+
+@pytest.mark.parametrize("latent", [0, 128], ids=["plain", "latent"])
+def test_a_recomputed_block_sums_rows_back_only_where_the_sum_is_kept(
+        latent, v5e_devices):
+    """A routed block of a model under ``remat="full"``, compiled for a
+    v5e: ``put_rows`` is a ``moe_tgmm`` under ``combine`` in the forward
+    pass and one under ``dispatch`` in the hand-written backward pass
+    (``d_h``), a layer and capacity; the recomputed forward's is dead code
+    (the stage's backward pass keeps the layer's operands, not its result)
+    unless something after the experts keeps their sum for its own
+    gradient, as a latent's up-projection does: three then."""
+    from horovod_tpu.models.hybrid import HybridLM
+    from horovod_tpu.ops import moe
+
+    layers, seq, top_k, experts, held = 2, 256, 2, 8, (0, 1)
+    model = HybridLM(
+        vocab_size=512, layer_kinds=("attention",) * layers, d_model=128,
+        ffn_width=256, attn_heads=2, attn_kv_heads=2, attn_head_dim=64,
+        ssm_heads=8, ssm_head_dim=16, ssm_state=128, ssm_chunk=64,
+        ffn_kinds=("moe",) * layers, moe_experts=experts, moe_held=held,
+        moe_top_k=top_k, moe_width=128, moe_latent=latent, remat="full")
+    sizes = moe.capacities(seq * top_k, len(held), experts)
+    assert sizes == (256, 512)
+    text = _model_grad_text(model, seq, v5e_devices)
+    each = layers * len(sizes)
+    assert _tgmm_scopes(text) == sorted(
+        each * ["experts", "experts", "dispatch"]
+        + each * (2 if latent else 1) * ["combine"])
+    sums = [path for _, _, path in _kernel_calls(text, "moe_tgmm")
+            if "/experts/" not in path]
+    assert sum("rematted_computation" in path for path in sums) \
+        == (each if latent else 0)
+    assert sum("/dispatch/" in path and "transpose(" in path
+               for path in sums) == each
 
 
 # name -> heads, groups (None: B and C [b, T, N]), chunk, head width,
@@ -549,11 +627,27 @@ def _scan_calls(hlo_text):
             _kernel_calls(hlo_text, "ssd_fwd|ssd_bwd")]
 
 
+def _model_grad_text(model, seq, v5e_devices):
+    """The compiled gradient, for a v5e, of the sum of ``model``'s logits on
+    one sequence of ``seq`` tokens."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    toks = jax.ShapeDtypeStruct((1, seq), jnp.int32, sharding=one)
+    params = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, seq), jnp.int32))["params"]))
+    with jax.enable_x64(False):
+        return jax.jit(jax.grad(lambda p, x: jnp.sum(
+            model.apply({"params": p}, x).astype(jnp.float32)))).trace(
+                params, toks).lower(
+                    lowering_platforms=("tpu",)).compile().as_text()
+
+
 def _hybrid_grad_text(v5e_devices, remat):
     """The compiled gradient of a two-layer Mamba-2 model whose scan the
     kernels take (8 heads of 16, state 128, 256 positions), for a v5e."""
-    from jax.sharding import SingleDeviceSharding
-
     from horovod_tpu.models.hybrid import HybridLM
 
     model = HybridLM(
@@ -561,17 +655,7 @@ def _hybrid_grad_text(v5e_devices, remat):
         ffn_width=256, attn_heads=4, attn_kv_heads=2, attn_head_dim=32,
         ssm_heads=8, ssm_head_dim=16, ssm_state=128, ssm_chunk=64,
         remat=remat)
-    one = SingleDeviceSharding(v5e_devices[0])
-    toks = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=one)
-    params = jax.tree_util.tree_map(
-        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one),
-        jax.eval_shape(lambda: model.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 256), jnp.int32))["params"]))
-    with jax.enable_x64(False):
-        return jax.jit(jax.grad(lambda p, x: jnp.sum(
-            model.apply({"params": p}, x).astype(jnp.float32)))).trace(
-                params, toks).lower(
-                    lowering_platforms=("tpu",)).compile().as_text()
+    return _model_grad_text(model, 256, v5e_devices)
 
 
 @pytest.mark.parametrize("remat", ["none", "full"])
