@@ -3,7 +3,8 @@ mixers (Mamba-2, attention, gated short convolution) and feed-forwards
 (SwiGLU, routed experts).
 
 ``TransformerLM`` is one recipe. Here a model is a tuple of per-layer mixer
-kinds (``"mamba"`` | ``"attention"`` | ``"short_conv"`` | ``"none"``), a
+kinds (``"mamba"`` | ``"attention"`` | ``"latent_attention"`` | ``"short_conv"``
+| ``"none"``), a
 tuple of per-layer feed-forward kinds (``"swiglu"`` | ``"moe"`` |
 ``"none"``) and the widths of each; every block is
 
@@ -27,6 +28,11 @@ scale ``head_dim ** -0.5``, unless given).
   by another rotary scheme (a base over the whole head against given
   frequencies over half of it, scaled), and every layer gates each head's
   output (``gate``).
+* The latent-attention mixer (MLA, DeepSeek-V2/V3's, without a query
+  latent) projects its input to one narrow normed latent, from which every
+  head's keys and values are made, and to one rotary key that all heads
+  share; a head's keys are wider than its values, and the flash kernels
+  take each at its own width.
 * The Mamba-2 mixer is ``ops/ssd.py``, with one group of ``B`` and ``C``
   or several; the gated short convolution (LFM2's
   ``conv`` layer) is ``W_out (C * conv(B * u))`` over ``[B, C, u] = W_in h``
@@ -244,6 +250,59 @@ class AttentionMixer(nn.Module):
             out.reshape(b, t, self.heads * self.head_dim))
 
 
+class LatentAttentionMixer(nn.Module):
+    """Multi-head latent attention in its training form (K and V made for
+    every head; no query latent): ``q = h Wq``, a head ``[nope | rope]``;
+    ``[c | k_r] = h Wkv_a`` with ``c`` ``kv_rank`` wide; ``[k_nope | v] =
+    RMSNorm(c) Wkv_b``, a head ``nope_dim + v_dim``; each head's rotary
+    part of q and the one ``k_r`` turn by position (``ops/rope.apply_rope``
+    at ``rope_theta``, pairs of neighbouring elements), and every head's
+    key is ``[k_nope | k_r]``. Causal softmax at the caller's ``scale``
+    through the flash kernels, keys ``nope_dim + rope_dim`` and values
+    ``v_dim`` wide; no bias. The scopes a device trace reads:
+    ``latent`` (the two latent products and the norm between them),
+    ``rope``, ``assemble`` (the splits, ``k_r`` handed to every head, the
+    concatenations)."""
+    heads: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    scale: float
+    eps: float
+    dtype: Any
+    rope_theta: float
+
+    @nn.compact
+    def __call__(self, h):
+        b, t, d_model = h.shape
+        heads, nope = self.heads, self.nope_dim
+        q = _dense(heads * (nope + self.rope_dim), self.dtype, "q")(
+            h).reshape(b, t, heads, nope + self.rope_dim)
+        with jax.named_scope("latent"):
+            c, k_rope = jnp.split(_dense(self.kv_rank + self.rope_dim,
+                                         self.dtype, "kv_a")(h),
+                                  [self.kv_rank], axis=-1)
+            c = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
+                           param_dtype=jnp.float32, name="kv_norm")(c)
+            kv = _dense(heads * (nope + self.v_dim), self.dtype, "kv_b")(
+                c).reshape(b, t, heads, nope + self.v_dim)
+        with jax.named_scope("assemble"):
+            q_nope, q_rope = jnp.split(q, [nope], axis=-1)
+            k_nope, v = jnp.split(kv, [nope], axis=-1)
+        with jax.named_scope("rope"):
+            turn = partial(apply_rope, theta=self.rope_theta,
+                           interleaved=True)
+            q_rope, k_rope = turn(q_rope), turn(k_rope[:, :, None, :])
+        with jax.named_scope("assemble"):
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                k_rope, (b, t, heads, self.rope_dim))], axis=-1)
+        out = flash_attention(q, k, v, causal=True, scale=self.scale)
+        return _dense(d_model, self.dtype, "o")(
+            out.astype(self.dtype).reshape(b, t, heads * self.v_dim))
+
+
 def _activate(kind: str, pre):
     """``"swiglu"``: ``silu(a) * b`` of ``pre = [a, b]``; ``"relu2"``:
     ``relu(pre) ** 2``."""
@@ -347,6 +406,7 @@ class HybridLM(nn.Module):
     """Tokens ``[B, T]`` -> float32 logits ``[B, T, vocab_size]``."""
     vocab_size: int
     layer_kinds: Tuple[str, ...]        # "mamba" | "attention" | "short_conv"
+                                        # | "latent_attention"
                                         # | "none": a feed-forward block
                                         # | a name of ``attn_kinds``
     d_model: int
@@ -389,6 +449,13 @@ class HybridLM(nn.Module):
     # ``AttentionMixer``'s fields differs from the "attention" kind's
     attn_kinds: Dict[str, Dict[str, Any]] = dataclasses.field(
         default_factory=dict)
+    # the "latent_attention" layers (``attn_heads`` heads; the softmax scale
+    # is (nope + rope) ** -0.5 unless ``attention_multiplier`` gives it)
+    mla_kv_rank: int = 0                # the KV latent's width
+    mla_nope_dim: int = 0               # a head's keys and queries: the part
+    mla_rope_dim: int = 0               # that does not turn, and the one that
+    mla_v_dim: int = 0                  # does; a head's values
+    mla_rope_theta: float = 0.0         # the rotary base: to be stated
 
     @nn.compact
     def __call__(self, tokens):
@@ -400,6 +467,16 @@ class HybridLM(nn.Module):
                              f"expected 'none' or 'rope'")
         scale = self.attn_head_dim ** -0.5 \
             if self.attention_multiplier is None else self.attention_multiplier
+        mla_scale = self.attention_multiplier
+        if "latent_attention" in self.layer_kinds:
+            mla = (self.mla_kv_rank, self.mla_nope_dim, self.mla_rope_dim,
+                   self.mla_v_dim, self.mla_rope_theta)
+            if not all(x > 0 for x in mla):
+                raise ValueError(
+                    f"latent_attention layers need mla_kv_rank, mla_nope_dim, "
+                    f"mla_rope_dim, mla_v_dim and mla_rope_theta; got {mla}")
+            if mla_scale is None:
+                mla_scale = (self.mla_nope_dim + self.mla_rope_dim) ** -0.5
         mixers = {
             "mamba": partial(MambaMixer, self.ssm_heads, self.ssm_head_dim,
                              self.ssm_state, self.ssm_conv_width,
@@ -414,6 +491,12 @@ class HybridLM(nn.Module):
                 gate=self.attn_gate),
             "short_conv": partial(ShortConvMixer, self.conv_width,
                                   self.dtype),
+            "latent_attention": partial(
+                LatentAttentionMixer, heads=self.attn_heads,
+                kv_rank=self.mla_kv_rank, nope_dim=self.mla_nope_dim,
+                rope_dim=self.mla_rope_dim, v_dim=self.mla_v_dim,
+                scale=mla_scale, eps=self.norm_eps, dtype=self.dtype,
+                rope_theta=self.mla_rope_theta),
             "none": None}
         fields = {f.name for f in dataclasses.fields(AttentionMixer)} - {
             "parent", "name"}

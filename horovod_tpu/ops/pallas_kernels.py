@@ -528,7 +528,7 @@ def _flash_fwd_once_kernel(offs_ref, q_ref, k_ref, v_ref, oo_ref, lse_ref,
     q = q_ref[0]                                      # [BQ, D]
     m = jnp.full((bq,), NEG_INF, jnp.float32)
     l = jnp.zeros((bq,), jnp.float32)
-    o = jnp.zeros((bq, q_ref.shape[2]), jnp.float32)
+    o = jnp.zeros((bq, v_ref.shape[2]), jnp.float32)
     m, l, o = _flash_accum(q, k_ref, v_ref, m, l, o,
                            q_off=q_off, k_off=k_off, causal=causal,
                            scale=scale, block_k=block_k, window=window)
@@ -545,10 +545,11 @@ def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
                          block_k, interpret, fusable, window=None):
     """Resident-layout dispatch of the single-shot forward (jitted: the
     rule below :func:`_named_call`).
-    qt: [BH, TQ, D]; kt/vt: [BH, TK, D] → (out [BH, TQ, D] in qt.dtype,
-    lse [BH, 1, TQ] f32). Caller guarantees the resident budget."""
+    qt: [BH, TQ, D]; kt: [BH, TK, D]; vt: [BH, TK, DV] → (out [BH, TQ, DV]
+    in qt.dtype, lse [BH, 1, TQ] f32). Caller guarantees the resident
+    budget."""
     bh, tq, d = qt.shape
-    tk = kt.shape[1]
+    tk, dv = vt.shape[1:]
     # the only caller passes zero offsets: the plan is the call's
     scores = bh * flash_plan(causal, tq, tk, 0, 0, block_k, block_q,
                              block_k, window)["scores"]
@@ -561,20 +562,20 @@ def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda i, j, offs: (i, j, 0)),
                 pl.BlockSpec((1, tk, d), lambda i, j, offs: (i, 0, 0)),
-                pl.BlockSpec((1, tk, d), lambda i, j, offs: (i, 0, 0)),
+                pl.BlockSpec((1, tk, dv), lambda i, j, offs: (i, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda i, j, offs: (i, j, 0)),
+                pl.BlockSpec((1, block_q, dv), lambda i, j, offs: (i, j, 0)),
                 _stat_spec(block_q, lambda i, j, offs: (i, j, 0)),
             ],
         ),
         out_shape=[
-            _struct((bh, tq, d), qt.dtype, qt, kt, offs),
+            _struct((bh, tq, dv), qt.dtype, qt, kt, offs),
             _struct((bh, 1, tq), jnp.float32, qt, kt, offs),
         ],
         cost_estimate=pl.CostEstimate(
-            flops=4 * scores * d,                     # 2 matmuls a score
-            bytes_accessed=(2 * (2 * bh * tq * d + 2 * bh * tk * d)
+            flops=2 * scores * (d + dv),              # 2 matmuls a score
+            bytes_accessed=(2 * bh * (tq + tk) * (d + dv)
                             + 4 * bh * tq),           # q, out, k, v; lse
             transcendentals=scores),
         compiler_params=_input_fusion(_sem_par2_res(), "ttt", fusable),
@@ -691,7 +692,7 @@ def _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs, *, causal,
     """Streaming-layout dispatch of the forward step (k/v too long to keep
     resident)."""
     bh, tq, d = qt.shape
-    tk = kt.shape[1]
+    tk, dv = vt.shape[1:]
     nq, nk = tq // block_q, tk // block_k
     kspan, scores = nk, bh * tq * tk
     if window is not None:
@@ -701,6 +702,7 @@ def _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs, *, causal,
 
     kmap, _ = _causal_maps(causal, block_q, block_k, nq, window, nk)
     qtile = pl.BlockSpec((1, block_q, d), lambda i, j, n, offs: (i, j, 0))
+    otile = pl.BlockSpec((1, block_q, dv), lambda i, j, n, offs: (i, j, 0))
     stat = _stat_spec(block_q, lambda i, j, n, offs: (i, j, 0))
 
     return _named_call("flash_step",
@@ -712,21 +714,21 @@ def _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs, *, causal,
             in_specs=[
                 qtile,
                 pl.BlockSpec((1, block_k, d), kmap),
-                pl.BlockSpec((1, block_k, d), kmap),
-                stat, stat, qtile,
+                pl.BlockSpec((1, block_k, dv), kmap),
+                stat, stat, otile,
             ],
-            out_specs=[stat, stat, qtile],
+            out_specs=[stat, stat, otile],
         ),
         out_shape=[
             _struct((bh, 1, tq), jnp.float32, qt, kt, mt, offs),
             _struct((bh, 1, tq), jnp.float32, qt, kt, mt, offs),
-            _struct((bh, tq, d), jnp.float32, qt, kt, mt, offs),
+            _struct((bh, tq, dv), jnp.float32, qt, kt, mt, offs),
         ],
         # k is innermost and ACCUMULATES into the revisited q-side tiles
         compiler_params=_sem_par2_arb(),
         cost_estimate=pl.CostEstimate(
-            flops=4 * scores * d,
-            bytes_accessed=4 * (2 * bh * tq * d + 2 * bh * tk * d
+            flops=2 * scores * (d + dv),
+            bytes_accessed=4 * (bh * (tq + tk) * (d + dv)
                                 + 4 * bh * tq),       # m, l in and out
             transcendentals=scores),
         interpret=interpret,
@@ -734,11 +736,13 @@ def _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs, *, causal,
 
 
 def _flash_step_call(qt, kt, vt, mt, lt, ot, offs, *, fusable, **static):
-    """qt/ot: [BH, T, D]; kt/vt: [BH, TK, D]; mt/lt: [BH, 1, T] f32.
-    ``flash_route`` picks the layout here, outside the two dispatchers."""
+    """qt: [BH, T, D]; kt: [BH, TK, D]; vt: [BH, TK, DV]; ot: [BH, T, DV];
+    mt/lt: [BH, 1, T] f32. ``flash_route`` picks the layout here, outside
+    the two dispatchers."""
     bh, tq, d = qt.shape
     tk = kt.shape[1]
-    if flash_route(tq, tk, d, kt.dtype.itemsize)["step"] == "step_streaming":
+    if flash_route(tq, tk, d, kt.dtype.itemsize,
+                   dv=vt.shape[2])["step"] == "step_streaming":
         return _flash_step_call_streaming(qt, kt, vt, mt, lt, ot, offs,
                                           **static)
     return _flash_step_call_resident(qt, kt, vt, mt, lt, ot, offs,
@@ -754,31 +758,34 @@ def _flash_step_call_resident(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
     k/v is resident takes ``flash_fwd``)."""
     assert window is None
     bh, tq, d = qt.shape
-    tk = kt.shape[1]
+    tk, dv = vt.shape[1:]
     kernel = functools.partial(_flash_step_kernel, causal=causal, scale=scale,
                                block_k=block_k)
     qtile = pl.BlockSpec((1, block_q, d), lambda i, j, offs: (i, j, 0))
+    otile = pl.BlockSpec((1, block_q, dv), lambda i, j, offs: (i, j, 0))
     stat = _stat_spec(block_q, lambda i, j, offs: (i, j, 0))
-    kv = pl.BlockSpec((1, tk, d), lambda i, j, offs: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bh, tq // block_q),
-        in_specs=[qtile, kv, kv, stat, stat, qtile],
-        out_specs=[stat, stat, qtile],
+        in_specs=[qtile,
+                  pl.BlockSpec((1, tk, d), lambda i, j, offs: (i, 0, 0)),
+                  pl.BlockSpec((1, tk, dv), lambda i, j, offs: (i, 0, 0)),
+                  stat, stat, otile],
+        out_specs=[stat, stat, otile],
     )
     # ring hops pass traced offsets: the whole rectangle, an upper bound
-    flops = 4 * bh * tq * tk * d  # 2 matmuls
+    flops = 2 * bh * tq * tk * (d + dv)  # 2 matmuls
     return _named_call("flash_step",
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             _struct((bh, 1, tq), jnp.float32, qt, kt, mt, offs),
             _struct((bh, 1, tq), jnp.float32, qt, kt, mt, offs),
-            _struct((bh, tq, d), jnp.float32, qt, kt, mt, offs),
+            _struct((bh, tq, dv), jnp.float32, qt, kt, mt, offs),
         ],
         cost_estimate=pl.CostEstimate(
             flops=flops,
-            bytes_accessed=4 * (2 * bh * tq * d + 2 * bh * tk * d
+            bytes_accessed=4 * (bh * (tq + tk) * (d + dv)
                                 + 4 * bh * tq),       # m, l in and out
             transcendentals=bh * tq * tk),
         # independent grid cells: Mosaic may pipeline across bh and q tiles;
@@ -802,31 +809,37 @@ _DQ_SCRATCH_CAP = 4 * 2 ** 20
 
 
 def flash_route(tq: int, tk: int, d: int, itemsize: int,
-                window: Optional[int] = None) -> dict:
+                window: Optional[int] = None, *,
+                dv: Optional[int] = None) -> dict:
     """Which kernels one head of ``tq`` queries against ``tk`` keys of
-    width ``d`` takes; the dispatchers and the tests both read it.
+    width ``d`` and values of width ``dv`` (``d`` unless given) takes; the
+    dispatchers and the tests both read it. K and V are each held to the
+    resident cap by their own bytes, and the dq scratch is as wide as q.
     ``forward`` (the full-attention call) is ``once`` or ``step_streaming``,
     ``step`` (a ring hop, carrying m, l, o) ``step`` or ``step_streaming``,
     ``backward`` ``fused`` or ``streaming`` (the dq / dkv pair). A
     ``window`` changes none of the three (the resident kernels hold a
     head's whole k/v and dq whatever the band; its tiles are
     :func:`flash_tiles`'s). No JAX."""
-    kv_resident = tk * d * itemsize <= _KV_VMEM_CAP
+    kv_resident = tk * max(d, dv or d) * itemsize <= _KV_VMEM_CAP
     return {"forward": "once" if kv_resident else "step_streaming",
             "step": "step" if kv_resident else "step_streaming",
             "backward": ("fused" if tq * d * 4 <= _DQ_SCRATCH_CAP
                          else "streaming")}
 
 
-def step_supported(q, k) -> bool:
+def step_supported(q, k, v=None) -> bool:
     """True if ``flash_attention_step`` can run these shapes as a TPU kernel
-    (tile-aligned seq lens, lane-aligned head dim — no length cap: k/v
-    beyond the resident VMEM budget take the streaming layout)."""
+    (tile-aligned seq lens, lane-aligned head dims, the values' where ``v``
+    is given — no length cap: k/v beyond the resident VMEM budget take the
+    streaming layout)."""
     if mode() == "off":
         return False
     b, tq, h, d = q.shape
     tk = k.shape[1]
-    if d % 128 != 0 and d not in (64,):  # MXU lane width; 64 still maps
+    # MXU lane width; 64 still maps, and 192 is a tile and a half
+    if any(w % 128 != 0 and w not in (64, 192)
+           for w in (d,) + (() if v is None else (v.shape[-1],))):
         return False
     if vma_active(q, k):
         return False
@@ -840,7 +853,8 @@ def flash_attention_step(q, k, v, m, l, o, q_off, k_off, *,
     """Flash-accumulate ``q`` against one resident ``(k, v)`` block.
 
     Same contract as the ring-attention inner step: shapes
-    q/o ``[B, T, H, D]``, k/v ``[B, TK, H, D]``, m/l ``[B, H, T]`` (f32 running
+    q ``[B, T, H, D]``, k ``[B, TK, H, D]``, v ``[B, TK, H, DV]``, o
+    ``[B, T, H, DV]``, m/l ``[B, H, T]`` (f32 running
     max / normalizer), ``q_off``/``k_off`` global sequence origins (traced
     scalars OK). Returns updated ``(m, l, o)``. No ``window`` under a ring
     hop yet: the band's grids count from offsets that are 0.
@@ -849,15 +863,15 @@ def flash_attention_step(q, k, v, m, l, o, q_off, k_off, *,
         raise ValueError("flash_attention_step takes no window: a band "
                          "under a ring hop is not written (ROADMAP V5)")
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[3]
     block_q = _pick_block(tq, side="q")
     block_k = _pick_block(tk, side="k")
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * h, tk, dv)
     mt = m.reshape(b * h, 1, tq)
     lt = l.reshape(b * h, 1, tq)
-    ot = o.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
+    ot = o.transpose(0, 2, 1, 3).reshape(b * h, tq, dv)
     offs = jnp.stack([jnp.asarray(q_off, jnp.int32),
                       jnp.asarray(k_off, jnp.int32)])
     mt, lt, ot = _flash_step_call(
@@ -866,7 +880,7 @@ def flash_attention_step(q, k, v, m, l, o, q_off, k_off, *,
         fusable=_relayout_fusable(b, h))
     m_new = mt.reshape(b, h, tq)
     l_new = lt.reshape(b, h, tq)
-    o_new = ot.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
+    o_new = ot.reshape(b, h, tq, dv).transpose(0, 2, 1, 3)
     return m_new, l_new, o_new
 
 
@@ -1131,7 +1145,7 @@ def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
     the whole rectangle (an upper bound) where they are traced."""
     out_dtype = jnp.float32 if out_dtype is None else out_dtype
     bh, tq, d = qt.shape
-    tk = kt.shape[1]
+    tk, dv = vt.shape[1:]
     nq, nk = tq // block_q, tk // block_k
     # a single k sweep writes each q tile's dq in its own cell: every q
     # tile is visited, and the band bounds the strips alone
@@ -1139,6 +1153,7 @@ def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
     band = (window, nk) if banded else ()
     _, qmap = _causal_maps(causal, block_q, block_k, nq, *band)
     ktile = pl.BlockSpec((1, block_k, d), lambda i, j, n, offs: (i, j, 0))
+    vtile = pl.BlockSpec((1, block_k, dv), lambda i, j, n, offs: (i, j, 0))
     sub_q, sub_k = _pick_sub_tile(causal, block_q, block_k)
     scores = bh * (tq * tk if static_offs is None else flash_plan(
         causal, tq, tk, *static_offs, block_k, sub_q, sub_k,
@@ -1157,14 +1172,14 @@ def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
             in_specs=[
                 _stat_spec(block_q, qmap),
                 pl.BlockSpec((1, block_q, d), qmap),
-                ktile, ktile,
-                pl.BlockSpec((1, block_q, d), qmap),
-                pl.BlockSpec((1, block_q, d), qmap),
+                ktile, vtile,
+                pl.BlockSpec((1, block_q, dv), qmap),
+                pl.BlockSpec((1, block_q, dv), qmap),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, d), qmap if banded else
                              lambda i, j, n, offs: (i, n, 0)),
-                ktile, ktile,
+                ktile, vtile,
             ],
             # single k sweep: dq finishes inside its cell — no dq scratch;
             # dk/dv always accumulate f32 in the scratch pair and cast on
@@ -1172,16 +1187,17 @@ def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
             scratch_shapes=(([] if tk // block_k == 1
                              else [pltpu.VMEM((tq, d), jnp.float32)])
                             + [pltpu.VMEM((block_k, d), jnp.float32),
-                               pltpu.VMEM((block_k, d), jnp.float32)]),
+                               pltpu.VMEM((block_k, dv), jnp.float32)]),
         ),
         out_shape=[
             _struct((bh, tq, d), out_dtype, qt, kt, offs),
             _struct((bh, tk, d), out_dtype, qt, kt, offs),
-            _struct((bh, tk, d), out_dtype, qt, kt, offs),
+            _struct((bh, tk, dv), out_dtype, qt, kt, offs),
         ],
         cost_estimate=pl.CostEstimate(
-            flops=10 * scores * d,                    # 5 matmuls a score
-            bytes_accessed=4 * bh * (5 * tq * d + 4 * tk * d
+            flops=2 * scores * (3 * d + 2 * dv),      # 5 matmuls a score
+            bytes_accessed=4 * bh * (tq * (3 * d + 2 * dv)
+                                     + 2 * tk * (d + dv)
                                      + tq),           # ..., out; lse
             transcendentals=scores),
         # j and the innermost q dim both accumulate into revisited state;
@@ -1207,7 +1223,7 @@ def _flash_bwd(q, k, v, out, lse, dout, q_off=0, k_off=0, *, causal, scale):
     bh = b * h
 
     def heads_major(x):
-        return x.transpose(0, 2, 1, 3).reshape(bh, x.shape[1], d)
+        return x.transpose(0, 2, 1, 3).reshape(bh, x.shape[1], x.shape[3])
 
     qt, kt, vt, ot, dot = map(heads_major, (q, k, v, out, dout))
     lset = lse.reshape(bh, 1, tq)
@@ -1215,13 +1231,14 @@ def _flash_bwd(q, k, v, out, lse, dout, q_off=0, k_off=0, *, causal, scale):
                                causal=causal, scale=scale,
                                fusable=_relayout_fusable(b, h))
     return (_heads_minor(dq, b, h, tq, d), _heads_minor(dk, b, h, tk, d),
-            _heads_minor(dv, b, h, tk, d))
+            _heads_minor(dv, b, h, tk, v.shape[3]))
 
 
 def _flash_bwd_hm(qt, kt, vt, ot, dot, lset, q_off=0, k_off=0, *,
                   causal, scale, fusable, out_dtype=None, window=None):
     """Heads-major core of :func:`_flash_bwd`: operands/grads all
-    ``[BH, T, D]`` (lse ``[BH, 1, T]``) so a caller that already holds
+    ``[BH, T, D]`` (v, out, dout and dv ``[BH, T, DV]``; lse ``[BH, 1, T]``)
+    so a caller that already holds
     heads-major tensors (the full-attention VJP saves its residuals that
     way) pays no relayout. Returns (dq, dk, dv) heads-major f32."""
     bh, tq, d = qt.shape
@@ -1233,7 +1250,8 @@ def _flash_bwd_hm(qt, kt, vt, ot, dot, lset, q_off=0, k_off=0, *,
                       jnp.asarray(k_off, jnp.int32)])
     interpret = _interpret()
 
-    if flash_route(tq, tk, d, qt.dtype.itemsize)["backward"] == "fused":
+    if flash_route(tq, tk, d, qt.dtype.itemsize,
+                   dv=vt.shape[2])["backward"] == "fused":
         static = all(isinstance(x, (int, np.integer))
                      for x in (q_off, k_off))
         return _flash_bwd_fused(
@@ -1253,7 +1271,7 @@ def _flash_bwd_streaming(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
     ``flash_bwd_dkv``: one tile of each operand in VMEM, any length.
     Returns (dq, dk, dv) heads-major f32."""
     bh, tq, d = qt.shape
-    tk = kt.shape[1]
+    tk, dv = vt.shape[1:]
     nq, nk = tq // block_q, tk // block_k
     kspan, qspan, scores = nk, nq, bh * tq * tk
     if window is not None:
@@ -1273,17 +1291,17 @@ def _flash_bwd_streaming(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
                 _stat_spec(block_q, lambda i, j, n, offs: (i, j, 0)),
                 pl.BlockSpec((1, block_q, d), lambda i, j, n, offs: (i, j, 0)),
                 pl.BlockSpec((1, block_k, d), kmap),
-                pl.BlockSpec((1, block_k, d), kmap),
-                pl.BlockSpec((1, block_q, d), lambda i, j, n, offs: (i, j, 0)),
-                pl.BlockSpec((1, block_q, d), lambda i, j, n, offs: (i, j, 0)),
+                pl.BlockSpec((1, block_k, dv), kmap),
+                pl.BlockSpec((1, block_q, dv), lambda i, j, n, offs: (i, j, 0)),
+                pl.BlockSpec((1, block_q, dv), lambda i, j, n, offs: (i, j, 0)),
             ],
             out_specs=pl.BlockSpec((1, block_q, d),
                                    lambda i, j, n, offs: (i, j, 0)),
         ),
         out_shape=_struct((bh, tq, d), jnp.float32, qt, kt, offs),
         cost_estimate=pl.CostEstimate(
-            flops=6 * scores * d,
-            bytes_accessed=4 * bh * (4 * tq * d + 2 * tk * d + tq),
+            flops=2 * scores * (2 * d + dv),
+            bytes_accessed=4 * bh * ((2 * tq + tk) * (d + dv) + tq),
             transcendentals=scores),
         compiler_params=_sem_par2_arb(),
         interpret=interpret,
@@ -1300,22 +1318,23 @@ def _flash_bwd_streaming(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
                 _stat_spec(block_q, qmap),
                 pl.BlockSpec((1, block_q, d), qmap),
                 pl.BlockSpec((1, block_k, d), lambda i, j, n, offs: (i, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda i, j, n, offs: (i, j, 0)),
-                pl.BlockSpec((1, block_q, d), qmap),
-                pl.BlockSpec((1, block_q, d), qmap),
+                pl.BlockSpec((1, block_k, dv), lambda i, j, n, offs: (i, j, 0)),
+                pl.BlockSpec((1, block_q, dv), qmap),
+                pl.BlockSpec((1, block_q, dv), qmap),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_k, d), lambda i, j, n, offs: (i, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda i, j, n, offs: (i, j, 0)),
+                pl.BlockSpec((1, block_k, dv), lambda i, j, n, offs: (i, j, 0)),
             ],
         ),
         out_shape=[
             _struct((bh, tk, d), jnp.float32, qt, kt, offs),
-            _struct((bh, tk, d), jnp.float32, qt, kt, offs),
+            _struct((bh, tk, dv), jnp.float32, qt, kt, offs),
         ],
         cost_estimate=pl.CostEstimate(
-            flops=8 * scores * d,
-            bytes_accessed=4 * bh * (4 * tq * d + 3 * tk * d + tq),
+            flops=4 * scores * (d + dv),
+            bytes_accessed=4 * bh * (2 * tq * (d + dv)
+                                     + tk * (2 * d + dv) + tq),
             transcendentals=scores),
         compiler_params=_sem_par2_arb(),
         interpret=interpret,
@@ -1371,14 +1390,15 @@ def _flash_fullattn_vjp(causal: bool, scale: float,
 
     def fwd_hm(q, k, v):
         b, tq, h, d = q.shape
-        tk = k.shape[1]
+        tk, dv = k.shape[1], v.shape[3]
         bh = b * h
         qt = q.transpose(0, 2, 1, 3).reshape(bh, tq, d)
         kt = k.transpose(0, 2, 1, 3).reshape(bh, tk, d)
-        vt = v.transpose(0, 2, 1, 3).reshape(bh, tk, d)
+        vt = v.transpose(0, 2, 1, 3).reshape(bh, tk, dv)
         offs = jnp.zeros((2,), jnp.int32)
         block_q, block_k = flash_tiles(tq, tk, window)
-        if flash_route(tq, tk, d, kt.dtype.itemsize)["forward"] == "once":
+        if flash_route(tq, tk, d, kt.dtype.itemsize,
+                       dv=dv)["forward"] == "once":
             # resident shapes take the single-shot kernel: no ring-carry
             # streams, normalized-in-kernel output
             out_t, lse_t = _flash_fwd_once_call(
@@ -1388,7 +1408,7 @@ def _flash_fullattn_vjp(causal: bool, scale: float,
             return qt, kt, vt, out_t, lse_t
         mt = jnp.full((bh, 1, tq), NEG_INF, jnp.float32)
         lt = jnp.zeros((bh, 1, tq), jnp.float32)
-        ot = jnp.zeros((bh, tq, d), jnp.float32)
+        ot = jnp.zeros((bh, tq, dv), jnp.float32)
         mt, lt, ot = _flash_step_call(
             qt, kt, vt, mt, lt, ot, offs, causal=causal, scale=scale,
             block_q=block_q, block_k=block_k, interpret=_interpret(),
@@ -1401,12 +1421,12 @@ def _flash_fullattn_vjp(causal: bool, scale: float,
 
     @jax.custom_vjp
     def fa(q, k, v):
-        b, tq, h, d = q.shape
+        b, tq, h, _ = q.shape
         out_t = fwd_hm(q, k, v)[3]
-        return _heads_minor(out_t, b, h, tq, d)
+        return _heads_minor(out_t, b, h, tq, v.shape[3])
 
     def fwd(q, k, v):
-        b, tq, h, d = q.shape
+        b, tq, h, _ = q.shape
         qt, kt, vt, out_t, lse_t = fwd_hm(q, k, v)
         # The kernel's two outputs carry names so that a recomputation
         # policy can keep them (models.transformer.REMAT_POLICIES): they are
@@ -1415,21 +1435,21 @@ def _flash_fullattn_vjp(causal: bool, scale: float,
         # the kernels write and read them, [BH, 1, T] rows: kept dense.
         out_t = checkpoint_name(out_t, "flash_out")
         lse_t = checkpoint_name(lse_t, "flash_lse")
-        return (_heads_minor(out_t, b, h, tq, d),
+        return (_heads_minor(out_t, b, h, tq, v.shape[3]),
                 (qt, kt, vt, out_t, lse_t))
 
     def bwd(res, dout):
         qt, kt, vt, out_t, lse_t = res
-        b, tq, h, d = dout.shape
-        tk = kt.shape[1]
-        dot = dout.transpose(0, 2, 1, 3).reshape(b * h, tq, d)
+        b, tq, h, v_width = dout.shape
+        tk, d = kt.shape[1:]
+        dot = dout.transpose(0, 2, 1, 3).reshape(b * h, tq, v_width)
         dq, dk, dv = _flash_bwd_hm(qt, kt, vt, out_t, dot, lse_t,
                                    causal=causal, scale=scale,
                                    fusable=_relayout_fusable(b, h),
                                    out_dtype=qt.dtype, window=window)
         return (_heads_minor(dq, b, h, tq, d).astype(qt.dtype),
                 _heads_minor(dk, b, h, tk, d).astype(kt.dtype),
-                _heads_minor(dv, b, h, tk, d).astype(vt.dtype))
+                _heads_minor(dv, b, h, tk, v_width).astype(vt.dtype))
 
     fa.defvjp(fwd, bwd)
     return fa
@@ -1444,6 +1464,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     with the Pallas FlashAttention-2 backward when shapes allow. Plain jnp
     attention when ``kernel_path("flash_attention", q, k, v)`` says
     ``"reference"`` (kernels off, or shapes not tile-aligned).
+
+    ``v`` may be ``[B, TK, H, DV]`` with a width of its own (latent
+    attention: keys 192 wide, values 128): the output and ``dv`` are that
+    wide, ``dq`` and ``dk`` as wide as ``q``; the default scale is still
+    ``D ** -0.5``. With ``DV == D`` the kernels are the ones a call of one
+    width traces.
 
     ``window=W`` on a causal call of as many keys as queries keeps, for the
     query at ``i``, the keys ``i - W < j <= i``: its last ``W`` positions,
@@ -2618,7 +2644,7 @@ def ssd_scan(x, dt, A, B, C, D):
 # dispatcher name -> shape gate over the dispatcher's operands; the only
 # reader is kernel_path above (which adds the mode and vma conditions)
 _GATES = {
-    "flash_attention": lambda q, k, v: step_supported(q, k),
+    "flash_attention": step_supported,
     "adasum_combine": lambda a, b: adasum_supported(
         int(np.prod(a.shape[1:])) if a.ndim > 1 else 1),
     "int8_quantize": lambda x2: int8_supported(*x2.shape),

@@ -4,7 +4,10 @@ out): pair ``i`` of a head is elements ``i`` and ``i + D/2``, turned by the
 angle ``position * theta ** (-2 i / D)``. A head may turn only its first
 ``rotary_dim`` elements (pair ``i`` is then ``i`` and ``i + rotary_dim/2``;
 the rest pass through), by frequencies that are given and not derived
-(:func:`yarn_inv_freq`), with cos and sin scaled by a factor."""
+(:func:`yarn_inv_freq`), with cos and sin scaled by a factor. The other
+layout of the same turn pairs neighbouring elements (pair ``i`` is ``2 i``
+and ``2 i + 1``, the paper's own and DeepSeek-V3's ``rope_interleave``):
+``interleaved=True``."""
 
 from __future__ import annotations
 
@@ -44,13 +47,14 @@ def yarn_inv_freq(theta: float, rotary_dim: int, factor: float,
 def apply_rope(x, theta: float, positions=None, *,
                rotary_dim: Optional[int] = None,
                inv_freq: Optional[Sequence[float]] = None,
-               factor: float = 1.0):
+               factor: float = 1.0, interleaved: bool = False):
     """``x`` ``[B, T, H, D]`` (D even) turned by its positions (``arange(T)``
     unless given, ``[T]``). Angles, sines and the rotation are float32; the
     result is in ``x.dtype``. ``rotary_dim`` (even, at most D; D unless
     given) elements of a head turn and the rest pass through; ``inv_freq``
     (``rotary_dim / 2`` of them) takes the place of ``theta``'s; ``factor``
-    multiplies cos and sin."""
+    multiplies cos and sin. ``interleaved``: pair ``i`` is elements ``2 i``
+    and ``2 i + 1``, turned in place, and not ``i`` and ``i + width / 2``."""
     width = x.shape[-1] if rotary_dim is None else rotary_dim
     half = width // 2
     if positions is None:
@@ -67,8 +71,13 @@ def apply_rope(x, theta: float, positions=None, *,
     xf = x.astype(jnp.float32)
     turned, rest = (xf, None) if width == x.shape[-1] else (
         xf[..., :width], xf[..., width:])
-    x1, x2 = jnp.split(turned, 2, axis=-1)
-    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    if interleaved:
+        x1, x2 = turned[..., 0::2], turned[..., 1::2]
+        parts = [jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).reshape(turned.shape)]
+    else:
+        x1, x2 = jnp.split(turned, 2, axis=-1)
+        parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
     if rest is not None:
         parts.append(rest)
     return jnp.concatenate(parts, axis=-1).astype(x.dtype)

@@ -268,6 +268,11 @@ class ServingWorker:
                 return  # connection down; replay after reconnect
             with self._unsent_lock:
                 self._unsent.pop(rid, None)
+                # answered as far as this replica can tell: a send into a
+                # frontend that has just died succeeds and is lost, and the
+                # promoted standby's re-dispatch of the id must then run
+                # again, not be swallowed as a duplicate
+                self._seen.pop(rid, None)
 
     # ---------------------------------------------------------- heartbeat
     def _heartbeat_loop(self) -> None:

@@ -302,6 +302,23 @@ class TestWorkerHandback:
         assert "r1" in w._seen
         assert w.engine.scheduler.queue_depth() == 1
 
+    def test_a_result_lost_with_its_frontend_does_not_swallow_the_redispatch(
+            self, lm):
+        """The SIGKILL drill's hang (a request not done 300 s after the
+        standby's promotion): a result sent as the frontend dies is taken
+        by the kernel and lost; the promoted standby re-dispatches the id,
+        and the replica must run it again."""
+        w = self._worker(lm)
+        w._send = lambda msg_type, payload: True   # sent, never received
+        payload = wire.encode_serve_submit("r1", [1, 2], 2, None)
+        w._on_submit(payload)
+        w.engine.run_until_idle(timeout=30)
+        assert not w._unsent and "r1" not in w._seen
+        w._on_submit(payload)
+        assert w.engine.scheduler.queue_depth() == 1
+        w.engine.run_until_idle(timeout=30)
+        assert not w._unsent
+
     def test_draining_cleared_on_new_session(self, lm):
         """A drain is scoped to the frontend session that issued it: after
         reconnecting (e.g. to a promoted standby that knows nothing of the

@@ -116,6 +116,44 @@ def test_flash_route_edges_compile_for_v5e(shape, v5e_devices):
     lowered.compile()
 
 
+# positions of one bf16 causal call with keys 192 and values 128 wide
+# (latent attention: kanana2-train-s16384's is the last) -> the route and
+# the Pallas calls of forward + backward: a key width of a lane tile and a
+# half, and K, V, out and dq each at its own width, through Mosaic
+_TWO_WIDTH_EDGES = {
+    2048: ("once", "fused", 2),
+    4096: ("step_streaming", "fused", 2),
+    16384: ("step_streaming", "streaming", 3),
+}
+
+
+@pytest.mark.parametrize("t", sorted(_TWO_WIDTH_EDGES))
+def test_two_width_flash_routes_compile_for_v5e(t, v5e_devices):
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    forward, backward, calls = _TWO_WIDTH_EDGES[t]
+    route = pk.flash_route(t, t, 192, 2, dv=128)
+    assert (route["forward"], route["backward"]) == (forward, backward)
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
+            q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2))(
+                q, k, v)
+
+    mesh = Mesh(np.array(v5e_devices[:1]), ("hvd",))
+    qk = jax.ShapeDtypeStruct((1, t, 2, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, t, 2, 128), jnp.bfloat16)
+    lowered = lower_tpu(grads, *on_mesh([qk, qk, v], mesh))
+    tensors = dict(flash_call_tensors(lowered.as_text()))
+    assert len(tensors) == calls
+    # every operand and result at its own width: nothing padded to the other
+    widths = {name: sorted({dims[-1] for dims, dtype in shapes
+                            if len(dims) == 3 and dims[1] == t})
+              for name, shapes in tensors.items()}
+    assert all(w == [128, 192] for w in widths.values()), widths
+    lowered.compile()
+
+
 # name -> (batch, heads, positions): the attention calls of the four cells
 # (gpt2-medium's is both its cells'), and one ring hop at chip_smoke's shape
 _STATISTICS_CASES = {
@@ -754,3 +792,65 @@ def test_window_and_full_attention_sites_are_named_and_classed(remat,
     assert gates == set("01234")
     assert re.search(attn_gate_ms.PATTERN, "x/block_1/mixer/gate/dot_general")
     assert not re.search(attn_gate_ms.PATTERN, "x/block_1/mixer/gate_norm/mul")
+
+
+def test_latent_attention_sites_are_named_and_classed(v5e_devices):
+    """A model whose every layer is latent attention at the published head
+    widths (2 heads, keys 128 + 64, values 128, a latent of 64; 4096
+    positions, where K streams and dq still fits its scratch), recomputed
+    as the cell's: in the compiled step every site is a ``tpu_custom_call
+    %flash_step`` / ``%flash_bwd`` under its own ``block_<i>/mixer``, the
+    backward's under ``transpose(``, which is what the benchmark's op class
+    ``attention_kernel`` and its scope classes ``attn_fwd`` / ``attn_bwd``
+    read; the latent's products and the assembling of q and k carry the
+    scopes ``mla_latent_ms`` and ``mla_assemble_ms`` read."""
+    from jax.sharding import SingleDeviceSharding
+
+    from chipbench import op_scopes, trace_reduce
+    from chipbench.families import deepseek_v3
+    from chipbench.layer_metrics import mla_assemble_ms, mla_latent_ms
+    from tests.test_kanana import CONFIG
+
+    op_classes = trace_reduce.load_classes()
+    scope_classes = trace_reduce.load_classes(op_scopes.SCOPE_CLASSES)
+    config = {**CONFIG, "num_attention_heads": 2, "num_key_value_heads": 2,
+              "kv_lora_rank": 64, "qk_nope_head_dim": 128,
+              "qk_rope_head_dim": 64, "qk_head_dim": 192, "v_head_dim": 128}
+    seq, layers = 4096, config["num_hidden_layers"]
+    model = deepseek_v3.build_model(config, 512, {"remat": "full"})
+    one = SingleDeviceSharding(v5e_devices[0])
+    toks = jax.ShapeDtypeStruct((1, seq), jnp.int32, sharding=one)
+    params = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, seq), jnp.int32))["params"])
+    with jax.enable_x64(False):
+        lowered = jax.jit(jax.grad(lambda p, x: jnp.sum(
+            model.apply({"params": p}, x).astype(jnp.float32)))).trace(
+                params, toks).lower(lowering_platforms=("tpu",))
+    # one shape of call: one body a kernel, whatever the depth
+    assert sorted(re.findall(r'kernel_name = "(flash_\w+)"',
+                             lowered.as_text())) == ["flash_bwd",
+                                                     "flash_step"]
+    text = lowered.compile().as_text()
+    calls = _kernel_calls(text, r"flash_\w+?")
+    assert sorted(name for name, _, _ in calls) == \
+        ["flash_bwd"] * layers + ["flash_step"] * layers
+    for name, instruction, path in calls:
+        dispatcher, scope = {
+            "flash_step": ("_flash_step_call_streaming", "attn_fwd"),
+            "flash_bwd": ("_flash_bwd_fused", "attn_bwd")}[name]
+        assert re.search(rf"/block_(\d)/mixer/jit\({dispatcher}\)/{name}/",
+                         path), path
+        assert ("transpose(" in path) == (name == "flash_bwd"), path
+        assert trace_reduce.classify(path, scope_classes) == scope
+        assert trace_reduce.classify(
+            f"tpu_custom_call %{instruction}", op_classes) \
+            == "attention_kernel"
+    for reader, part in ((mla_latent_ms, "latent"),
+                         (mla_assemble_ms, "assemble")):
+        paths = re.findall(rf'op_name="([^"]*/mixer/{part}[/"][^"]*)"', text)
+        assert {re.search(r"block_(\d)", p).group(1) for p in paths} \
+            == set("012"), part
+        assert all(re.search(reader.PATTERN, p) for p in paths)
+        assert any("transpose(" in p for p in paths)
