@@ -372,6 +372,13 @@ def kernel_cases():
         # independent, so the reference takes two of them
         return dense(q[:, :, :2], k[:, :, :2], v[:, :, :2])
 
+    def dense_two_heads_exact(q, k, v):
+        # 192 ** -0.5 is no power of two: at the TPU's default precision the
+        # reference's own products round q * scale to bf16 and its dq leaves
+        # the kernel's by 2.0e-2 (head 64's 0.125 is exact: 5e-3)
+        with jax.default_matmul_precision("highest"):
+            return dense_two_heads(q, k, v)
+
     def flash_two_heads(q, k, v):
         out, (dq, dk, dv) = flash(q, k, v)
         return out[:, :, :2], (dq[:, :, :2], dk[:, :, :2], dv[:, :, :2])
@@ -393,6 +400,12 @@ def kernel_cases():
     # four k sweeps with the dq scratch, no input fusion at batch 1)
     qkv_large = [s((4, T, 20, D), jnp.bfloat16)] * 3
     qkv_4k = [s((1, 4096, 32, D), jnp.bfloat16)] * 3
+    # latent attention's widths (keys 192, values 128): at 8192 positions a
+    # head's f32 dq is 6 MiB, over what Mosaic's default VMEM limit holds,
+    # so the multi-sweep backward names its own (flash_route's
+    # backward_vmem), as kanana2-train-s16384's does at 16,384
+    qkv_two_widths = ([s((1, LONG_T, H, 192), jnp.bfloat16)] * 2
+                      + [s((1, LONG_T, H, 128), jnp.bfloat16)])
     rows = s((CHUNK_ROWS, BLOCK), jnp.float32)
     return {
         "flash_attention fwd+bwd 8x1024": KernelCase(
@@ -403,6 +416,9 @@ def kernel_cases():
             flash, qkv_large, 2, dense, TOL_BF16),
         "flash_attention fwd+bwd 1x32x4096": KernelCase(
             flash_two_heads, qkv_4k, 2, dense_two_heads, TOL_BF16),
+        "flash_attention fwd+bwd 1x8192 keys 192 values 128": KernelCase(
+            flash_two_heads, qkv_two_widths, 2, dense_two_heads_exact,
+            TOL_BF16),
         "flash_attention_step (ring hop)": KernelCase(
             lambda q, k, v, m, l, o: pk.flash_attention_step(
                 q, k, v, m, l, o, hop["q_off"], hop["k_off"],

@@ -61,14 +61,26 @@ _LN2 = 0.6931471805599453
 # must stay sequential ("arbitrary").
 
 
-def _cparams(*semantics, resident: bool = False):
+# What a kernel of this file may ask Mosaic for of a core's VMEM (128 MiB on
+# a v5e; the default scoped limit is 16 MiB): the rest is left to Mosaic's
+# own scratch and to what XLA keeps there around the call.
+_VMEM_LIMIT = 96 * 2 ** 20
+
+
+def _cparams(*semantics, resident: bool = False,
+             vmem_limit: Optional[int] = None):
     """CompilerParams with the given dimension semantics. RESIDENT-layout
-    kernels (whole k/v in VMEM, or one k sweep of the fused backward) get a
-    96 MiB VMEM limit: the default 16 MiB scoped limit leaves double-buffer
-    room unused. STREAMING kernels keep Mosaic's default."""
+    kernels (whole k/v in VMEM, or one k sweep of the fused backward) get
+    the whole of ``_VMEM_LIMIT``: the default 16 MiB scoped limit leaves
+    double-buffer room unused. STREAMING kernels keep Mosaic's default, but
+    for a call that names its own ``vmem_limit`` in bytes: the multi-sweep
+    fused backward of a head whose dq scratch does not fit the default
+    (:func:`flash_route` reckons it from the shape)."""
     kw = {"dimension_semantics": semantics}
     if resident:
-        kw["vmem_limit_bytes"] = 96 * 2 ** 20
+        vmem_limit = _VMEM_LIMIT
+    if vmem_limit is not None:
+        kw["vmem_limit_bytes"] = vmem_limit
     return pltpu.CompilerParams(**kw)
 
 
@@ -801,11 +813,44 @@ def _flash_step_call_resident(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
 # compiles within the 16 MB scoped-VMEM limit, 2 MB (seq 16384) does not —
 # longer k/v take the streaming forward.
 _KV_VMEM_CAP = 2 ** 20
-# dq-scratch budget for the ONE-pass fused backward: the whole [TQ, D] f32
-# dq accumulator lives in VMEM beside the f32 score/p/dp tiles (~2 MB each
-# at Q512/K1024) and the streamed operand tiles. 4 MB covers seq 16384 at
-# d=64 (or 8192 at d=128); a longer head takes the streaming pair.
-_DQ_SCRATCH_CAP = 4 * 2 ** 20
+# dq-scratch budgets for the ONE-pass fused backward, both in the bytes of a
+# head's [TQ, D] f32 dq, which the multi-sweep form holds whole in VMEM
+# beside the dk / dv accumulators, the pipeline's tiles and a strip's
+# temporaries. Up to ``_DQ_SCRATCH_DEFAULT`` (16,384 positions at d=64,
+# 8,192 at d=128) the call compiles within Mosaic's default 16 MiB and names
+# no limit. Up to ``_DQ_SCRATCH_CAP`` it asks Mosaic for what its shape
+# holds (:func:`_flash_bwd_vmem`: 35 MiB at 16,384 x 192, whose 12 MiB fill
+# 16 in VMEM's lanes): 32 MiB are at most 64 in lanes (d=64), half a v5e's
+# VMEM, and with the rest of the cell's working set, 15-20 MiB at
+# 512 x 1024 tiles, within ``_VMEM_LIMIT``. That covers 131,072 positions at
+# d=64, 65,536 at 128, 32,768 at 192; a longer head takes the streaming
+# pair.
+_DQ_SCRATCH_DEFAULT = 4 * 2 ** 20
+_DQ_SCRATCH_CAP = 32 * 2 ** 20
+
+
+def _flash_bwd_vmem(tq: int, d: int, dv: int, itemsize: int,
+                    block_q: int, block_k: int) -> int:
+    """Bytes of VMEM one grid cell of the multi-sweep fused backward holds,
+    to the next MiB: what :func:`flash_route` has the call ask Mosaic for
+    where the default limit is too little. Widths as VMEM lays them out,
+    the last dimension filled to 128 lanes (192 takes 256, 64 takes 128).
+    The dq scratch ``[TQ, D]`` f32; the dk / dv accumulators; every
+    operand's and every gradient's tile twice (the pipeline's two buffers),
+    gradients as f32, the widest a caller asks for (a ring hop's); one
+    strip's f32 ``s``, ``p``, ``dp``, ``ds`` at the whole
+    ``block_q x block_k`` and ``p``, ``ds`` again in the operands' dtype.
+    Mosaic's own report counts the scratch, the accumulators and the tiles
+    (22 MiB of the 35 at 16,384 x 192 / 128, PERF.md §6, PR 43)."""
+    wq, wv = (-(-w // 128) * 128 for w in (d, dv))
+    scratch = 4 * tq * wq
+    accumulators = 4 * block_k * (wq + wv)
+    tiles = 2 * (itemsize * (block_q * (wq + 2 * wv) + block_k * (wq + wv))
+                 + 4 * 8 * block_q                    # the LSE row
+                 + 4 * (block_q * wq + block_k * (wq + wv)))
+    strip = (4 * 4 + 2 * itemsize) * block_q * block_k
+    need = scratch + accumulators + tiles + strip
+    return -(-need // 2 ** 20) * 2 ** 20
 
 
 def flash_route(tq: int, tk: int, d: int, itemsize: int,
@@ -817,15 +862,24 @@ def flash_route(tq: int, tk: int, d: int, itemsize: int,
     resident cap by their own bytes, and the dq scratch is as wide as q.
     ``forward`` (the full-attention call) is ``once`` or ``step_streaming``,
     ``step`` (a ring hop, carrying m, l, o) ``step`` or ``step_streaming``,
-    ``backward`` ``fused`` or ``streaming`` (the dq / dkv pair). A
-    ``window`` changes none of the three (the resident kernels hold a
-    head's whole k/v and dq whatever the band; its tiles are
-    :func:`flash_tiles`'s). No JAX."""
+    ``backward`` ``fused`` or ``streaming`` (the dq / dkv pair), and
+    ``backward_vmem`` the VMEM limit in bytes that the multi-sweep fused
+    call names: ``None``, Mosaic's default, for a dq scratch up to
+    ``_DQ_SCRATCH_DEFAULT``, the call there has always been; over it what
+    the shape holds at its tiles. A ``window`` changes none of the routes
+    (the resident kernels hold a head's whole k/v and dq whatever the
+    band), only the tiles the reckoning counts, which are
+    :func:`flash_tiles`'s. No JAX."""
     kv_resident = tk * max(d, dv or d) * itemsize <= _KV_VMEM_CAP
+    scratch = tq * d * 4
+    backward = "fused" if scratch <= _DQ_SCRATCH_CAP else "streaming"
+    vmem = None
+    if _DQ_SCRATCH_DEFAULT < scratch <= _DQ_SCRATCH_CAP:
+        vmem = _flash_bwd_vmem(tq, d, dv or d, itemsize,
+                               *flash_tiles(tq, tk, window))
     return {"forward": "once" if kv_resident else "step_streaming",
             "step": "step" if kv_resident else "step_streaming",
-            "backward": ("fused" if tq * d * 4 <= _DQ_SCRATCH_CAP
-                         else "streaming")}
+            "backward": backward, "backward_vmem": vmem}
 
 
 def step_supported(q, k, v=None) -> bool:
@@ -1131,18 +1185,21 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=_FLASH_STATIC + (
-    "fusable", "out_dtype", "static_offs"))
+    "fusable", "out_dtype", "static_offs", "vmem_limit"))
 def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
                      block_q, block_k, interpret, fusable, out_dtype=None,
-                     static_offs=None, window=None):
-    """Dispatch of the one-pass backward (any length: k/v tiles stream
-    through the grid, dq rides the VMEM scratch). ``out_dtype`` picks the
-    gradient output dtype (default f32); the ring path keeps f32 so its
-    cross-hop accumulators never ingest pre-rounded contributions, while
-    the single-device VJP requests the input dtype directly.
-    ``static_offs`` is ``(q_off, k_off)`` where the caller knows them as
-    Python ints: the cost estimate then counts the call's own plan, and
-    the whole rectangle (an upper bound) where they are traced."""
+                     static_offs=None, window=None, vmem_limit=None):
+    """Dispatch of the one-pass backward (every head whose dq scratch is
+    within ``_DQ_SCRATCH_CAP``: k/v tiles stream through the grid, dq rides
+    the VMEM scratch). ``out_dtype`` picks the gradient output dtype
+    (default f32); the ring path keeps f32 so its cross-hop accumulators
+    never ingest pre-rounded contributions, while the single-device VJP
+    requests the input dtype directly. ``static_offs`` is ``(q_off,
+    k_off)`` where the caller knows them as Python ints: the cost estimate
+    then counts the call's own plan, and the whole rectangle (an upper
+    bound) where they are traced. ``vmem_limit`` is ``flash_route``'s
+    ``backward_vmem``: the bytes the multi-sweep call asks Mosaic for,
+    ``None`` its default."""
     out_dtype = jnp.float32 if out_dtype is None else out_dtype
     bh, tq, d = qt.shape
     tk, dv = vt.shape[1:]
@@ -1204,12 +1261,14 @@ def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
         # single-sweep (k resident per cell) gets the resident VMEM budget
         # and producer input fusion (the multi-sweep form measured -1.9%
         # with fusion at seq 8192 — streaming re-reads amplify any fused
-        # producer recompute, so it stays off there)
+        # producer recompute, so it stays off there); the multi-sweep form
+        # keeps Mosaic's default unless its scratch needs a limit named
         compiler_params=(
             _input_fusion(_cparams("parallel", "arbitrary", "arbitrary",
                                    resident=True), "sttttt", fusable)
             if tk // block_k == 1
-            else _cparams("parallel", "arbitrary", "arbitrary")),
+            else _cparams("parallel", "arbitrary", "arbitrary",
+                          vmem_limit=vmem_limit)),
         interpret=interpret,
     )(offs, lset, qt, kt, vt, ot, dot)
 
@@ -1250,15 +1309,17 @@ def _flash_bwd_hm(qt, kt, vt, ot, dot, lset, q_off=0, k_off=0, *,
                       jnp.asarray(k_off, jnp.int32)])
     interpret = _interpret()
 
-    if flash_route(tq, tk, d, qt.dtype.itemsize,
-                   dv=vt.shape[2])["backward"] == "fused":
+    route = flash_route(tq, tk, d, qt.dtype.itemsize, window,
+                        dv=vt.shape[2])
+    if route["backward"] == "fused":
         static = all(isinstance(x, (int, np.integer))
                      for x in (q_off, k_off))
         return _flash_bwd_fused(
             qt, kt, vt, ot, dot, lset, offs, causal=causal, scale=scale,
             block_q=block_q, block_k=block_k, interpret=interpret,
             fusable=fusable, out_dtype=out_dtype,
-            static_offs=(q_off, k_off) if static else None, window=window)
+            static_offs=(q_off, k_off) if static else None, window=window,
+            vmem_limit=route["backward_vmem"])
     return _flash_bwd_streaming(
         qt, kt, vt, ot, dot, lset, offs, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret, window=window)
@@ -1268,8 +1329,12 @@ def _flash_bwd_hm(qt, kt, vt, ot, dot, lset, q_off=0, k_off=0, *,
 def _flash_bwd_streaming(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
                          block_q, block_k, interpret, window=None):
     """Dispatch of the streaming pair, ``flash_bwd_dq`` and
-    ``flash_bwd_dkv``: one tile of each operand in VMEM, any length.
-    Returns (dq, dk, dv) heads-major f32."""
+    ``flash_bwd_dkv``: one tile of each operand in VMEM, so any length,
+    and the lengths it is left with are those whose dq scratch is over
+    ``_DQ_SCRATCH_CAP`` (past 131,072 positions at d=64, 65,536 at 128,
+    32,768 at 192; no cell's since PR 43). It rebuilds the scores twice, 7
+    products a tile pair where the fused kernel runs 5, over whole grid
+    tiles under the mask. Returns (dq, dk, dv) heads-major f32."""
     bh, tq, d = qt.shape
     tk, dv = vt.shape[1:]
     nq, nk = tq // block_q, tk // block_k

@@ -324,6 +324,54 @@ def test_the_two_width_kernels_are_reference_attention(route, causal,
                                    atol=2e-5, err_msg=f"{route} {name}")
 
 
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("against", ["reference", "pair"])
+def test_a_scratch_over_the_default_is_the_same_one_pass_backward(
+        against, causal, small_tiles):
+    """The cell's rule at a size the interpreter runs: a dq scratch over
+    ``_DQ_SCRATCH_DEFAULT`` and within ``_DQ_SCRATCH_CAP`` takes the
+    multi-sweep fused kernel (4 x 4 tiles here) with the VMEM limit the
+    route reckons for it, and its dq, dk and dv are the f32 reference's and
+    the streaming pair's, which a scratch over the cap takes."""
+    t, scratch = 256, 256 * 192 * 4
+    small_tiles.setattr(pk, "_KV_VMEM_CAP", 1)
+    small_tiles.setattr(pk, "_DQ_SCRATCH_DEFAULT", scratch - 1)
+    route = pk.flash_route(t, t, 192, 4, dv=128)
+    assert route["backward"] == "fused"
+    assert route["backward_vmem"] == pk._flash_bwd_vmem(
+        t, 192, 128, 4, 64, 64) > scratch
+    q, k, v, weight = _qkv(t)
+    scale = 192 ** -0.5
+
+    def grads(q, k, v):
+        return _with_grads(lambda q, k, v: pk.flash_attention(
+            q, k, v, causal=causal, scale=scale), q, k, v, weight)[1:]
+
+    _forget_traces()
+    params = _pallas_params(lambda *a: grads(*a), q, k, v)
+    assert set(params) == {"flash_step", "flash_bwd"}
+    bwd = params["flash_bwd"]
+    assert bwd["compiler_params"]["mosaic_tpu"].vmem_limit_bytes \
+        == route["backward_vmem"]
+    assert bwd["grid_mapping"].grid == (2, 4, 4)     # four key sweeps
+    got = grads(q, k, v)
+    if against == "reference":
+        want = _with_grads(lambda q, k, v: reference_attention(
+            q, k, v, causal=causal, scale=scale), q, k, v, weight)[1:]
+    else:
+        small_tiles.setattr(pk, "_DQ_SCRATCH_CAP", scratch - 1)
+        _forget_traces()
+        assert pk.flash_route(t, t, 192, 4, dv=128)["backward"] \
+            == "streaming"
+        taken = _spy_kernels(small_tiles)
+        want = grads(q, k, v)
+        assert taken == ["flash_step", "flash_bwd_dq", "flash_bwd_dkv"]
+    assert [a.shape[-1] for a in got] == [192, 192, 128]
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-5, err_msg=f"{against} {name}")
+
+
 def test_the_ring_hops_step_takes_a_value_width_too(small_tiles):
     """``flash_attention_step`` + ``finalize_attention_stats`` and the ring
     path's ``_flash_bwd`` at two widths, resident and streaming."""
@@ -361,6 +409,53 @@ def _pallas_params(fn, *args):
 
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
     return found
+
+
+# cell -> (batch, positions, heads, key width, value width, window) of its
+# flash calls, and the VMEM limit its backward call names, in MiB: 96 for a
+# single key sweep (the resident budget, as it has been), ``None`` (Mosaic's
+# default) for a multi-sweep call whose dq scratch is at most 4 MiB, and for
+# kanana2-train-s16384's 16,384 x 192 what the shape is reckoned to hold
+_CELL_BACKWARD_VMEM = {
+    "gpt2m-train-s1024_and_dp4": ((8, 1024, 16, 64, 64, None), 96),
+    "gpt2l-train-s1024": ((4, 1024, 20, 64, 64, None), 96),
+    "granite4hm-train-s4096": ((1, 4096, 32, 64, 64, None), None),
+    "lfm2moe-train-s8192": ((2, 8192, 32, 64, 64, None), None),
+    "nemotron3s-train-s4096": ((1, 4096, 32, 128, 128, None), None),
+    "lagunas-train-s8192_full": ((1, 8192, 48, 128, 128, None), None),
+    "lagunas-train-s8192_window": ((1, 8192, 72, 128, 128, 512), None),
+    "kanana2-train-s16384": ((1, 16384, 32, 192, 128, None), 35),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_BACKWARD_VMEM))
+def test_a_cells_backward_names_the_vmem_its_shape_needs(cell, monkeypatch):
+    """Every cell but one compiles the backward call it compiled before the
+    dq scratch's budget moved, with the VMEM limit it had; the one whose
+    scratch is over 4 MiB takes the same kernel and names its own, what
+    ``_flash_bwd_vmem`` reckons, under the chip's 128 MiB."""
+    monkeypatch.setenv("HVD_PALLAS", "interpret")
+    (b, t, h, d, dv, window), mib = _CELL_BACKWARD_VMEM[cell]
+    route = pk.flash_route(t, t, d, 2, window, dv=dv)
+    assert route["backward"] == "fused"
+    assert (route["backward_vmem"] is None) == (mib in (None, 96))
+    qk = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, t, h, dv), jnp.bfloat16)
+    _forget_traces()
+    calls = _pallas_params(jax.grad(
+        lambda q, k, v: jnp.sum(pk.flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32)),
+        argnums=(0, 1, 2)), qk, qk, v)
+    _forget_traces()
+    assert "flash_bwd" in calls and "flash_bwd_dq" not in calls
+    params = calls["flash_bwd"]["compiler_params"]["mosaic_tpu"]
+    assert params.vmem_limit_bytes == (mib and mib * 2 ** 20)
+    if route["backward_vmem"] is not None:
+        tiles = pk.flash_tiles(t, t, window)
+        # the scratch as VMEM lays it out (192 in 256 lanes), and no more
+        # than the chip has
+        assert 4 * t * 256 < params.vmem_limit_bytes == pk._flash_bwd_vmem(
+            t, d, dv, 2, *tiles) <= pk._VMEM_LIMIT < 128 * 2 ** 20
 
 
 def test_equal_widths_are_the_kernels_there_were(small_tiles):
@@ -416,10 +511,11 @@ def test_widths_the_kernels_do_not_take_go_to_the_reference(monkeypatch):
     assert pk.kernel_path("flash_attention", q[..., :160], k[..., :160],
                           v) == "reference"
     # K and V are each held to the resident cap by their own bytes, and the
-    # dq scratch is as wide as q: the cell's head streams both ways
+    # dq scratch is as wide as q: the cell's head streams forward, and its
+    # one-pass backward names the 35 MiB of VMEM its 16 MiB scratch needs
     assert pk.flash_route(16384, 16384, 192, 2, dv=128) == {
         "forward": "step_streaming", "step": "step_streaming",
-        "backward": "streaming"}
+        "backward": "fused", "backward_vmem": 35 * 2 ** 20}
     assert pk.flash_route(2048, 2048, 192, 2, dv=128)["forward"] == "once"
     assert pk.flash_route(4096, 4096, 128, 2, dv=192)["forward"] \
         == "step_streaming"
@@ -815,9 +911,13 @@ def test_the_family_counts_what_the_issue_counted():
     # no share of the experts' roofline while a run's routing is far from
     # the balanced rows that share divides by (the family's docstring)
     assert not hasattr(family, "moe_train_costs")
-    assert family.kernel_plan(config, 16384)["route"] == {
+    plan = family.kernel_plan(config, 16384)
+    assert plan["route"] == {
         "forward": "step_streaming", "step": "step_streaming",
-        "backward": "streaming"}
+        "backward": "fused", "backward_vmem": 35 * 2 ** 20}
+    # whole 512 x 1024 tiles forward, 512 x 512 strips in the fused backward
+    assert (plan["forward"], plan["backward"]) == pytest.approx(
+        (1.0625, 1.03125), rel=1e-3)
 
 
 def test_the_cell_is_sized_and_declared():

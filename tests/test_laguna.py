@@ -377,7 +377,8 @@ def test_the_cells_band_is_skipped_and_not_only_masked():
     # the route is the full layers': a head's keys are over the resident cap
     assert pk.flash_route(t, t, 128, 2, window) == pk.flash_route(
         t, t, 128, 2) == {"forward": "step_streaming",
-                          "step": "step_streaming", "backward": "fused"}
+                          "step": "step_streaming", "backward": "fused",
+                          "backward_vmem": None}
 
 
 # --------------------------------------------------------------- the rotary

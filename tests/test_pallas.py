@@ -433,6 +433,13 @@ def test_vmem_policy_and_input_fusion(monkeypatch):
                        resident=True).vmem_limit_bytes == 96 * 2 ** 20
     for streaming in (pk._sem_par2(), pk._sem_par_arb(), pk._sem_par2_arb()):
         assert streaming.vmem_limit_bytes is None
+    # but for the one call that names its own (``flash_route``'s
+    # ``backward_vmem``), and ``None`` there is the default again
+    assert pk._cparams("parallel", "arbitrary", "arbitrary",
+                       vmem_limit=35 * 2 ** 20).vmem_limit_bytes == 35 * 2 ** 20
+    assert pk._cparams("parallel", "arbitrary", "arbitrary",
+                       vmem_limit=None) == pk._cparams(
+                           "parallel", "arbitrary", "arbitrary")
 
     # input fusion: default on, disabled per-call by the env
     monkeypatch.delenv("HVD_PALLAS_INPUT_FUSION", raising=False)
@@ -529,31 +536,47 @@ def test_flash_kernel_is_traced_once_a_shape_not_once_a_call_site(
 
 # ------------------------------------------------ which kernels a shape takes
 # name -> (tq, tk, d, itemsize), then what ``flash_route`` must say: the
-# forward, the step (a ring hop) and the backward. The four cells' calls, and
-# both sides of each boundary: k/v of 1 MiB a head (``_KV_VMEM_CAP``), a dq
-# scratch of 4 MiB (``_DQ_SCRATCH_CAP``).
+# forward, the step (a ring hop), the backward and the MiB of VMEM the fused
+# backward names (``None``: Mosaic's default, the call there has always
+# been). The four cells' calls, and both sides of each boundary: k/v of
+# 1 MiB a head (``_KV_VMEM_CAP``), a dq scratch of 4 MiB
+# (``_DQ_SCRATCH_DEFAULT``: the last call with no limit of its own) and one
+# of 32 MiB (``_DQ_SCRATCH_CAP``: the last fused call).
 _ROUTE_CASES = {
     "cells_gpt2_1024x64_bf16": (
-        (1024, 1024, 64, 2), ("once", "step", "fused")),
+        (1024, 1024, 64, 2), ("once", "step", "fused", None)),
     "cell_granite_4096x64_bf16": (
-        (4096, 4096, 64, 2), ("once", "step", "fused")),
+        (4096, 4096, 64, 2), ("once", "step", "fused", None)),
     "kv_8192x64_bf16_the_last_resident": (
-        (1024, 8192, 64, 2), ("once", "step", "fused")),
+        (1024, 8192, 64, 2), ("once", "step", "fused", None)),
     "kv_16384x64_bf16_the_first_streamed": (
-        (1024, 16384, 64, 2), ("step_streaming", "step_streaming", "fused")),
+        (1024, 16384, 64, 2),
+        ("step_streaming", "step_streaming", "fused", None)),
     "kv_4096x64_f32_the_last_resident": (
-        (4096, 4096, 64, 4), ("once", "step", "fused")),
+        (4096, 4096, 64, 4), ("once", "step", "fused", None)),
     "kv_8192x64_f32_the_first_streamed": (
-        (8192, 8192, 64, 4), ("step_streaming", "step_streaming", "fused")),
-    "dq_16384x64_the_last_fused": (
-        (16384, 1024, 64, 2), ("once", "step", "fused")),
-    "dq_32768x64_the_first_streamed": (
-        (32768, 1024, 64, 2), ("once", "step", "streaming")),
-    "dq_8192x128_the_last_fused": (
-        (8192, 8192, 128, 2), ("step_streaming", "step_streaming", "fused")),
-    "dq_16384x128_the_first_streamed": (
+        (8192, 8192, 64, 4),
+        ("step_streaming", "step_streaming", "fused", None)),
+    "dq_16384x64_the_last_at_the_default": (
+        (16384, 1024, 64, 2), ("once", "step", "fused", None)),
+    "dq_32768x64_the_first_with_a_limit": (
+        (32768, 1024, 64, 2), ("once", "step", "fused", 32)),
+    "dq_131072x64_the_last_fused": (
+        (131072, 1024, 64, 2), ("once", "step", "fused", 80)),
+    "dq_262144x64_the_first_streamed": (
+        (262144, 1024, 64, 2), ("once", "step", "streaming", None)),
+    "dq_8192x128_the_last_at_the_default": (
+        (8192, 8192, 128, 2),
+        ("step_streaming", "step_streaming", "fused", None)),
+    "dq_16384x128_the_first_with_a_limit": (
         (16384, 16384, 128, 2),
-        ("step_streaming", "step_streaming", "streaming")),
+        ("step_streaming", "step_streaming", "fused", 24)),
+    "dq_65536x128_the_last_fused": (
+        (65536, 65536, 128, 2),
+        ("step_streaming", "step_streaming", "fused", 48)),
+    "dq_131072x128_the_first_streamed": (
+        (131072, 131072, 128, 2),
+        ("step_streaming", "step_streaming", "streaming", None)),
 }
 _ROUTE_KERNELS = {
     "once": ["_flash_fwd_once_kernel"],
@@ -570,9 +593,10 @@ def test_flash_route(name, monkeypatch):
     takes, and the dispatchers follow it: the shape is traced at its real
     size (nothing runs) through ``flash_attention``'s forward and backward
     and through ``flash_attention_step``, and a spy names the kernels."""
-    (tq, tk, d, itemsize), (forward, step, backward) = _ROUTE_CASES[name]
+    (tq, tk, d, itemsize), (forward, step, backward, mib) = _ROUTE_CASES[name]
     assert pk.flash_route(tq, tk, d, itemsize) == {
-        "forward": forward, "step": step, "backward": backward}
+        "forward": forward, "step": step, "backward": backward,
+        "backward_vmem": mib and mib * 2 ** 20}
 
     dtype = {2: jnp.bfloat16, 4: jnp.float32}[itemsize]
     q = jax.ShapeDtypeStruct((2, tq, 2, d), dtype)
@@ -596,7 +620,7 @@ def test_flash_route(name, monkeypatch):
 
 
 def test_only_flash_route_compares_a_shape_with_the_caps():
-    """Under ``horovod_tpu/`` the two budgets are read in one function."""
+    """Under ``horovod_tpu/`` the three budgets are read in one function."""
     import ast
     import inspect
 
@@ -604,7 +628,8 @@ def test_only_flash_route_compares_a_shape_with_the_caps():
     readers = {
         fn.name for fn in ast.walk(tree) if isinstance(fn, ast.FunctionDef)
         for node in ast.walk(fn) if isinstance(node, ast.Name)
-        and node.id in ("_KV_VMEM_CAP", "_DQ_SCRATCH_CAP")}
+        and node.id in ("_KV_VMEM_CAP", "_DQ_SCRATCH_DEFAULT",
+                        "_DQ_SCRATCH_CAP")}
     assert readers == {"flash_route"}
 
 

@@ -88,11 +88,19 @@ def test_kernel_compiles_for_v5e(name, v5e_devices):
 # TPU compiler can say that the chosen kernel fits (chip_smoke's 1x8192 case
 # is the longest single-shot forward).
 _ROUTE_EDGES = {
-    (16384, 64): ("step_streaming", "fused", 2),    # the largest dq scratch
-    (32768, 64): ("step_streaming", "streaming", 3),
+    # the largest dq scratch within Mosaic's default VMEM limit, and the
+    # first whose fused backward names a limit of its own
+    (16384, 64): ("step_streaming", "fused", 2),
+    (32768, 64): ("step_streaming", "fused", 2),
     (8192, 128): ("step_streaming", "fused", 2),
     # nemotron3s-train-s4096's: the widest head whose keys still stay resident
     (4096, 128): ("once", "fused", 2),
+    # both sides of ``_DQ_SCRATCH_CAP``: a 32 MiB scratch asks 48 MiB, and
+    # a 64 MiB one takes the streaming pair; at d=64 the last is 32 MiB too,
+    # 64 in VMEM's lanes, and asks 80
+    (65536, 128): ("step_streaming", "fused", 2),
+    (131072, 128): ("step_streaming", "streaming", 3),
+    (131072, 64): ("step_streaming", "fused", 2),
 }
 
 
@@ -117,13 +125,17 @@ def test_flash_route_edges_compile_for_v5e(shape, v5e_devices):
 
 
 # positions of one bf16 causal call with keys 192 and values 128 wide
-# (latent attention: kanana2-train-s16384's is the last) -> the route and
-# the Pallas calls of forward + backward: a key width of a lane tile and a
-# half, and K, V, out and dq each at its own width, through Mosaic
+# (latent attention: kanana2-train-s16384's is 16,384, whose 12 MiB dq
+# scratch, 16 in VMEM's 256 lanes, is the first to name its own limit) ->
+# the route and the Pallas calls of forward + backward: a key width of a lane
+# tile and a half, and K, V, out and dq each at its own width, through
+# Mosaic; 32,768 and 65,536 are the two sides of ``_DQ_SCRATCH_CAP``
 _TWO_WIDTH_EDGES = {
     2048: ("once", "fused", 2),
     4096: ("step_streaming", "fused", 2),
-    16384: ("step_streaming", "streaming", 3),
+    16384: ("step_streaming", "fused", 2),
+    32768: ("step_streaming", "fused", 2),
+    65536: ("step_streaming", "streaming", 3),
 }
 
 
