@@ -383,6 +383,16 @@ def kernel_cases():
         out, (dq, dk, dv) = flash(q, k, v)
         return out[:, :, :2], (dq[:, :, :2], dk[:, :, :2], dv[:, :, :2])
 
+    def flash_first_head(q, k, v):
+        out, grads = flash(q, k, v)
+        return out[:, :, :1], tuple(g[:, :, :1] for g in grads)
+
+    def dense_first_head_exact(q, k, v):
+        # at 16,384 positions one head's f32 scores are 1 GiB, and the
+        # reference holds several such tensors: one head of the two
+        with jax.default_matmul_precision("highest"):
+            return dense(q[:, :, :1], k[:, :, :1], v[:, :, :1])
+
     # q rows 1024..3071 against k rows 0..2047: part of the tile masked
     hop = dict(q_off=1024, k_off=0, causal=True, scale=D ** -0.5)
 
@@ -406,6 +416,11 @@ def kernel_cases():
     # backward_vmem), as kanana2-train-s16384's does at 16,384
     qkv_two_widths = ([s((1, LONG_T, H, 192), jnp.bfloat16)] * 2
                       + [s((1, LONG_T, H, 128), jnp.bfloat16)])
+    # kanana2-train-s16384's head: 24 MiB of K and V in VMEM for the
+    # single-shot forward (the longest in a cell; flash_route's
+    # _KV_VMEM_CAP), a 16 MiB dq scratch under the backward's own limit
+    qkv_16k = ([s((1, 16384, 2, 192), jnp.bfloat16)] * 2
+               + [s((1, 16384, 2, 128), jnp.bfloat16)])
     rows = s((CHUNK_ROWS, BLOCK), jnp.float32)
     return {
         "flash_attention fwd+bwd 8x1024": KernelCase(
@@ -419,6 +434,8 @@ def kernel_cases():
         "flash_attention fwd+bwd 1x8192 keys 192 values 128": KernelCase(
             flash_two_heads, qkv_two_widths, 2, dense_two_heads_exact,
             TOL_BF16),
+        "flash_attention fwd+bwd 1x16384 keys 192 values 128": KernelCase(
+            flash_first_head, qkv_16k, 2, dense_first_head_exact, TOL_BF16),
         "flash_attention_step (ring hop)": KernelCase(
             lambda q, k, v, m, l, o: pk.flash_attention_step(
                 q, k, v, m, l, o, hop["q_off"], hop["k_off"],

@@ -807,12 +807,22 @@ def _flash_step_call_resident(qt, kt, vt, mt, lt, ot, offs, *, causal, scale,
     )(offs, qt, kt, vt, mt, lt, ot)
 
 
-# Per-operand VMEM budget for the resident k/v block: the pipeline double-
-# buffers input blocks, so worst-case VMEM ≈ 2 (buffering) × 2 (k+v) × this
-# plus the q/o tiles. Measured on v5e: 1 MB/operand (seq 8192 at d=64 bf16)
-# compiles within the 16 MB scoped-VMEM limit, 2 MB (seq 16384) does not —
-# longer k/v take the streaming forward.
-_KV_VMEM_CAP = 2 ** 20
+# VMEM budget for a head's resident K and V, in the bytes VMEM holds of them:
+# both operands at its lane widths (:func:`_lanes`), twice for the
+# pipeline's two buffers (:func:`_kv_vmem`). The resident calls name
+# ``_VMEM_LIMIT`` (96 MiB), and 64 MiB of K and V leave 32 for the q and out
+# tiles twice, a block's f32 ``s`` and ``p`` and bf16 ``p`` (7 MiB at
+# 512 x 1024 tiles) and Mosaic's own scratch. That holds 65,536 positions at
+# d=64 and d=128 and 32,768 at keys 192 / values 128; a longer head takes
+# the streaming forward. Until PR 44 the cap was 1 MiB an operand (8,192
+# positions at d=64), measured against Mosaic's default 16 MiB, which the
+# resident calls had long stopped running under. Measured on a v5e
+# (PERF.md §6, PR 44): a layer's forward call at 16,384 x 192 / 128 and 32
+# heads takes 26.3-26.6 ms resident and 41.7 streaming, and the resident
+# one is 1.56-1.64 times quicker at the cap's last shapes too; Mosaic
+# refuses 96 MiB of K and V (``Scoped allocation with size 96.50M``: its
+# count is :func:`_kv_vmem` plus the tiles) and compiles 88.
+_KV_VMEM_CAP = 64 * 2 ** 20
 # dq-scratch budgets for the ONE-pass fused backward, both in the bytes of a
 # head's [TQ, D] f32 dq, which the multi-sweep form holds whole in VMEM
 # beside the dk / dv accumulators, the pipeline's tiles and a strip's
@@ -829,12 +839,25 @@ _DQ_SCRATCH_DEFAULT = 4 * 2 ** 20
 _DQ_SCRATCH_CAP = 32 * 2 ** 20
 
 
+def _lanes(width: int) -> int:
+    """A last dimension as VMEM lays it out, filled to whole tiles of 128
+    lanes: 64 takes 128, 192 takes 256."""
+    return -(-width // 128) * 128
+
+
+def _kv_vmem(tk: int, d: int, dv: int, itemsize: int) -> int:
+    """Bytes of VMEM a head's resident K ``[TK, D]`` and V ``[TK, DV]``
+    take, twice over for the pipeline's two buffers: what
+    :func:`flash_route` holds to ``_KV_VMEM_CAP``."""
+    return 2 * itemsize * tk * (_lanes(d) + _lanes(dv))
+
+
 def _flash_bwd_vmem(tq: int, d: int, dv: int, itemsize: int,
                     block_q: int, block_k: int) -> int:
     """Bytes of VMEM one grid cell of the multi-sweep fused backward holds,
     to the next MiB: what :func:`flash_route` has the call ask Mosaic for
     where the default limit is too little. Widths as VMEM lays them out,
-    the last dimension filled to 128 lanes (192 takes 256, 64 takes 128).
+    the last dimension filled to whole lane tiles (:func:`_lanes`).
     The dq scratch ``[TQ, D]`` f32; the dk / dv accumulators; every
     operand's and every gradient's tile twice (the pipeline's two buffers),
     gradients as f32, the widest a caller asks for (a ring hop's); one
@@ -842,7 +865,7 @@ def _flash_bwd_vmem(tq: int, d: int, dv: int, itemsize: int,
     ``block_q x block_k`` and ``p``, ``ds`` again in the operands' dtype.
     Mosaic's own report counts the scratch, the accumulators and the tiles
     (22 MiB of the 35 at 16,384 x 192 / 128, PERF.md §6, PR 43)."""
-    wq, wv = (-(-w // 128) * 128 for w in (d, dv))
+    wq, wv = _lanes(d), _lanes(dv)
     scratch = 4 * tq * wq
     accumulators = 4 * block_k * (wq + wv)
     tiles = 2 * (itemsize * (block_q * (wq + 2 * wv) + block_k * (wq + wv))
@@ -858,8 +881,9 @@ def flash_route(tq: int, tk: int, d: int, itemsize: int,
                 dv: Optional[int] = None) -> dict:
     """Which kernels one head of ``tq`` queries against ``tk`` keys of
     width ``d`` and values of width ``dv`` (``d`` unless given) takes; the
-    dispatchers and the tests both read it. K and V are each held to the
-    resident cap by their own bytes, and the dq scratch is as wide as q.
+    dispatchers and the tests both read it. K and V stay resident while
+    VMEM holds both (:func:`_kv_vmem` within ``_KV_VMEM_CAP``), each at its
+    own width, and the dq scratch is as wide as q.
     ``forward`` (the full-attention call) is ``once`` or ``step_streaming``,
     ``step`` (a ring hop, carrying m, l, o) ``step`` or ``step_streaming``,
     ``backward`` ``fused`` or ``streaming`` (the dq / dkv pair), and
@@ -870,7 +894,7 @@ def flash_route(tq: int, tk: int, d: int, itemsize: int,
     (the resident kernels hold a head's whole k/v and dq whatever the
     band), only the tiles the reckoning counts, which are
     :func:`flash_tiles`'s. No JAX."""
-    kv_resident = tk * max(d, dv or d) * itemsize <= _KV_VMEM_CAP
+    kv_resident = _kv_vmem(tk, d, dv or d, itemsize) <= _KV_VMEM_CAP
     scratch = tq * d * 4
     backward = "fused" if scratch <= _DQ_SCRATCH_CAP else "streaming"
     vmem = None

@@ -448,6 +448,12 @@ def test_a_cells_backward_names_the_vmem_its_shape_needs(cell, monkeypatch):
         argnums=(0, 1, 2)), qk, qk, v)
     _forget_traces()
     assert "flash_bwd" in calls and "flash_bwd_dq" not in calls
+    # and every cell's forward keeps a head's K and V in VMEM: the
+    # single-shot kernel under the resident calls' limit, no carried m, l, o
+    assert route["forward"] == "once"
+    assert "flash_fwd" in calls and "flash_step" not in calls
+    assert calls["flash_fwd"]["compiler_params"][
+        "mosaic_tpu"].vmem_limit_bytes == pk._VMEM_LIMIT > pk._KV_VMEM_CAP
     params = calls["flash_bwd"]["compiler_params"]["mosaic_tpu"]
     assert params.vmem_limit_bytes == (mib and mib * 2 ** 20)
     if route["backward_vmem"] is not None:
@@ -475,11 +481,11 @@ def test_equal_widths_are_the_kernels_there_were(small_tiles):
 
     bh, t, d = 2, 256, 128
     scores = {}
+    shipped = {name: getattr(pk, name)
+               for name in ("_KV_VMEM_CAP", "_DQ_SCRATCH_CAP")}
     for route, (caps, _) in sorted(ROUTES.items()):
-        for name in ("_KV_VMEM_CAP", "_DQ_SCRATCH_CAP"):
-            small_tiles.setattr(pk, name, caps.get(
-                name, {"_KV_VMEM_CAP": 2 ** 20,
-                       "_DQ_SCRATCH_CAP": 4 * 2 ** 20}[name]))
+        for name in shipped:
+            small_tiles.setattr(pk, name, caps.get(name, shipped[name]))
         _forget_traces()
         # a fresh function: make_jaxpr keeps the trace of one it has seen
         for name, params in _pallas_params(
@@ -510,15 +516,21 @@ def test_widths_the_kernels_do_not_take_go_to_the_reference(monkeypatch):
     assert pk.kernel_path("flash_attention", q, k, v[..., :96]) == "reference"
     assert pk.kernel_path("flash_attention", q[..., :160], k[..., :160],
                           v) == "reference"
-    # K and V are each held to the resident cap by their own bytes, and the
-    # dq scratch is as wide as q: the cell's head streams forward, and its
-    # one-pass backward names the 35 MiB of VMEM its 16 MiB scratch needs
+    # K and V are held to the resident cap together, each at its own width
+    # as VMEM lays it out (192 in 256 lanes), and the dq scratch is as wide
+    # as q: the cell's head keeps its 24 MiB of K and V in VMEM forward, and
+    # its one-pass backward names the 35 MiB its 16 MiB scratch needs
+    assert pk._kv_vmem(16384, 192, 128, 2) == 24 * 2 ** 20
     assert pk.flash_route(16384, 16384, 192, 2, dv=128) == {
-        "forward": "step_streaming", "step": "step_streaming",
+        "forward": "once", "step": "step",
         "backward": "fused", "backward_vmem": 35 * 2 ** 20}
-    assert pk.flash_route(2048, 2048, 192, 2, dv=128)["forward"] == "once"
-    assert pk.flash_route(4096, 4096, 128, 2, dv=192)["forward"] \
-        == "step_streaming"
+    # whichever of the two is the wider: 1.5 KiB a position, so 64 MiB are
+    # 42 key blocks of 1024 (and 32,768 the last power of two)
+    for d, dv in ((192, 128), (128, 192)):
+        assert pk.flash_route(42 * 1024, 42 * 1024, d, 2,
+                              dv=dv)["forward"] == "once"
+        assert pk.flash_route(43 * 1024, 43 * 1024, d, 2,
+                              dv=dv)["forward"] == "step_streaming"
     assert pk.flash_route(4096, 4096, 192, 2, dv=128)["backward"] == "fused"
     out = pk.flash_attention(q, k, v[..., :96], causal=True)
     assert out.shape == (1, 64, 2, 96)
@@ -913,7 +925,7 @@ def test_the_family_counts_what_the_issue_counted():
     assert not hasattr(family, "moe_train_costs")
     plan = family.kernel_plan(config, 16384)
     assert plan["route"] == {
-        "forward": "step_streaming", "step": "step_streaming",
+        "forward": "once", "step": "step",
         "backward": "fused", "backward_vmem": 35 * 2 ** 20}
     # whole 512 x 1024 tiles forward, 512 x 512 strips in the fused backward
     assert (plan["forward"], plan["backward"]) == pytest.approx(

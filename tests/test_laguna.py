@@ -374,11 +374,12 @@ def test_the_cells_band_is_skipped_and_not_only_masked():
     assert shares["sliding_attention"] == (
         forward["scores"] / needed, backward["scores"] / needed)
     assert max(shares["full_attention"]) < 1.15
-    # the route is the full layers': a head's keys are over the resident cap
+    # the route is the full layers': a head's 2 MiB of K and 2 of V stay in
+    # VMEM (8 MiB with the pipeline's second buffers, of the cap's 64)
+    assert pk._kv_vmem(t, 128, 128, 2) == 8 * 2 ** 20
     assert pk.flash_route(t, t, 128, 2, window) == pk.flash_route(
-        t, t, 128, 2) == {"forward": "step_streaming",
-                          "step": "step_streaming", "backward": "fused",
-                          "backward_vmem": None}
+        t, t, 128, 2) == {"forward": "once", "step": "step",
+                          "backward": "fused", "backward_vmem": None}
 
 
 # --------------------------------------------------------------- the rotary

@@ -276,6 +276,86 @@ def test_flash_streaming_forward_variant(causal, monkeypatch):
                                    rtol=2e-4, atol=2e-4)
 
 
+# name -> (key width, value width, causal, window): one head count, 256
+# positions over 4 x 4 tiles of 64
+_FORWARD_PAIR_CASES = {
+    "causal_64": (64, 64, True, None),
+    "full_64": (64, 64, False, None),
+    "causal_128": (128, 128, True, None),
+    "window_96_128": (128, 128, True, 96),
+    "window_64_64": (64, 64, True, 64),
+    "causal_keys_192_values_128": (192, 128, True, None),
+    "full_keys_192_values_128": (192, 128, False, None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_FORWARD_PAIR_CASES))
+def test_resident_and_streaming_forward_are_the_same_sums(name, monkeypatch):
+    """What ``_KV_VMEM_CAP`` decides is a layout, not a result: the
+    single-shot forward (K and V of a head in VMEM, m, l, o in registers,
+    normalised in the kernel) and the streaming one (a grid step a key
+    tile, m, l and an f32 o carried through the revisited output tiles, an
+    XLA epilogue) accumulate the same key blocks in the same order a q
+    tile, so ``out`` and the LSE the backward reads agree far inside the
+    tolerance each holds against plain attention, causal, windowed and at
+    two widths, and so do the gradients taken through either's residuals."""
+    d, dv, causal, window = _FORWARD_PAIR_CASES[name]
+    monkeypatch.setattr(pk, "_BLOCK_Q", 64)
+    monkeypatch.setattr(pk, "_BLOCK_K", 64)
+    monkeypatch.setattr(pk, "_SUB_TILE", 32)
+    _at_every_call_site(monkeypatch)     # eager: a kernel's results are seen
+    ks = jax.random.split(jax.random.PRNGKey(d + dv), 4)
+    b, t, h = 1, 256, 2
+    q, k = (jax.random.normal(kk, (b, t, h, d)) for kk in ks[:2])
+    v, w = (jax.random.normal(kk, (b, t, h, dv)) for kk in ks[2:])
+    scale = d ** -0.5
+    results = {}
+    real = pk._named_call
+
+    def spy(kernel_name, kernel, **kw):
+        call = real(kernel_name, kernel, **kw)
+
+        def recorded(*operands):
+            results[kernel_name] = call(*operands)
+            return results[kernel_name]
+
+        return recorded
+
+    monkeypatch.setattr(pk, "_named_call", spy)
+
+    def attend(q, k, v):
+        return pk.flash_attention(q, k, v, causal=causal, scale=scale,
+                                  window=window)
+
+    def run():
+        results.clear()
+        out = attend(q, k, v)
+        forward = dict(results)
+        grads = jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) * w),
+                         argnums=(0, 1, 2))(q, k, v)
+        return out, forward, grads
+
+    out_r, resident, grads_r = run()
+    monkeypatch.setattr(pk, "_KV_VMEM_CAP", 1)
+    out_s, streaming, grads_s = run()
+    assert list(resident) == ["flash_fwd"]
+    assert list(streaming) == ["flash_step"]
+    lse_r = resident["flash_fwd"][1]
+    lse_s = pk._masked_row_stats(*streaming["flash_step"][:2])[1]
+    assert lse_r.shape == lse_s.shape == (b * h, 1, t)
+    np.testing.assert_allclose(np.asarray(lse_r), np.asarray(lse_s),
+                               rtol=2e-6, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(out_r), np.asarray(out_s),
+                               rtol=2e-6, atol=2e-6)
+    for a, b_, nm in zip(grads_r, grads_s, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=2e-5,
+                                   atol=2e-5, err_msg=nm)
+    ref = reference_attention(q, k, v, causal=causal, scale=scale,
+                              window=window)
+    np.testing.assert_allclose(np.asarray(out_r), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
 def _spy_kernels(monkeypatch):
     """The kernel functions handed to ``pk._named_call`` from here on, by
     name: which dispatch a call took."""
@@ -538,8 +618,10 @@ def test_flash_kernel_is_traced_once_a_shape_not_once_a_call_site(
 # name -> (tq, tk, d, itemsize), then what ``flash_route`` must say: the
 # forward, the step (a ring hop), the backward and the MiB of VMEM the fused
 # backward names (``None``: Mosaic's default, the call there has always
-# been). The four cells' calls, and both sides of each boundary: k/v of
-# 1 MiB a head (``_KV_VMEM_CAP``), a dq scratch of 4 MiB
+# been). The cells' calls, and both sides of each boundary: 64 MiB of a
+# head's k + v as VMEM holds them, 128 lanes to a head of 64 and both of the
+# pipeline's buffers (``_KV_VMEM_CAP``; the two sides of the 1 MiB an operand
+# it was until PR 44 are both resident now), a dq scratch of 4 MiB
 # (``_DQ_SCRATCH_DEFAULT``: the last call with no limit of its own) and one
 # of 32 MiB (``_DQ_SCRATCH_CAP``: the last fused call).
 _ROUTE_CASES = {
@@ -547,15 +629,30 @@ _ROUTE_CASES = {
         (1024, 1024, 64, 2), ("once", "step", "fused", None)),
     "cell_granite_4096x64_bf16": (
         (4096, 4096, 64, 2), ("once", "step", "fused", None)),
-    "kv_8192x64_bf16_the_last_resident": (
+    "cell_laguna_8192x128_bf16": (
+        (8192, 8192, 128, 2), ("once", "step", "fused", None)),
+    "kv_8192x64_bf16_the_last_under_the_old_cap": (
         (1024, 8192, 64, 2), ("once", "step", "fused", None)),
-    "kv_16384x64_bf16_the_first_streamed": (
-        (1024, 16384, 64, 2),
+    "kv_16384x64_bf16_the_first_over_the_old_cap": (
+        (1024, 16384, 64, 2), ("once", "step", "fused", None)),
+    "kv_65536x64_bf16_the_last_resident": (
+        (1024, 65536, 64, 2), ("once", "step", "fused", None)),
+    "kv_131072x64_bf16_the_first_streamed": (
+        (1024, 131072, 64, 2),
         ("step_streaming", "step_streaming", "fused", None)),
-    "kv_4096x64_f32_the_last_resident": (
+    "kv_4096x64_f32_the_last_under_the_old_cap": (
         (4096, 4096, 64, 4), ("once", "step", "fused", None)),
-    "kv_8192x64_f32_the_first_streamed": (
-        (8192, 8192, 64, 4),
+    "kv_8192x64_f32_the_first_over_the_old_cap": (
+        (8192, 8192, 64, 4), ("once", "step", "fused", None)),
+    "kv_32768x64_f32_the_last_resident": (
+        (1024, 32768, 64, 4), ("once", "step", "fused", None)),
+    "kv_65536x64_f32_the_first_streamed": (
+        (1024, 65536, 64, 4),
+        ("step_streaming", "step_streaming", "fused", None)),
+    "kv_65536x128_bf16_the_last_resident": (
+        (1024, 65536, 128, 2), ("once", "step", "fused", None)),
+    "kv_131072x128_bf16_the_first_streamed": (
+        (1024, 131072, 128, 2),
         ("step_streaming", "step_streaming", "fused", None)),
     "dq_16384x64_the_last_at_the_default": (
         (16384, 1024, 64, 2), ("once", "step", "fused", None)),
@@ -566,14 +663,11 @@ _ROUTE_CASES = {
     "dq_262144x64_the_first_streamed": (
         (262144, 1024, 64, 2), ("once", "step", "streaming", None)),
     "dq_8192x128_the_last_at_the_default": (
-        (8192, 8192, 128, 2),
-        ("step_streaming", "step_streaming", "fused", None)),
+        (8192, 8192, 128, 2), ("once", "step", "fused", None)),
     "dq_16384x128_the_first_with_a_limit": (
-        (16384, 16384, 128, 2),
-        ("step_streaming", "step_streaming", "fused", 24)),
+        (16384, 16384, 128, 2), ("once", "step", "fused", 24)),
     "dq_65536x128_the_last_fused": (
-        (65536, 65536, 128, 2),
-        ("step_streaming", "step_streaming", "fused", 48)),
+        (65536, 65536, 128, 2), ("once", "step", "fused", 48)),
     "dq_131072x128_the_first_streamed": (
         (131072, 131072, 128, 2),
         ("step_streaming", "step_streaming", "streaming", None)),

@@ -85,23 +85,37 @@ def test_kernel_compiles_for_v5e(name, v5e_devices):
 # positions x head width of one bf16 causal call -> what
 # ``pallas_kernels.flash_route`` says of it and the Pallas calls of forward
 # + backward: the shapes on the far side of each boundary, where only the
-# TPU compiler can say that the chosen kernel fits (chip_smoke's 1x8192 case
-# is the longest single-shot forward).
+# TPU compiler can say that the chosen kernel fits.
 _ROUTE_EDGES = {
     # the largest dq scratch within Mosaic's default VMEM limit, and the
-    # first whose fused backward names a limit of its own
-    (16384, 64): ("step_streaming", "fused", 2),
-    (32768, 64): ("step_streaming", "fused", 2),
-    (8192, 128): ("step_streaming", "fused", 2),
-    # nemotron3s-train-s4096's: the widest head whose keys still stay resident
+    # first whose fused backward names a limit of its own; their K and V
+    # (4 and 8 MiB in VMEM, over the 1 MiB an operand that was the cap until
+    # PR 44) stay resident forward, as lagunas-train-s8192's (8192, 128) do
+    (16384, 64): ("once", "fused", 2),
+    (32768, 64): ("once", "fused", 2),
+    (8192, 128): ("once", "fused", 2),
+    # nemotron3s-train-s4096's
     (4096, 128): ("once", "fused", 2),
+    # both sides of ``_KV_VMEM_CAP``: 64 MiB of K + V in VMEM's lanes, both
+    # pipeline buffers, is the last single-shot forward at either width, and
+    # 128 MiB streams
+    (65536, 64): ("once", "fused", 2),
     # both sides of ``_DQ_SCRATCH_CAP``: a 32 MiB scratch asks 48 MiB, and
     # a 64 MiB one takes the streaming pair; at d=64 the last is 32 MiB too,
     # 64 in VMEM's lanes, and asks 80
-    (65536, 128): ("step_streaming", "fused", 2),
+    (65536, 128): ("once", "fused", 2),
     (131072, 128): ("step_streaming", "streaming", 3),
     (131072, 64): ("step_streaming", "fused", 2),
 }
+
+
+def _flash_grads(window=None):
+    """All three gradients of one causal flash call, forward inside."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    return jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
+        q, k, v, causal=True, window=window).astype(jnp.float32)),
+        argnums=(0, 1, 2))
 
 
 @pytest.mark.parametrize("shape", sorted(_ROUTE_EDGES))
@@ -112,14 +126,9 @@ def test_flash_route_edges_compile_for_v5e(shape, v5e_devices):
     route = pk.flash_route(t, t, d, 2)
     assert (route["forward"], route["backward"]) == (forward, backward)
 
-    def grads(q, k, v):
-        return jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
-            q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2))(
-                q, k, v)
-
     mesh = Mesh(np.array(v5e_devices[:1]), ("hvd",))
     x = jax.ShapeDtypeStruct((1, t, 2, d), jnp.bfloat16)
-    lowered = lower_tpu(grads, *on_mesh([x, x, x], mesh))
+    lowered = lower_tpu(_flash_grads(), *on_mesh([x, x, x], mesh))
     assert lowered.as_text().count("tpu_custom_call") == calls
     lowered.compile()
 
@@ -129,12 +138,13 @@ def test_flash_route_edges_compile_for_v5e(shape, v5e_devices):
 # scratch, 16 in VMEM's 256 lanes, is the first to name its own limit) ->
 # the route and the Pallas calls of forward + backward: a key width of a lane
 # tile and a half, and K, V, out and dq each at its own width, through
-# Mosaic; 32,768 and 65,536 are the two sides of ``_DQ_SCRATCH_CAP``
+# Mosaic; 32,768 and 65,536 are the two sides of ``_DQ_SCRATCH_CAP`` and of
+# ``_KV_VMEM_CAP`` (48 and 96 MiB of K + V in VMEM)
 _TWO_WIDTH_EDGES = {
     2048: ("once", "fused", 2),
-    4096: ("step_streaming", "fused", 2),
-    16384: ("step_streaming", "fused", 2),
-    32768: ("step_streaming", "fused", 2),
+    4096: ("once", "fused", 2),
+    16384: ("once", "fused", 2),
+    32768: ("once", "fused", 2),
     65536: ("step_streaming", "streaming", 3),
 }
 
@@ -147,15 +157,10 @@ def test_two_width_flash_routes_compile_for_v5e(t, v5e_devices):
     route = pk.flash_route(t, t, 192, 2, dv=128)
     assert (route["forward"], route["backward"]) == (forward, backward)
 
-    def grads(q, k, v):
-        return jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
-            q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2))(
-                q, k, v)
-
     mesh = Mesh(np.array(v5e_devices[:1]), ("hvd",))
     qk = jax.ShapeDtypeStruct((1, t, 2, 192), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((1, t, 2, 128), jnp.bfloat16)
-    lowered = lower_tpu(grads, *on_mesh([qk, qk, v], mesh))
+    lowered = lower_tpu(_flash_grads(), *on_mesh([qk, qk, v], mesh))
     tensors = dict(flash_call_tensors(lowered.as_text()))
     assert len(tensors) == calls
     # every operand and result at its own width: nothing padded to the other
@@ -163,6 +168,31 @@ def test_two_width_flash_routes_compile_for_v5e(t, v5e_devices):
                             if len(dims) == 3 and dims[1] == t})
               for name, shapes in tensors.items()}
     assert all(w == [128, 192] for w in widths.values()), widths
+    lowered.compile()
+
+
+@pytest.mark.parametrize("layer", [
+    "kanana2-train-s16384", "lagunas-train-s8192_full",
+    "lagunas-train-s8192_window"])
+def test_long_cells_layers_compile_resident_inside_the_gradient(layer,
+                                                                v5e_devices):
+    """Forward + all three gradients of one layer's flash call at the
+    cell's own head count (the two cells whose K and V are over 1 MiB an
+    operand), inside ``jax.grad`` (Mosaic counts a few MiB more there than
+    for the kernel alone, PERF.md §6, PR 43): the single-shot forward with
+    a head's K and V in VMEM and the one-pass backward, compiled for a v5e
+    (the limits the two calls name: ``tests/test_kanana.py``)."""
+    from horovod_tpu.ops import pallas_kernels as pk
+    from tests.test_kanana import _CELL_BACKWARD_VMEM
+
+    (b, t, h, d, dv, window), _ = _CELL_BACKWARD_VMEM[layer]
+    assert 4 * 2 ** 20 < pk._kv_vmem(t, d, dv, 2) <= pk._KV_VMEM_CAP
+    mesh = Mesh(np.array(v5e_devices[:1]), ("hvd",))
+    qk = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((b, t, h, dv), jnp.bfloat16)
+    lowered = lower_tpu(_flash_grads(window), *on_mesh([qk, qk, v], mesh))
+    assert sorted(re.findall(r'kernel_name = "(flash_\w+)"',
+                             lowered.as_text())) == ["flash_bwd", "flash_fwd"]
     lowered.compile()
 
 
@@ -806,23 +836,41 @@ def test_window_and_full_attention_sites_are_named_and_classed(remat,
     assert not re.search(attn_gate_ms.PATTERN, "x/block_1/mixer/gate_norm/mul")
 
 
-def test_latent_attention_sites_are_named_and_classed(v5e_devices):
+# the forward a head's K and V take -> its kernel and its dispatcher: the
+# cell's (resident since PR 44), and the streaming one a head past
+# ``_KV_VMEM_CAP`` takes, here by a cap of 1
+_LATENT_FORWARDS = {
+    "once": ("flash_fwd", "_flash_fwd_once_call"),
+    "step_streaming": ("flash_step", "_flash_step_call_streaming"),
+}
+
+
+@pytest.mark.parametrize("forward", sorted(_LATENT_FORWARDS))
+def test_latent_attention_sites_are_named_and_classed(forward, v5e_devices,
+                                                      monkeypatch):
     """A model whose every layer is latent attention at the published head
     widths (2 heads, keys 128 + 64, values 128, a latent of 64; 4096
-    positions, where K streams and dq still fits its scratch), recomputed
-    as the cell's: in the compiled step every site is a ``tpu_custom_call
-    %flash_step`` / ``%flash_bwd`` under its own ``block_<i>/mixer``, the
-    backward's under ``transpose(``, which is what the benchmark's op class
-    ``attention_kernel`` and its scope classes ``attn_fwd`` / ``attn_bwd``
-    read; the latent's products and the assembling of q and k carry the
-    scopes ``mla_latent_ms`` and ``mla_assemble_ms`` read."""
+    positions, where dq still fits its scratch), recomputed as the cell's:
+    in the compiled step every site is a ``tpu_custom_call %flash_fwd``
+    (``%flash_step`` where K and V stream) / ``%flash_bwd`` under its own
+    ``block_<i>/mixer``, the backward's under ``transpose(``, which is what
+    the benchmark's op class ``attention_kernel`` and its scope classes
+    ``attn_fwd`` / ``attn_bwd`` read; the latent's products and the
+    assembling of q and k carry the scopes ``mla_latent_ms`` and
+    ``mla_assemble_ms`` read."""
     from jax.sharding import SingleDeviceSharding
 
     from chipbench import op_scopes, trace_reduce
     from chipbench.families import deepseek_v3
     from chipbench.layer_metrics import mla_assemble_ms, mla_latent_ms
+    from horovod_tpu.ops import pallas_kernels as pk
     from tests.test_kanana import CONFIG
 
+    if forward == "step_streaming":
+        monkeypatch.setattr(pk, "_KV_VMEM_CAP", 1)
+    pk._flash_fullattn_vjp.cache_clear()
+    assert pk.flash_route(4096, 4096, 192, 2, dv=128)["forward"] == forward
+    fwd_kernel, fwd_dispatcher = _LATENT_FORWARDS[forward]
     op_classes = trace_reduce.load_classes()
     scope_classes = trace_reduce.load_classes(op_scopes.SCOPE_CLASSES)
     config = {**CONFIG, "num_attention_heads": 2, "num_key_value_heads": 2,
@@ -841,16 +889,17 @@ def test_latent_attention_sites_are_named_and_classed(v5e_devices):
             model.apply({"params": p}, x).astype(jnp.float32)))).trace(
                 params, toks).lower(lowering_platforms=("tpu",))
     # one shape of call: one body a kernel, whatever the depth
+    pk._flash_fullattn_vjp.cache_clear()
     assert sorted(re.findall(r'kernel_name = "(flash_\w+)"',
-                             lowered.as_text())) == ["flash_bwd",
-                                                     "flash_step"]
+                             lowered.as_text())) == sorted(
+                                 ["flash_bwd", fwd_kernel])
     text = lowered.compile().as_text()
     calls = _kernel_calls(text, r"flash_\w+?")
-    assert sorted(name for name, _, _ in calls) == \
-        ["flash_bwd"] * layers + ["flash_step"] * layers
+    assert sorted(name for name, _, _ in calls) == sorted(
+        ["flash_bwd"] * layers + [fwd_kernel] * layers)
     for name, instruction, path in calls:
         dispatcher, scope = {
-            "flash_step": ("_flash_step_call_streaming", "attn_fwd"),
+            fwd_kernel: (fwd_dispatcher, "attn_fwd"),
             "flash_bwd": ("_flash_bwd_fused", "attn_bwd")}[name]
         assert re.search(rf"/block_(\d)/mixer/jit\({dispatcher}\)/{name}/",
                          path), path
