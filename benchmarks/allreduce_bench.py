@@ -1,5 +1,12 @@
 #!/usr/bin/env python
-"""Allreduce bus-bandwidth microbench — SPMD data plane and eager engine.
+"""Allreduce drill — SPMD data plane, eager engine, wire modes, algorithms.
+
+A drill of features no benchmark cell runs yet, kept for what it checks
+inside one run: bytes on the wire per mode, the adaptive wire's 60% target,
+the straggler policy's step ratio, which algorithm each size settles on. Its
+timings on the CPU mesh are not measurements of this system (the benchmark
+is ``python3 -m chipbench.run``, docs/benchmarks.md); the cell that would
+time the quantized ring on ICI is ROADMAP W4, the eager engine's is W6.
 
 The reference's perf story is collective bandwidth (NCCL ring allreduce,
 `nccl_operations.cc:55-105`; timeline makes per-op cost visible). This
@@ -17,7 +24,7 @@ Reports, per message size: algorithm bandwidth (bytes/s of one rank's buffer)
 and bus bandwidth (algbw x 2(n-1)/n — the ring-transfer normalization NCCL
 uses, so numbers are comparable to `nccl-tests`).
 
-Run on a virtual pod:
+Run on the virtual mesh:
   XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
       python benchmarks/allreduce_bench.py
 
@@ -492,21 +499,12 @@ def main(argv=None):
     ap.add_argument("--straggler-deadline", default="3x",
                     help="HOROVOD_STRAGGLER_DEADLINE for the chaos phase "
                          "(default 3x = 3x the median arrival spread)")
-    ap.add_argument("--history", default=None,
-                    help="JSONL perf-history file (benchmarks/history.py); "
-                         "with --path compression the headline "
-                         "allreduce_compressed_algbw_gbps appends to it")
-    ap.add_argument("--check-regression", action="store_true",
-                    help="exit 3 when the headline metric regresses "
-                         "against --history")
-    ap.add_argument("--regression-window", type=int, default=None)
-    ap.add_argument("--regression-tolerance", type=float, default=None)
     ap.add_argument("--algo-sweep", action="store_true",
                     help="sweep the collective-algorithm zoo (ring/tree/"
                          "hier x off/int8/int4) on the compiled fast path; "
                          "one JSON row per cell plus the per-size tuned "
-                         "argmin; headline allreduce_algo_tuned_algbw_gbps "
-                         "feeds --history/--check-regression")
+                         "argmin and the headline "
+                         "allreduce_algo_tuned_algbw_gbps")
     args = ap.parse_args(argv)
     from horovod_tpu.utils import compile_cache
 
@@ -524,44 +522,17 @@ def main(argv=None):
         r = bench_straggler_chaos(args.chaos, args.iters, args.warmup,
                                   np_=args.np_, victim=args.chaos_victim,
                                   deadline=args.straggler_deadline)
-        result = {"metric": "straggler_chaos_step_ratio",
-                  "value": r["step_ratio"], "unit": "x",
-                  "config": {k: r[k] for k in ("chaos", "n", "victim",
-                                               "deadline")}}
-        print(json.dumps(result))
-        rc = 0
+        print(json.dumps({"metric": "straggler_chaos_step_ratio",
+                          "value": r["step_ratio"], "unit": "x",
+                          "config": {k: r[k] for k in ("chaos", "n",
+                                                       "victim",
+                                                       "deadline")}}))
         if r["step_ratio"] > args.chaos_budget:
             print(f"# REGRESSION: straggler_chaos_step_ratio = "
                   f"{r['step_ratio']} exceeds the --chaos-budget "
                   f"{args.chaos_budget} (survivors' step time did not "
                   f"track the median rank)", file=sys.stderr)
-            rc = 3
-        if args.history:
-            from benchmarks.history import (append_record, check_regression,
-                                            load_history)
-
-            # ratio: LOWER is better; compare before appending, same as
-            # the compression headline below
-            if args.check_regression:
-                verdict = check_regression(
-                    load_history(args.history, metric=result["metric"]),
-                    result["value"], direction="lower",
-                    **{k: v for k, v in (
-                        ("window", args.regression_window),
-                        ("tolerance", args.regression_tolerance))
-                       if v is not None})
-                print("# regression check: %s" % json.dumps(verdict),
-                      file=sys.stderr)
-                if verdict["regression"]:
-                    print(f"# REGRESSION: {result['metric']} = "
-                          f"{result['value']} rose above the ceiling "
-                          f"{verdict['floor']} (baseline "
-                          f"{verdict['baseline']} over "
-                          f"{verdict['samples']} runs)", file=sys.stderr)
-                    rc = 3
-            append_record(args.history, result)
-        if rc:
-            sys.exit(rc)
+            sys.exit(3)
         return [r]
 
     if args.bucket_mb is not None:
@@ -609,38 +580,11 @@ def main(argv=None):
                                   "value": round(ratio, 4), "size_mb": mb,
                                   "meets_60pct_target": ratio <= 0.6}))
         best = max(results, key=lambda r: r["effective_algbw_gbps"])
-        result = {"metric": "allreduce_compressed_algbw_gbps",
-                  "value": best["effective_algbw_gbps"],
-                  "unit": "GB/s",
-                  "config": {k: best[k] for k in ("mode", "size_mb", "n")}}
-        print(json.dumps(result))
-        rc = 0
-        if args.history:
-            from benchmarks.history import (append_record, check_regression,
-                                            load_history)
-
-            # compare against the trajectory BEFORE appending: today's run
-            # must not vote in its own baseline
-            if args.check_regression:
-                verdict = check_regression(
-                    load_history(args.history, metric=result["metric"]),
-                    result["value"],
-                    **{k: v for k, v in (
-                        ("window", args.regression_window),
-                        ("tolerance", args.regression_tolerance))
-                       if v is not None})
-                print("# regression check: %s" % json.dumps(verdict),
-                      file=sys.stderr)
-                if verdict["regression"]:
-                    print(f"# REGRESSION: {result['metric']} = "
-                          f"{result['value']} fell below the floor "
-                          f"{verdict['floor']} (baseline "
-                          f"{verdict['baseline']} over "
-                          f"{verdict['samples']} runs)", file=sys.stderr)
-                    rc = 3
-            append_record(args.history, result)
-        if rc:
-            sys.exit(rc)
+        print(json.dumps({"metric": "allreduce_compressed_algbw_gbps",
+                          "value": best["effective_algbw_gbps"],
+                          "unit": "GB/s",
+                          "config": {k: best[k]
+                                     for k in ("mode", "size_mb", "n")}}))
         return results
 
     if args.algo_sweep:
@@ -663,39 +607,12 @@ def main(argv=None):
                               "time_us": best["time_us"],
                               "algbw_gbps": best["algbw_gbps"]}))
         peak = max(tuned, key=lambda r: r["algbw_gbps"])
-        result = {"metric": "allreduce_algo_tuned_algbw_gbps",
-                  "value": peak["algbw_gbps"], "unit": "GB/s",
-                  "config": {k: peak[k] for k in ("algorithm", "mode",
-                                                  "size_mb", "n")}}
-        print(json.dumps(result))
-        rc = 0
-        if args.history:
-            from benchmarks.history import (append_record, check_regression,
-                                            load_history)
-
-            # compare against the trajectory BEFORE appending, same as the
-            # compression headline below
-            if args.check_regression:
-                verdict = check_regression(
-                    load_history(args.history, metric=result["metric"]),
-                    result["value"],
-                    **{k: v for k, v in (
-                        ("window", args.regression_window),
-                        ("tolerance", args.regression_tolerance))
-                       if v is not None})
-                print("# regression check: %s" % json.dumps(verdict),
-                      file=sys.stderr)
-                if verdict["regression"]:
-                    print(f"# REGRESSION: {result['metric']} = "
-                          f"{result['value']} fell below the floor "
-                          f"{verdict['floor']} (baseline "
-                          f"{verdict['baseline']} over "
-                          f"{verdict['samples']} runs)", file=sys.stderr)
-                    rc = 3
-            append_record(args.history, result)
+        print(json.dumps({"metric": "allreduce_algo_tuned_algbw_gbps",
+                          "value": peak["algbw_gbps"], "unit": "GB/s",
+                          "config": {k: peak[k]
+                                     for k in ("algorithm", "mode",
+                                               "size_mb", "n")}}))
         hvd.shutdown()
-        if rc:
-            sys.exit(rc)
         return results
 
     if args.path == "allgather":

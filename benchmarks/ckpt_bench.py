@@ -1,4 +1,10 @@
-"""Checkpoint subsystem benchmark: save-interval sweep + recovery breakdown.
+"""Checkpoint subsystem drill: save-interval sweep + recovery breakdown.
+
+A drill of a feature no benchmark cell runs yet (stall per save and time to
+resume at real state sizes are ROADMAP V10's cell, on the dp4 cell first),
+kept for the write-behind contract it checks inside one run. Its timings on
+the CPU are not measurements of this system (the benchmark is
+``python3 -m chipbench.run``, docs/benchmarks.md).
 
 Two questions an operator sizing ``HOROVOD_CKPT_INTERVAL`` actually asks
 (docs/checkpoint.md):
@@ -21,13 +27,10 @@ Two questions an operator sizing ``HOROVOD_CKPT_INTERVAL`` actually asks
 Usage::
 
     python benchmarks/ckpt_bench.py --shard-mb 4 --intervals 1,5,10
-    python benchmarks/ckpt_bench.py --history perf.jsonl --check-regression
 
-With ``--history`` the headline metrics append to the JSONL perf history
-(benchmarks/history.py): ``ckpt_commit_stall_ms`` (worst per-commit
-hand-off) and ``ckpt_peer_restore_ms`` (fetch + unpack), both gated
-``direction="lower"``; ``--check-regression`` exits 3 when either rises
-above its recorded trajectory.
+The headline lines are ``ckpt_commit_stall_ms`` (the write-behind stall a
+commit, gated by ``--stall-gate-ms``) and the recovery breakdown with its
+``peer_restore_ms`` (fetch + unpack).
 """
 
 from __future__ import annotations
@@ -171,13 +174,6 @@ def main(argv=None):
                          "averages above this per commit (the async "
                          "contract: the step path pays a buffer swap, "
                          "never disk I/O)")
-    ap.add_argument("--history", default=None,
-                    help="JSONL perf-history file (benchmarks/history.py)")
-    ap.add_argument("--check-regression", action="store_true",
-                    help="exit 3 when a headline metric regresses "
-                         "against --history")
-    ap.add_argument("--regression-window", type=int, default=None)
-    ap.add_argument("--regression-tolerance", type=float, default=None)
     args = ap.parse_args(argv)
 
     intervals = [int(i) for i in args.intervals.split(",")]
@@ -199,30 +195,6 @@ def main(argv=None):
                           "value_ms": stall_per_commit_ms,
                           "gate_ms": args.stall_gate_ms}))
         rc = 4
-
-    if args.history:
-        from benchmarks.history import (append_record, check_regression,
-                                        load_history)
-
-        kw = {}
-        if args.regression_window is not None:
-            kw["window"] = args.regression_window
-        if args.regression_tolerance is not None:
-            kw["tolerance"] = args.regression_tolerance
-        for metric, value in (
-                ("ckpt_commit_stall_ms", stall_per_commit_ms),
-                ("ckpt_peer_restore_ms", breakdown["peer_restore_ms"])):
-            if args.check_regression:
-                verdict = check_regression(
-                    load_history(args.history, metric), value,
-                    direction="lower", **kw)
-                print(json.dumps({"metric": metric, "verdict": verdict}))
-                if verdict["regression"]:
-                    rc = rc or 3
-            append_record(args.history, {
-                "metric": metric, "value": value,
-                "shard_mb": args.shard_mb,
-                "intervals": intervals, "commits": args.commits})
     return rc
 
 

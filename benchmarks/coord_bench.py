@@ -1,4 +1,10 @@
-"""Control-plane negotiation benchmark: flat vs hierarchical coordination.
+"""Control-plane negotiation drill: flat vs hierarchical coordination.
+
+A drill of a feature no benchmark cell runs yet (the eager plane's
+coordinator; its cell is ROADMAP W6), kept for the ratios it checks inside
+one run. It runs on the host and touches no device: its rounds/s are not
+measurements of this system (the benchmark is ``python3 -m chipbench.run``,
+docs/benchmarks.md).
 
 Drives the REAL ``CoordState`` barrier with simulated ranks and measures
 negotiation rounds per second and p99 round latency as the rank count
@@ -25,15 +31,9 @@ Usage::
     python benchmarks/coord_bench.py --ranks 64,256,1024 --mode both
     python benchmarks/coord_bench.py --mode tier \
         --ranks 1024,10240,102400 --p99-gate 5.0
-    python benchmarks/coord_bench.py --history perf.jsonl --check-regression
 
-With ``--history`` the headline metric (hier/tier rounds/s at the largest
-rank count) is appended to the JSONL perf history, plus one
-``coord_round_p99_ms`` row per (mode, ranks) sweep point gated with
-``direction="lower"``; ``--check-regression`` exits 3 when either the
-headline falls below — or any sweep point's p99 rises above — the
-recorded trajectory (benchmarks/history.py). Flat mode is capped at
-``--flat-cap`` simulated ranks (one OS thread per rank).
+The headline line is hier/tier rounds/s at the largest rank count. Flat
+mode is capped at ``--flat-cap`` simulated ranks (one OS thread per rank).
 """
 
 from __future__ import annotations
@@ -202,13 +202,6 @@ def main(argv=None):
                          "rank count exceeds this multiple of the "
                          "smallest point's p99 (the 100k-rank scaling "
                          "acceptance gate)")
-    ap.add_argument("--history", default=None,
-                    help="JSONL perf-history file (benchmarks/history.py)")
-    ap.add_argument("--check-regression", action="store_true",
-                    help="exit 3 when the headline metric regresses "
-                         "against --history")
-    ap.add_argument("--regression-window", type=int, default=None)
-    ap.add_argument("--regression-tolerance", type=float, default=None)
     args = ap.parse_args(argv)
 
     rank_counts = [int(r) for r in args.ranks.split(",")]
@@ -246,13 +239,12 @@ def main(argv=None):
     headline = next((r for r in results
                      if r["ranks"] == biggest and r["mode"] == best_mode),
                     results[-1])
-    result = {
+    print(json.dumps({
         "metric": "coord_%s_rounds_per_sec" % best_mode,
         "value": headline["rounds_per_sec"],
         "unit": "rounds/s",
         "ranks": headline["ranks"],
-    }
-    print(json.dumps(result))
+    }))
 
     rc = 0
     # the 100k scaling gate (ISSUE 15 acceptance): p99 round latency at
@@ -282,68 +274,6 @@ def main(argv=None):
                                         scale, args.p99_gate),
                       file=sys.stderr)
                 rc = 3
-    if args.history:
-        from benchmarks.history import (append_record, check_regression,
-                                        load_history)
-
-        # compare against the trajectory BEFORE appending: today's run
-        # must not be allowed to vote in its own baseline
-        if args.check_regression:
-            verdict = check_regression(
-                load_history(args.history, metric=result["metric"]),
-                result["value"],
-                **{k: v for k, v in (
-                    ("window", args.regression_window),
-                    ("tolerance", args.regression_tolerance))
-                   if v is not None})
-            print("# regression check: %s" % json.dumps(verdict),
-                  file=sys.stderr)
-            if verdict["regression"]:
-                print(f"# REGRESSION: {result['metric']} = "
-                      f"{result['value']} fell below the floor "
-                      f"{verdict['floor']} (baseline {verdict['baseline']} "
-                      f"over {verdict['samples']} runs)", file=sys.stderr)
-                rc = 3
-        # one p99 row per sweep point, gated direction="lower": a latency
-        # regression at ANY scale (not just the headline's throughput)
-        # fails CI. Trajectories are per (mode, ranks) — history rows for
-        # other sweep points must not vote in this point's baseline.
-        p99_history = load_history(args.history,
-                                   metric="coord_round_p99_ms")
-        for r in results:
-            if args.check_regression:
-                verdict = check_regression(
-                    [h for h in p99_history
-                     if h.get("ranks") == r["ranks"]
-                     and h.get("mode") == r["mode"]],
-                    r["p99_round_ms"], direction="lower",
-                    **{k: v for k, v in (
-                        ("window", args.regression_window),
-                        ("tolerance", args.regression_tolerance))
-                       if v is not None})
-                if verdict["regression"]:
-                    print("# REGRESSION: coord_round_p99_ms[%s,%d] = %s "
-                          "rose above the gate %s (baseline %s over %d "
-                          "runs)" % (r["mode"], r["ranks"],
-                                     r["p99_round_ms"], verdict["floor"],
-                                     verdict["baseline"],
-                                     verdict["samples"]), file=sys.stderr)
-                    rc = 3
-            append_record(args.history, {
-                "metric": "coord_round_p99_ms",
-                "value": r["p99_round_ms"], "unit": "ms",
-                "direction": "lower", "mode": r["mode"],
-                "ranks": r["ranks"],
-                "ranks_per_host": args.ranks_per_host,
-                "rounds": args.rounds,
-            })
-        append_record(args.history, {
-            "metric": result["metric"], "value": result["value"],
-            "unit": result["unit"], "ranks": result["ranks"],
-            "ranks_per_host": args.ranks_per_host,
-            "rounds": args.rounds,
-        })
-        print(f"# perf history appended to {args.history}", file=sys.stderr)
     return rc
 
 

@@ -1,5 +1,12 @@
 #!/usr/bin/env python
-"""Weak-scaling harness — the BASELINE headline metric, finally measured.
+"""Weak-scaling drill — the shape of the BASELINE headline metric.
+
+A drill of features no benchmark cell runs yet, kept for what it checks
+inside one run: the train step partitions over 1..n devices, and the
+``--three-way`` byte floors hold. Its timings on the CPU mesh are not
+measurements of this system (the benchmark is ``python3 -m chipbench.run``,
+docs/benchmarks.md); the cell that would measure the source's scaling
+efficiency on chips is ROADMAP W6, the quantized ring's is W4.
 
 The reference's headline claim is *scaling efficiency*: 90% on Inception
 V3/ResNet-101 at 512 GPUs (`README.rst:74-79`, `docs/benchmarks.rst:13-14`),
@@ -18,12 +25,6 @@ Run:
 
 Prints one JSON line per world size; final line is the summary
 {"metric": "weak_scaling_efficiency", ...} with efficiency at the largest n.
-
-With ``--history PATH`` the summary appends to the schema-versioned JSONL
-perf store (benchmarks/history.py); ``--check-regression`` compares the
-run against the recorded trajectory BEFORE appending and exits 3 below
-the tolerance floor — the same gate allreduce_bench/lm_bench/coord_bench
-carry.
 
 ``--three-way`` switches to the quantized-GSPMD head-to-head instead
 (docs/gspmd.md): the same linear-regression step on (a) the coordinator
@@ -280,20 +281,6 @@ def main(argv=None):
                          "(docs/gspmd.md)")
     ap.add_argument("--elements", type=int, default=262144,
                     help="gradient elements for --three-way (default 256k)")
-    ap.add_argument("--history", metavar="PATH", default=None,
-                    help="append the weak-scaling summary to a "
-                         "schema-versioned JSONL perf history "
-                         "(benchmarks/history.py)")
-    ap.add_argument("--check-regression", action="store_true",
-                    help="with --history: compare this run against the "
-                         "recorded trajectory BEFORE appending; exit 3 "
-                         "when it falls below the tolerance floor")
-    ap.add_argument("--regression-window", type=int, default=None,
-                    metavar="N", help="trailing records the baseline "
-                                      "median uses (default 5)")
-    ap.add_argument("--regression-tolerance", type=float, default=None,
-                    metavar="F", help="fraction below baseline that fails "
-                                      "(default 0.15)")
     args = ap.parse_args(argv)
     from horovod_tpu.utils import compile_cache
 
@@ -376,38 +363,6 @@ def main(argv=None):
                                  "backend": jax.default_backend(),
                                  "shared_core_virtual_devices":
                                      shared_cores}}))
-
-    if args.history:
-        from benchmarks.history import (append_record, check_regression,
-                                        load_history)
-
-        # compare against the trajectory BEFORE appending: today's run
-        # must not be allowed to vote in its own baseline
-        verdict = None
-        if args.check_regression:
-            verdict = check_regression(
-                load_history(args.history, metric="weak_scaling_efficiency"),
-                headline,
-                **{k: v for k, v in (
-                    ("window", args.regression_window),
-                    ("tolerance", args.regression_tolerance))
-                   if v is not None})
-            print("# regression check: %s" % json.dumps(verdict),
-                  file=sys.stderr)
-        append_record(args.history, {
-            "metric": "weak_scaling_efficiency",
-            "value": round(headline, 1), "unit": "%",
-            "model": args.model, "max_devices": n_max,
-            "batch_per_device": bpd, "backend": jax.default_backend(),
-            "shared_core_virtual_devices": shared_cores,
-        })
-        print(f"# perf history appended to {args.history}", file=sys.stderr)
-        if verdict and verdict["regression"]:
-            print(f"# REGRESSION: weak_scaling_efficiency = "
-                  f"{round(headline, 1)} fell below the floor "
-                  f"{verdict['floor']} (baseline {verdict['baseline']} "
-                  f"over {verdict['samples']} runs)", file=sys.stderr)
-            raise SystemExit(3)
     return rates
 
 

@@ -1,5 +1,11 @@
 #!/usr/bin/env python
-"""Serving-mode load benchmark: sustained QPS, p50/p99 latency, tokens/s.
+"""Serving-mode load drill: lost requests, shed classes, failover; QPS, p99.
+
+A drill of a feature no benchmark cell runs yet (the served path's cell,
+``gpt2m-serve-c8``, is ROADMAP V1 / W1), kept for what it checks inside one
+run: no request lost or delivered twice, the right class shed, the drain
+contract. Its rates and latencies on the CPU are not measurements of this
+system (the benchmark is ``python3 -m chipbench.run``, docs/benchmarks.md).
 
 A Poisson load generator over the inference serving subsystem
 (horovod_tpu/serving/, docs/inference.md). Requests arrive with
@@ -10,7 +16,7 @@ every completion and reports the sustained rate and the latency tail.
 Two modes:
 
 * **in-process** (default): one ``ServingEngine`` replica, submits go
-  straight to the engine. This is the deterministic perf-gate mode, and
+  straight to the engine. This is the deterministic mode, and
   the only one that runs on the chip: a chip belongs to one process, and
   here one process holds the engine.
 * **pod** (``--workers N``): spawns a ``ServingFrontend`` plus N worker
@@ -23,12 +29,6 @@ Two modes:
   control-plane drills: several replicas cannot share a chip, so their
   workers run on the CPU, and asking for them without ``JAX_PLATFORMS=cpu``
   in the environment is an error, not a silent move off the device.
-
-With ``--history PATH`` the run's p99 appends to the schema-versioned
-JSONL store (benchmarks/history.py); ``--check-regression`` compares
-against the trajectory BEFORE appending with ``direction="lower"``
-(latency: smaller is better) and exits 3 when the fresh p99 rises above
-the tolerance bound.
 
     JAX_PLATFORMS=cpu python benchmarks/serving_bench.py            # smoke
     python benchmarks/serving_bench.py --workers 2 --kill-one       # pod
@@ -74,16 +74,6 @@ def parse_args(argv=None):
     p.add_argument("--max-batch", type=int, default=8)
     p.add_argument("--blocks", type=int, default=256)
     p.add_argument("--block-size", type=int, default=16)
-    p.add_argument("--history", metavar="PATH", default=None,
-                   help="append this run's p99 to a schema-versioned JSONL "
-                        "perf history (benchmarks/history.py)")
-    p.add_argument("--check-regression", action="store_true",
-                   help="with --history: compare this run's p99 against "
-                        "the recorded trajectory BEFORE appending "
-                        "(direction=lower); exit 3 above the tolerance "
-                        "bound")
-    p.add_argument("--regression-window", type=int, default=None)
-    p.add_argument("--regression-tolerance", type=float, default=None)
     return p.parse_args(argv)
 
 
@@ -485,24 +475,6 @@ def chaos_overload(args):
             print("# FAIL: the burst never tripped the shed path",
                   file=sys.stderr)
             rc = 1
-        # the ratio gate rides the perf-history machinery so drift is
-        # caught across runs, not just against the in-run baseline
-        if args.history and rc == 0:
-            from benchmarks.history import (append_record,
-                                            check_regression, load_history)
-
-            metric = "serving_overload_high_p99_ratio"
-            if args.check_regression:
-                verdict = check_regression(
-                    load_history(args.history, metric=metric),
-                    ratio, direction="lower")
-                print("# regression check: %s" % json.dumps(verdict),
-                      file=sys.stderr)
-                if verdict["regression"]:
-                    rc = 3
-            append_record(args.history, {
-                "metric": metric, "value": round(ratio, 3), "unit": "x",
-                "shed": stats["shed"], "requests": args.requests})
         if rc == 0 and ratio > 1.5 and hi_p99 > 0.25:
             # absolute guard rail from the acceptance criterion (the
             # 0.25s floor keeps millisecond-scale noise from flaking CI)
@@ -639,7 +611,7 @@ def main(argv=None):
           f"p50: {p50 * 1e3:.1f}ms; p99: {p99 * 1e3:.1f}ms "
           f"(bucketed: {p99_bucketed * 1e3:.1f}ms); lost: {lost}",
           file=sys.stderr)
-    result = {
+    print(json.dumps({
         "metric": "serving_p99_seconds",
         "value": round(p99, 4),
         "unit": "s",
@@ -647,46 +619,13 @@ def main(argv=None):
         "tokens_per_sec": round(tok_s, 1),
         "p50_seconds": round(p50, 4),
         "lost": lost,
-    }
-    print(json.dumps(result))
+    }))
 
-    rc = 0
     if lost:
         print(f"# FAIL: {lost} request(s) lost — elastic re-admission must "
               "leave zero behind", file=sys.stderr)
-        rc = 4
-    if args.history:
-        from benchmarks.history import (append_record, check_regression,
-                                        load_history)
-
-        # compare against the trajectory BEFORE appending: today's run
-        # must not be allowed to vote in its own baseline
-        if args.check_regression:
-            verdict = check_regression(
-                load_history(args.history, metric=result["metric"]),
-                result["value"], direction="lower",
-                **{k: v for k, v in (
-                    ("window", args.regression_window),
-                    ("tolerance", args.regression_tolerance))
-                   if v is not None})
-            print("# regression check: %s" % json.dumps(verdict),
-                  file=sys.stderr)
-            if verdict["regression"]:
-                print(f"# REGRESSION: p99 {result['value']}s rose above "
-                      f"the bound {verdict['floor']}s (baseline "
-                      f"{verdict['baseline']}s over {verdict['samples']} "
-                      "runs)", file=sys.stderr)
-                rc = rc or 3
-        append_record(args.history, {
-            "metric": result["metric"], "value": result["value"],
-            "unit": result["unit"], "qps": result["qps"],
-            "tokens_per_sec": result["tokens_per_sec"],
-            "p50_seconds": result["p50_seconds"],
-            "workers": args.workers, "requests": args.requests,
-            "prompt_len": args.prompt_len, "max_new": args.max_new,
-        })
-        print(f"# perf history appended to {args.history}", file=sys.stderr)
-    return rc
+        return 4
+    return 0
 
 
 if __name__ == "__main__":

@@ -6,9 +6,9 @@
 One process — the only one that touches JAX, because a chip belongs to one
 process — drives the system's main paths once through the entry points a
 user calls, at the full width of the repo's ``medium`` LM
-(``benchmarks/lm_bench.py`` ``PRESETS["medium"]``: 24 layers, d_model 1024,
-16 heads, vocab 32768, seq 1024, batch 8 a chip, bf16 compute / f32
-params; weights random from a seed), and checks what comes out:
+(``full_sizes()``: 24 layers, d_model 1024, 16 heads, vocab 32768, seq
+1024, batch 8 a chip, bf16 compute / f32 params; weights random from a
+seed), and checks what comes out:
 
 * **A — trainer, one chip**: ``hvd.init`` → ``spmd.make_train_step`` →
   ``TransformerLM`` with its default (Pallas flash) attention, AdamW with
@@ -81,12 +81,8 @@ class Sizes:
 
 
 def full_sizes() -> Sizes:
-    from benchmarks.lm_bench import PRESETS
-
-    m = PRESETS["medium"]
-    return Sizes(vocab=m["vocab"], layers=m["num_layers"],
-                 heads=m["num_heads"], d_model=m["d_model"], seq=m["seq"],
-                 batch=m["batch"])
+    return Sizes(vocab=32768, layers=24, heads=16, d_model=1024, seq=1024,
+                 batch=8)
 
 
 #: Phase A: the first loss sits above ln(vocab) by about half the logit

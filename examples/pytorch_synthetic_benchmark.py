@@ -5,8 +5,8 @@ Reference parity: `examples/pytorch_synthetic_benchmark.py` — torchvision
 ResNet-50, DistributedOptimizer with per-parameter backward-hook
 allreduces, warmup + timed rounds, img/sec ± 1.96σ. torch runs on CPU in
 this build; the collectives execute on the device mesh through the shared
-engine — use this to benchmark the binding/engine overhead, and bench.py
-(SPMD path) for device throughput.
+engine — use this to see the binding/engine overhead; device throughput
+is the SPMD path's, measured by ``python3 -m chipbench.run``.
 
     hvdrun -np 2 python examples/pytorch_synthetic_benchmark.py \
         --model resnet18 --batch-size 8

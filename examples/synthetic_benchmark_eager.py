@@ -3,8 +3,9 @@
 
 Reference parity: `examples/pytorch_synthetic_benchmark.py` — per-gradient
 async allreduce through the background engine (DistributedOptimizer hook
-flow), 10 warmup + 10x10 timed iters, img/sec ± 1.96σ. Compare with bench.py
-(the SPMD whole-step path) to see what XLA static scheduling buys.
+flow), 10 warmup + 10x10 timed iters, img/sec ± 1.96σ. The SPMD
+whole-step path (`spmd.make_train_step`) is what the benchmark's cells
+run (`python3 -m chipbench.run`); this is the per-gradient flow beside it.
 
     hvdrun -np 1 python examples/synthetic_benchmark_eager.py
 """

@@ -5,8 +5,8 @@ Reference parity: `examples/tensorflow2_synthetic_benchmark.py` — synthetic
 ImageNet-shaped data, DistributedGradientTape around a compiled train step,
 warmup + timed rounds, img/sec ± 1.96σ. TF runs on the host in this build;
 the per-gradient collectives execute on the device mesh through the shared
-engine — use this to price the TF-binding/engine path, and `bench.py` (SPMD
-fast path) for peak device throughput.
+engine — use this to see the TF-binding/engine path; device throughput is
+the SPMD fast path's, measured by `python3 -m chipbench.run`.
 
     hvdrun -np 2 python examples/tensorflow2_synthetic_benchmark.py \
         --batch-size 4 --num-iters 3

@@ -5,7 +5,7 @@ faults — connection drops, stalls, partial writes, corrupted/truncated
 frames — injected at named points of the coordinator wire on either side.
 The harness exists so the hardening in `runtime/coordinator.py` (reconnect,
 replay, heartbeats, CRC frame checks) is provable from tests and
-``bench.py --chaos`` rather than only observable in production incidents.
+chaos drills rather than only observable in production incidents.
 
 Usage from instrumented code::
 

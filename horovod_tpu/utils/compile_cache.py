@@ -1,7 +1,7 @@
 """Where compiled programs are kept between processes.
 
 Compiling the GPT-2-medium train step takes most of a minute, and every
-entry point (``chip_smoke.py``, ``bench.py``, ``benchmarks/*.py``, the
+entry point (``chip_smoke.py``, ``chipbench``, ``benchmarks/*.py``, the
 serving worker) starts in a new process. JAX's persistent compilation cache
 keys an entry by the program *and* the cache directory, so a directory
 that moves (a temp name, a pid, a timestamp) never hits: the path is

@@ -4,11 +4,10 @@ Unit layer: registry semantics (counter/gauge/histogram, label children,
 kind collisions), snapshot/merge aggregation modes, Prometheus rendering
 and the strict parser, the MetricsReport wire codec, and the HTTP endpoint
 (ephemeral port, urllib scrape). API layer: ``hvd.metrics()`` against a
-live thread-cluster run, ``MetricsCallback``, ``bench.py --metrics-dump``
-arg parsing. Integration layer: a real 2-process job with
-``HOROVOD_METRICS_PORT`` set — rank 1 ships its snapshot over the control
-channel and rank 0's endpoint serves counts no single rank could have
-produced alone (the acceptance criterion).
+live thread-cluster run, ``MetricsCallback``. Integration layer: a real
+2-process job with ``HOROVOD_METRICS_PORT`` set — rank 1 ships its snapshot
+over the control channel and rank 0's endpoint serves counts no single rank
+could have produced alone (the acceptance criterion).
 """
 
 import json
@@ -323,18 +322,6 @@ class TestLiveAPI:
         cb.on_epoch_end(1, {})
         data = json.loads(path.read_text())
         assert data["epoch"] == 1 and isinstance(data["metrics"], dict)
-
-    def test_bench_metrics_dump_flag(self):
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        try:
-            import bench
-
-            args = bench.parse_args(["--metrics-dump", "/tmp/x.json"])
-            assert args.metrics_dump == "/tmp/x.json"
-            assert bench.parse_args([]).metrics_dump is None
-        finally:
-            sys.path.pop(0)
 
 
 # ----------------------------------------------------------- integration (2p)

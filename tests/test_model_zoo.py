@@ -1,87 +1,19 @@
-"""Model zoo structural tests — the reference's benchmark model families.
+"""Model zoo structural test — the reference's benchmark model family.
 
-Parity model: the reference benches ResNet / Inception V3 / VGG-16 via
-keras.applications / torchvision; here each flax implementation is checked
-for output shape, canonical parameter count (ImageNet config), and a
-gradient step at CPU-friendly sizes.
+Parity model: the reference benches ResNet via keras.applications /
+torchvision; here the flax implementation is checked for its canonical
+parameter count (ImageNet config).
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from horovod_tpu import models
 
 
 def _param_count(params):
     return sum(int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(params))
-
-
-def test_inception_v3_shapes_and_grad():
-    m = models.InceptionV3(num_classes=10, dtype=jnp.float32)
-    # 139 is the smallest size keeping every VALID-stride stage >= 1x1 with
-    # headroom; full ImageNet config uses 299
-    x = jnp.zeros((2, 139, 139, 3))
-    variables = m.init(jax.random.PRNGKey(0), x, train=False)
-    out = m.apply(variables, x, train=False)
-    assert out.shape == (2, 10)
-    assert out.dtype == jnp.float32
-
-    def loss(p):
-        logits, _ = m.apply(
-            {"params": p, "batch_stats": variables["batch_stats"]}, x,
-            train=True, mutable=["batch_stats"])
-        return (logits ** 2).mean()
-
-    g = jax.grad(loss)(variables["params"])
-    assert jnp.isfinite(
-        jax.tree_util.tree_leaves(g)[0].astype(jnp.float32)).all()
-
-
-def test_inception_v3_imagenet_param_count():
-    """Canonical Inception V3 (1000 classes) has ~23.9M parameters
-    (23,851,784 in keras.applications with the fc head)."""
-    m = models.InceptionV3(num_classes=1000, dtype=jnp.float32)
-    variables = jax.eval_shape(
-        lambda: m.init(jax.random.PRNGKey(0),
-                       jnp.zeros((1, 299, 299, 3)), train=False))
-    n = _param_count(variables["params"])
-    assert 23.0e6 < n < 24.5e6, n
-
-
-def test_vgg16_shapes_param_count_and_grad():
-    m = models.VGG16(num_classes=1000, dtype=jnp.float32)
-    variables = jax.eval_shape(
-        lambda: m.init(jax.random.PRNGKey(0),
-                       jnp.zeros((1, 224, 224, 3)), train=False))
-    # canonical VGG-16: 138,357,544 parameters
-    n = _param_count(variables["params"])
-    assert abs(n - 138_357_544) < 1e4, n
-
-    small = models.VGG16(num_classes=7, dtype=jnp.float32)
-    x = jnp.zeros((2, 32, 32, 3))
-    v = small.init(jax.random.PRNGKey(0), x, train=False)
-    out = small.apply(v, x, train=False)
-    assert out.shape == (2, 7)
-
-    def loss(p):
-        return (small.apply({"params": p}, x, train=True,
-                            rngs={"dropout": jax.random.PRNGKey(1)})
-                ** 2).mean()
-
-    g = jax.grad(loss)(v["params"])
-    assert jnp.isfinite(jax.tree_util.tree_leaves(g)[0]).all()
-
-
-def test_vgg19_config():
-    m = models.VGG19(num_classes=1000, dtype=jnp.float32)
-    variables = jax.eval_shape(
-        lambda: m.init(jax.random.PRNGKey(0),
-                       jnp.zeros((1, 224, 224, 3)), train=False))
-    # canonical VGG-19: 143,667,240 parameters
-    n = _param_count(variables["params"])
-    assert abs(n - 143_667_240) < 1e4, n
 
 
 def test_resnet50_imagenet_param_count():

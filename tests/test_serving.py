@@ -5,8 +5,7 @@ double-free detection, padded gather), the continuous-batching scheduler
 (FCFS admission control, strict-FIFO head-of-line semantics,
 iteration-level prefill/decode interleave), the SERVE_* wire codecs, the
 serving-latency anomaly-watch signals and the hvddoctor
-``latency_regression`` detector, and the ``direction="lower"`` perf-gate
-mode serving_bench relies on. Acceptance: batched decode through the
+``latency_regression`` detector. Acceptance: batched decode through the
 :class:`ServingEngine` is BIT-IDENTICAL to sequential decode of the same
 prompts (the fixed-shape + exact-masking invariant), and a real
 frontend + 2 worker-replica pod survives a SIGKILL mid-flight with the
@@ -460,32 +459,6 @@ class TestLatencyRegressionDetector:
 
     def test_clean_bundle_is_silent(self):
         assert sigs.detect_latency_regression(_anomaly_bundle([])) == []
-
-
-# ------------------------------------------------------ perf-gate direction
-class TestLowerIsBetterGate:
-    def test_direction_lower_flags_rises_only(self):
-        from benchmarks import history
-
-        hist = [{"value": v} for v in (0.10, 0.11, 0.09, 0.10)]
-        ok = history.check_regression(hist, 0.105, direction="lower",
-                                      tolerance=0.15)
-        assert ok["regression"] is False and ok["direction"] == "lower"
-        bad = history.check_regression(hist, 0.5, direction="lower",
-                                       tolerance=0.15)
-        assert bad["regression"] is True
-        assert bad["reason"] == "above_tolerance"
-        assert bad["floor"] == pytest.approx(bad["baseline"] * 1.15)
-        # a big IMPROVEMENT (drop) is never a regression in lower mode
-        good = history.check_regression(hist, 0.001, direction="lower")
-        assert good["regression"] is False
-
-    def test_invalid_direction_rejected(self):
-        from benchmarks import history
-
-        with pytest.raises(ValueError, match="direction"):
-            history.check_regression([{"value": 1.0}], 1.0,
-                                     direction="sideways")
 
 
 # ------------------------------------------------------- pod integration
