@@ -1,10 +1,10 @@
 """Decoder-only LM whose blocks are described by data: a hybrid of sequence
-mixers (Mamba-2, attention, gated short convolution) and feed-forwards
-(SwiGLU, routed experts).
+mixers (Mamba-2, gated delta rule, attention, gated short convolution) and
+feed-forwards (SwiGLU, routed experts).
 
 ``TransformerLM`` is one recipe. Here a model is a tuple of per-layer mixer
-kinds (``"mamba"`` | ``"attention"`` | ``"latent_attention"`` | ``"short_conv"``
-| ``"none"``), a
+kinds (``"mamba"`` | ``"gated_delta"`` | ``"attention"`` |
+``"latent_attention"`` | ``"short_conv"`` | ``"none"``), a
 tuple of per-layer feed-forward kinds (``"swiglu"`` | ``"moe"`` |
 ``"none"``) and the widths of each; every block is
 
@@ -27,7 +27,7 @@ scale ``head_dim ** -0.5``, unless given).
   positions only (``window``, a band the flash kernels skip by) and turn
   by another rotary scheme (a base over the whole head against given
   frequencies over half of it, scaled), and every layer gates each head's
-  output (``gate``).
+  output (``gate``: one scalar a head, or one a head and channel).
 * The latent-attention mixer (MLA, DeepSeek-V2/V3's, without a query
   latent) projects its input to one narrow normed latent, from which every
   head's keys and values are made, and to one rotary key that all heads
@@ -37,11 +37,16 @@ scale ``head_dim ** -0.5``, unless given).
   or several; the gated short convolution (LFM2's
   ``conv`` layer) is ``W_out (C * conv(B * u))`` over ``[B, C, u] = W_in h``
   with the same depthwise causal conv.
+* The gated delta-rule mixer (Gated DeltaNet) is ``ops/gated_delta.py``:
+  a ``[K, V]`` state a value head that decays by one scalar a position and
+  is corrected towards each new key's value, fewer key heads than value
+  heads, the same conv before it and the gated norm after it in its other
+  order.
 * The routed feed-forward is ``ops/moe.py``: top-k routing over sigmoid or
   softmax scores that drops no token, told which experts it holds. Its experts are SwiGLU or
   squared-ReLU, read the block's input or a latent of it (projected down
   before them and up after their sum), and may stand beside a shared
-  expert that every token passes.
+  expert that every token passes, whole or under a sigmoid gate a token.
 
 bf16 compute and f32 parameters, ``remat=`` with ``TransformerLM``'s three
 names and policies, and the module names the trace's scope classes read
@@ -53,13 +58,14 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from ..ops import moe, ssd
+from ..ops.gated_delta import gated_delta_chunked
 from ..ops.pallas_kernels import flash_attention
 from ..ops.rope import apply_rope
 from .transformer import REMAT_POLICIES
@@ -73,12 +79,14 @@ def _dense(features: int, dtype, name: str) -> nn.Dense:
 class GatedRMSNorm(nn.Module):
     eps: float
     groups: int = 1
+    norm_first: bool = False
 
     @nn.compact
     def __call__(self, y, gate):
         scale = self.param("scale", nn.initializers.ones, (y.shape[-1],),
                            jnp.float32)
-        return ssd.gated_rms_norm(y, gate, scale, self.eps, self.groups)
+        return ssd.gated_rms_norm(y, gate, scale, self.eps, self.groups,
+                                  self.norm_first)
 
 
 def _conv_kernel_init(width: int):
@@ -94,15 +102,16 @@ def _conv_kernel_init(width: int):
 
 class CausalConv(nn.Module):
     """Depthwise causal convolution over time, with bias (which starts at
-    0; the kernel: :func:`_conv_kernel_init`)."""
+    0; the kernel: :func:`_conv_kernel_init`) unless ``use_bias`` is off."""
     width: int
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
         kernel = self.param("kernel", _conv_kernel_init(self.width),
                             (self.width, x.shape[-1]), jnp.float32)
         bias = self.param("bias", nn.initializers.zeros, (x.shape[-1],),
-                          jnp.float32)
+                          jnp.float32) if self.use_bias else None
         return ssd.causal_conv1d(x, kernel, bias)
 
 
@@ -146,6 +155,74 @@ class MambaMixer(nn.Module):
         y = GatedRMSNorm(self.eps, self.groups, name="gate_norm")(
             y.reshape(b, t, inner), z)
         return _dense(d_model, self.dtype, "out_proj")(y)
+
+
+class GatedDeltaMixer(nn.Module):
+    """Gated DeltaNet: one projection to ``[q | k | v | z]`` (``key_heads``
+    heads of ``key_dim`` for q and for k, ``value_heads`` of ``value_dim``
+    for v and for the gate ``z``) and one to ``[b | a]`` (a write strength
+    and a decay a value head); a causal conv (no bias) and SiLU over ``[q |
+    k | v]``; ``beta = sigmoid(b)``, ``g = -exp(A_log) * softplus(a +
+    dt_bias)`` in float32; q and k L2-normalised a head (``x * rsqrt(sum
+    x^2 + 1e-6)``), q times ``key_dim ** -0.5``, each key head handed to
+    its ``value_heads // key_heads`` consecutive value heads (all of that
+    under the scope ``prep``); the delta rule
+    (``ops/gated_delta.gated_delta_chunked``, scope ``delta_rule``); the
+    gated norm a head, norm first, under one ``[value_dim]`` weight; the
+    output projection. ``A_log`` and the conv's kernel start as
+    ``MambaMixer``'s; ``dt_bias`` where Mamba-2's authors start theirs, at
+    the inverse softplus of steps between 0.001 and 0.1 (here spaced evenly
+    in the logarithm over the heads, not drawn), so that ``g`` runs from
+    -0.001 to -0.1 x heads a position and the state remembers: at a bias
+    of 1 every head forgets within a position or two and the rule has
+    nothing to correct."""
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_width: int
+    eps: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, h):
+        b, t, d_model = h.shape
+        heads, rep = self.value_heads, self.value_heads // self.key_heads
+        keys, values = self.key_heads * self.key_dim, heads * self.value_dim
+        qkv, z = jnp.split(
+            _dense(2 * keys + 2 * values, self.dtype, "in_proj")(h),
+            [2 * keys + values], axis=-1)
+        write, decay = jnp.split(
+            _dense(2 * heads, self.dtype, "in_gates")(h), 2, axis=-1)
+
+        def per_head(name, init):
+            return self.param(name, lambda *_: init, (heads,))
+
+        a_log = per_head("A_log", jnp.log(jnp.arange(1, heads + 1,
+                                                     dtype=jnp.float32)))
+        steps = jnp.exp(jnp.linspace(jnp.log(1e-3), jnp.log(1e-1), heads))
+        dt_bias = per_head("dt_bias", steps + jnp.log(-jnp.expm1(-steps)))
+        conv = CausalConv(self.conv_width, use_bias=False, name="conv")
+        with jax.named_scope("prep"):
+            q, k, v = jnp.split(nn.silu(conv(qkv)), [keys, 2 * keys], axis=-1)
+            beta = nn.sigmoid(write.astype(jnp.float32))
+            g = -jnp.exp(a_log) * jax.nn.softplus(
+                decay.astype(jnp.float32) + dt_bias)
+
+            def unit(x, scale=1.0):    # L2-normalised a head, in float32
+                x = x.reshape(b, t, self.key_heads,
+                              self.key_dim).astype(jnp.float32)
+                x = x * (scale * jax.lax.rsqrt(
+                    jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6))
+                return jnp.repeat(x.astype(self.dtype), rep, axis=2)
+
+            q, k = unit(q, self.key_dim ** -0.5), unit(k)
+        o = gated_delta_chunked(q, k, v.reshape(b, t, heads, self.value_dim),
+                                g, beta)
+        y = GatedRMSNorm(self.eps, norm_first=True, name="gate_norm")(
+            o, z.reshape(o.shape))
+        return _dense(d_model, self.dtype, "out_proj")(
+            y.reshape(b, t, values))
 
 
 class GatedShortConv(nn.Module):
@@ -195,8 +272,9 @@ class AttentionMixer(nn.Module):
     cos and sin times ``rope_factor``. With ``window`` a query sees its
     last ``window`` positions only (``flash_attention``'s band, under the
     scope ``window``); with ``gate`` each head's output is multiplied by
-    the sigmoid of a projection of the mixer's input (``gate``: one scalar
-    a head and position) before ``o``."""
+    the sigmoid of a projection of the mixer's input (``gate``) before
+    ``o``: ``"head"`` (or ``True``) one scalar a head and position,
+    ``"channel"`` one a head, channel and position."""
     heads: int
     kv_heads: int
     head_dim: int
@@ -209,11 +287,14 @@ class AttentionMixer(nn.Module):
     rope_inv_freq: Optional[Tuple[float, ...]] = None
     rope_factor: float = 1.0
     window: Optional[int] = None
-    gate: bool = False
+    gate: Union[bool, str] = False      # | "head" (True) | "channel"
 
     @nn.compact
     def __call__(self, h):
         b, t, d_model = h.shape
+        if self.gate not in (False, True, "head", "channel"):
+            raise ValueError(f"gate={self.gate!r}; expected False, 'head' "
+                             f"(or True) or 'channel'")
 
         def project(name, heads):
             return _dense(heads * self.head_dim, self.dtype, name)(h).reshape(
@@ -243,7 +324,11 @@ class AttentionMixer(nn.Module):
             out = flash_attention(q, k, v, causal=True, scale=self.scale,
                                   window=self.window)
         out = out.astype(self.dtype)
-        if self.gate:
+        if self.gate == "channel":
+            out = out * nn.sigmoid(_dense(self.heads * self.head_dim,
+                                          self.dtype, "gate")(h)).reshape(
+                out.shape)
+        elif self.gate:
             out = out * nn.sigmoid(
                 _dense(self.heads, self.dtype, "gate")(h))[..., None]
         return _dense(d_model, self.dtype, "o")(
@@ -321,7 +406,9 @@ class RoutedFeedForward(nn.Module):
     weighted sum goes through ``latent_out`` back to the model's width; the
     router reads ``h`` all the same. With ``shared_width`` a shared expert
     of that width and the same kind (``shared_in``, ``shared_out``), which
-    every token passes, is added. A routed token's weights sum to
+    every token passes, is added, with ``shared_gate`` under the sigmoid of
+    one more projection of ``h`` (``shared_gate``: a scalar a token). A
+    routed token's weights sum to
     ``scale``, their sum taking ``norm_eps`` before it divides; the scores
     they are made of are ``scoring`` of the router's logits (``"sigmoid"``
     | ``"softmax"`` over all the experts). Sows the
@@ -338,6 +425,7 @@ class RoutedFeedForward(nn.Module):
     scale: float = 1.0
     norm_eps: float = moe.NORM_EPS
     scoring: str = "sigmoid"
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, h):
@@ -366,9 +454,13 @@ class RoutedFeedForward(nn.Module):
         if self.latent:
             y = _dense(d, self.dtype, "latent_out")(y)
         if self.shared_width:
-            y = y + _dense(d, self.dtype, "shared_out")(_activate(
+            shared = _dense(d, self.dtype, "shared_out")(_activate(
                 self.activation, _dense(sides * self.shared_width, self.dtype,
                                         "shared_in")(h)))
+            if self.shared_gate:
+                shared = shared * nn.sigmoid(
+                    _dense(1, self.dtype, "shared_gate")(h))
+            y = y + shared
         return y
 
 
@@ -406,7 +498,7 @@ class HybridLM(nn.Module):
     """Tokens ``[B, T]`` -> float32 logits ``[B, T, vocab_size]``."""
     vocab_size: int
     layer_kinds: Tuple[str, ...]        # "mamba" | "attention" | "short_conv"
-                                        # | "latent_attention"
+                                        # | "latent_attention" | "gated_delta"
                                         # | "none": a feed-forward block
                                         # | a name of ``attn_kinds``
     d_model: int
@@ -444,7 +536,8 @@ class HybridLM(nn.Module):
     moe_norm_eps: float = moe.NORM_EPS  # added to their sum before it divides
     tied_head: bool = True              # the head is the table
     moe_scoring: str = "sigmoid"        # | "softmax", over all the experts
-    attn_gate: bool = False             # a sigmoid gate on each head's output
+    # a sigmoid gate on each head's output: True / "head", or "channel"
+    attn_gate: Union[bool, str] = False
     # further attention kinds by name, for ``layer_kinds``: what of
     # ``AttentionMixer``'s fields differs from the "attention" kind's
     attn_kinds: Dict[str, Dict[str, Any]] = dataclasses.field(
@@ -456,6 +549,18 @@ class HybridLM(nn.Module):
     mla_rope_dim: int = 0               # that does not turn, and the one that
     mla_v_dim: int = 0                  # does; a head's values
     mla_rope_theta: float = 0.0         # the rotary base: to be stated
+    moe_shared_gate: bool = False       # a sigmoid gate on the shared expert
+    # the "gated_delta" layers' sizes (the conv is ``ssm_conv_width`` wide)
+    delta_key_heads: int = 0            # heads of q and k, each serving
+    delta_value_heads: int = 0          # value_heads / key_heads value heads
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    # hold the residual stream as it stands at every block's entry and after
+    # the last (``jax.lax.optimization_barrier``): XLA then fuses nothing
+    # across a block's boundary, and the bf16 stream is rounded where the
+    # blocks say, whatever else a program made from the model hands out
+    # (``capture_intermediates``) or keeps (``remat``)
+    pin_stream: bool = False
 
     @nn.compact
     def __call__(self, tokens):
@@ -477,6 +582,15 @@ class HybridLM(nn.Module):
                     f"mla_rope_dim, mla_v_dim and mla_rope_theta; got {mla}")
             if mla_scale is None:
                 mla_scale = (self.mla_nope_dim + self.mla_rope_dim) ** -0.5
+        if "gated_delta" in self.layer_kinds:
+            delta = (self.delta_key_heads, self.delta_value_heads,
+                     self.delta_key_dim, self.delta_value_dim)
+            if not all(x > 0 for x in delta) \
+                    or self.delta_value_heads % self.delta_key_heads:
+                raise ValueError(
+                    f"gated_delta layers need delta_key_heads, "
+                    f"delta_value_heads (a multiple of them), delta_key_dim "
+                    f"and delta_value_dim; got {delta}")
         mixers = {
             "mamba": partial(MambaMixer, self.ssm_heads, self.ssm_head_dim,
                              self.ssm_state, self.ssm_conv_width,
@@ -491,6 +605,10 @@ class HybridLM(nn.Module):
                 gate=self.attn_gate),
             "short_conv": partial(ShortConvMixer, self.conv_width,
                                   self.dtype),
+            "gated_delta": partial(
+                GatedDeltaMixer, self.delta_key_heads, self.delta_value_heads,
+                self.delta_key_dim, self.delta_value_dim, self.ssm_conv_width,
+                self.norm_eps, self.dtype),
             "latent_attention": partial(
                 LatentAttentionMixer, heads=self.attn_heads,
                 kv_rank=self.mla_kv_rank, nope_dim=self.mla_nope_dim,
@@ -534,7 +652,8 @@ class HybridLM(nn.Module):
                              self.moe_top_k, self.moe_width, self.dtype,
                              self.moe_activation, self.moe_latent,
                              self.moe_shared_width, self.moe_scale,
-                             self.moe_norm_eps, self.moe_scoring)
+                             self.moe_norm_eps, self.moe_scoring,
+                             self.moe_shared_gate)
         use_remat, policy = REMAT_POLICIES[self.remat]
         block_cls = nn.remat(HybridBlock, policy=policy) if use_remat \
             else HybridBlock
@@ -544,11 +663,14 @@ class HybridLM(nn.Module):
                        param_dtype=jnp.float32, dtype=self.dtype,
                        name="tok_emb")
         x = emb(tokens) * self.embedding_multiplier
+        pin = jax.lax.optimization_barrier if self.pin_stream else (
+            lambda x: x)
         for i, (kind, ffn) in enumerate(zip(self.layer_kinds, ffn_kinds)):
             x = block_cls(mixers[kind], ffn, self.ffn_width,
                           self.residual_multiplier, self.norm_eps, self.dtype,
                           routed if ffn == "moe" else None,
-                          name=f"block_{i}")(x)
+                          name=f"block_{i}")(pin(x))
+        x = pin(x)
         x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                        param_dtype=jnp.float32, name="norm_f")(x)
         # the head in the model's dtype as TransformerLM's: the table, or a

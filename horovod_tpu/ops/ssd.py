@@ -21,7 +21,9 @@ every head's state in VMEM, at its own tile edge, the groups of ``B`` and
 which path a call takes.
 
 ``causal_conv1d`` and ``gated_rms_norm`` are the depthwise convolution before
-the scan and the gated normalisation after it (``models/hybrid.py``).
+the scan and the gated normalisation after it (``models/hybrid.py``); the
+gated delta rule's mixer (``ops/gated_delta.py``) uses both, the norm in
+its other order.
 """
 
 from __future__ import annotations
@@ -130,12 +132,18 @@ def causal_conv1d(x, kernel, bias=None):
     return y.astype(x.dtype)
 
 
-def gated_rms_norm(y, gate, scale, eps: float, groups: int = 1):
+def gated_rms_norm(y, gate, scale, eps: float, groups: int = 1,
+                   norm_first: bool = False):
     """``RMSNorm(y * silu(gate)) * scale``, the mean square taken over each
     of ``groups`` equal runs of the last axis, in float32, returned in
-    ``y.dtype``."""
-    h = y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+    ``y.dtype``. With ``norm_first`` the other order, ``RMSNorm(y) * scale
+    * silu(gate)``: the norm over the last axis itself (a head's width),
+    under a ``scale`` that wide."""
+    h, gate = y.astype(jnp.float32), jax.nn.silu(gate.astype(jnp.float32))
+    if not norm_first:
+        h = h * gate
     if groups > 1:
         h = h.reshape(h.shape[:-1] + (groups, -1))
     h = h * jax.lax.rsqrt(jnp.mean(jnp.square(h), axis=-1, keepdims=True) + eps)
-    return (h.reshape(y.shape) * scale.astype(jnp.float32)).astype(y.dtype)
+    h = h.reshape(y.shape) * scale.astype(jnp.float32)
+    return (h * gate if norm_first else h).astype(y.dtype)

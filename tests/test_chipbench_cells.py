@@ -30,7 +30,11 @@ HYBRID_CELLS = {
                             "attn_window_kernel_ms"},
     "kanana2-train-s16384": {"moe_ms", "moe_experts_ms", "moe_route_ms",
                              "moe_shared_ms", "lm_head_ms", "mla_latent_ms",
-                             "mla_assemble_ms"}}
+                             "mla_assemble_ms"},
+    "qwen3next-train-s16384": {"moe_ms", "moe_experts_ms", "moe_route_ms",
+                               "moe_shared_ms", "lm_head_ms", "attn_gate_ms",
+                               "delta_rule_ms", "delta_rule_roofline",
+                               "delta_rule_prep_ms"}}
 
 #: the interpreter's kernels are no custom calls: a reader of class
 #: ``attention_kernel`` finds its scope and no time under it

@@ -415,7 +415,8 @@ def _pallas_params(fn, *args):
 # flash calls, and the VMEM limit its backward call names, in MiB: 96 for a
 # single key sweep (the resident budget, as it has been), ``None`` (Mosaic's
 # default) for a multi-sweep call whose dq scratch is at most 4 MiB, and for
-# kanana2-train-s16384's 16,384 x 192 what the shape is reckoned to hold
+# kanana2-train-s16384's 16,384 x 192 and qwen3next-train-s16384's 16,384 x
+# 256 what the shape is reckoned to hold
 _CELL_BACKWARD_VMEM = {
     "gpt2m-train-s1024_and_dp4": ((8, 1024, 16, 64, 64, None), 96),
     "gpt2l-train-s1024": ((4, 1024, 20, 64, 64, None), 96),
@@ -425,6 +426,8 @@ _CELL_BACKWARD_VMEM = {
     "lagunas-train-s8192_full": ((1, 8192, 48, 128, 128, None), None),
     "lagunas-train-s8192_window": ((1, 8192, 72, 128, 128, 512), None),
     "kanana2-train-s16384": ((1, 16384, 32, 192, 128, None), 35),
+    # a 16 MiB dq scratch at 256 lanes; K + V resident are 32 MiB
+    "qwen3next-train-s16384": ((1, 16384, 16, 256, 256, None), 37),
 }
 
 

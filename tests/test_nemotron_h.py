@@ -633,7 +633,7 @@ def test_control_a_gated_norm_over_one_group_fails_the_chips_check(
     inner width where the model has one a group."""
     sound = ssd.gated_rms_norm
     monkeypatch.setattr(ssd, "gated_rms_norm",
-                        lambda y, gate, scale, eps, groups=1:
+                        lambda y, gate, scale, eps, groups=1, norm_first=False:
                         sound(y, gate, scale, eps))
     assert not chip_check(randomised_params(), tokens(64)[0]) <= 0.02
 

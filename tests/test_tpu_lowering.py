@@ -96,6 +96,9 @@ _ROUTE_EDGES = {
     (8192, 128): ("once", "fused", 2),
     # nemotron3s-train-s4096's
     (4096, 128): ("once", "fused", 2),
+    # qwen3next-train-s16384's: 32 MiB of K + V resident, a 16 MiB scratch
+    # that asks 37
+    (16384, 256): ("once", "fused", 2),
     # both sides of ``_KV_VMEM_CAP``: 64 MiB of K + V in VMEM's lanes, both
     # pipeline buffers, is the last single-shot forward at either width, and
     # 128 MiB streams
@@ -173,11 +176,11 @@ def test_two_width_flash_routes_compile_for_v5e(t, v5e_devices):
 
 @pytest.mark.parametrize("layer", [
     "kanana2-train-s16384", "lagunas-train-s8192_full",
-    "lagunas-train-s8192_window"])
+    "lagunas-train-s8192_window", "qwen3next-train-s16384"])
 def test_long_cells_layers_compile_resident_inside_the_gradient(layer,
                                                                 v5e_devices):
     """Forward + all three gradients of one layer's flash call at the
-    cell's own head count (the two cells whose K and V are over 1 MiB an
+    cell's own head count (the three cells whose K and V are over 1 MiB an
     operand), inside ``jax.grad`` (Mosaic counts a few MiB more there than
     for the kernel alone, PERF.md §6, PR 43): the single-shot forward with
     a head's K and V in VMEM and the one-pass backward, compiled for a v5e
@@ -915,3 +918,69 @@ def test_latent_attention_sites_are_named_and_classed(forward, v5e_devices,
             == set("012"), part
         assert all(re.search(reader.PATTERN, p) for p in paths)
         assert any("transpose(" in p for p in paths)
+
+
+def test_gated_delta_and_gated_attention_sites_are_named_and_classed(
+        v5e_devices):
+    """One period of the Qwen3-Next family at its toy widths (three
+    delta-rule layers, then gated attention at head 64; 512 positions, eight
+    chunks a layer), recomputed as the cell's and compiled for a v5e: every
+    operation of the chunked rule, the scan over chunks among them, carries
+    ``block_<i>/mixer/delta_rule`` in the forward pass, the recomputed one
+    and the backward, which ``delta_rule_ms`` reads and the scope classes
+    book to the blocks; the conv, the L2 norms and the decays carry
+    ``mixer/prep``; the attention layer's gate ``mixer/gate`` as Laguna's,
+    and its flash calls are classed as every cell's; the shared expert's
+    gate is ``ffn/shared_gate``, in every block."""
+    from chipbench import op_scopes, trace_reduce
+    from chipbench.families import qwen3_next
+    from chipbench.layer_metrics import (attn_gate_ms, delta_rule_ms,
+                                         delta_rule_prep_ms, moe_shared_ms)
+    from tests.test_qwen3_next import CONFIG
+
+    op_classes = trace_reduce.load_classes()
+    scope_classes = trace_reduce.load_classes(op_scopes.SCOPE_CLASSES)
+    model = qwen3_next.build_model(CONFIG, 512, {"remat": "full"})
+    text = _model_grad_text(model, 512, v5e_devices)
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    # (the scan's own checkpoint leaves a ``rematted_computation`` *behind*
+    # the block's name: a chunk run again inside the rule's backward pass,
+    # which is the backward pass's)
+    def recomputed(p):
+        return bool(re.search(r"rematted_computation/block_\d", p))
+
+    passes = {"blocks_fwd": lambda p: "transpose(" not in p,
+              "blocks_recompute": recomputed,
+              "blocks_bwd": lambda p: "transpose(" in p and not recomputed(p)}
+    for reader, part, blocks in ((delta_rule_ms, "delta_rule", "012"),
+                                 (delta_rule_prep_ms, "prep", "012"),
+                                 (attn_gate_ms, "gate", "3")):
+        under = [p for p in paths if f"/mixer/{part}/" in p + "/"]
+        assert {re.search(r"block_(\d)", p).group(1) for p in under} \
+            == set(blocks), part
+        assert all(re.search(reader.PATTERN, p) for p in under)
+        classes = {trace_reduce.classify(p, scope_classes) for p in under}
+        assert classes == set(passes), (part, classes)
+        for name, holds in passes.items():
+            assert all(holds(p) for p in under
+                       if trace_reduce.classify(p, scope_classes) == name)
+    # the scan over chunks is inside the scope, a while loop of the program
+    assert any("/mixer/delta_rule/" in p and "while" in p for p in paths)
+    # no reader of another family's part matches the new ones
+    assert not [p for p in paths if "/mixer/gate_norm" in p
+                and re.search(attn_gate_ms.PATTERN, p)]
+    gates = [p for p in paths if "/ffn/shared_gate/" in p]
+    assert {re.search(r"block_(\d)", p).group(1) for p in gates} \
+        == set("0123")
+    assert not [p for p in gates if re.search(moe_shared_ms.PATTERN, p)]
+    assert {trace_reduce.classify(p, scope_classes) for p in gates} \
+        <= set(passes)
+    calls = _kernel_calls(text, r"flash_\w+?")
+    assert sorted(name for name, _, _ in calls) == ["flash_bwd", "flash_fwd"]
+    for name, instruction, path in calls:
+        assert "/block_3/mixer/jit(" in path, path
+        assert trace_reduce.classify(path, scope_classes) == {
+            "flash_fwd": "attn_fwd", "flash_bwd": "attn_bwd"}[name]
+        assert trace_reduce.classify(
+            f"tpu_custom_call %{instruction}", op_classes) \
+            == "attention_kernel"
