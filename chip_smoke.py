@@ -461,6 +461,7 @@ def kernel_cases():
             None),
         **grouped_cases(),
         **scan_cases(),
+        **delta_cases(),
         "matmul_2d (LM head chunk)": KernelCase(
             pk.matmul_2d,
             [s((B * T // 4, DM // 4), jnp.bfloat16),
@@ -595,6 +596,47 @@ def scan_cases():
 
     return {"ssd_scan fwd+bwd 64 heads, one group": case(64, None, 256),
             "ssd_scan fwd+bwd 128 heads, 8 groups": case(128, 8, 128)}
+
+
+def delta_cases():
+    """The gated delta rule (``ops/gated_delta.gated_delta_chunked``) and
+    its five gradients at a delta-rule layer of the benchmark's Qwen3-Next
+    cell (32 heads of 128 / 128), one sequence of 4,096 positions: the
+    Pallas pair against the chunked form in XLA, which is what the same
+    call runs with kernels off. The operands are drawn as every case's are
+    and brought into the rule's ranges here: keys of unit length, queries
+    a ``128 ** -0.5`` of it, decays ``-softplus`` of a normal, write
+    strengths its sigmoid."""
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import gated_delta
+
+    def unit(x, scale=1.0):
+        x = x.astype(jnp.float32)
+        return (x * scale * jax.lax.rsqrt(
+            jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)).astype(
+                jnp.bfloat16)
+
+    def rule(q, k, v, g, beta):
+        def loss(*a):
+            o = gated_delta.gated_delta_chunked(*a).astype(jnp.float32)
+            return jnp.sum(o * cotangent(o.shape)), o
+        grads, o = jax.grad(loss, argnums=range(5), has_aux=True)(
+            unit(q, 128 ** -0.5), unit(k), v, -jax.nn.softplus(g - 2.0),
+            jax.nn.sigmoid(beta))
+        return o, grads
+
+    def kernels_off(*operands):
+        with mock.patch.dict(os.environ, HVD_PALLAS="0"):
+            return rule(*operands)
+
+    s, t, h = jax.ShapeDtypeStruct, 4096, 32
+    return {"gated_delta fwd+bwd 32 heads of 128": KernelCase(
+        rule, [s((1, t, h, 128), jnp.bfloat16)] * 3
+        + [s((1, t, h), jnp.float32)] * 2, 2, kernels_off, TOL_BF16)}
 
 
 def matmul_reduce_scatter_case(mesh):

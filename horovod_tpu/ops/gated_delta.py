@@ -25,15 +25,27 @@ triangular system an ``L x L`` a head and chunk
 is the state alone, which each chunk *transforms* (``V' = U - W S``) and
 not only decays: a ``lax.scan`` over chunks, not ``ssd_chunked``'s one
 einsum over cumulative decays, and over that a scan over segments of
-chunks that bounds what the gradient keeps. Batched matmuls and elementwise ops that XLA
-lays out and autodiff takes back; one path on the chip and off it (no
-kernel yet: ``ROADMAP.md`` V15).
+chunks that bounds what the gradient keeps. Batched matmuls and elementwise
+ops that XLA lays out and autodiff takes back: the reference path, off the
+chip, with kernels off, for a shape ``pallas_kernels.delta_route`` refuses
+and for varying operands under ``shard_map(check_vma=True)``. On the chip
+the same chunked form runs on a Pallas kernel pair
+(``pallas_kernels.delta_rule``: ``delta_fwd``, and ``delta_bwd`` under a
+hand-written backward pass) that walks a sequence's tiles in order with a
+head's float32 state in VMEM and makes a chunk's decay matrix, system,
+inverse and masked products there, none of which crosses HBM; the forward
+that a backward pass follows saves each tile's entering state and each
+chunk's inverse, which take the segments' place.
+``pallas_kernels.kernel_path("gated_delta", q, k, v)`` says which path a
+call takes.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from . import pallas_kernels as pk
 
 
 @jax.custom_vjp
@@ -89,7 +101,10 @@ unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
 #: positions a chunk (a power of two: one unit lower triangular system a
-#: head), and positions a segment of chunks whose state the gradient keeps
+#: head; the kernels' own is ``pallas_kernels._DELTA_CHUNK``, the same 64),
+#: and, on the reference path, positions a segment of chunks whose state the
+#: gradient keeps (the kernel path keeps a tile's entering state and a
+#: chunk's inverse, and walks no segments)
 CHUNK = 64
 SEGMENT = 4096
 
@@ -107,9 +122,13 @@ def gated_delta_chunked(q, k, v, g, beta):
     ``A`` and its inverse, and the state between chunks are float32, and
     every exponent is <= 0: a decay is taken between two positions of one
     chunk or from a chunk's start or to its end, the mask going in before
-    the ``exp``.
+    the ``exp``. That holds of both paths: the Pallas pair
+    (``pallas_kernels.delta_rule``, where ``pallas_kernels.delta_route``
+    admits the shape: heads of one lane width each way, 2- or 4-byte
+    elements) rounds where this form rounds, and makes the inverse by the
+    same recursion at full float32 precision.
 
-    The sequence is walked a segment of :data:`SEGMENT` positions at a time
+    On the reference path the sequence is walked a segment of :data:`SEGMENT` positions at a time
     (of the whole chunks that hold ``T``, where that is less), the state
     handed on, and the backward pass makes a segment's chunks again from
     its operands and the state it started with: what the chunked form keeps
@@ -120,6 +139,9 @@ def gated_delta_chunked(q, k, v, g, beta):
     of the rule. A ``T`` that the segment does not divide is padded with
     steps of ``g = 0, beta = 0``, which leave the state alone."""
     b, t, h, dk = q.shape
+    if pk.kernel_path("gated_delta", q, k, v) == "pallas":
+        with jax.named_scope("delta_rule"):
+            return pk.delta_rule(q, k, v, g, beta)
     if SEGMENT % CHUNK:
         raise ValueError(f"SEGMENT={SEGMENT} is no multiple of CHUNK={CHUNK}")
     segment = min(SEGMENT, t + -t % CHUNK)

@@ -2729,6 +2729,559 @@ def ssd_scan(x, dt, A, B, C, D):
     return y2[:, :t].reshape(x.shape)
 
 
+# ------------------------------------------------------- gated delta rule
+# The gated delta rule's chunked form (ops/gated_delta.py has the recurrence
+# and the XLA form this is measured against) as two kernels whose grid walks
+# the tiles of a sequence in order, a value head's ``[K, V]`` float32 state
+# in VMEM from one tile to the next, and with it everything a chunk makes on
+# the way: the decay matrix, ``K K^T``, the unit lower triangular system,
+# its inverse and the masked products never cross HBM. Grid ``(batch, head
+# cell, tile)``, the tile axis last and sequential.
+#
+# A cell works a PAIR of chunks at a time: 128 positions, whose two 64 x 64
+# systems are the diagonal blocks of one ``[128, 128]`` matrix, so that
+# every vector register is whole lanes wide and every product has the MXU's
+# edge; the masks keep the two chunks apart (a product over a pair's 128
+# columns streams as many rows as two over 64), and the state is handed from
+# the pair's first chunk to its second as from any chunk to the next.
+#
+# Layouts. q, k, v, o and their gradients are ``[b, T, H 128]``, a head a
+# lane width (the model's ``[b, T, H, 128]`` reshaped: on the chip a relayout
+# a tensor, heads on the sublanes to positions on them, which XLA books to
+# whatever produced the tensor). The per-position scalars (the cumulative
+# sum of ``g`` inside a chunk and ``beta``, float32, formed in XLA where
+# autodiff takes the sum back to ``g``) come as ``rows`` ``[b, H, 8, T]``,
+# positions on the lanes, a head's two in the first two of eight sublanes
+# (a whole tile of sublanes a head, whatever the heads a cell); what varies
+# along a matrix's rows is their transpose, taken once a pair by the XLU.
+# The variant of the forward that a backward pass follows saves each tile's
+# entering state and each chunk's inverse, a pair's two as the halves of a
+# ``[64, 128]`` float32 block; the backward kernel walks the tiles in
+# reverse with the state's gradient in VMEM, makes a tile's chunks again
+# from the entering state, and reads the inverse.
+
+#: positions a chunk (one unit lower triangular system), as
+#: ``ops/gated_delta.CHUNK``; a pair is two
+_DELTA_CHUNK = 64
+_DELTA_PAIR = 2 * _DELTA_CHUNK
+#: the kernel tile of a sequence longer than a pair, and the heads a grid
+#: cell takes, in the order ``delta_route`` tries them (at most 15: eight
+#: sublanes of ``rows`` a head, laid over a lane width for one transpose).
+#: Read on a v5e, one layer of the Qwen3-Next cell (1 x 16,384 positions, 32
+#: heads of 128 / 128, bf16), the saving forward / the backward kernel in ms
+#: (PERF.md §6, PR 47; the XLA form 17.4 forward and 62.2 with its gradient):
+#: tile 256 and 2 heads 6.59 / 6.66, 256 and 4 6.02 / 5.62, 512 and 4 5.95 /
+#: 5.66, 128 and 8 5.67 / 5.05, **256 and 8 5.54 / 5.32**, 512 and 8 5.53 /
+#: 5.66. What pays is the pairs of chunks a cell holds at once, heads before
+#: tile: a pair's products wait for one another (the recursion's levels, the
+#: state from chunk to chunk) and the pairs of a cell do not, so the kernels
+#: take every stage for all of a cell's pairs before the next (the same
+#: kernels a pair after the other: 9.22 / 8.28 at 256 and 4).
+_DELTA_TILE = 256
+_DELTA_HEADS = (8, 4, 2, 1)
+_DELTA_VMEM = 64 * 2 ** 20
+
+
+def delta_route(t: int, h: int, dk: int, dv: int, itemsize: int) -> dict:
+    """Which path the gated delta rule over ``t`` positions and ``h`` heads
+    of key width ``dk`` and value width ``dv`` takes, and at which kernel
+    tile and heads a grid cell: ``{"path", "tile", "heads"}``, ``path``
+    ``"pallas"`` or ``"reference"`` (the chunked form in XLA: a head that
+    is not one lane width each way, an element that is not 2 or 4 bytes).
+    The dispatcher (``ops/gated_delta.py``) and the tests both read it; a
+    ``t`` the tile does not divide is padded with steps of ``g = 0, beta =
+    0``, which leave the state alone. The only place a tile is written. No
+    JAX."""
+    if dk != _LANES or dv != _LANES or itemsize not in (2, 4) or t < 1:
+        return {"path": "reference", "tile": None, "heads": None}
+    heads = next(n for n in _DELTA_HEADS if h % n == 0)
+    tile = _DELTA_TILE if t > _DELTA_PAIR else _DELTA_PAIR
+    return {"path": "pallas", "tile": tile, "heads": heads}
+
+
+def delta_supported(q, k, v) -> bool:
+    """``q``, ``k`` ``[b, T, H, K]`` and ``v`` ``[b, T, H, V]``."""
+    _, t, h, dk = q.shape
+    return q.dtype == k.dtype == v.dtype and delta_route(
+        t, h, dk, v.shape[-1], q.dtype.itemsize)["path"] == "pallas"
+
+
+def _dot_f32(a, b):
+    """``a b`` of float32 operands at full precision (the MXU's passes over
+    the three bf16 parts of each, as XLA's ``highest``)."""
+    return jnp.dot(a, b, precision=lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+
+
+def _delta_masks():
+    """What keeps a pair's two chunks apart and orders a chunk's positions,
+    ``[128, 128]`` each: ``lower`` (the same chunk and ``s <= l``), the
+    identity, and the recursion's levels: the strictly lower part
+    inside blocks of two, then for every block size ``s`` up to half a
+    chunk the part inside blocks of ``2 s`` and outside those of ``s``."""
+    row = lax.broadcasted_iota(jnp.int32, (_DELTA_PAIR, _DELTA_PAIR), 0)
+    col = lax.broadcasted_iota(jnp.int32, (_DELTA_PAIR, _DELTA_PAIR), 1)
+    same = row // _DELTA_CHUNK == col // _DELTA_CHUNK
+    levels, s = [(row // 2 == col // 2) & (row > col)], 2
+    while s < _DELTA_CHUNK:
+        levels.append((row // (2 * s) == col // (2 * s))
+                      & (row // s > col // s))
+        s *= 2
+    return {"lower": same & (row >= col),
+            "eye": (row == col).astype(jnp.float32), "levels": levels}
+
+
+def _delta_inverses(systems, masks):
+    """``(I + A)^-1`` for the strictly lower part ``A`` of each chunk's
+    block of every ``[128, 128]`` system of the list:
+    ``ops/gated_delta.unit_lower_inverse``'s recursion on both chunks of a
+    pair at once, float32 at full precision. Blocks of one to two is ``I -
+    A_1``; two to four the vector unit's (``T_2`` has one entry beside its
+    diagonal a row, so ``T_2 A_2 T_2`` is a row of ``A_2`` added to the
+    next and a column to the one before); from there the MXU's, and once a
+    half block is whole sublanes only the rows that are not zero are
+    streamed: the lower half of every block of ``2 s``. A level is taken
+    for every system before the next (each system's products wait for one
+    another, and a cell's systems do not: read on the chip, PERF.md §6,
+    PR 47)."""
+    first, second, *levels = masks["levels"]
+    lowest = [jnp.where(first, a, 0.0) for a in systems]
+    steps = [jnp.where(second, a, 0.0) for a in systems]
+    # T_2[i, i - 1] down the rows, T_2[k + 1, k] along the columns
+    steps = [step - jnp.sum(low, axis=1, keepdims=True)
+             * pltpu.roll(step, 1, 0) for step, low in zip(steps, lowest)]
+    ts = [masks["eye"] - low - (step - jnp.sum(low, axis=0, keepdims=True)
+                                * pltpu.roll(step, _LANES - 1, 1))
+          for step, low in zip(steps, lowest)]
+    s = 4
+    for inside in levels:
+        steps = [jnp.where(inside, a, 0.0) for a in systems]
+        if s < 8:
+            rows = ts
+        else:
+            blocks = range(0, _DELTA_PAIR, 2 * s)
+            rows = [jnp.concatenate([t[at + s:at + 2 * s] for at in blocks],
+                                    axis=0) for t in ts]
+        products = [_dot_f32(r, step) for r, step in zip(rows, steps)]
+        rows = [r - _dot_f32(p, t) for r, p, t in zip(rows, products, ts)]
+        if s < 8:
+            ts = rows
+        else:
+            ts = [jnp.concatenate(
+                [part for i, at in enumerate(blocks)
+                 for part in (t[at:at + s], r[i * s:(i + 1) * s])], axis=0)
+                for t, r in zip(ts, rows)]
+        s *= 2
+    return ts
+
+
+def _delta_cols(rows_ref, heads: int, at):
+    """``[128, 128]`` whose columns ``8 h`` and ``8 h + 1`` are head
+    ``h``'s cumulative decay and ``beta`` at a pair's positions: one
+    transpose by the XLU of the cell's rows laid over a lane width of
+    sublanes."""
+    rows = [rows_ref[0, h, :, at] for h in range(heads)]
+    rows.append(jnp.zeros((_LANES - 8 * heads, _DELTA_PAIR), jnp.float32))
+    return jnp.concatenate(rows, axis=0).T
+
+
+def _delta_units(refs, rows_ref, tile: int, heads: int):
+    """A cell's pairs of chunks, a head's of one pair consecutive: ``(p,
+    h)``, where each lies in the cell's blocks, and its operands: the ``[128,
+    128]`` blocks of ``refs`` (q, k, v and whatever else is laid out as
+    they are), the cumulative decay as a column and as a row and ``beta``
+    as a column."""
+    units = []
+    for p in range(tile // _DELTA_PAIR):
+        at = slice(p * _DELTA_PAIR, (p + 1) * _DELTA_PAIR)
+        cols = _delta_cols(rows_ref, heads, at)
+        for h in range(heads):
+            lanes = slice(h * _LANES, (h + 1) * _LANES)
+            units.append({
+                "p": p, "h": h, "at": at, "lanes": lanes,
+                "blocks": [ref[0, at, lanes] for ref in refs],
+                "cum_c": cols[:, 8 * h:8 * h + 1],
+                "cum_r": rows_ref[0, h, 0:1, at],
+                "beta": cols[:, 8 * h + 1:8 * h + 2]})
+    return units
+
+
+def _delta_pairs(units, masks, inverses=None):
+    """What each pair of chunks makes before it meets the state: into
+    every unit's dict the decay matrix, ``K K^T``, ``Q K^T``, the system's
+    inverse (made here unless handed in) and the operands of the products
+    with the state, each as ``ops/gated_delta._segment`` rounds it; a
+    stage for every unit before the next."""
+    f32, dtype = jnp.float32, units[0]["blocks"][0].dtype
+    half = lax.broadcasted_iota(jnp.int32, (_DELTA_PAIR, 1), 0) < _DELTA_CHUNK
+    for u in units:
+        q, k = u["blocks"][:2]
+        # exp(G_l - G_s) for s <= l of one chunk, 0 elsewhere: the mask goes
+        # in before the exp, where the exponent is positive and may overflow
+        u["decay"] = jnp.exp(jnp.where(masks["lower"],
+                                       u["cum_c"] - u["cum_r"], NEG_INF))
+        u["kk"], u["qk_raw"] = _dot_nt(k, k), _dot_nt(q, k)
+    if inverses is None:
+        inverses = _delta_inverses(
+            [u["beta"] * u["kk"] * u["decay"] for u in units], masks)
+    for u, inverse in zip(units, inverses):
+        q, k, v = u["blocks"][:3]
+        cum_c, beta = u["cum_c"], u["beta"]
+        ends = [cum_c[at - 1:at] for at in (_DELTA_CHUNK, _DELTA_PAIR)]
+        last = jnp.where(half, *ends)                   # G_L, a chunk's
+        from_start, to_end = jnp.exp(cum_c), jnp.exp(last - cum_c)
+        kf, qf, vf = k.astype(f32), q.astype(f32), v.astype(f32)
+        u.update(
+            last=ends, inverse=inverse, low=inverse.astype(dtype),
+            from_start=from_start, to_end=to_end,
+            k_in=(beta * from_start * kf).astype(dtype),
+            v_in=(beta * vf).astype(dtype),
+            qk=(u["qk_raw"] * u["decay"]).astype(dtype),
+            q_in=(from_start * qf).astype(dtype),
+            k_out=(to_end * kf).astype(dtype),
+            # exp(G_L) a chunk, over the lanes (a [1, 1] spread along both
+            # axes at once is what Mosaic does not lower)
+            whole=[jnp.exp(jnp.broadcast_to(end, (1, _LANES)))
+                   for end in ends])
+    for u in units:
+        u["w"] = jnp.dot(u["low"], u["k_in"],
+                         preferred_element_type=f32).astype(dtype)
+        u["u"] = jnp.dot(u["low"], u["v_in"], preferred_element_type=f32)
+    return units
+
+
+def _delta_chunks():
+    return [slice(c * _DELTA_CHUNK, (c + 1) * _DELTA_CHUNK) for c in (0, 1)]
+
+
+def _delta_states(units, states, heads: int):
+    """The chunks' walk: every unit's corrected values (``new``, a chunk
+    each) and the state each chunk entered with (``entered``), from the
+    heads' ``states`` at the cell's start; returns the states after. A
+    head's chunks wait for one another, the heads of a cell do not."""
+    f32, dtype = jnp.float32, units[0]["low"].dtype
+    states = list(states)
+    for u in units:
+        u["new"], u["entered"] = [], []
+    for p in range(len(units) // heads):
+        for c, rows in enumerate(_delta_chunks()):
+            for u in units[p * heads:(p + 1) * heads]:
+                state = states[u["h"]]
+                u["entered"].append(state)
+                # the corrected values, given the state the chunk starts
+                # from
+                u["new"].append((u["u"][rows] - jnp.dot(
+                    u["w"][rows], state.astype(dtype),
+                    preferred_element_type=f32)).astype(dtype))
+                states[u["h"]] = u["whole"][c] * state + _dot_tn(
+                    u["k_out"][rows], u["new"][c])
+    return states
+
+
+def _delta_fwd_kernel(q_ref, k_ref, v_ref, rows_ref, o_ref, *rest, tile,
+                      heads, save):
+    state_ref = rest[-1]
+    f32, dtype = jnp.float32, q_ref.dtype
+    masks = _delta_masks()
+
+    @pl.when(pl.program_id(2) == 0)
+    def _a_sequence_starts():
+        state_ref[...] = jnp.zeros(state_ref.shape, f32)
+
+    states = [state_ref[h] for h in range(heads)]
+    if save:
+        for h in range(heads):
+            rest[0][0, 0, h] = states[h]
+    units = _delta_pairs(
+        _delta_units((q_ref, k_ref, v_ref), rows_ref, tile, heads), masks)
+    if save:
+        for u in units:
+            # the two chunks' inverses side by side: what lies outside a
+            # chunk's block is zero
+            rest[1][0, u["h"], u["p"] * _DELTA_CHUNK:
+                    (u["p"] + 1) * _DELTA_CHUNK] = (
+                u["inverse"][:_DELTA_CHUNK] + u["inverse"][_DELTA_CHUNK:])
+    states = _delta_states(units, states, heads)
+    for h in range(heads):
+        state_ref[h] = states[h]
+    for u in units:
+        read = [jnp.dot(u["q_in"][rows], state.astype(dtype),
+                        preferred_element_type=f32)
+                for rows, state in zip(_delta_chunks(), u["entered"])]
+        o_ref[0, u["at"], u["lanes"]] = (
+            jnp.concatenate(read, axis=0) + jnp.dot(
+                u["qk"], jnp.concatenate(u["new"], axis=0),
+                preferred_element_type=f32)).astype(dtype)
+
+
+def _delta_specs(tile: int, heads: int, nt: int, reverse: bool):
+    """The block specs both kernels share, by operand name; the backward
+    kernel walks the tiles from the last."""
+
+    def at(i):
+        return nt - 1 - i if reverse else i
+
+    return {
+        "x": pl.BlockSpec((1, tile, heads * _LANES),
+                          lambda b, j, i: (b, at(i), j)),
+        "rows": pl.BlockSpec((1, heads, 8, tile),
+                             lambda b, j, i: (b, j, 0, at(i))),
+        "states": pl.BlockSpec((1, 1, heads, _LANES, _LANES),
+                               lambda b, j, i: (b, at(i), j, 0, 0)),
+        "inverses": pl.BlockSpec((1, heads, tile // 2, _LANES),
+                                 lambda b, j, i: (b, j, at(i), 0)),
+    }
+
+
+# Jitted by the rule below :func:`_named_call`: a step calls the rule a
+# delta-rule layer and pass.
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7))
+def _delta_fwd(q2, k2, v2, rows, tile, heads, save, interpret):
+    b, t, wide = q2.shape
+    h, nt = wide // _LANES, t // tile
+    spec = _delta_specs(tile, heads, nt, False)
+    like = (q2, k2, v2, rows)
+    o = _struct(q2.shape, q2.dtype, *like)
+    saved = [_struct((b, nt, h, _LANES, _LANES), jnp.float32, *like),
+             _struct((b, h, t // 2, _LANES), jnp.float32, *like)]
+    pairs = b * h * t // _DELTA_PAIR
+    return _named_call(
+        "delta_fwd",
+        functools.partial(_delta_fwd_kernel, tile=tile, heads=heads,
+                          save=save),
+        grid=(b, h // heads, nt),
+        in_specs=[spec["x"], spec["x"], spec["x"], spec["rows"]],
+        out_specs=[spec["x"], spec["states"], spec["inverses"]] if save
+        else spec["x"],
+        out_shape=[o] + saved if save else o,
+        scratch_shapes=[pltpu.VMEM((heads, _LANES, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_DELTA_VMEM),
+        cost_estimate=pl.CostEstimate(
+            flops=pairs * 2 * _DELTA_PAIR ** 3 * (5 * 6 + 9),
+            transcendentals=pairs * _DELTA_PAIR ** 2,
+            bytes_accessed=4 * q2.size * q2.dtype.itemsize + rows.size * 4
+            + (sum(s.size for s in saved) * 4 if save else 0)),
+        interpret=interpret,
+    )(q2, k2, v2, rows)
+
+
+def _delta_bwd_kernel(q_ref, k_ref, v_ref, rows_ref, do_ref, s_ref, inv_ref,
+                      dq_ref, dk_ref, dv_ref, drows_ref, dstate_ref, *, tile,
+                      heads):
+    f32, dtype = jnp.float32, q_ref.dtype
+    masks = _delta_masks()
+    row = lax.broadcasted_iota(jnp.int32, (_DELTA_PAIR, 1), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (_DELTA_PAIR, _LANES), 1)
+    strict = masks["lower"] & (masks["eye"] == 0.0)
+    left = lax.broadcasted_iota(
+        jnp.int32, (_DELTA_CHUNK, _LANES), 1) < _DELTA_CHUNK
+    pairs, chunks = tile // _DELTA_PAIR, _delta_chunks()
+
+    @pl.when(pl.program_id(2) == 0)
+    def _a_sequence_ends():
+        dstate_ref[...] = jnp.zeros(dstate_ref.shape, f32)
+
+    # a tile's chunks again, from the state the tile started with: every
+    # chunk's entering state and corrected values
+    units = _delta_units((q_ref, k_ref, v_ref, do_ref), rows_ref, tile,
+                         heads)
+    inverses = []
+    for u in units:
+        both = inv_ref[0, u["h"], u["p"] * _DELTA_CHUNK:
+                       (u["p"] + 1) * _DELTA_CHUNK]
+        inverses.append(jnp.concatenate(
+            [jnp.where(left, both, 0.0), jnp.where(left, 0.0, both)], axis=0))
+    _delta_pairs(units, masks, inverses)
+    _delta_states(units, [s_ref[0, 0, h] for h in range(heads)], heads)
+
+    def pair(parts):
+        return jnp.concatenate([parts[0], parts[1]], axis=0)
+
+    def lanes_sum(a):
+        return jnp.sum(a, axis=1, keepdims=True)
+
+    # and backwards through them, the gradient of the state a chunk leaves
+    # coming from the right; a stage for every head of the cell before the
+    # next, as the forward takes them
+    dstates = [dstate_ref[h] for h in range(heads)]
+    first = lax.broadcasted_iota(jnp.int32, (8, _DELTA_PAIR), 0) == 0
+    for p in reversed(range(pairs)):
+        here = units[p * heads:(p + 1) * heads]
+        for u in here:
+            do = u["blocks"][3]
+            # d new through the chunk's own later queries; d (Q K^T decay)
+            u["dnew_own"] = _dot_tn(u["qk"], do)
+            u["d_qk"] = _dot_nt(do, pair(u["new"]))
+            for name in ("dnew", "dw", "dq_in", "dk_out", "tails"):
+                u[name] = {}
+        for c in (1, 0):
+            rows = chunks[c]
+            for u in here:
+                do, dstate = u["blocks"][3][rows], dstates[u["h"]]
+                state = u["entered"][c]
+                held, dheld = state.astype(dtype), dstate.astype(dtype)
+                u["dnew"][c] = u["dnew_own"][rows] + jnp.dot(
+                    u["k_out"][rows], dheld, preferred_element_type=f32)
+                u["dk_out"][c] = _dot_nt(u["new"][c], dheld)
+                low = u["dnew"][c].astype(dtype)
+                u["dw"][c] = -_dot_nt(low, held)
+                u["dq_in"][c] = _dot_nt(do, held)
+                # d G_L of the chunk: through the state's whole decay
+                u["tails"][c] = jnp.exp(u["last"][c]) * jnp.sum(
+                    jnp.sum(dstate * state, axis=1, keepdims=True),
+                    axis=0, keepdims=True)
+                dstates[u["h"]] = u["whole"][c] * dstate \
+                    + _dot_tn(u["q_in"][rows], do) \
+                    - _dot_tn(u["w"][rows], low)
+        for u in here:
+            for name in ("dnew", "dw", "dq_in", "dk_out"):
+                u[name] = pair(u[name])
+            dnew_low, dw_low = u["dnew"].astype(dtype), u["dw"].astype(dtype)
+            # W = T k_in, U = T v_in
+            u["d_inverse"] = _dot_nt(dw_low, u["k_in"]) \
+                + _dot_nt(dnew_low, u["v_in"])
+            u["dk_in"] = _dot_tn(u["low"], dw_low)
+            u["dv_in"] = _dot_tn(u["low"], dnew_low)
+            u["turned"] = u["inverse"].T
+        # the inverse's own gradient, -T^T dT T^T under the strictly lower
+        # triangle of a chunk, float32 at full precision
+        for u in here:
+            u["d_system"] = _dot_f32(u["turned"], u["d_inverse"])
+        for u in here:
+            u["d_system"] = jnp.where(
+                strict, -_dot_f32(u["d_system"], u["turned"]), 0.0)
+        dcols = jnp.zeros((_DELTA_PAIR, _LANES), f32)
+        for u in here:
+            q, k, v = u["blocks"][:3]
+            kf, qf, vf = k.astype(f32), q.astype(f32), v.astype(f32)
+            beta, decay, kk = u["beta"], u["decay"], u["kk"]
+            d_system, d_qk = u["d_system"], u["d_qk"]
+            dk_in, dv_in, dq_in, dk_out = (u[name] for name in (
+                "dk_in", "dv_in", "dq_in", "dk_out"))
+            dkk = (d_system * beta * decay).astype(dtype)
+            dqk_raw = (d_qk * decay).astype(dtype)
+            # d of every decay's exponent: the row sums of d decay * decay
+            # less its column sums, of ONE product
+            by_decay = (d_system * beta * kk + d_qk * u["qk_raw"]) * decay
+            dq = jnp.dot(dqk_raw, k, preferred_element_type=f32) \
+                + u["from_start"] * dq_in
+            dk = _dot_tn(dqk_raw, q) \
+                + jnp.dot(dkk, k, preferred_element_type=f32) \
+                + _dot_tn(dkk, k) \
+                + beta * u["from_start"] * dk_in + u["to_end"] * dk_out
+            dq_ref[0, u["at"], u["lanes"]] = dq.astype(dtype)
+            dk_ref[0, u["at"], u["lanes"]] = dk.astype(dtype)
+            dv_ref[0, u["at"], u["lanes"]] = (beta * dv_in).astype(dtype)
+            dbeta = lanes_sum(d_system * kk * decay) \
+                + lanes_sum(dk_in * u["from_start"] * kf) \
+                + lanes_sum(dv_in * vf)
+            by_end = lanes_sum(dk_out * kf) * u["to_end"]
+            dcum = lanes_sum(by_decay) - by_end + u["from_start"] * (
+                lanes_sum(dq_in * qf) + beta * lanes_sum(dk_in * kf))
+            # G_L is the cumulative sum at the chunk's last position
+            for c, rows in enumerate(chunks):
+                tail = u["tails"][c] + jnp.sum(by_end[rows], axis=0,
+                                               keepdims=True)
+                dcum = jnp.where(row == rows.stop - 1, dcum + tail, dcum)
+            dcols = jnp.where(lane == 8 * u["h"], dcum, dcols)
+            dcols = jnp.where(lane == 8 * u["h"] + 1, dbeta, dcols)
+            u["dcum_row"] = -jnp.sum(by_decay, axis=0, keepdims=True)
+        drows = dcols.T
+        for u in here:
+            drows_ref[0, u["h"], :, u["at"]] = \
+                drows[8 * u["h"]:8 * u["h"] + 8] + jnp.where(
+                    first, u["dcum_row"], 0.0)
+    for h in range(heads):
+        dstate_ref[h] = dstates[h]
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _delta_bwd(q2, k2, v2, rows, do2, states, inverses, tile, heads,
+               interpret):
+    b, t, wide = q2.shape
+    h, nt = wide // _LANES, t // tile
+    spec = _delta_specs(tile, heads, nt, True)
+    like = (q2, k2, v2, rows, do2)
+    pairs = b * h * t // _DELTA_PAIR
+    return _named_call(
+        "delta_bwd",
+        functools.partial(_delta_bwd_kernel, tile=tile, heads=heads),
+        grid=(b, h // heads, nt),
+        in_specs=[spec["x"], spec["x"], spec["x"], spec["rows"], spec["x"],
+                  spec["states"], spec["inverses"]],
+        out_specs=[spec["x"], spec["x"], spec["x"], spec["rows"]],
+        out_shape=[_struct(q2.shape, q2.dtype, *like)] * 3
+        + [_struct(rows.shape, jnp.float32, *like)],
+        scratch_shapes=[pltpu.VMEM((heads, _LANES, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_DELTA_VMEM),
+        cost_estimate=pl.CostEstimate(
+            flops=pairs * 2 * _DELTA_PAIR ** 3 * (2 * 6 + 26),
+            transcendentals=pairs * _DELTA_PAIR ** 2,
+            bytes_accessed=7 * q2.size * q2.dtype.itemsize
+            + 2 * rows.size * 4 + (states.size + inverses.size) * 4),
+        interpret=interpret,
+    )(q2, k2, v2, rows, do2, states, inverses)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _delta_core(q2, k2, v2, rows, tile, heads):
+    return _delta_fwd(q2, k2, v2, rows, tile, heads, False, _interpret())
+
+
+def _delta_core_fwd(q2, k2, v2, rows, tile, heads):
+    o2, states, inverses = _delta_fwd(q2, k2, v2, rows, tile, heads, True,
+                                      _interpret())
+    return o2, (q2, k2, v2, rows, states, inverses)
+
+
+def _delta_core_bwd(tile, heads, saved, do2):
+    # (the backward pass's operations carry the name stack of the call
+    # site, ``.../mixer/delta_rule`` as ``ops/gated_delta.py`` calls this:
+    # ``delta_rule_ms`` reads the scope, and tests/test_tpu_lowering.py
+    # holds the path)
+    q2, k2, v2, rows, states, inverses = saved
+    return tuple(_delta_bwd(q2, k2, v2, rows, do2, states, inverses, tile,
+                            heads, _interpret()))
+
+
+_delta_core.defvjp(_delta_core_fwd, _delta_core_bwd)
+
+
+def delta_rule(q, k, v, g, beta):
+    """``ops/gated_delta.gated_delta_chunked``'s rule on the kernels above,
+    for operands ``delta_supported`` admits: the same mathematics at the
+    same precision (matmul operands in ``q.dtype`` with float32
+    accumulation; ``g``, its sums, every ``exp``, the system and its
+    inverse float32, the inverse's products at full precision; every
+    exponent <= 0), the state between chunks and tiles float32 and cast at
+    the MXU's operand only. The caller opens the ``delta_rule`` scope."""
+    b, t, h, _ = q.shape
+    f32 = jnp.float32
+    route = delta_route(t, h, q.shape[-1], v.shape[-1], q.dtype.itemsize)
+    tile, heads = route["tile"], route["heads"]
+    pad = -t % tile
+    q2, k2, v2 = (a.reshape(b, t, h * _LANES) for a in (q, k, v))
+    g, beta = g.astype(f32), beta.astype(f32)
+    if pad:
+        # steps of g = 0, beta = 0 leave the state alone
+        q2, k2, v2, g, beta = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+                               for a in (q2, k2, v2, g, beta))
+    # the cumulative sum inside a chunk as a product with a triangle of
+    # ones, as ``ssd_scan`` forms its own
+    sums = jnp.tril(jnp.ones((_DELTA_CHUNK, _DELTA_CHUNK), f32))
+    cum = jnp.einsum("ls,bcsh->bclh", sums,
+                     g.reshape(b, -1, _DELTA_CHUNK, h),
+                     precision="highest").reshape(g.shape)
+    rows = jnp.pad(jnp.stack([cum, beta], axis=2),      # [b, T, 2, H]
+                   ((0, 0), (0, 0), (0, 6), (0, 0))).transpose(0, 3, 2, 1)
+    o2 = _delta_core(q2, k2, v2, rows, tile, heads)
+    return o2[:, :t].reshape(v.shape)
+
+
 # ------------------------------------------------------------- path gates
 # dispatcher name -> shape gate over the dispatcher's operands; the only
 # reader is kernel_path above (which adds the mode and vma conditions)
@@ -2750,4 +3303,6 @@ _GATES = {
     "grouped_outer": lambda lhs, rhs: _grouped_ok(lhs, rhs.shape[1], rhs),
     # x [b, T, H, P] and B [b, T, N] or [b, T, G, N]
     "ssd_scan": ssd_supported,
+    # q, k [b, T, H, K] and v [b, T, H, V]
+    "gated_delta": delta_supported,
 }

@@ -210,6 +210,207 @@ def test_bf16_operands_accumulate_in_float32_and_return_bf16():
     assert 1e-4 < relative(got.astype(jnp.float32), want) < 2e-2
 
 
+# --------------------------------- the kernel pair against the recurrence
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The Pallas pair through the interpreter: ``delta_fwd`` and
+    ``delta_bwd``'s own bodies on the CPU."""
+    monkeypatch.setenv("HVD_PALLAS", "interpret")
+
+
+def wide(t, heads=2, **kw):
+    """Operands the route admits: heads a lane width each way."""
+    return operands(t, b=1, heads=heads, key_dim=128, value_dim=128, **kw)
+
+
+def kernels(*ops):
+    assert pk.kernel_path("gated_delta", *ops[:3]) == "pallas"
+    return gated_delta.gated_delta_chunked(*ops)
+
+
+#: t, heads, and whether a key head serves two value heads
+PAIR_CASES = {"one_tile_of_one_chunk": (40, 2, False),
+              "one_tile_of_a_pair": (128, 1, False),
+              "several_tiles": (600, 4, False),
+              "a_padded_length": (300, 3, False),
+              "two_value_heads_to_a_key_head": (200, 4, True)}
+
+
+@pytest.mark.parametrize("case", sorted(PAIR_CASES))
+def test_the_kernel_pair_is_the_recurrence_values_and_gradients(
+        case, interpreted):
+    """``delta_fwd`` (and the variant that saves the tiles' entering states
+    and the chunks' inverses) and ``delta_bwd`` against the literal
+    recurrence, float32 operands: ``o`` and the gradients of all five
+    operands. Under one chunk (the pair's second is padding), one pair
+    exactly, three tiles of 256 (the last padded, four heads a cell), 300
+    over 256 at three heads (one a cell), and q and k handed to two
+    consecutive value heads each as the mixer hands them, whose gradients
+    leave at the heads they came in at and are summed by the repeat's."""
+    t, heads, shared = PAIR_CASES[case]
+    route = pk.delta_route(t, heads, 128, 128, 4)
+    assert route == {"path": "pallas", "tile": 128 if t <= 128 else 256,
+                     "heads": {1: 1, 2: 2, 3: 1, 4: 4}[heads]}
+    ops = wide(t, heads)
+    if shared:
+        ops = tuple(a[:, :, ::2] for a in ops[:2]) + ops[2:]
+
+    def through(fn):
+        def run(q, k, v, g, beta):
+            if shared:
+                q, k = (jnp.repeat(a, 2, axis=2) for a in (q, k))
+            return fn(q, k, v, g, beta)
+        return run
+
+    got, want = through(kernels)(*ops), through(reference.delta_rule)(*ops)
+    assert got.shape == want.shape == (1, t, heads, 128)
+    assert got.dtype == ops[0].dtype and relative(got, want) <= 2e-6
+    (_, grads), (_, ref_grads) = (_with_grads(through(kernels), ops),
+                                  _with_grads(through(reference.delta_rule),
+                                              ops))
+    for name, a, b in zip("qkvgb", grads, ref_grads):
+        assert a.shape == b.shape and relative(a, b) <= 1e-5, name
+
+
+def test_the_state_crosses_tile_boundaries_in_vmem(interpreted):
+    """The kernels' state from one tile to the next: with no decay and a
+    first key that nothing later overlaps, the value written at position 0
+    is read back whole at position 599, two tiles and nine chunks on; and
+    its gradient comes back to ``v`` at position 0 through the backward
+    kernel's state."""
+    q, k, v, g, beta = wide(600)
+    first = jnp.zeros_like(k[:, 0]).at[..., 0].set(1.0)
+    k = k.at[..., 0].set(0.0)
+    k = (k / jnp.linalg.norm(k, axis=-1, keepdims=True)).at[:, 0].set(first)
+    q = q.at[:, 599].set(first)
+    g, beta = 0.0 * g, jnp.ones_like(beta)
+    out = kernels(q, k, v, g, beta)
+    np.testing.assert_allclose(out[:, 599], v[:, 0], rtol=1e-5, atol=1e-5)
+    assert relative(out, reference.delta_rule(q, k, v, g, beta)) <= 2e-6
+    dv = jax.grad(lambda v: jnp.sum(kernels(q, k, v, g, beta)[:, 599]))(v)
+    np.testing.assert_allclose(dv[:, 0], jnp.ones_like(dv[:, 0]),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_the_kernels_neither_overflow_nor_lose_the_rule_under_a_strong_decay(
+        interpreted):
+    """``g`` down to -4,000 a position: every exponent the kernels take is
+    <= 0 (the mask goes in before the ``exp``, a decay runs from a chunk's
+    start or to its end), so nothing overflows, no NaN in value or
+    gradient, and the state is forgotten as the recurrence forgets it."""
+    q, k, v, g, beta = wide(200)
+    g = 1000.0 * g
+    got, want = kernels(q, k, v, g, beta), reference.delta_rule(q, k, v, g,
+                                                                beta)
+    assert bool(jnp.all(jnp.isfinite(got))) and relative(got, want) <= 2e-6
+    _, grads = _with_grads(kernels, (q, k, v, g, beta))
+    assert all(bool(jnp.all(jnp.isfinite(a))) for a in grads)
+
+
+def test_the_kernels_special_cases_and_repeated_keys(interpreted):
+    """``beta = 0`` writes nothing; ``g = 0`` is the plain delta rule;
+    one key repeated with ``beta = 1`` (the system all ones under the
+    diagonal, whose inverse is bidiagonal: forward substitution whatever
+    the keys) reads back the last value written; keys that never overlap
+    leave gated linear attention."""
+    q, k, v, g, beta = wide(140)
+    assert not np.any(np.asarray(kernels(q, k, v, g, 0.0 * beta)))
+    assert relative(kernels(q, k, v, 0.0 * g, beta),
+                    reference.delta_rule(q, k, v, 0.0 * g, beta)) <= 2e-6
+    same = jnp.broadcast_to(k[:, :1], k.shape)
+    read_back = kernels(same, same, v, 0.0 * g, jnp.ones_like(beta))
+    np.testing.assert_allclose(read_back, v, rtol=1e-4, atol=1e-4)
+    eye = jnp.broadcast_to(jnp.eye(128, dtype=q.dtype)[None, :, None, :],
+                           (1, 128, 2, 128))
+    q128, _, v128, g128, beta128 = wide(128)
+    assert relative(kernels(q128, eye, v128, g128, beta128),
+                    _linear_attention(q128, eye, v128, g128, beta128)) <= 2e-6
+    # (random keys of 128 overlap by 0.09, those of 16 by 0.25: 0.08 here)
+    assert relative(kernels(q, k, v, 0.1 * g, beta),
+                    _linear_attention(q, k, v, 0.1 * g, beta)) > 0.05
+
+
+def test_the_kernels_bf16_operands_stay_by_the_chunked_form(interpreted,
+                                                            monkeypatch):
+    """bf16 operands: the kernels round where the XLA form rounds (the
+    inverse, ``W``, the corrected values and the masked scores cast at the
+    MXU's operand, the state float32), so ``o`` is the chunked form's to a
+    bf16 rounding or two, and as far from the float32 recurrence as it
+    is; the gradients, whose cotangents the kernel keeps float32 for
+    longer, within a bf16 rounding's reach of the XLA form's."""
+    ops = wide(300, dtype=jnp.bfloat16)
+    got = kernels(*ops)
+    (_, grads) = _with_grads(kernels, ops)
+    monkeypatch.setenv("HVD_PALLAS", "0")
+    assert pk.kernel_path("gated_delta", *ops[:3]) == "reference"
+    form = gated_delta.gated_delta_chunked(*ops)
+    (_, form_grads) = _with_grads(gated_delta.gated_delta_chunked, ops)
+    assert got.dtype == form.dtype == jnp.bfloat16
+    f32 = jnp.float32
+    assert relative(got.astype(f32), form.astype(f32)) <= 3e-3
+    want = reference.delta_rule(*ops)
+    assert 1e-4 < relative(got.astype(f32), want) < 2e-2
+    for name, a, b in zip("qkvgb", grads, form_grads):
+        assert a.dtype == b.dtype
+        assert relative(a.astype(f32), b.astype(f32)) <= 2e-2, name
+
+
+# ------------------------------------------------ which path a call takes
+#: key width, value width, dtype, HVD_PALLAS -> the path
+ROUTE_CASES = {
+    "published_on": (128, 128, jnp.bfloat16, "on", "pallas"),
+    "published_interpreted": (128, 128, jnp.bfloat16, "interpret", "pallas"),
+    "published_float32": (128, 128, jnp.float32, "on", "pallas"),
+    "published_off": (128, 128, jnp.bfloat16, "0", "reference"),
+    "published_off_the_chip": (128, 128, jnp.bfloat16, "", "reference"),
+    "no_lane_width_of_keys": (64, 128, jnp.bfloat16, "on", "reference"),
+    "no_lane_width_of_values": (128, 96, jnp.bfloat16, "on", "reference"),
+    "two_lane_widths": (256, 256, jnp.bfloat16, "on", "reference"),
+    "eight_byte_elements": (128, 128, jnp.float64, "on", "reference")}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTE_CASES))
+def test_kernel_path_says_which_rule_runs(case, monkeypatch):
+    """The published shape (32 heads of 128 / 128 over 16,384 positions,
+    bf16) on the chip, interpreted, switched off and off the chip; what the
+    route refuses: a head that is not one lane width each way, 8-byte
+    elements."""
+    dk, dv, dtype, env, path = ROUTE_CASES[case]
+    monkeypatch.setenv("HVD_PALLAS", env)
+    q, k, v = (jax.ShapeDtypeStruct((1, 16384, 32, d), dtype)
+               for d in (dk, dk, dv))
+    assert pk.kernel_path("gated_delta", q, k, v) == path
+    route = pk.delta_route(16384, 32, dk, dv, jnp.dtype(dtype).itemsize)
+    assert (route["path"] == "pallas") == (path == "pallas"
+                                           or env in ("0", ""))
+    if route["path"] == "pallas":
+        assert route == {"path": "pallas", "tile": 256, "heads": 8}
+    else:
+        assert route == {"path": "reference", "tile": None, "heads": None}
+
+
+def test_varying_operands_are_not_the_kernels(monkeypatch):
+    """Under ``shard_map(check_vma=True)`` a ``pallas_call`` cannot meet
+    the checker's rules, so ``kernel_path`` hands varying operands to the
+    XLA form; the same call with the check off keeps the kernels."""
+    from jax.sharding import PartitionSpec as P
+
+    monkeypatch.setenv("HVD_PALLAS", "interpret")
+    mesh = jax.make_mesh((2,), ("x",), devices=jax.devices()[:2])
+    ops = operands(16, b=2, heads=1, key_dim=128, value_dim=128)
+    seen = []
+
+    def body(*a):
+        seen.append(pk.kernel_path("gated_delta", *a[:3]))
+        return kernels(*a) if seen[-1] == "pallas" else a[2]
+
+    for check, path in ((True, "reference"), (False, "pallas")):
+        out = jax.shard_map(body, mesh=mesh, in_specs=P("x"),
+                            out_specs=P("x"), check_vma=check)(*ops)
+        assert seen[-1] == path
+    assert relative(out, reference.delta_rule(*ops)) <= 2e-6
+
+
 def test_the_gated_norm_in_its_other_order():
     y = jax.random.normal(jax.random.PRNGKey(0), (2, 5, 4, 32))
     gate = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 4, 32))
@@ -644,6 +845,36 @@ def test_a_recomputed_model_agrees():
     assert worst_leaf(grads, base_grads)[1] <= 1e-4
 
 
+def test_a_recomputed_model_agrees_on_the_kernel_path(interpreted,
+                                                      monkeypatch):
+    """The same with heads the route admits (two of 128 / 128 to a key
+    head; one delta-rule layer and one of attention) and the kernels through the interpreter:
+    under ``remat="full"`` the recomputed block runs the forward that saves
+    and the backward kernel reads what it saved; and the model with the
+    kernels is the model without."""
+    wide_config = dict(CONFIG, num_hidden_layers=2, full_attention_interval=2,
+                       linear_num_key_heads=1, linear_num_value_heads=2,
+                       linear_key_head_dim=128, linear_value_head_dim=128)
+    toks, targets = tokens(72, batch=1)
+    params = model(config=wide_config).init(jax.random.PRNGKey(1),
+                                            toks)["params"]
+
+    def loss_and_grads(remat):
+        m = model(remat=remat, config=wide_config)
+        return jax.jit(jax.value_and_grad(lambda p: lm_loss(
+            m.apply({"params": p}, toks), targets)))(params)
+
+    base_loss, base_grads = loss_and_grads("none")
+    loss, grads = loss_and_grads("full")
+    assert abs(float(loss) - float(base_loss)) <= 1e-6 * float(base_loss)
+    assert worst_leaf(grads, base_grads)[1] <= 1e-4
+    monkeypatch.setenv("HVD_PALLAS", "0")
+    jax.clear_caches()
+    form_loss, form_grads = loss_and_grads("full")
+    assert abs(float(loss) - float(form_loss)) <= 1e-5 * float(form_loss)
+    assert worst_leaf(grads, form_grads)[1] <= 2e-4
+
+
 def test_three_train_steps_on_the_mesh_reproduce_the_reference_losses():
     """``spmd.make_train_step`` + ``lm_loss`` + the job's AdamW as
     ``chipbench/jobs/train_lm.build`` calls them, batch 8 over the 8-device
@@ -799,7 +1030,9 @@ def test_the_cell_is_sized_and_declared():
     layers = cell.config["num_hidden_layers"]
     # two flash kernels a full layer (flash_fwd, whose output remat full
     # keeps, and flash_bwd); nine grouped products a routed layer and
-    # capacity; the delta rule has no kernel
+    # capacity: the benchmark file's reading, PR 46's, when the delta rule
+    # had no kernel (166 with PR 47's three a delta-rule layer, which
+    # tests/test_tpu_lowering.py counts; the file is the benchmark's)
     assert step["pallas_calls"] == 2 * (layers // 4) + layers * 2 * 9
     declared = harness.declared_metrics(cell.name)
     names = {m["name"] for m in declared["per_layer"]}

@@ -984,3 +984,62 @@ def test_gated_delta_and_gated_attention_sites_are_named_and_classed(
         assert trace_reduce.classify(
             f"tpu_custom_call %{instruction}", op_classes) \
             == "attention_kernel"
+
+
+@pytest.mark.parametrize("remat", ["none", "full"])
+def test_delta_rule_kernels_sit_under_the_delta_rule_scope(remat,
+                                                           v5e_devices):
+    """One period of the Qwen3-Next family with heads the route admits
+    (four value heads of 128 / 128 over two key heads; 512 positions, two
+    tiles a head cell), compiled for a v5e: a delta-rule layer's rule is
+    ``delta_fwd`` in the forward pass, again in a recomputed block
+    (``remat="full"`` keeps a block's input; the recomputed call is the
+    one that saves the tiles' entering states and the chunks' inverses)
+    and ``delta_bwd``; every one of them sits under
+    ``block_<i>/mixer/delta_rule``, the backward kernel too, which
+    ``delta_rule_ms`` and ``delta_rule_roofline`` read as they are (a
+    kernel outside the scope would read as a gain) and the scope classes
+    book to the blocks' three passes; and nothing of the XLA form is left:
+    no loop over chunks, no chunk's system or state in HBM."""
+    from chipbench import op_scopes, trace_reduce
+    from chipbench.families import qwen3_next
+    from chipbench.layer_metrics import delta_rule_ms
+    from horovod_tpu.ops import pallas_kernels as pk
+    from tests.test_qwen3_next import CONFIG
+
+    config = dict(CONFIG, linear_key_head_dim=128, linear_value_head_dim=128)
+    operand = jax.ShapeDtypeStruct((1, 512, 4, 128), jnp.bfloat16)
+    assert pk.kernel_path("gated_delta", operand, operand, operand) \
+        == "pallas"
+    scope_classes = trace_reduce.load_classes(op_scopes.SCOPE_CLASSES)
+    model = qwen3_next.build_model(config, 512, {"remat": remat})
+    text = _model_grad_text(model, 512, v5e_devices)
+    calls = _kernel_calls(text, "delta_fwd|delta_bwd")
+    layers, forwards = 3, 2 if remat == "full" else 1
+    assert sorted(name for name, _, _ in calls) == \
+        ["delta_bwd"] * layers + ["delta_fwd"] * layers * forwards
+    for name, _, path in calls:
+        assert re.search(delta_rule_ms.PATTERN, path), path
+        block, = re.findall(
+            r"block_(\d)/mixer/delta_rule/jit\(_" + name + r"\)/" + name,
+            path)
+        assert block in "012"
+        recomputed = bool(re.search(r"rematted_computation/block_\d", path))
+        assert ("transpose(" in path) == (name == "delta_bwd" or recomputed)
+        assert trace_reduce.classify(path, scope_classes) == (
+            "blocks_recompute" if recomputed else
+            "blocks_bwd" if name == "delta_bwd" else "blocks_fwd")
+    for block in range(layers):
+        assert sum(f"block_{block}/" in path for _, _, path in calls) \
+            == 1 + forwards
+    assert sum("rematted_computation" in path for _, _, path in calls) \
+        == (layers if remat == "full" else 0)
+    # what XLA keeps inside the scope is there in every pass, and no loop
+    paths = re.findall(r'op_name="([^"]*/mixer/delta_rule/[^"]*)"', text)
+    assert not [p for p in paths if "while" in p]
+    assert {trace_reduce.classify(p, scope_classes) for p in paths} == {
+        "blocks_fwd", "blocks_bwd"} | ({"blocks_recompute"}
+                                       if remat == "full" else set())
+    # the saved states a tile and inverses a pair, and no chunk's system
+    assert "f32[1,2,4,128,128]" in text and "f32[1,4,256,128]" in text
+    assert not re.search(r"f32\[\d+,1,4,64,64\]", text)
