@@ -334,6 +334,17 @@ def cotangent(shape):
         for axis, c in enumerate((0.11, 0.37, 0.71, 1.3)[-len(shape):])))
 
 
+def kernels_off(fn):
+    """``fn`` traced with the Pallas kernels off: a dispatcher's reference
+    path (the XLA form, ``ragged_dot``) on the same operands."""
+    from unittest import mock
+
+    def traced_off(*operands):
+        with mock.patch.dict(os.environ, HVD_PALLAS="0"):
+            return fn(*operands)
+    return traced_off
+
+
 def kernel_cases():
     """name -> :class:`KernelCase` for every public single-device kernel.
     ``tests/test_tpu_lowering.py`` lowers the same list without a chip."""
@@ -514,11 +525,19 @@ def grouped_cases():
         scores = np.random.RandomState(0).gumbel(size=(tokens, experts))
         scores[:, :held] += 0.75
         chosen = np.argsort(-scores, axis=1)[:, :top_k]
-        assert 5120 < (chosen < held).sum() <= capacity
-        _, token, slots, valid, back = moe._rows_at(
-            capacity, top_k, *moe.dispatch(jnp.asarray(chosen, jnp.int32),
-                                           tuple(range(held)), experts))
-        return jnp.where(valid, rows, 0), token, slots, back
+        here = int((chosen < held).sum())
+        assert 5120 < here <= capacity
+        order, per_token, group_sizes = moe.dispatch(
+            jnp.asarray(chosen, jnp.int32), tuple(range(held)))
+        _, token, valid, back = moe._rows_at(capacity, top_k, order,
+                                             per_token, group_sizes)
+        # the plain sum's index, which the stage never builds: the sorted
+        # row of each of a token's assignments, ``capacity`` where none here
+        inverse = np.argsort(np.argsort(np.where(chosen < held, chosen, held
+                                                 ).ravel(), kind="stable"))
+        slots = np.where(inverse < here, inverse, capacity)
+        return (jnp.where(valid, rows, 0), token,
+                jnp.asarray(slots.reshape(tokens, top_k), jnp.int32), back)
 
     def segment_sum(rows):
         rows, token, _, back = way_back(rows)
@@ -547,7 +566,50 @@ def grouped_cases():
             lambda lhs, rhs: jnp.stack([
                 dot(lhs[a:b], rhs[a:b], ((0,), (0,))) for a, b in spans]),
             TOL_BF16),
+        **routed_layer_cases(),
     }
+
+
+def routed_layer_cases():
+    """``ops/moe.routed_ffn`` and its four gradients at a routed layer of
+    the benchmark's Qwen3-Next cell (16,384 tokens 2048 wide, top-10 of 512
+    softmax experts 512 wide, 16 held), at each of its two row capacities:
+    a router that spreads its tokens (about 5,000 rows of the 25,600) and
+    one whose selection bias sends every assignment to the experts held
+    (163,840 rows, the worst case). The kernels against the same call with
+    kernels off, which is the ``ragged_dot`` path; nine grouped products a
+    capacity, both capacities in each program. The operands are drawn as
+    every case's are and scaled here to a layer's ranges."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import moe
+
+    tokens, d, width, experts, held, top_k = 16384, 2048, 512, 512, 16, 10
+    assert moe.capacities(tokens * top_k, held, experts) == (25600, 163840)
+
+    def layer(prefer_held):
+        bias = jnp.zeros((experts,), jnp.float32).at[:held].set(prefer_held)
+
+        def f(h, router, w_in, w_out):
+            def loss(*a):
+                y, _, _, load = moe.routed_ffn(
+                    a[0], a[1], bias, a[2], a[3], held=tuple(range(held)),
+                    top_k=top_k, scoring="softmax")
+                y = y.astype(jnp.float32)
+                return jnp.sum(y * cotangent(y.shape)), (y, load)
+            grads, (y, load) = jax.grad(loss, argnums=range(4), has_aux=True)(
+                h / 3, router / 60, w_in / 100, w_out / 50)
+            return y, grads, load[:held]
+        return f
+
+    s = jax.ShapeDtypeStruct
+    args = [s((tokens, d), jnp.bfloat16), s((d, experts), jnp.float32),
+            s((held, d, 2 * width), jnp.float32),
+            s((held, width, d), jnp.float32)]
+    return {f"moe routed_ffn fwd+bwd 16 of 512 held, {name}": KernelCase(
+        layer(prefer), args, 18, kernels_off(layer(prefer)), TOL_BF16)
+        for name, prefer in (("rows spread", 0.0), ("every row here", 10.0))}
 
 
 def scan_cases():
@@ -557,8 +619,6 @@ def scan_cases():
     what the same call runs with kernels off. The operands are drawn as
     every case's are and brought into the scan's ranges here: steps
     ``softplus`` of a normal, rates ``-exp`` of one."""
-    from unittest import mock
-
     import jax
     import jax.numpy as jnp
 
@@ -576,12 +636,6 @@ def scan_cases():
                 x, dt, A, B / 3.0, C / 3.0, D)
             return y, grads
         return f
-
-    def kernels_off(fn):
-        def traced_off(*operands):
-            with mock.patch.dict(os.environ, HVD_PALLAS="0"):
-                return fn(*operands)
-        return traced_off
 
     def case(heads, groups, chunk):
         t, p, n = 4096, 64, 128
@@ -607,8 +661,6 @@ def delta_cases():
     and brought into the rule's ranges here: keys of unit length, queries
     a ``128 ** -0.5`` of it, decays ``-softplus`` of a normal, write
     strengths its sigmoid."""
-    from unittest import mock
-
     import jax
     import jax.numpy as jnp
 
@@ -629,14 +681,10 @@ def delta_cases():
             jax.nn.sigmoid(beta))
         return o, grads
 
-    def kernels_off(*operands):
-        with mock.patch.dict(os.environ, HVD_PALLAS="0"):
-            return rule(*operands)
-
     s, t, h = jax.ShapeDtypeStruct, 4096, 32
     return {"gated_delta fwd+bwd 32 heads of 128": KernelCase(
         rule, [s((1, t, h, 128), jnp.bfloat16)] * 3
-        + [s((1, t, h), jnp.float32)] * 2, 2, kernels_off, TOL_BF16)}
+        + [s((1, t, h), jnp.float32)] * 2, 2, kernels_off(rule), TOL_BF16)}
 
 
 def matmul_reduce_scatter_case(mesh):

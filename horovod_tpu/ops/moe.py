@@ -16,15 +16,22 @@ experts held elsewhere add is their chips' to compute and to send: nothing
 here stands in for them, and on one chip the layer runs without its
 exchange.
 
-No capacity that drops and no one-hot dispatch over experts x tokens: the
-(token, expert) assignments are sorted by expert, the rows of the experts
-held gathered in that order, the two matrix products done as **grouped**
-products over the experts' row ranges, and the rows summed back into their
-tokens: put in token order (a sort of the stage's rows, not of the
-assignments) and summed tile of tokens by tile as one more grouped product,
-a one-hot of each row's place in its tile against the rows
-(:func:`put_rows`), so that the way back costs by the rows held here and not
-by every assignment. Shapes are static: the expert stage is compiled at two
+No capacity that drops and no one-hot dispatch over experts x tokens: each
+(token, expert) assignment's group is its expert's place among those held
+(a range test, elementwise), one stable sort of those keys puts the
+assignments in order, those held here first, and **everything after it is
+built over the rows held here**: the first ``rows`` places of the order
+are the stage's rows, gathered in that order, the two matrix products are
+done as **grouped** products over the experts' row ranges, and the rows
+are summed back into their tokens: put in token order (a sort of the
+stage's rows, not of the assignments) and summed tile of tokens by tile as
+one more grouped product, a one-hot of each row's place in its tile against
+the rows (:func:`put_rows`). The routing weights' gradient is placed at the
+rows' assignments. Of all ``tokens * k`` assignments nothing is gathered,
+nothing scattered and no inverse of the order made (:func:`dispatch`), so
+that the stage costs by the rows held here and not by every assignment
+(31 in 32 of them are other chips' where a 32nd of the experts is held).
+Shapes are static: the expert stage is compiled at two
 row counts, up to the worst case, every assignment landing here
 (``tokens * k`` rows), and a step runs the smallest that holds its rows
 (:func:`capacities`), so that the cost follows the rows really routed here.
@@ -102,25 +109,47 @@ def route(logits, bias, top_k: int, scale: float = 1.0,
     return chosen, weights if scale == 1.0 else scale * weights, scores
 
 
-def dispatch(chosen, held: Sequence[int], num_experts: int):
-    """Sort the ``N * k`` (token, expert) assignments by expert, those of
+def _runs(held: Sequence[int]):
+    """``held`` as its maximal runs of consecutive ids: ``[first id, its
+    place in held, length]`` of each (one run for a chip's contiguous
+    share, and for the layer held whole)."""
+    runs = []
+    for place, expert in enumerate(held):
+        if runs and expert == runs[-1][0] + runs[-1][2]:
+            runs[-1][2] += 1
+        else:
+            runs.append([expert, place, 1])
+    return runs
+
+
+def dispatch(chosen, held: Sequence[int]):
+    """Order the ``N * k`` (token, expert) assignments by expert, those of
     the experts held here first, in the order of ``held``.
 
-    Returns ``(order, inverse, group_sizes)``: ``order[r]`` is the
+    Returns ``(order, per_token, group_sizes)``: ``order[r]`` is the
     assignment (``token * k + slot``) that sorted row ``r`` holds,
-    ``inverse`` its inverse permutation, and ``group_sizes [len(held)]`` the
-    rows of each expert held. Rows from ``sum(group_sizes)`` on belong to
-    experts held elsewhere."""
-    local = np.full((num_experts,), len(held), np.int32)
-    local[list(held)] = np.arange(len(held), dtype=np.int32)
-    group = jnp.asarray(local)[chosen.reshape(-1)]           # [N * k]
-    order = jnp.argsort(group, stable=True).astype(jnp.int32)
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=jnp.int32), unique_indices=True)
+    ``per_token [N]`` the rows each token has here and ``group_sizes
+    [len(held)]`` the rows of each expert held. Rows from
+    ``sum(group_sizes)`` on belong to experts held elsewhere.
+
+    What walks all ``N k`` assignments is elementwise, a count or the one
+    stable sort of their keys (0.15 ms for 163,840 on a v5e, where a gather
+    or a scatter of as many scalars is 0.8-1.3: PERF.md, PR 48): an
+    assignment's group is its expert's place in ``held``, found by a range
+    test against each run of consecutive ids (``len(held)`` for an expert
+    held elsewhere), not looked up in a table of ``num_experts``; nothing
+    is gathered, and no inverse of ``order`` is made (a row finds its
+    assignment, ``_rows_at``; an assignment never looks for its row)."""
+    group = jnp.full(chosen.shape, len(held), jnp.int32)
+    for first, place, length in _runs(held):
+        inside = (chosen >= first) & (chosen < first + length)
+        group = jnp.where(inside, chosen - (first - place), group)
+    order = jnp.argsort(group.reshape(-1), stable=True).astype(jnp.int32)
+    per_token = jnp.sum(group < len(held), axis=1, dtype=jnp.int32)
     group_sizes = jnp.sum(
-        group[:, None] == jnp.arange(len(held), dtype=jnp.int32), axis=0,
-        dtype=jnp.int32)
-    return order, inverse, group_sizes
+        group.reshape(-1, 1) == jnp.arange(len(held), dtype=jnp.int32),
+        axis=0, dtype=jnp.int32)
+    return order, per_token, group_sizes
 
 
 def capacities(assignments: int, held: int, num_experts: int) -> Tuple[int, ...]:
@@ -264,33 +293,30 @@ put_rows.defvjp(
     lambda res, g: (take_rows(g, *res), None, None))
 
 
-def _rows_at(rows: int, top_k: int, order, inverse, group_sizes):
-    """Of the first ``rows`` sorted rows: ``(picked, token, slots, valid,
-    back)``, the assignment and the token each holds, the sorted row of
-    each of a token's assignments (``[N, k]``, ``rows`` where it has none
-    here), ``[rows, 1]`` whether the row is in a group here, and the way
-    back to token order (:func:`put_rows`' ``back``)."""
+def _rows_at(rows: int, top_k: int, order, per_token, group_sizes):
+    """Of the first ``rows`` sorted rows: ``(picked, token, valid, back)``,
+    the assignment and the token each holds, ``[rows, 1]`` whether the row
+    is in a group here (an assignment held elsewhere has no row here,
+    whatever its place in the sorted order: the rows from
+    ``sum(group_sizes)`` on are in no group), and the way back to token
+    order (:func:`put_rows`' ``back``). Nothing here is sized by the
+    ``N k`` assignments: ``rows`` of ``order`` are read."""
     picked = order[:rows]
     token = picked // top_k
-    here = jnp.sum(group_sizes)
-    # an assignment held elsewhere has no row here, whatever its place in
-    # the sorted order: the rows from ``here`` on are in no group
-    mine = (inverse < here).reshape(-1, top_k)
-    slots = jnp.where(mine, inverse.reshape(-1, top_k), rows)
-    valid = jnp.arange(rows, dtype=jnp.int32) < here
+    valid = jnp.arange(rows, dtype=jnp.int32) < jnp.sum(group_sizes)
     # (a sort of ``rows`` keys, not of the N k assignments again)
-    by_token = jnp.argsort(jnp.where(valid, token, mine.shape[0]))
-    back = (by_token.astype(jnp.int32), jnp.sum(mine, axis=1, dtype=jnp.int32))
-    return picked, token, slots, valid[:, None], back
+    by_token = jnp.argsort(jnp.where(valid, token, per_token.shape[0]))
+    return picked, token, valid[:, None], (by_token.astype(jnp.int32),
+                                          per_token)
 
 
-def _experts_at(rows: int, h, w_in, w_out, weights, order, inverse,
+def _experts_at(rows: int, h, w_in, w_out, weights, order, per_token,
                 group_sizes, activation: str = "swiglu"):
     """The expert stage at a static capacity of ``rows`` sorted rows, which
     must hold every row of the experts here (``sum(group_sizes) <= rows``)."""
     with jax.named_scope("dispatch"):
-        picked, token, _, valid, back = _rows_at(
-            rows, weights.shape[1], order, inverse, group_sizes)
+        picked, token, valid, back = _rows_at(
+            rows, weights.shape[1], order, per_token, group_sizes)
         x = take_rows(h, token, back)
     with jax.named_scope("experts"):
         first = grouped_matmul(x, w_in.astype(h.dtype), group_sizes)
@@ -308,7 +334,7 @@ def _experts_at(rows: int, h, w_in, w_out, weights, order, inverse,
         return put_rows(weighted.astype(h.dtype), token, back)
 
 
-def _experts_bwd_at(rows: int, h, w_in, w_out, weights, order, inverse,
+def _experts_bwd_at(rows: int, h, w_in, w_out, weights, order, per_token,
                     group_sizes, dy, activation: str = "swiglu"):
     """The gradients of :func:`_experts_at` in ``h``, ``w_in``, ``w_out``
     and ``weights`` for the cotangent ``dy`` of its result, at the same
@@ -325,8 +351,8 @@ def _experts_bwd_at(rows: int, h, w_in, w_out, weights, order, inverse,
     dtype = h.dtype
     wide = jnp.promote_types(dtype, jnp.float32)    # between the products
     with jax.named_scope("dispatch"):
-        picked, token, slots, valid, back = _rows_at(
-            rows, weights.shape[1], order, inverse, group_sizes)
+        picked, token, valid, back = _rows_at(
+            rows, weights.shape[1], order, per_token, group_sizes)
         x = take_rows(h, token, back)
     with jax.named_scope("combine"):
         g = take_rows(dy, token, back)
@@ -360,7 +386,10 @@ def _experts_bwd_at(rows: int, h, w_in, w_out, weights, order, inverse,
         d_x = grouped_matmul_t(d_first, w1, group_sizes)
         d_w1 = grouped_outer(x, d_first, group_sizes)
     with jax.named_scope("combine"):
-        d_weights = jnp.concatenate([d_w, jnp.zeros((1,), d_w.dtype)])[slots]
+        # each row's gradient placed at its assignment (``rows`` places, and
+        # a row of no group brings the zero its assignment has anyway)
+        d_weights = jnp.zeros((weights.size,), d_w.dtype).at[picked].set(
+            d_w, unique_indices=True).reshape(weights.shape)
     with jax.named_scope("dispatch"):
         d_h = put_rows(d_x, token, back)
     return (d_h, d_w1.astype(w_in.dtype), d_w2.astype(w_out.dtype),
@@ -376,11 +405,11 @@ def _smallest_that_holds(sizes: Tuple[int, ...], group_sizes, fn, *operands):
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 8))
-def _experts(sizes, h, w_in, w_out, weights, order, inverse, group_sizes,
+def _experts(sizes, h, w_in, w_out, weights, order, per_token, group_sizes,
              activation="swiglu"):
     return _smallest_that_holds(
         sizes, group_sizes, partial(_experts_at, activation=activation), h,
-        w_in, w_out, weights, order, inverse, group_sizes)
+        w_in, w_out, weights, order, per_token, group_sizes)
 
 
 def _experts_fwd(sizes, *operands_and_activation):
@@ -437,10 +466,10 @@ def routed_ffn(h, router, bias, w_in, w_out, *, held: Tuple[int, ...],
             load = jnp.sum(jax.nn.one_hot(chosen, num_experts,
                                           dtype=jnp.int32), axis=(0, 1))
         with jax.named_scope("dispatch"):
-            order, inverse, group_sizes = dispatch(chosen, held, num_experts)
+            order, per_token, group_sizes = dispatch(chosen, held)
         y = _experts(capacities(order.shape[0], len(held), num_experts),
                      h if x is None else x, w_in, w_out, weights, order,
-                     inverse, group_sizes, activation)
+                     per_token, group_sizes, activation)
     return y, chosen, scores, load
 
 

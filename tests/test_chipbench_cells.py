@@ -12,27 +12,29 @@ import pytest
 
 from chipbench import harness
 from chipbench.tests.test_cells import *  # noqa: F401,F403
+from chipbench.tests.test_moe_dispatch_ms import *  # noqa: F401,F403
 from chipbench.tests.test_rehearsal import KEYS, rehearse
 from chipbench.tests.test_setup_phases import *  # noqa: F401,F403
 
 #: cell -> the per-layer metrics that are its architecture's own
 HYBRID_CELLS = {
     "granite4hm-train-s4096": {"ssd_ms", "ssd_roofline"},
-    "lfm2moe-train-s8192": {"moe_ms", "moe_experts_ms",
+    "lfm2moe-train-s8192": {"moe_ms", "moe_experts_ms", "moe_dispatch_ms",
                             "moe_experts_roofline", "short_conv_ms"},
     "nemotron3s-train-s4096": {"ssd_ms", "ssd_roofline", "moe_ms",
                                "moe_experts_ms", "moe_experts_roofline",
-                               "moe_route_ms", "moe_shared_ms",
-                               "moe_latent_ms", "lm_head_ms"},
+                               "moe_route_ms", "moe_dispatch_ms",
+                               "moe_shared_ms", "moe_latent_ms", "lm_head_ms"},
     "lagunas-train-s8192": {"moe_ms", "moe_experts_ms",
                             "moe_experts_roofline", "moe_route_ms",
-                            "moe_shared_ms", "lm_head_ms", "attn_gate_ms",
-                            "attn_window_kernel_ms"},
+                            "moe_dispatch_ms", "moe_shared_ms", "lm_head_ms",
+                            "attn_gate_ms", "attn_window_kernel_ms"},
     "kanana2-train-s16384": {"moe_ms", "moe_experts_ms", "moe_route_ms",
-                             "moe_shared_ms", "lm_head_ms", "mla_latent_ms",
-                             "mla_assemble_ms"},
+                             "moe_dispatch_ms", "moe_shared_ms", "lm_head_ms",
+                             "mla_latent_ms", "mla_assemble_ms"},
     "qwen3next-train-s16384": {"moe_ms", "moe_experts_ms", "moe_route_ms",
-                               "moe_shared_ms", "lm_head_ms", "attn_gate_ms",
+                               "moe_dispatch_ms", "moe_shared_ms",
+                               "lm_head_ms", "attn_gate_ms",
                                "delta_rule_ms", "delta_rule_roofline",
                                "delta_rule_prep_ms"}}
 
@@ -120,6 +122,9 @@ def test_hybrid_cell_rehearsal_reads_every_per_layer_metric_it_lists(cell):
         # overlays: the routed feed-forward's time stays in the blocks'
         assert 0 < values["moe_experts_ms"] <= values["moe_ms"] \
             < values["xla_ops_ms"]
+        # ``dispatch`` and ``experts`` are scopes beside each other
+        assert values["moe_dispatch_ms"] + values["moe_experts_ms"] \
+            < values["moe_ms"]
         if "moe_route_ms" in HYBRID_CELLS[cell]:
             # what is under ``moe`` and not under ``experts``; the latent's
             # and the shared expert's products lie beside ``moe``, the

@@ -429,10 +429,9 @@ def _stage_operands(held, n=256, d=128, f=128, top_k=2, seed=11):
     bias = jnp.zeros((8,)).at[jnp.asarray(held)].set(
         10.0 if len(held) == 1 else 0.0)
     chosen, weights, _ = moe.route(logits, bias, top_k)
-    order, inverse, group_sizes = moe.dispatch(chosen, held, 8)
     return (h, 0.2 * jax.random.normal(keys[2], (len(held), d, 2 * f), f32),
             0.2 * jax.random.normal(keys[3], (len(held), f, d), f32), weights,
-            order, inverse, group_sizes)
+            *moe.dispatch(chosen, held))
 
 
 @pytest.mark.parametrize("mode", ["off", "interpret"])
@@ -556,13 +555,18 @@ def test_take_and_put_rows_are_each_others_transposes(case, mode,
     monkeypatch.setenv("HVD_PALLAS", mode)
     chosen, experts, held, capacity = _ROWS_CASES[case]
     (tokens, top_k), width = chosen.shape, 128
-    order, inverse, group_sizes = moe.dispatch(
-        jnp.asarray(chosen, jnp.int32), held, experts)
+    order, per_token, group_sizes = moe.dispatch(
+        jnp.asarray(chosen, jnp.int32), held)
     rows = capacity or tokens * top_k
     here = int(jnp.sum(group_sizes))
     assert here <= rows
-    _, token, slots, valid, back = moe._rows_at(rows, top_k, order, inverse,
-                                                group_sizes)
+    _, token, valid, back = moe._rows_at(rows, top_k, order, per_token,
+                                         group_sizes)
+    # the sorted row of each of a token's assignments, ``rows`` where it has
+    # none here: the plain definition's index, which the stage never builds
+    inverse = np.empty(tokens * top_k, np.int64)
+    inverse[np.asarray(order)] = np.arange(tokens * top_k)
+    slots = np.where(inverse < here, inverse, rows).reshape(tokens, top_k)
     held_by = np.asarray(back[1])
     assert held_by.sum() == here and held_by.shape == (tokens,)
     if case == "a_token_with_none_and_one_with_all_k":

@@ -518,10 +518,9 @@ def _stage_operands(held, n=256, d=128, f=128, top_k=2, seed=11):
     bias = jnp.zeros((8,)).at[jnp.asarray(held)].set(
         10.0 if len(held) == 1 else 0.0)
     chosen, weights, _ = moe.route(logits, bias, top_k, 5.0, 1e-20)
-    order, inverse, group_sizes = moe.dispatch(chosen, held, 8)
     return (h, 0.2 * jax.random.normal(keys[2], (len(held), d, f), f32),
             0.2 * jax.random.normal(keys[3], (len(held), f, d), f32), weights,
-            order, inverse, group_sizes)
+            *moe.dispatch(chosen, held))
 
 
 @pytest.mark.parametrize("mode", ["off", "interpret"])
