@@ -50,21 +50,21 @@ def analysis(compiled) -> dict:
 
 def train_programs(cell: harness.Cell, devices) -> dict:
     import jax
-    import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from horovod_tpu import basics
 
     mesh = Mesh(np.asarray(devices[:cell.chips]), (basics.MESH_AXIS,))
     model, _, tx, step = train_lm.build(cell, mesh)
-    seq, batch = cell.mix["seq"], cell.mix["global_batch"]
     repl = NamedSharding(mesh, P())
-    params = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, seq), jnp.int32))["params"])
+    params = jax.eval_shape(
+        train_lm.init_params(model, train_lm.input_shapes(cell, 1)),
+        jax.random.PRNGKey(0))
     opt = abstract(jax.eval_shape(tx.init, params), repl)
-    tok = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
-                               sharding=NamedSharding(mesh, P(basics.MESH_AXIS)))
-    lowered = step.trace(abstract(params, repl), opt, (tok, tok)).lower(
+    batch = cell.objective.abstract_batch(
+        cell.mix["global_batch"], cell.mix["seq"],
+        NamedSharding(mesh, P(basics.MESH_AXIS)))
+    lowered = step.trace(abstract(params, repl), opt, batch).lower(
         lowering_platforms=("tpu",))
     return {"train_step": analysis(lowered.compile())}
 

@@ -4,7 +4,8 @@ set-up bookkeeping (first calls, compilations), the profiler slice, and the
 
 Nothing here knows a particular cell, configuration, architecture, mix or
 metric: those are files found by name (``workloads/``, ``configs/``,
-``families/``, ``mixes/``, ``jobs/``, ``layer_metrics/``, ``op_classes/``).
+``families/``, ``objectives/``, ``mixes/``, ``jobs/``, ``layer_metrics/``,
+``op_classes/``).
 """
 
 from __future__ import annotations
@@ -28,6 +29,10 @@ ROOT = os.path.dirname(HERE)
 #: what a family module gives (``families/<model_type>.py``)
 FAMILY_NAMES = ("build_model", "reference_forward", "train_flops_per_token",
                 "attention_train_costs", "expected_first_loss", "REHEARSAL")
+
+#: what an objective module gives (``objectives/<name>.py``)
+OBJECTIVE_NAMES = ("make_batches", "loss", "model_inputs", "abstract_batch",
+                   "first_loss")
 
 
 class BenchmarkError(Exception):
@@ -70,21 +75,42 @@ class Cell:
         architecture's (:data:`FAMILY_NAMES`)."""
         return load_family(self.config["model_type"])
 
+    @property
+    def objective(self):
+        """The module ``objectives/<name>.py`` a training mix names: what of
+        the cell is its training objective's (:data:`OBJECTIVE_NAMES`)."""
+        if "objective" not in self.mix:
+            raise BenchmarkError(
+                f"chipbench/mixes/{self.spec['traffic']}.json has no "
+                f"\"objective\": a training mix names its "
+                f"chipbench/objectives/<name>.py")
+        return load_objective(self.mix["objective"])
+
+
+def load_module(folder: str, name: str, wanted: tuple, why: str):
+    """``chipbench/<folder>/<name>.py``, which has to give ``wanted``."""
+    path = f"chipbench/{folder}/{name}.py"
+    module_name = f"{__package__}.{folder}.{name}"
+    try:
+        module = importlib.import_module(module_name)
+    except ModuleNotFoundError as exc:
+        if exc.name != module_name:
+            raise
+        raise BenchmarkError(f"no {path} {why}")
+    missing = [n for n in wanted if not hasattr(module, n)]
+    if missing:
+        raise BenchmarkError(f"{path} lacks {missing}")
+    return module
+
 
 def load_family(model_type: str):
-    name = f"{__package__}.families.{model_type}"
-    try:
-        module = importlib.import_module(name)
-    except ModuleNotFoundError as exc:
-        if exc.name != name:
-            raise
-        raise BenchmarkError(f"no family chipbench/families/{model_type}.py "
-                             f"for model_type {model_type!r}")
-    missing = [n for n in FAMILY_NAMES if not hasattr(module, n)]
-    if missing:
-        raise BenchmarkError(f"chipbench/families/{model_type}.py lacks "
-                             f"{missing}")
-    return module
+    return load_module("families", model_type, FAMILY_NAMES,
+                       f"for model_type {model_type!r}")
+
+
+def load_objective(name: str):
+    return load_module("objectives", name, OBJECTIVE_NAMES,
+                       f"for the mix's objective {name!r}")
 
 
 def load_cell(name: str, rehearse: bool = False) -> Cell:

@@ -3,14 +3,20 @@
 ``hvd.init()`` -> ``hvd.mesh()`` -> ``spmd.make_train_step(loss_fn, tx,
 mesh=...)`` with its defaults, the model its family builds through the
 library's public constructor with its defaults, ``optax.adamw(3e-4,
-weight_decay=0.01, mu_dtype=bf16)``, the full-logit ``lm_loss``. No
-environment knob of the program is set and no compiler option passed.
+weight_decay=0.01, mu_dtype=bf16)``, the loss its objective builds from a
+loss function of the library. No environment knob of the program is set and
+no compiler option passed.
 
 What is one architecture's comes from the cell's family,
 ``families/<model_type>.py``: the model, its plain reference, its operation
 counts and its attention layers' costs, the first loss its initialisation
-gives. The loss, the optimizer, the step builder, the window and every
-tolerance are the job's, and no family can set them.
+gives. What is one training objective's comes from the module the mix names,
+``objectives/<name>.py``: the batch, the loss, what of a batch the model
+takes, the batch's shapes, the first loss under its weighting. The
+optimizer, the step builder, the window, how tokens are counted (the
+sequences' own ``global_batch * seq`` a step, whatever an objective lays
+beside them) and every tolerance are the job's, and no family or objective
+can set them.
 
 Steps are dispatched back to back; every ``chunk_steps`` steps the loss is
 waited for and the chunk's host time recorded, until ``--seconds`` is up.
@@ -23,10 +29,11 @@ import time
 
 import numpy as np
 
-from .. import harness, traffic
+from .. import harness
 
-#: first loss: |loss - family.expected_first_loss(config, rows)|, what the
-#: architecture's initialisation gives (the band is chip_smoke.py's)
+#: first loss: |loss - objective.first_loss(family.expected_first_loss(config,
+#: rows))|, what the architecture's initialisation gives under the
+#: objective's weighting (the band is chip_smoke.py's)
 FIRST_LOSS_BAND = 0.5
 
 #: program logits against the float32 reference, as the root-mean-square
@@ -56,14 +63,9 @@ def build(cell: harness.Cell, mesh):
     import optax
 
     from horovod_tpu import spmd
-    from horovod_tpu.models.transformer import lm_loss
 
     model = cell.family.build_model(cell.config, cell.vocab_rows, cell.mix)
-
-    def loss_fn(params, batch):
-        tokens, targets = batch
-        return lm_loss(model.apply({"params": params}, tokens), targets)
-
+    loss_fn = cell.objective.loss(model)
     tx = optax.adamw(3e-4, weight_decay=0.01, mu_dtype=jnp.bfloat16)
     return model, loss_fn, tx, spmd.make_train_step(loss_fn, tx, mesh=mesh)
 
@@ -82,6 +84,25 @@ def cell_mesh(chips: int):
     return Mesh(np.asarray(jax.devices()[:chips]), (basics.MESH_AXIS,))
 
 
+def input_shapes(cell: harness.Cell, sequences: int):
+    """The shapes of what the model takes for ``sequences`` sequences of the
+    mix's length: the objective's ``model_inputs`` of its abstract batch."""
+    import jax
+
+    objective, mix = cell.objective, cell.mix
+    return jax.eval_shape(
+        lambda batch: objective.model_inputs(batch, sequences),
+        objective.abstract_batch(mix["global_batch"], mix["seq"], None))
+
+
+def init_params(model, shapes):
+    """``key -> params``: the model initialised on zeros of ``shapes``."""
+    import jax.numpy as jnp
+
+    return lambda key: model.init(
+        key, *(jnp.zeros(s.shape, s.dtype) for s in shapes))["params"]
+
+
 def on_first_chip(tree):
     """The first chip's copy of a replicated tree (no transfer)."""
     import jax
@@ -90,19 +111,22 @@ def on_first_chip(tree):
                                   tree)
 
 
-def check_logits(ctx, model, params, tokens, dev):
-    """(a): program against reference on ``tokens`` ([2, seq]) on ``dev``."""
+def check_logits(ctx, model, params, inputs, dev):
+    """(a): program against reference on ``inputs``, the objective's
+    ``model_inputs`` of two sequences, on ``dev``. The family's reference
+    takes the same tuple, or the array itself where there is one."""
     import jax
     import jax.numpy as jnp
 
     cell = ctx.cell
     dev0 = on_first_chip(params)
-    tokens = jax.device_put(tokens, dev)
+    inputs = jax.device_put(inputs, dev)
     got = ctx.first_call("program_forward", jax.jit(
-        lambda p, t: model.apply({"params": p}, t).astype(jnp.float32)),
-        dev0, tokens)
+        lambda p, *t: model.apply({"params": p}, *t).astype(jnp.float32)),
+        dev0, *inputs)
     want = ctx.first_call("reference_forward", cell.family.reference_forward,
-                          dev0, tokens, cell.config)
+                          dev0, inputs[0] if len(inputs) == 1 else inputs,
+                          cell.config)
 
     @jax.jit
     def compare(got, want):
@@ -161,7 +185,7 @@ def run(ctx: harness.Context) -> harness.Window:
     from horovod_tpu import spmd
 
     cell, mix, c = ctx.cell, ctx.cell.mix, ctx.cell.config
-    family = cell.family
+    family, objective = cell.family, cell.objective
     mesh = cell_mesh(cell.chips)
     chips = mesh.devices.size
     devices = list(mesh.devices.flat)
@@ -178,13 +202,13 @@ def run(ctx: harness.Context) -> harness.Window:
     # ---- set-up: weights, optimizer state and batches made on the device
     repl = spmd.replicated_sharding(mesh)
     params = ctx.first_call("init_params", jax.jit(
-        lambda k: model.init(k, jnp.zeros((1, seq), jnp.int32))["params"],
-        out_shardings=repl), jax.random.PRNGKey(ctx.seed))
+        init_params(model, input_shapes(cell, 1)), out_shardings=repl),
+        jax.random.PRNGKey(ctx.seed))
     opt_state = ctx.first_call(
         "init_optimizer", jax.jit(tx.init, out_shardings=repl), params)
     batches = ctx.first_call(
-        "make_batches", traffic.token_batches, ctx.seed + 1, mix["batches"],
-        global_batch, seq, c["vocab_size"], spmd.batch_sharding(mesh))
+        "make_batches", objective.make_batches, ctx.seed + 1, mix["batches"],
+        global_batch, seq, c, spmd.batch_sharding(mesh))
 
     params, opt_state, first_loss = ctx.first_call(
         "train_step", step, params, opt_state, batches[0])
@@ -237,14 +261,15 @@ def run(ctx: harness.Context) -> harness.Window:
 
     # ---- correctness, outside the window, on the weights it left
     memory_peak = harness.memory_peak_bytes(devices)
-    rms, rel_max = check_logits(ctx, model, params, batches[0][0][:2],
+    rms, rel_max = check_logits(ctx, model, params,
+                                objective.model_inputs(batches[0], 2),
                                 devices[0])
     notes.append(f"reference check: logit rms error {rms:.5f} of the "
                  f"reference's rms (tolerance {LOGIT_RMS_TOL}), max error "
                  f"{rel_max:.5f} of its max")
     expect(rms <= LOGIT_RMS_TOL, f"logit rms error {rms} > {LOGIT_RMS_TOL}")
     rows = cell.vocab_rows
-    want_first = family.expected_first_loss(c, rows)
+    want_first = objective.first_loss(family.expected_first_loss(c, rows))
     expect(abs(first_loss - want_first) <= FIRST_LOSS_BAND,
            f"first loss {first_loss:.4f} outside {want_first:.3f} +- "
            f"{FIRST_LOSS_BAND}")

@@ -181,7 +181,40 @@ def test_op_classes_and_peaks_load():
     assert all("source" in p for p in peaks.values())
 
 
+#: every mix file, and those of the training job, which name an objective
+MIXES = sorted(os.path.basename(p)[:-5] for p in glob.glob(
+    os.path.join(harness.HERE, "mixes", "*.json")))
+TRAINING_MIXES = [m for m in MIXES if traffic.load(m)["job"] == "train_lm"]
+
+
 def test_traffic_files_load():
-    for path in glob.glob(os.path.join(harness.HERE, "mixes", "*.json")):
-        mix = traffic.load(os.path.basename(path)[:-5])
+    assert TRAINING_MIXES
+    for name in MIXES:
+        mix = traffic.load(name)
         assert mix["job"] in JOBS and mix["what"]
+        assert ("objective" in mix) == (name in TRAINING_MIXES), name
+
+
+@pytest.mark.parametrize("name", TRAINING_MIXES)
+def test_training_mix_names_an_objective_with_the_five_names(name):
+    mix = traffic.load(name)
+    assert NAME.match(mix["objective"])
+    objective = harness.load_objective(mix["objective"])   # the file exists
+    for attr in harness.OBJECTIVE_NAMES:
+        assert callable(getattr(objective, attr)), (name, attr)
+    for cell in map(harness.load_cell, CELLS):
+        if cell.spec["traffic"] == name:
+            assert cell.objective is objective
+
+
+def test_a_training_mix_without_its_objective_names_the_missing_file():
+    cell = harness.load_cell("gpt2m-train-s1024")
+    cell.mix = {k: v for k, v in cell.mix.items() if k != "objective"}
+    with pytest.raises(harness.BenchmarkError,
+                       match="mixes/train-b8-s1024.json has no \"objective\""):
+        cell.objective
+    cell.mix["objective"] = "no_such"
+    with pytest.raises(harness.BenchmarkError, match="objectives/no_such.py"):
+        cell.objective
+    # a serving cell has none to name, and is not asked for one
+    assert "objective" not in harness.load_cell("gpt2m-serve-c8").mix

@@ -113,19 +113,19 @@ def test_a_copied_workload_file_is_a_new_cell_with_no_code_edit(tmp_path):
     assert "rehearsal_train_tokens_per_s_chip" in line["metrics"]
 
 
-def add_the_second_architecture(tmp_path):
-    """What a ``model_config`` PR brings, as ``tests/data/second_family``
-    holds it for a toy: a family module, its model's reference, a
-    configuration, a mix and a workload file laid over a copy of
-    ``chipbench/``, and entries appended to ``BENCHMARK.json``. Returns the
-    new cell's name."""
+def add_as_files_only(tmp_path, what="second_family"):
+    """What a ``model_config`` PR brings, as ``tests/data/<what>`` holds it
+    for a toy: a family module, its model's reference, a configuration, a
+    mix and a workload file (``second_objective``: an objective module too)
+    laid over a copy of ``chipbench/``, and entries appended to
+    ``BENCHMARK.json``. Returns the new cell's name."""
     bench = copy_of_the_benchmark(tmp_path)
-    copy, data = tmp_path / "chipbench", os.path.join(DATA, "second_family")
+    copy, data = tmp_path / "chipbench", os.path.join(DATA, what)
     added = {os.path.relpath(os.path.join(path, f), data)
              for path, _, files in os.walk(data) for f in files}
     assert not [f for f in added if os.path.exists(copy / f)]
     shutil.copytree(data, copy, dirs_exist_ok=True)
-    entries = harness.load_json("tests", "data", "second_family",
+    entries = harness.load_json("tests", "data", what,
                                 "benchmark_entries.json")
     cell = entries["workload"]["name"]
     bench["configs"].append(entries["config"])
@@ -136,10 +136,12 @@ def add_the_second_architecture(tmp_path):
     return cell
 
 
-def test_a_second_architecture_is_new_files_and_entries_with_no_code_edit(tmp_path):
-    """No file of the copy is edited, and every reader of the training job
-    applies to a model that is not ``TransformerLM``."""
-    cell = add_the_second_architecture(tmp_path)
+def rehearse_as_files_only(tmp_path, what, module):
+    """Lay ``tests/data/<what>`` over a copy, see that no file of the copy
+    is edited, rehearse the new cell untraced and traced, and see ``module``
+    (found by a name a data file gives) named when its file is missing.
+    Returns the traced run's metric names."""
+    cell = add_as_files_only(tmp_path, what)
     copy = tmp_path / "chipbench"
     for path, _, files in os.walk(harness.HERE):
         for f in files if "__pycache__" not in path else ():
@@ -159,33 +161,69 @@ def test_a_second_architecture_is_new_files_and_entries_with_no_code_edit(tmp_pa
             "blocks_bwd_ms", "head_loss_ms", "optimizer_ms", "model_other_ms",
             "device_idle_share", "compile_s"} <= got
     assert line["metrics"]["rehearsal_train_mfu"]["value"] > 0
-    assert not got & {"flash_attention_roofline", "attn_kernel_ms",
-                      "attn_fwd_kernel_ms", "attn_bwd_kernel_ms",
-                      "allreduce_ms", "blocks_recompute_ms"}
-    # the family is found by the configuration's model_type, or named missing
-    os.remove(copy / "families" / "toymixer.py")
+    assert line["metrics"]["rehearsal_train_step_ms"]["value"] > 0
+    os.remove(copy / module)
     proc = subprocess.run(
         [sys.executable, "-m", "chipbench.run", "--workload", cell, "--seed",
          "1", "--seconds", "1", "--trace", "0", "--rehearse"], cwd=tmp_path,
         env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
         text=True, timeout=120)
     assert proc.returncode != 0 and proc.stdout.strip() == ""
-    assert "chipbench/families/toymixer.py" in proc.stderr
+    assert f"chipbench/{module}" in proc.stderr
+    return got
+
+
+def rehearse_broken_underneath(tmp_path, what, model, sound, broken):
+    """The rest of a run with the timed path broken underneath: ``sound``
+    replaced by ``broken`` in the toy's model file, which its reference and
+    its objective do not read."""
+    cell = add_as_files_only(tmp_path, what)
+    path = tmp_path / "chipbench" / model
+    text = path.read_text()
+    assert text.count(sound) == 1
+    path.write_text(text.replace(sound, broken))
+    line, out = rehearse(cell, 0, cwd=str(tmp_path), seconds="1")
+    assert line["correct"] is False and line["failed"] == 0
+    return out
+
+
+def test_a_second_architecture_is_new_files_and_entries_with_no_code_edit(tmp_path):
+    """No file of the copy is edited, and every reader of the training job
+    applies to a model that is not ``TransformerLM``; the family is found
+    by the configuration's ``model_type``."""
+    got = rehearse_as_files_only(tmp_path, "second_family",
+                                 "families/toymixer.py")
+    assert not got & {"flash_attention_roofline", "attn_kernel_ms",
+                      "attn_fwd_kernel_ms", "attn_bwd_kernel_ms",
+                      "allreduce_ms", "blocks_recompute_ms"}
+
+
+def test_a_second_objective_is_new_files_and_entries_with_no_code_edit(tmp_path):
+    """No file of the copy is edited, and the training job with its readers
+    applies to a model that takes two token arrays, a batch of three arrays
+    and a loss that is not ``lm_loss``; the objective is found by the mix's
+    ``objective``."""
+    rehearse_as_files_only(tmp_path, "second_objective",
+                           "objectives/toy_denoise.py")
 
 
 def test_a_timed_path_that_computes_something_else_is_not_correct(tmp_path):
-    """The rest of a run with the timed path broken underneath: the model
-    the window trains halves each block's update of the residual stream,
-    its reference does not, and the run says so."""
-    cell = add_the_second_architecture(tmp_path)
-    model = tmp_path / "chipbench" / "toymixer_model.py"
-    sound = model.read_text()
-    assert sound.count("return x + nn.Dense(") == 1
-    model.write_text(sound.replace("return x + nn.Dense(",
-                                   "return x + 0.5 * nn.Dense("))
-    line, out = rehearse(cell, 0, cwd=str(tmp_path), seconds="1")
-    assert line["correct"] is False and line["failed"] == 0
+    """The model the window trains halves each block's update of the
+    residual stream, its reference does not, and the run says so."""
+    out = rehearse_broken_underneath(
+        tmp_path, "second_family", "toymixer_model.py",
+        "return x + nn.Dense(", "return x + 0.5 * nn.Dense(")
     assert "CHECK FAILED: logit rms error" in out
+
+
+def test_a_timed_loss_that_drops_its_weights_is_not_correct(tmp_path):
+    """The loss the window trains on counts a masked position once, not by
+    the inverse of its block's rate; the objective states what its weighting
+    gives at the start, and the run says so."""
+    out = rehearse_broken_underneath(
+        tmp_path, "second_objective", "toydenoiser_model.py",
+        "jnp.sum(weights * nll)", "jnp.sum((weights > 0) * nll)")
+    assert "CHECK FAILED: first loss" in out
 
 
 def test_a_directory_with_only_the_benchmark_fails_without_a_result(tmp_path):

@@ -6,10 +6,13 @@ lists (serving) and arrival times (open-loop cells, none yet). The same seed
 gives the same inputs. The program under test receives only what is
 generated here.
 
-The synthetic batch follows ``benchmarks/lm_bench.py`` (uniform token ids)
-and the arrival arithmetic ``benchmarks/serving_bench.py:poisson_load``
-(a running sum of seeded exponential gaps); both originals are listed in
-PERF.md for deletion.
+The synthetic batch is uniform token ids (``benchmarks/lm_bench.py``'s,
+which PR 45 deleted) and the arrival arithmetic
+``benchmarks/serving_bench.py:poisson_load``'s (a running sum of seeded
+exponential gaps), listed in PERF.md for deletion. Which generator a
+training cell's batches come from is its objective's to say
+(``objectives/<name>.py``); next-token prediction's is
+:func:`token_batches`.
 """
 
 from __future__ import annotations
