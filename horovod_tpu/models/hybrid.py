@@ -27,7 +27,11 @@ scale ``head_dim ** -0.5``, unless given).
   positions only (``window``, a band the flash kernels skip by) and turn
   by another rotary scheme (a base over the whole head against given
   frequencies over half of it, scaled), and every layer gates each head's
-  output (``gate``: one scalar a head, or one a head and channel).
+  output (``gate``: one scalar a head, or one a head and channel). A model
+  trained by denoising blocks (``denoise_blocks``: SDAR's) runs on a
+  sequence's noised copy and then its clean one, both at the sequence's
+  own positions, under the block-diffusion mask the flash kernels skip by
+  (``block_diffusion``), and hands its head the noised half.
 * The latent-attention mixer (MLA, DeepSeek-V2/V3's, without a query
   latent) projects its input to one narrow normed latent, from which every
   head's keys and values are made, and to one rotary key that all heads
@@ -274,7 +278,15 @@ class AttentionMixer(nn.Module):
     scope ``window``); with ``gate`` each head's output is multiplied by
     the sigmoid of a projection of the mixer's input (``gate``) before
     ``o``: ``"head"`` (or ``True``) one scalar a head and position,
-    ``"channel"`` one a head, channel and position."""
+    ``"channel"`` one a head, channel and position. With
+    ``q_norm_init`` is where ``q_norm``'s weight starts (1 unless given:
+    at ``g`` a fresh model's scores spread ``g`` times as wide, and its
+    softmax tells its keys apart as a trained one's does). With
+    ``block_diffusion`` (a block length ``B``) the input is a sequence's
+    noised copy and then its clean one, ``[noised | clean]`` on the time
+    axis: both halves turn by positions ``0..T-1``, and a query sees what
+    ``flash_attention``'s mask of that name lets it see (under the scope
+    ``block_diffusion``)."""
     heads: int
     kv_heads: int
     head_dim: int
@@ -288,6 +300,8 @@ class AttentionMixer(nn.Module):
     rope_factor: float = 1.0
     window: Optional[int] = None
     gate: Union[bool, str] = False      # | "head" (True) | "channel"
+    block_diffusion: Optional[int] = None
+    q_norm_init: float = 1.0
 
     @nn.compact
     def __call__(self, h):
@@ -295,6 +309,9 @@ class AttentionMixer(nn.Module):
         if self.gate not in (False, True, "head", "channel"):
             raise ValueError(f"gate={self.gate!r}; expected False, 'head' "
                              f"(or True) or 'channel'")
+        if self.block_diffusion is not None and self.window is not None:
+            raise ValueError("block_diffusion with a window: the mask takes "
+                             "the triangle's place, not a band's")
 
         def project(name, heads):
             return _dense(heads * self.head_dim, self.dtype, name)(h).reshape(
@@ -303,26 +320,34 @@ class AttentionMixer(nn.Module):
         q = project("q", self.heads)
         k, v = project("k", self.kv_heads), project("v", self.kv_heads)
         if self.qk_norm_eps is not None:
-            def weight(name):
-                return self.param(name, nn.initializers.ones,
-                                  (self.head_dim,), jnp.float32)
+            def weight(name, start=1.0):
+                return self.param(
+                    name, nn.initializers.ones if start == 1.0
+                    else nn.initializers.constant(start), (self.head_dim,),
+                    jnp.float32)
 
             with jax.named_scope("qk_norm"):
-                q = _head_rms_norm(q, weight("q_norm"), self.qk_norm_eps)
+                q = _head_rms_norm(q, weight("q_norm", self.q_norm_init),
+                                   self.qk_norm_eps)
                 k = _head_rms_norm(k, weight("k_norm"), self.qk_norm_eps)
         if self.position == "rope":
+            # each half of [noised | clean] at its own positions 0..T-1
+            positions = None if self.block_diffusion is None \
+                else jnp.arange(t) % (t // 2)
             turn = partial(apply_rope, theta=self.rope_theta,
-                           rotary_dim=self.rotary_dim,
+                           positions=positions, rotary_dim=self.rotary_dim,
                            inv_freq=self.rope_inv_freq,
                            factor=self.rope_factor)
             with jax.named_scope("rope"):
                 q, k = turn(q), turn(k)
         k, v = (jnp.repeat(a, self.heads // self.kv_heads, axis=2)
                 for a in (k, v))
-        with jax.named_scope("window") if self.window is not None \
-                else contextlib.nullcontext():
+        scope = "window" if self.window is not None else \
+            "block_diffusion" if self.block_diffusion is not None else None
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
             out = flash_attention(q, k, v, causal=True, scale=self.scale,
-                                  window=self.window)
+                                  window=self.window,
+                                  block_diffusion=self.block_diffusion)
         out = out.astype(self.dtype)
         if self.gate == "channel":
             out = out * nn.sigmoid(_dense(self.heads * self.head_dim,
@@ -411,7 +436,9 @@ class RoutedFeedForward(nn.Module):
     routed token's weights sum to
     ``scale``, their sum taking ``norm_eps`` before it divides; the scores
     they are made of are ``scoring`` of the router's logits (``"sigmoid"``
-    | ``"softmax"`` over all the experts). Sows the
+    | ``"softmax"`` over all the experts). With ``aux_loss`` the layer's
+    auxiliary balancing loss times that coefficient rides the backward
+    pass (``ops/moe.balancing``). Sows the
     experts each token chose, their scores and the tokens an expert
     (``intermediates``: free unless asked for)."""
     experts: int
@@ -426,6 +453,7 @@ class RoutedFeedForward(nn.Module):
     norm_eps: float = moe.NORM_EPS
     scoring: str = "sigmoid"
     shared_gate: bool = False
+    aux_loss: float = 0.0
 
     @nn.compact
     def __call__(self, h):
@@ -446,7 +474,8 @@ class RoutedFeedForward(nn.Module):
                        jnp.float32),
             held=self.held, top_k=self.top_k, x=x,
             activation=self.activation, scale=self.scale,
-            norm_eps=self.norm_eps, scoring=self.scoring)
+            norm_eps=self.norm_eps, scoring=self.scoring,
+            aux_loss=self.aux_loss)
         self.sow("intermediates", "chosen", chosen.reshape(b, t, self.top_k))
         self.sow("intermediates", "scores", scores.reshape(b, t, self.experts))
         self.sow("intermediates", "load", load)
@@ -495,7 +524,10 @@ class HybridBlock(nn.Module):
 
 
 class HybridLM(nn.Module):
-    """Tokens ``[B, T]`` -> float32 logits ``[B, T, vocab_size]``."""
+    """Tokens ``[B, T]`` -> float32 logits ``[B, T, vocab_size]``. A model
+    built to denoise by blocks (``denoise_blocks``, the block length) takes
+    ``(noised, clean)``, both ``[B, T]``, and gives the logits of the
+    noised half."""
     vocab_size: int
     layer_kinds: Tuple[str, ...]        # "mamba" | "attention" | "short_conv"
                                         # | "latent_attention" | "gated_delta"
@@ -561,9 +593,31 @@ class HybridLM(nn.Module):
     # blocks say, whatever else a program made from the model hands out
     # (``capture_intermediates``) or keeps (``remat``)
     pin_stream: bool = False
+    # denoising by blocks of this length (0: next-token prediction): the
+    # blocks run on ``2 T`` rows, ``[noised | clean]`` on the time axis
+    # (laid side by side and cut apart under the scope ``denoise_io``);
+    # every attention layer takes the block-diffusion mask and turns both
+    # halves by positions ``0..T-1``; the final norm and the head run on the
+    # noised half alone. Attention is the one mixer that knows the layout
+    denoise_blocks: int = 0
+    # the coefficient of each routed layer's auxiliary balancing loss, which
+    # rides the backward pass (``ops/moe.balancing``); 0: none
+    moe_aux_loss: float = 0.0
+    # where the attention layers' ``q_norm`` weight starts
+    attn_q_norm_init: float = 1.0
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, clean=None):
+        if (clean is None) != (self.denoise_blocks == 0):
+            raise ValueError(
+                f"denoise_blocks={self.denoise_blocks}: a model built to "
+                f"denoise by blocks takes (noised, clean), any other model "
+                f"one token array")
+        if self.denoise_blocks and set(self.layer_kinds) - {
+                "attention", "none", *self.attn_kinds}:
+            raise ValueError(
+                f"denoise_blocks with layer_kinds={self.layer_kinds!r}: "
+                f"only attention layers take [noised | clean]")
         if self.remat not in REMAT_POLICIES:
             raise ValueError(f"remat={self.remat!r}; expected one of "
                              f"{sorted(REMAT_POLICIES)}")
@@ -602,7 +656,8 @@ class HybridLM(nn.Module):
                 scale=scale, dtype=self.dtype, position=self.attn_position,
                 rope_theta=self.attn_rope_theta,
                 qk_norm_eps=self.norm_eps if self.attn_qk_norm else None,
-                gate=self.attn_gate),
+                gate=self.attn_gate, q_norm_init=self.attn_q_norm_init,
+                block_diffusion=self.denoise_blocks or None),
             "short_conv": partial(ShortConvMixer, self.conv_width,
                                   self.dtype),
             "gated_delta": partial(
@@ -653,7 +708,7 @@ class HybridLM(nn.Module):
                              self.moe_activation, self.moe_latent,
                              self.moe_shared_width, self.moe_scale,
                              self.moe_norm_eps, self.moe_scoring,
-                             self.moe_shared_gate)
+                             self.moe_shared_gate, self.moe_aux_loss)
         use_remat, policy = REMAT_POLICIES[self.remat]
         block_cls = nn.remat(HybridBlock, policy=policy) if use_remat \
             else HybridBlock
@@ -662,6 +717,9 @@ class HybridLM(nn.Module):
                        embedding_init=nn.initializers.normal(0.02),
                        param_dtype=jnp.float32, dtype=self.dtype,
                        name="tok_emb")
+        if self.denoise_blocks:
+            with jax.named_scope("denoise_io"):
+                tokens = jnp.concatenate([tokens, clean], axis=1)
         x = emb(tokens) * self.embedding_multiplier
         pin = jax.lax.optimization_barrier if self.pin_stream else (
             lambda x: x)
@@ -671,6 +729,9 @@ class HybridLM(nn.Module):
                           routed if ffn == "moe" else None,
                           name=f"block_{i}")(pin(x))
         x = pin(x)
+        if self.denoise_blocks:
+            with jax.named_scope("denoise_io"):
+                x = x[:, :clean.shape[1]]
         x = nn.RMSNorm(epsilon=self.norm_eps, dtype=self.dtype,
                        param_dtype=jnp.float32, name="norm_f")(x)
         # the head in the model's dtype as TransformerLM's: the table, or a

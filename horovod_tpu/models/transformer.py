@@ -257,6 +257,18 @@ def lm_loss(logits, targets):
 
 
 @jax.named_scope("loss")
+def denoise_loss(logits, clean, weights):
+    """Weighted cross entropy of denoising: ``sum(weights * CE(logits,
+    clean)) / weights.size``, position by position (no shift). ``weights``
+    ``[B, T]`` float is what the noise schedule gives a position: nought
+    where the model was shown the clean token, ``1 / t`` where it was
+    masked at rate ``t``, 1 a token on average."""
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    ll = jnp.take_along_axis(logp, clean[..., None], axis=-1)[..., 0]
+    return -jnp.sum(weights * ll) / weights.size
+
+
+@jax.named_scope("loss")
 def lm_loss_chunked(hidden, emb_table, targets, chunk_tokens=2048,
                     unroll=1):
     """Weight-tied-head cross entropy WITHOUT materializing [B, T, vocab].

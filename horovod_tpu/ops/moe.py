@@ -83,15 +83,19 @@ NORM_EPS = 1e-6
 
 
 def route(logits, bias, top_k: int, scale: float = 1.0,
-          norm_eps: float = NORM_EPS, scoring: str = "sigmoid"):
+          norm_eps: float = NORM_EPS, scoring: str = "sigmoid",
+          aux_loss: float = 0.0):
     """Top-k routing with a selection bias: ``scores`` ``[N, E]`` in
     float32 are ``sigmoid(logits)`` or, with ``scoring="softmax"``, the
     softmax of a token's logits over all ``E``; each token's ``top_k``
     experts are those with the largest ``scores + bias`` (the bias steers
     the choice only: it is under ``stop_gradient`` and not in the weights);
     the weights are the chosen experts' scores renormalised to sum to
-    ``scale`` (their sum takes ``norm_eps`` before it divides). Returns
-    ``(chosen [N, k] int32, weights [N, k] float32, scores [N, E])``."""
+    ``scale`` (their sum takes ``norm_eps`` before it divides). With
+    ``aux_loss`` the scores the weights are made of carry the layer's
+    auxiliary balancing loss in their backward pass (:func:`balancing`).
+    Returns ``(chosen [N, k] int32, weights [N, k] float32, scores [N,
+    E])``."""
     if scoring not in ("sigmoid", "softmax"):
         raise ValueError(f"scoring={scoring!r}; expected 'sigmoid' or "
                          f"'softmax'")
@@ -100,6 +104,8 @@ def route(logits, bias, top_k: int, scale: float = 1.0,
         else jax.nn.softmax(logits, axis=-1)
     _, chosen = jax.lax.top_k(
         scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+    if aux_loss:
+        scores = balancing(scores, chosen, aux_loss)
     # (a masked sum, not take_along_axis: its gather is the slowest thing in
     # the router on the chip, and its transpose a scatter)
     picked = jnp.sum(jax.nn.one_hot(chosen, scores.shape[-1],
@@ -107,6 +113,37 @@ def route(logits, bias, top_k: int, scale: float = 1.0,
                      * scores[..., None, :], axis=-1)
     weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + norm_eps)
     return chosen, weights if scale == 1.0 else scale * weights, scores
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def balancing(scores, chosen, coefficient: float):
+    """``scores`` ``[N, E]``, as they are. Their backward pass adds the
+    gradient of ``coefficient`` times the layer's auxiliary balancing loss,
+    ``E sum_e f_e P_e`` with ``f_e`` the share of the ``N`` tokens that
+    chose expert ``e`` (a count: no gradient; the ``f_e`` sum to ``top_k``)
+    and ``P_e`` the mean of their scores for it: ``coefficient E f_e / N``
+    on every token's score for ``e``. It is 'top_k' where every expert is
+    chosen as often as every other and rises as the choice narrows. This
+    is the load-balancing loss of the Switch Transformer (arXiv:2101.03961,
+    eq. 4-6) as the Qwen3-MoE lineage computes it, a layer at a time;
+    attached here, the step's loss stays the objective's own and the model
+    returns nothing more (as Megatron-LM's ``MoEAuxLossAutoScaler``
+    attaches its)."""
+    return scores
+
+
+def _balancing_fwd(scores, chosen, coefficient):
+    load = jnp.sum(jax.nn.one_hot(chosen, scores.shape[-1],
+                                  dtype=jnp.float32), axis=(0, 1))
+    return scores, load
+
+
+def _balancing_bwd(coefficient, load, d_scores):
+    n, e = d_scores.shape
+    return d_scores + (coefficient * e / (n * n)) * load, None
+
+
+balancing.defvjp(_balancing_fwd, _balancing_bwd)
 
 
 def _runs(held: Sequence[int]):
@@ -432,17 +469,17 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
 def routed_ffn(h, router, bias, w_in, w_out, *, held: Tuple[int, ...],
                top_k: int, x=None, activation: str = "swiglu",
                scale: float = 1.0, norm_eps: float = NORM_EPS,
-               scoring: str = "sigmoid"):
+               scoring: str = "sigmoid", aux_loss: float = 0.0):
     """The layer above for ``h`` ``[N, d]``: ``router`` ``[d, E]`` and
     ``bias`` ``[E]`` (float32), ``w_in`` ``[H, d, 2 f]`` (gate and up side
     by side; ``[H, d, f]`` for ``activation="relu2"``) and ``w_out``
     ``[H, f, d]`` for the ``H = len(held)`` experts held (cast to
     ``h.dtype`` here). The router always reads ``h``; the experts read
     ``x`` ``[N, l]`` where one is given (a latent of ``h``: their ``d`` is
-    then ``l``, and so is ``y``'s). ``scale``, ``norm_eps`` and ``scoring``
-    are :func:`route`'s. Returns ``(y [N, d], chosen [N, k], scores [N, E],
-    load [E])``: ``load`` counts the tokens each of the ``E`` experts was
-    chosen by (held or not).
+    then ``l``, and so is ``y``'s). ``scale``, ``norm_eps``, ``scoring``
+    and ``aux_loss`` are :func:`route`'s. Returns ``(y [N, d], chosen [N,
+    k], scores [N, E], load [E])``: ``load`` counts the tokens each of the
+    ``E`` experts was chosen by (held or not).
 
     The expert stage keeps the layer's inputs and the routing, not the
     ``[rows, 2 f]`` activations, whatever the model's ``remat``: its
@@ -461,6 +498,8 @@ def routed_ffn(h, router, bias, w_in, w_out, *, held: Tuple[int, ...],
             renorm = () if (scale, norm_eps) == (1.0, NORM_EPS) \
                 else (scale, norm_eps)
             kind = {} if scoring == "sigmoid" else {"scoring": scoring}
+            if aux_loss:
+                kind["aux_loss"] = aux_loss
             chosen, weights, scores = route(logits, bias, top_k, *renorm,
                                             **kind)
             load = jnp.sum(jax.nn.one_hot(chosen, num_experts,

@@ -350,9 +350,95 @@ def _band_spans(window, block_q, block_k, nq, nk):
                    for j in range(nk)))
 
 
+# THE BLOCK-DIFFUSION MASK. ``blocks=(B, T)`` on a causal call of ``2 T``
+# queries and as many keys, both from position 0: rows ``[0, T)`` are a
+# sequence's noised copy and rows ``[T, 2 T)`` the clean one, row ``i`` at
+# position ``i mod T`` in block ``(i mod T) // B``. A query sees a CLEAN key
+# of an earlier block, and of its own block if it is clean itself; a NOISED
+# key of its own block if it is noised itself; nothing else. Of the
+# ``2 T x 2 T`` square that is two triangles and a diagonal, ``T^2 + T B``
+# scores: the clean-to-noised quadrant is dead and the noised-to-noised one
+# live on its diagonal. The mask replaces the triangle wherever the kernels
+# mask (:func:`_causal_mask`); where they skip, the live tiles are no band
+# that two bounds hold, so they are listed: a table of Python ints made
+# here (:func:`_blockdiff_live` says which tile pairs hold a score), which
+# rides the kernels' scalar prefetch behind the two offsets. The forward's
+# key loop walks a q tile's key blocks (:func:`_blockdiff_spans`), the
+# fused backward's grid the live cells, key tile by key tile
+# (:func:`_blockdiff_cells`): a dead tile is no loop trip and no grid cell.
+# The resident forward and the one-pass backward take it, which is every
+# head up to 65,536 rows at d=128; the streaming kernels do not
+# (:func:`flash_attention` refuses), nor does a ring hop.
+def _blockdiff_live(rows: int, sub_q: int, sub_k: int, blocks) -> np.ndarray:
+    """``[rows // sub_q, rows // sub_k]`` bool: the tile pairs in which a
+    query sees a key. No JAX."""
+    length, half = blocks
+    q_lo = np.arange(0, rows, sub_q)[:, None]
+    k_lo = np.arange(0, rows, sub_k)[None, :]
+
+    def part(lo, hi, clean):
+        """(first block, last block, any) of a tile's rows in one half."""
+        if clean:
+            lo, hi = np.maximum(lo, half) - half, hi - half
+        else:
+            hi = np.minimum(hi, half)
+        return lo // length, (hi - 1) // length, hi > lo
+
+    qn0, qn1, qn = part(q_lo, q_lo + sub_q, False)
+    _, qc1, qc = part(q_lo, q_lo + sub_q, True)
+    kn0, kn1, kn = part(k_lo, k_lo + sub_k, False)
+    kc0, _, kc = part(k_lo, k_lo + sub_k, True)
+    return ((qn & kn & (kn0 <= qn1) & (kn1 >= qn0))
+            | (qn & kc & (kc0 < qn1)) | (qc & kc & (kc0 <= qc1)))
+
+
+def _blockdiff_spans(rows: int, block_q: int, block_k: int,
+                     blocks) -> np.ndarray:
+    """``[q tiles, 4]`` int32: the key blocks ``[a0, a1)`` and ``[b0, b1)``
+    a q tile's key loop walks: a noised tile's own diagonal and the clean
+    blocks before it, a clean tile's clean blocks alone. The first run of
+    live blocks, then from the next live block to the last (whole where a
+    tile straddles the halves: what is dead in between is masked)."""
+    spans = []
+    for live in _blockdiff_live(rows, block_q, block_k, blocks):
+        at = np.flatnonzero(live)
+        end = at[0] + 1
+        while end in at:
+            end += 1
+        rest = at[at >= end]
+        spans.append((at[0], end) + ((rest[0], rest[-1] + 1) if len(rest)
+                                     else (end, end)))
+    return np.asarray(spans, np.int32)
+
+
+def _blockdiff_cells(rows: int, block_q: int, block_k: int, sub_q: int,
+                     sub_k: int, blocks) -> np.ndarray:
+    """``[cells, 4 + 2 block_q / sub_q]`` int32: the fused backward's grid
+    under the mask, one row a ``block_q x block_k`` cell that holds a score,
+    key tile by key tile and q tile by q tile within it: ``(key tile, q
+    tile, first of its key tile, last of it)`` and, a row sub-tile, the
+    strip ``(lo, w)`` of the key tile's sub-tiles it computes (the hull of
+    the live ones; ``(0, 0)``: none)."""
+    live = _blockdiff_live(rows, sub_q, sub_k, blocks)
+    rq, n = block_q // sub_q, block_k // sub_k
+    cells = []
+    for jk in range(rows // block_k):
+        held = []
+        for iq in range(rows // block_q):
+            strips = []
+            for row in live[iq * rq:(iq + 1) * rq, jk * n:(jk + 1) * n]:
+                at = np.flatnonzero(row)
+                strips += [at[0], at[-1] + 1] if len(at) else [0, 0]
+            if any(strips):
+                held.append([jk, iq, 0, 0] + strips)
+        held[0][2] = held[-1][3] = 1      # every key tile has its diagonal
+        cells += held
+    return np.asarray(cells, np.int32)
+
+
 def flash_plan(causal: bool, tq: int, tk: int, q_off: int, k_off: int,
                block_k: int, sub_q: int, sub_k: int,
-               window: Optional[int] = None) -> dict:
+               window: Optional[int] = None, blocks=None) -> dict:
     """What one head of a flash call computes, counted in sub-tiles of
     ``sub_q x sub_k`` scores by the bound the kernels themselves run
     (:func:`_live_sub_tiles` a row sub-tile and key block of ``block_k``):
@@ -365,8 +451,25 @@ def flash_plan(causal: bool, tq: int, tk: int, q_off: int, k_off: int,
     cell of skipped sub-tiles. With ``window`` (a causal call) the band's
     second bound counts as the kernels run it (:func:`_band_first`).
     ``needed`` is the scores the mathematics asks for, one by one: what
-    ``scores`` is measured against. No JAX."""
+    ``scores`` is measured against. With ``blocks`` (the block-diffusion
+    mask: ``tq = tk = 2 T`` from position 0) the tables the kernels walk
+    are counted: the forward's key-loop trips where ``sub_k`` is
+    ``block_k``, else the backward's strips; ``needed`` is ``T^2 + T B``.
+    No JAX."""
     n = block_k // sub_k
+    if blocks is not None:
+        if n == 1:
+            spans = _blockdiff_spans(tq, sub_q, block_k, blocks)
+            computed = int((spans[:, 1] - spans[:, 0]
+                            + spans[:, 3] - spans[:, 2]).sum())
+        else:
+            strips = _blockdiff_cells(tq, sub_q, block_k, sub_q, sub_k,
+                                      blocks)[:, 4:]
+            computed = int((strips[:, 1] - strips[:, 0]).sum())
+        return {"computed": computed, "masked": computed,
+                "skipped": (tq // sub_q) * (tk // sub_k) - computed,
+                "scores": computed * sub_q * sub_k,
+                "needed": blocks[1] * (blocks[1] + blocks[0])}
     rows, blocks = tq // sub_q, tk // block_k
 
     def live(q_lo, k_lo):
@@ -389,10 +492,40 @@ def flash_plan(causal: bool, tq: int, tk: int, q_off: int, k_off: int,
 
 
 # =========================================================== flash attention
-def _causal_mask(s, q_lo, k_lo, q_axis=0, window=None):
+def _blockdiff_keep(shape, q_lo, k_lo, q_axis, blocks):
+    """The block-diffusion mask (the note at :func:`_blockdiff_live`) of a
+    tile of scores, queries from row ``q_lo`` along ``q_axis`` and keys
+    from ``k_lo`` along the other. A row's block among the ``2 T / B`` of
+    both halves is worked out on a column of queries and a row of keys,
+    ``O(BQ + BK)``, and turned into one number a key, ``u`` (a clean key
+    its block, a noised key its block and ``T / B`` more), and two a query:
+    ``hi``, the last clean block it sees, and ``eq``, the noised block it
+    sees (none: -1). A score then costs two comparisons and an or: a
+    key block of 512 x 1024 takes 2.13 us forward on a v5e, the causal
+    mask's price (PERF.md section 6, PR 50)."""
+    length, half = blocks
+    nb = half // length
+    q_shape, k_shape = [1, 1], [1, 1]
+    q_shape[q_axis], k_shape[1 - q_axis] = shape[q_axis], shape[1 - q_axis]
+    gq = lax.div(q_lo + lax.broadcasted_iota(jnp.int32, q_shape, q_axis),
+                 jnp.int32(length))
+    gk = lax.div(k_lo + lax.broadcasted_iota(jnp.int32, k_shape, 1 - q_axis),
+                 jnp.int32(length))
+    u = jnp.where(gk >= nb, gk - nb, gk + nb)
+    clean = gq >= nb
+    hi = jnp.where(clean, gq - nb, gq - 1)
+    eq = jnp.where(clean, -1, gq + nb)
+    return (u <= hi) | (u == eq)
+
+
+def _causal_mask(s, q_lo, k_lo, q_axis=0, window=None, blocks=None):
     """The scores ``s`` of queries from global position ``q_lo`` (along
     ``q_axis``; the keys, from ``k_lo``, along the other), -inf above the
-    diagonal and, with ``window``, ``window`` positions or more below it."""
+    diagonal and, with ``window``, ``window`` positions or more below it;
+    with ``blocks``, -inf where the block-diffusion mask hides the key."""
+    if blocks is not None:
+        return jnp.where(_blockdiff_keep(s.shape, q_lo, k_lo, q_axis,
+                                         blocks), s, NEG_INF)
     delta = (lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
              - lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
     keep = delta >= k_lo - q_lo
@@ -443,7 +576,7 @@ def _stat_col(row):
 
 
 def _flash_accum(q, k_ref, v_ref, m, l, o, *, q_off, k_off, causal,
-                 scale, block_k, window=None):
+                 scale, block_k, window=None, blocks=None, spans=None):
     """Online-softmax accumulation of the q tile (global first row
     ``q_off``) against the slice's resident k/v, a key block of
     ``block_k`` at a time — THE shared inner body of the ring-step and
@@ -461,7 +594,9 @@ def _flash_accum(q, k_ref, v_ref, m, l, o, *, q_off, k_off, causal,
     costs nothing. A ring hop the diagonal leaves wholly dead is then
     computed to exact zeros (p = 0 on every masked score) and not skipped.
     With ``window`` the loop also starts at the first key block of the
-    band (:func:`_band_first`)."""
+    band (:func:`_band_first`). With ``blocks`` (the block-diffusion mask)
+    it walks the key blocks of ``spans``, the q tile's four scalars of
+    :func:`_blockdiff_spans`: ``[a0, a1)``, then ``[b0, b1)``."""
     bq = q.shape[0]
     in_dt = q.dtype
     nblk = k_ref.shape[1] // block_k
@@ -475,7 +610,8 @@ def _flash_accum(q, k_ref, v_ref, m, l, o, *, q_off, k_off, causal,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         if causal:
-            s = _causal_mask(s, q_off, k_off + j * block_k, window=window)
+            s = _causal_mask(s, q_off, k_off + j * block_k, window=window,
+                             blocks=blocks)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
         p = jnp.exp2(s - m_safe[:, None])             # exp2(-inf) == 0
@@ -487,6 +623,12 @@ def _flash_accum(q, k_ref, v_ref, m, l, o, *, q_off, k_off, causal,
 
     if nblk == 1:
         return body(0, (m, l, o))
+    if blocks is not None:
+        a0, a1, b0, b1 = spans
+        return lax.fori_loop(
+            0, a1 - a0 + b1 - b0, lambda t, carry: body(
+                jnp.where(t < a1 - a0, a0 + t, b0 - (a1 - a0) + t), carry),
+            (m, l, o))
     # k blocks past the last unmasked key for this q tile contribute
     # nothing — bound the loop (exact: those blocks are fully masked)
     first, live = 0, nblk
@@ -525,7 +667,8 @@ def _flash_step_kernel(offs_ref, q_ref, k_ref, v_ref, m_ref, l_ref, o_ref,
 
 
 def _flash_fwd_once_kernel(offs_ref, q_ref, k_ref, v_ref, oo_ref, lse_ref,
-                           *, causal, scale, block_k, window=None):
+                           *, causal, scale, block_k, window=None,
+                           blocks=None):
     """Single-shot forward: the resident step kernel minus the ring-carry
     plumbing. No (m, l, o) stream in — the statistics initialize in
     registers — and the output is NORMALIZED in-kernel (FlashAttention-2
@@ -541,9 +684,12 @@ def _flash_fwd_once_kernel(offs_ref, q_ref, k_ref, v_ref, oo_ref, lse_ref,
     m = jnp.full((bq,), NEG_INF, jnp.float32)
     l = jnp.zeros((bq,), jnp.float32)
     o = jnp.zeros((bq, v_ref.shape[2]), jnp.float32)
+    spans = None if blocks is None else tuple(     # behind the offsets
+        offs_ref[2 + 4 * pl.program_id(1) + c] for c in range(4))
     m, l, o = _flash_accum(q, k_ref, v_ref, m, l, o,
                            q_off=q_off, k_off=k_off, causal=causal,
-                           scale=scale, block_k=block_k, window=window)
+                           scale=scale, block_k=block_k, window=window,
+                           blocks=blocks, spans=spans)
     # the _masked_row_stats convention, fused into the epilogue:
     # l == 0 -> out 0, lse sentinel log(1) on top of a zeroed m
     l_safe = jnp.where(l == 0, 1.0, l)
@@ -552,9 +698,11 @@ def _flash_fwd_once_kernel(offs_ref, q_ref, k_ref, v_ref, oo_ref, lse_ref,
     lse_ref[0] = _stat_row(m_nat + jnp.log(l_safe))
 
 
-@functools.partial(jax.jit, static_argnames=_FLASH_STATIC + ("fusable",))
+@functools.partial(jax.jit, static_argnames=_FLASH_STATIC + ("fusable",
+                                                             "blocks"))
 def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
-                         block_k, interpret, fusable, window=None):
+                         block_k, interpret, fusable, window=None,
+                         blocks=None):
     """Resident-layout dispatch of the single-shot forward (jitted: the
     rule below :func:`_named_call`).
     qt: [BH, TQ, D]; kt: [BH, TK, D]; vt: [BH, TK, DV] → (out [BH, TQ, DV]
@@ -564,10 +712,15 @@ def _flash_fwd_once_call(qt, kt, vt, offs, *, causal, scale, block_q,
     tk, dv = vt.shape[1:]
     # the only caller passes zero offsets: the plan is the call's
     scores = bh * flash_plan(causal, tq, tk, 0, 0, block_k, block_q,
-                             block_k, window)["scores"]
+                             block_k, window, blocks)["scores"]
+    kernel = functools.partial(_flash_fwd_once_kernel, causal=causal,
+                               scale=scale, block_k=block_k, window=window)
+    if blocks is not None:
+        kernel = functools.partial(kernel, blocks=blocks)
+        offs = jnp.concatenate([offs, jnp.asarray(_blockdiff_spans(
+            tq, block_q, block_k, blocks).ravel())])
     return _named_call("flash_fwd",
-        functools.partial(_flash_fwd_once_kernel, causal=causal,
-                          scale=scale, block_k=block_k, window=window),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, tq // block_q),
@@ -970,7 +1123,7 @@ def _dot_tn(a, b):
 
 
 def _bwd_scores_t(q, k, v, out, do, lse, q_lo, k_lo, *, causal, scale,
-                  window=None):
+                  window=None, blocks=None):
     """``(p^T, ds^T)`` of q rows ``[SQ, D]`` (from global position
     ``q_lo``) against keys ``[W, D]`` (from ``k_lo``), both ``[W, SQ]`` in
     the operands' dtype — THE recompute every backward kernel shares:
@@ -992,7 +1145,8 @@ def _bwd_scores_t(q, k, v, out, do, lse, q_lo, k_lo, *, causal, scale,
     s_t = (scale * _LOG2E) * lax.dot_general(
         k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
     if causal:
-        s_t = _causal_mask(s_t, q_lo, k_lo, q_axis=1, window=window)
+        s_t = _causal_mask(s_t, q_lo, k_lo, q_axis=1, window=window,
+                           blocks=blocks)
     p_t = jnp.exp2(s_t - lse * _LOG2E)                # exp2(-inf) == 0
     dp_t = lax.dot_general(v, do, (((1,), (1,)), ((), ())),
                            preferred_element_type=jnp.float32)
@@ -1081,7 +1235,7 @@ def _flash_bwd_dkv_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
 def _flash_bwd_fused_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
                             do_ref, dq_ref, dk_ref, dv_ref, *maybe_acc,
                             causal, scale, sub_q, sub_k, window=None,
-                            nq=None):
+                            nq=None, blocks=None):
     """ONE-pass FlashAttention-2 backward: grid (bh, k tiles, q tiles) with
     q innermost; each cell recomputes p ONCE and emits all three gradient
     contributions. The streaming pair of kernels (dq pass + dkv pass) each
@@ -1124,29 +1278,52 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
     tile, dead; a strip starts at the band's first sub-tile
     (:func:`_band_first`) as it ends at the diagonal's last. Every q tile
     is in the sweep of its own diagonal's key block, which is the last
-    that adds to it: its dq is final when it is last written."""
+    that adds to it: its dq is final when it is last written.
+
+    With ``blocks`` (the block-diffusion mask) the grid is ``(bh, cells)``:
+    the cells that hold a score and no other, in the order of
+    :func:`_blockdiff_cells`, whose rows lie behind the two offsets. A
+    cell reads its key tile and q tile there, whether it opens or closes
+    its key tile's sweep, and each row sub-tile's strip ``(lo, w)``; the
+    bodies are the band's. A q tile's dq is final at its last cell."""
     if len(maybe_acc) == 3:
         dq_acc, dk_acc, dv_acc = maybe_acc
     else:
         dq_acc, (dk_acc, dv_acc) = None, maybe_acc
-    jk, step = pl.program_id(1), pl.program_id(2)
-    steps = pl.num_programs(2)
     bq, bk = q_ref.shape[1], k_ref.shape[1]
-    iq = tile = step
-    if nq is not None:
-        first, last = _band_q(offs_ref[1] + jk * bk, offs_ref[0], bq, bk,
-                              nq, window)
-        iq = first + step
-        tile = jnp.minimum(iq, last)      # the q-side index maps' clamp
+    if blocks is not None:
+        cell = pl.program_id(1)
+        width = 4 + 2 * (bq // sub_q)
+
+        def listed(c):
+            return offs_ref[2 + width * cell + c]
+
+        jk, iq = listed(0), listed(1)
+        tile = iq
+        opens, closes = (lambda: listed(2) == 1), (lambda: listed(3) == 1)
+        first_cell = lambda: cell == 0
+    else:
+        jk, step = pl.program_id(1), pl.program_id(2)
+        steps = pl.num_programs(2)
+        iq = tile = step
+        if nq is not None:
+            first, last = _band_q(offs_ref[1] + jk * bk, offs_ref[0], bq,
+                                  bk, nq, window)
+            iq = first + step
+            tile = jnp.minimum(iq, last)  # the q-side index maps' clamp
+        # (worked out where they are used, as they always were: the
+        # kernel's operations keep their order)
+        opens, closes = (lambda: step == 0), (lambda: step == steps - 1)
+        first_cell = lambda: jnp.logical_and(jk == 0, step == 0)
     q_off = offs_ref[0] + iq * bq
     k_off = offs_ref[1] + jk * bk
 
     if dq_acc is not None:
-        @pl.when(jnp.logical_and(jk == 0, step == 0))
+        @pl.when(first_cell())
         def _():
             dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(step == 0)
+    @pl.when(opens())
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -1162,7 +1339,7 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
             q, k, v_ref[0, cols, :], o_ref[0, rows, :], do,
             lse_ref[0, :, rows], q_off + rows.start,
             k_off + lo * sub_k if lo else k_off, causal=causal,
-            scale=scale, window=window)
+            scale=scale, window=window, blocks=blocks)
         dv_acc[cols, :] += jnp.dot(p_t, do,
                                    preferred_element_type=jnp.float32)
         dk_acc[cols, :] += jnp.dot(ds_t, q,
@@ -1174,13 +1351,19 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
             dq_acc[pl.ds(iq * bq + rows.start, sub_q), :] += dq
 
     n = bk // sub_k
+    # a strip may start past the key tile's first sub-tile
+    ranged = window is not None or blocks is not None
     for r0 in range(0, bq, sub_q):
         rows = pl.ds(r0, sub_q)
         if not causal:
             strip(rows, 0, n)
             continue
-        live = _live_sub_tiles(q_off + r0, k_off, sub_q, sub_k, n)
-        lo = 0
+        if blocks is not None:
+            lo, live = listed(4 + 2 * (r0 // sub_q)), listed(
+                5 + 2 * (r0 // sub_q))
+        else:
+            live = _live_sub_tiles(q_off + r0, k_off, sub_q, sub_k, n)
+            lo = 0
         if window is not None:
             lo = _band_first(q_off + r0, k_off, sub_k, n, window)
             if nq is not None:        # a step past the band's last q tile
@@ -1188,18 +1371,18 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
         if dq_acc is None:
             # nothing of the k tile is in the band for these rows (every
             # row sub-tile of a dead cell): their dq is zero
-            @pl.when(live == 0 if window is None else live <= lo)
+            @pl.when(live <= lo if ranged else live == 0)
             def _(rows=rows):
                 dq_ref[0, rows, :] = jnp.zeros(
                     (sub_q, dq_ref.shape[2]), dq_ref.dtype)
         # one body a strip: from sub-tile ``a`` (0 without a window) to ``w``
-        for a in range(n if window is not None else 1):
+        for a in range(n if ranged else 1):
             for w in range(a + 1, n + 1):
-                pl.when(live == w if window is None
-                        else jnp.logical_and(lo == a, live == w))(
+                pl.when(jnp.logical_and(lo == a, live == w) if ranged
+                        else live == w)(
                     functools.partial(strip, rows, a, w))
 
-    @pl.when(step == steps - 1)
+    @pl.when(closes())
     def _():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -1209,10 +1392,11 @@ def _flash_bwd_fused_kernel(offs_ref, lse_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=_FLASH_STATIC + (
-    "fusable", "out_dtype", "static_offs", "vmem_limit"))
+    "fusable", "out_dtype", "static_offs", "vmem_limit", "blocks"))
 def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
                      block_q, block_k, interpret, fusable, out_dtype=None,
-                     static_offs=None, window=None, vmem_limit=None):
+                     static_offs=None, window=None, vmem_limit=None,
+                     blocks=None):
     """Dispatch of the one-pass backward (every head whose dq scratch is
     within ``_DQ_SCRATCH_CAP``: k/v tiles stream through the grid, dq rides
     the VMEM scratch). ``out_dtype`` picks the gradient output dtype
@@ -1223,7 +1407,9 @@ def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
     then counts the call's own plan, and the whole rectangle (an upper
     bound) where they are traced. ``vmem_limit`` is ``flash_route``'s
     ``backward_vmem``: the bytes the multi-sweep call asks Mosaic for,
-    ``None`` its default."""
+    ``None`` its default. With ``blocks`` (the block-diffusion mask;
+    offsets 0) the grid is the listed cells (:func:`_blockdiff_cells`),
+    whose table follows the offsets."""
     out_dtype = jnp.float32 if out_dtype is None else out_dtype
     bh, tq, d = qt.shape
     tk, dv = vt.shape[1:]
@@ -1233,23 +1419,36 @@ def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
     banded = window is not None and nk > 1
     band = (window, nk) if banded else ()
     _, qmap = _causal_maps(causal, block_q, block_k, nq, *band)
-    ktile = pl.BlockSpec((1, block_k, d), lambda i, j, n, offs: (i, j, 0))
-    vtile = pl.BlockSpec((1, block_k, dv), lambda i, j, n, offs: (i, j, 0))
+    kmap = lambda i, j, n, offs: (i, j, 0)
+    dqmap = qmap if banded else lambda i, j, n, offs: (i, n, 0)
     sub_q, sub_k = _pick_sub_tile(causal, block_q, block_k)
     scores = bh * (tq * tk if static_offs is None else flash_plan(
         causal, tq, tk, *static_offs, block_k, sub_q, sub_k,
-        window)["scores"])
+        window, blocks)["scores"])
+    kernel = functools.partial(_flash_bwd_fused_kernel, causal=causal,
+                               scale=scale, sub_q=sub_q, sub_k=sub_k,
+                               window=window, nq=nq if banded else None)
+    # q innermost: dk/dv revisits are consecutive; j sweeps accumulate dq
+    # in the persistent scratch
+    grid = (bh, nk, _band_spans(window, block_q, block_k, nq, nk)[1]
+            if banded else nq)
+    sweep = ("arbitrary", "arbitrary")
+    if blocks is not None:
+        cells = _blockdiff_cells(tq, block_q, block_k, sub_q, sub_k, blocks)
+        width = cells.shape[1]
+        offs = jnp.concatenate([offs, jnp.asarray(cells.ravel())])
+        kernel = functools.partial(kernel, blocks=blocks)
+        grid, sweep = (bh, len(cells)), ("arbitrary",)
+        kmap = lambda i, c, offs: (i, offs[2 + width * c], 0)
+        qmap = dqmap = lambda i, c, offs: (i, offs[3 + width * c], 0)
+    ktile = pl.BlockSpec((1, block_k, d), kmap)
+    vtile = pl.BlockSpec((1, block_k, dv), kmap)
 
     return _named_call("flash_bwd",
-        functools.partial(_flash_bwd_fused_kernel, causal=causal,
-                          scale=scale, sub_q=sub_q, sub_k=sub_k,
-                          window=window, nq=nq if banded else None),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            # q innermost: dk/dv revisits are consecutive; j sweeps
-            # accumulate dq in the persistent scratch
-            grid=(bh, nk, _band_spans(window, block_q, block_k, nq, nk)[1]
-                  if banded else nq),
+            grid=grid,
             in_specs=[
                 _stat_spec(block_q, qmap),
                 pl.BlockSpec((1, block_q, d), qmap),
@@ -1258,8 +1457,7 @@ def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
                 pl.BlockSpec((1, block_q, dv), qmap),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, d), qmap if banded else
-                             lambda i, j, n, offs: (i, n, 0)),
+                pl.BlockSpec((1, block_q, d), dqmap),
                 ktile, vtile,
             ],
             # single k sweep: dq finishes inside its cell — no dq scratch;
@@ -1288,11 +1486,10 @@ def _flash_bwd_fused(qt, kt, vt, ot, dot, lset, offs, *, causal, scale,
         # producer recompute, so it stays off there); the multi-sweep form
         # keeps Mosaic's default unless its scratch needs a limit named
         compiler_params=(
-            _input_fusion(_cparams("parallel", "arbitrary", "arbitrary",
-                                   resident=True), "sttttt", fusable)
+            _input_fusion(_cparams("parallel", *sweep, resident=True),
+                          "sttttt", fusable)
             if tk // block_k == 1
-            else _cparams("parallel", "arbitrary", "arbitrary",
-                          vmem_limit=vmem_limit)),
+            else _cparams("parallel", *sweep, vmem_limit=vmem_limit)),
         interpret=interpret,
     )(offs, lset, qt, kt, vt, ot, dot)
 
@@ -1318,7 +1515,8 @@ def _flash_bwd(q, k, v, out, lse, dout, q_off=0, k_off=0, *, causal, scale):
 
 
 def _flash_bwd_hm(qt, kt, vt, ot, dot, lset, q_off=0, k_off=0, *,
-                  causal, scale, fusable, out_dtype=None, window=None):
+                  causal, scale, fusable, out_dtype=None, window=None,
+                  blocks=None):
     """Heads-major core of :func:`_flash_bwd`: operands/grads all
     ``[BH, T, D]`` (v, out, dout and dv ``[BH, T, DV]``; lse ``[BH, 1, T]``)
     so a caller that already holds
@@ -1343,7 +1541,8 @@ def _flash_bwd_hm(qt, kt, vt, ot, dot, lset, q_off=0, k_off=0, *,
             block_q=block_q, block_k=block_k, interpret=interpret,
             fusable=fusable, out_dtype=out_dtype,
             static_offs=(q_off, k_off) if static else None, window=window,
-            vmem_limit=route["backward_vmem"])
+            vmem_limit=route["backward_vmem"], blocks=blocks)
+    assert blocks is None          # flash_attention refused it
     return _flash_bwd_streaming(
         qt, kt, vt, ot, dot, lset, offs, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret, window=window)
@@ -1463,7 +1662,7 @@ def finalize_attention_stats(m, l, o, out_dtype):
 
 @functools.lru_cache(maxsize=None)
 def _flash_fullattn_vjp(causal: bool, scale: float,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None, blocks=None):
     """Normalized flash attention with a full Pallas backward
     (FlashAttention-2): forward saves only (q, k, v, out, LSE) — O(T)
     residuals — and the backward recomputes p blockwise on the MXU instead
@@ -1493,8 +1692,10 @@ def _flash_fullattn_vjp(causal: bool, scale: float,
             out_t, lse_t = _flash_fwd_once_call(
                 qt, kt, vt, offs, causal=causal, scale=scale,
                 block_q=block_q, block_k=block_k, interpret=_interpret(),
-                fusable=_relayout_fusable(b, h), window=window)
+                fusable=_relayout_fusable(b, h), window=window,
+                blocks=blocks)
             return qt, kt, vt, out_t, lse_t
+        assert blocks is None      # flash_attention refused it
         mt = jnp.full((bh, 1, tq), NEG_INF, jnp.float32)
         lt = jnp.zeros((bh, 1, tq), jnp.float32)
         ot = jnp.zeros((bh, tq, dv), jnp.float32)
@@ -1535,7 +1736,8 @@ def _flash_fullattn_vjp(causal: bool, scale: float,
         dq, dk, dv = _flash_bwd_hm(qt, kt, vt, out_t, dot, lse_t,
                                    causal=causal, scale=scale,
                                    fusable=_relayout_fusable(b, h),
-                                   out_dtype=qt.dtype, window=window)
+                                   out_dtype=qt.dtype, window=window,
+                                   blocks=blocks)
         return (_heads_minor(dq, b, h, tq, d).astype(qt.dtype),
                 _heads_minor(dk, b, h, tk, d).astype(kt.dtype),
                 _heads_minor(dv, b, h, tk, v_width).astype(vt.dtype))
@@ -1546,7 +1748,8 @@ def _flash_fullattn_vjp(causal: bool, scale: float,
 
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    block_diffusion: Optional[int] = None):
     """Single-device flash attention, ``[B, T, H, D]`` layout.
 
     The full-sequence special case of the ring step (one hop, offsets 0),
@@ -1565,10 +1768,32 @@ def flash_attention(q, k, v, *, causal: bool = False,
     itself among them. The kernels skip what lies behind the band as they
     skip what lies above the diagonal (the note at :func:`_band_first`);
     a window that reaches the first key from the last query is no window.
+
+    ``block_diffusion=B`` on a causal call of ``2 T`` queries and as many
+    keys takes the block-diffusion mask in the triangle's place: rows
+    ``[0, T)`` are a sequence's noised copy and rows ``[T, 2 T)`` the clean
+    one, each half at positions ``0..T-1`` in blocks of ``B``. A query sees
+    the clean keys of the blocks before its own, the clean keys of its own
+    block if it is clean itself, and the noised keys of its own block if it
+    is noised itself: ``T^2 + T B`` scores a head. The kernels skip the
+    dead tiles (the note at :func:`_blockdiff_live`); ``window`` with it is
+    refused, as is a length the resident forward and the one-pass backward
+    do not take.
     """
     d = q.shape[-1]
     if scale is None:
         scale = d ** -0.5
+    blocks = None
+    if block_diffusion is not None:
+        rows, length = q.shape[1], block_diffusion
+        if window is not None or not causal or length < 1 \
+                or rows != k.shape[1] or rows % (2 * length):
+            raise ValueError(
+                f"block_diffusion={length!r} needs causal=True, no window, "
+                f"a positive block length and 2 T queries and keys with T "
+                f"a multiple of it; got causal={causal}, window={window!r}, "
+                f"{rows} queries, {k.shape[1]} keys")
+        blocks = (int(length), rows // 2)
     if window is not None:
         if not causal or window < 1 or q.shape[1] != k.shape[1]:
             raise ValueError(
@@ -1579,8 +1804,16 @@ def flash_attention(q, k, v, *, causal: bool = False,
     if kernel_path("flash_attention", q, k, v) == "reference":
         from ..parallel.ring_attention import reference_attention
         return reference_attention(q, k, v, causal=causal, scale=scale,
-                                   window=window)
-    return _flash_fullattn_vjp(causal, float(scale), window)(q, k, v)
+                                   window=window,
+                                   block_diffusion=block_diffusion)
+    if blocks is not None:
+        route = flash_route(q.shape[1], k.shape[1], d, q.dtype.itemsize,
+                            dv=v.shape[-1])
+        if (route["forward"], route["backward"]) != ("once", "fused"):
+            raise ValueError(
+                f"block_diffusion at {q.shape[1]} rows of width {d}: the "
+                f"streaming kernels do not take the mask ({route})")
+    return _flash_fullattn_vjp(causal, float(scale), window, blocks)(q, k, v)
 
 
 # ==================================================================== adasum

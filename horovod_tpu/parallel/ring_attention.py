@@ -209,9 +209,13 @@ def make_ring_attention(mesh, axis_name: str = "sp", causal: bool = False):
 
 def reference_attention(q, k, v, causal: bool = False,
                         scale: Optional[float] = None,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None,
+                        block_diffusion: Optional[int] = None):
     """Plain full attention (for tests / single-device fallback); with
-    ``window`` (causal) a query sees its last ``window`` positions only."""
+    ``window`` (causal) a query sees its last ``window`` positions only;
+    with ``block_diffusion`` (causal, ``2 T`` rows: a noised half then a
+    clean one, in blocks of that length) ``flash_attention``'s mask of that
+    name, its three clauses written out."""
     d = q.shape[-1]
     if scale is None:
         scale = d ** -0.5
@@ -221,6 +225,14 @@ def reference_attention(q, k, v, causal: bool = False,
         tq, tk = q.shape[1], k.shape[1]
         delta = jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
         mask = delta >= 0 if window is None else (delta >= 0) & (delta < window)
+        if block_diffusion is not None:
+            half = tq // 2
+            clean = jnp.arange(tq) >= half
+            block = jnp.arange(tq) % half // block_diffusion
+            (cq, ck), (bq, bk) = ((a[:, None], a[None, :])
+                                  for a in (clean, block))
+            mask = (ck & ((bk < bq) | ((bk == bq) & cq))) \
+                | (~ck & ~cq & (bk == bq))
         s = jnp.where(mask[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p,

@@ -36,7 +36,10 @@ HYBRID_CELLS = {
                                "moe_dispatch_ms", "moe_shared_ms",
                                "lm_head_ms", "attn_gate_ms",
                                "delta_rule_ms", "delta_rule_roofline",
-                               "delta_rule_prep_ms"}}
+                               "delta_rule_prep_ms"},
+    "sdarmoe-train-s8192": {"moe_ms", "moe_experts_ms", "moe_route_ms",
+                            "moe_dispatch_ms", "lm_head_ms",
+                            "attn_blockdiff_relayout_ms", "denoise_io_ms"}}
 
 #: the interpreter's kernels are no custom calls: a reader of class
 #: ``attention_kernel`` finds its scope and no time under it

@@ -1259,3 +1259,40 @@ def test_packed_wire_rows_golden_bytes(wire, digest, scale_hex):
         p = np.asarray(getattr(pk, fn)(jnp.asarray(x)))
         assert p[:, -4:].astype(np.uint8).tobytes().hex() == scale_hex
         assert hashlib.sha256(p.tobytes()).hexdigest() == digest
+
+
+@pytest.mark.parametrize("why,shape,kw", [
+    ("a window with it", (1, 128, 2, 64), dict(causal=True, window=16)),
+    ("no causal flag", (1, 128, 2, 64), dict(causal=False)),
+    ("T no multiple of B", (1, 136, 2, 64), dict(causal=True)),
+    ("an odd number of rows", (1, 129, 2, 64), dict(causal=True)),
+    ("a block length of nought", (1, 128, 2, 64),
+     dict(causal=True, block_diffusion=0)),
+])
+def test_block_diffusion_mask_refuses_what_it_is_not_written_for(why, shape,
+                                                                 kw):
+    """``flash_attention(block_diffusion=B)``: ``window`` together with the
+    mask is refused and not ignored, as is a call that is not ``2 T`` rows
+    of queries and keys with ``T`` a multiple of ``B``; on either path."""
+    x = jnp.zeros(shape, jnp.float32)
+    kw = {"block_diffusion": 8, **kw}
+    with pytest.raises(ValueError, match="block_diffusion"):
+        pk.flash_attention(x, x, x, **kw)
+    with pytest.raises(ValueError, match="block_diffusion"):
+        pk.flash_attention(x, x[:, :64], x[:, :64], causal=True,
+                           block_diffusion=8)
+
+
+def test_block_diffusion_mask_refuses_the_streaming_kernels(monkeypatch):
+    """A head whose K and V are not resident, or whose dq scratch is over
+    the cap, takes kernels the mask is not written for: refused where the
+    kernels are on, by shape alone."""
+    monkeypatch.setenv("HVD_PALLAS", "interpret")
+    monkeypatch.setattr(pk, "_KV_VMEM_CAP", 1)
+    x = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.bfloat16)
+    with pytest.raises(ValueError, match="streaming kernels"):
+        jax.eval_shape(lambda q: pk.flash_attention(
+            q, q, q, causal=True, block_diffusion=4), x)
+    with pytest.raises(ValueError, match="takes no window"):
+        pk.flash_attention_step(None, None, None, None, None, None, 0, 0,
+                                window=4)

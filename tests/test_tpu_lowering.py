@@ -839,6 +839,88 @@ def test_window_and_full_attention_sites_are_named_and_classed(remat,
     assert not re.search(attn_gate_ms.PATTERN, "x/block_1/mixer/gate_norm/mul")
 
 
+@pytest.mark.parametrize("rows,width,block", [(16384, 128, 4),
+                                              (768, 128, 12)])
+def test_block_diffusion_mask_compiles_for_v5e(rows, width, block,
+                                               v5e_devices):
+    """``sdarmoe-train-s8192``'s layer (16,384 rows, 32 heads of 128, blocks
+    of 4: K and V resident, a dq scratch that asks for its own limit, the
+    tables of live tiles behind the offsets in the scalar prefetch), and a
+    block length that is no power of two and that the tiles' edges cut
+    (an integer division on a column and a row): forward and backward."""
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    mesh = Mesh(np.array(v5e_devices[:1]), ("hvd",))
+    heads = 32 if rows > 1024 else 4
+    q = jax.ShapeDtypeStruct((1, rows, heads, width), jnp.bfloat16)
+    route = pk.flash_route(rows, rows, width, 2)
+    assert (route["forward"], route["backward"]) == ("once", "fused")
+    grads = jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
+        q, k, v, causal=True, block_diffusion=block).astype(jnp.float32)),
+        argnums=(0, 1, 2))
+    lowered = lower_tpu(grads, *on_mesh([q, q, q], mesh))
+    assert sorted(re.findall(r'kernel_name = "(flash_\w+)"',
+                             lowered.as_text())) == ["flash_bwd", "flash_fwd"]
+    lowered.compile()
+
+
+@pytest.mark.parametrize("remat", ["none", "full"])
+def test_block_diffusion_sites_are_named_and_classed(remat, v5e_devices):
+    """A model built to denoise by blocks (the SDAR family's toy at 1,024
+    data positions, 2,048 rows: two key tiles a head): in the compiled step
+    every site is a ``tpu_custom_call %flash_fwd`` / ``%flash_bwd`` under
+    its own ``block_<i>/mixer/block_diffusion``, the backward's under
+    ``transpose(``: what the benchmark's op class ``attention_kernel``, its
+    scope classes ``attn_fwd`` / ``attn_bwd`` and
+    ``attn_blockdiff_relayout_ms``'s own pattern read; the halves are laid
+    side by side and cut apart under ``denoise_io``."""
+    from jax.sharding import SingleDeviceSharding
+
+    from chipbench import op_scopes, trace_reduce
+    from chipbench.families import sdar_moe
+    from chipbench.layer_metrics import (attn_blockdiff_relayout_ms,
+                                         denoise_io_ms)
+    from tests.test_sdar_moe import CONFIG
+
+    op_classes = trace_reduce.load_classes()
+    scope_classes = trace_reduce.load_classes(op_scopes.SCOPE_CLASSES)
+    model = sdar_moe.build_model(CONFIG, 512, {"remat": remat})
+    one = SingleDeviceSharding(v5e_devices[0])
+    ids = jnp.zeros((1, 1024), jnp.int32)
+    toks = jax.ShapeDtypeStruct(ids.shape, ids.dtype, sharding=one)
+    params = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0), ids,
+                       ids)["params"])
+    with jax.enable_x64(False):
+        lowered = jax.jit(jax.grad(lambda p, x, y: jnp.sum(
+            model.apply({"params": p}, x, y).astype(jnp.float32)))).trace(
+                params, toks, toks).lower(lowering_platforms=("tpu",))
+    # one shape of flash call: one body a kernel
+    assert sorted(re.findall(r'kernel_name = "(flash_\w+)"',
+                             lowered.as_text())) == ["flash_bwd", "flash_fwd"]
+    text = lowered.compile().as_text()
+    calls = _kernel_calls(text, r"flash_\w+?")
+    assert sorted(name for name, _, _ in calls) == \
+        ["flash_bwd"] * 2 + ["flash_fwd"] * 2
+    for name, instruction, path in calls:
+        dispatcher, scope = {
+            "flash_fwd": ("_flash_fwd_once_call", "attn_fwd"),
+            "flash_bwd": ("_flash_bwd_fused", "attn_bwd")}[name]
+        assert re.search(
+            rf"/block_\d/mixer/block_diffusion/jit\({dispatcher}\)/{name}/",
+            path), path
+        assert attn_blockdiff_relayout_ms.PATTERN.search(path)
+        assert ("transpose(" in path) == (name == "flash_bwd"), path
+        assert trace_reduce.classify(path, scope_classes) == scope
+        assert trace_reduce.classify(
+            f"tpu_custom_call %{instruction}", op_classes) \
+            == "attention_kernel"
+    assert re.search(r'op_name="[^"]*/denoise_io/[^"]*"', text)
+    assert re.search(denoise_io_ms.PATTERN, "jit(f)/HybridLM/denoise_io/slice")
+    assert not re.search(denoise_io_ms.PATTERN, "jit(f)/denoise_io_x/slice")
+
+
 # the forward a head's K and V take -> its kernel and its dispatcher: the
 # cell's (resident since PR 44), and the streaming one a head past
 # ``_KV_VMEM_CAP`` takes, here by a cap of 1
