@@ -473,6 +473,7 @@ def kernel_cases():
         **grouped_cases(),
         **scan_cases(),
         **delta_cases(),
+        **conv_cases(),
         "matmul_2d (LM head chunk)": KernelCase(
             pk.matmul_2d,
             [s((B * T // 4, DM // 4), jnp.bfloat16),
@@ -685,6 +686,45 @@ def delta_cases():
     return {"gated_delta fwd+bwd 32 heads of 128": KernelCase(
         rule, [s((1, t, h, 128), jnp.bfloat16)] * 3
         + [s((1, t, h), jnp.float32)] * 2, 2, kernels_off(rule), TOL_BF16)}
+
+
+def conv_cases():
+    """The depthwise causal conv (``ops/ssd.causal_conv1d``) and its three
+    gradients at the widest part a mixer of the benchmark hands it, the
+    ``x`` of a Mamba-2 layer of the Nemotron-H cell (4,096 positions, 8,192
+    channels, 4 taps, bias, SiLU), and at a short-conv layer of the LFM2
+    cell (2 x 8,192 positions, 2,048 channels, 3 taps, neither): the Pallas
+    pair against the shifted sums in XLA, which is what the same call runs
+    with kernels off. Taps of a fresh layer's range."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import ssd
+
+    def conv(activation, biased):
+        def f(x, kernel, bias):
+            def loss(x, kernel, bias):
+                y = ssd.causal_conv1d(
+                    x, kernel, bias if biased else None,
+                    activation=activation).astype(jnp.float32)
+                return jnp.sum(y * cotangent(y.shape)), y
+            grads, y = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(
+                x, kernel / 2.0, bias / 10.0)
+            return y, grads if biased else grads[:2]
+        return f
+
+    def case(b, t, c, k, activation, biased):
+        s = jax.ShapeDtypeStruct
+        fn = conv(activation, biased)
+        return KernelCase(
+            fn, [s((b, t, c), jnp.bfloat16), s((k, c), jnp.float32),
+                 s((c,), jnp.float32)], 2, kernels_off(fn), TOL_BF16)
+
+    return {
+        "causal_conv fwd+bwd 4096x8192, 4 taps, bias, SiLU": case(
+            1, 4096, 8192, 4, "silu", True),
+        "causal_conv fwd+bwd 2x8192x2048, 3 taps": case(
+            2, 8192, 2048, 3, None, False)}
 
 
 def matmul_reduce_scatter_case(mesh):

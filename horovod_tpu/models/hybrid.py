@@ -67,6 +67,7 @@ from typing import Any, Callable, Dict, Optional, Tuple, Union
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops import moe, ssd
 from ..ops.gated_delta import gated_delta_chunked
@@ -106,17 +107,31 @@ def _conv_kernel_init(width: int):
 
 class CausalConv(nn.Module):
     """Depthwise causal convolution over time, with bias (which starts at
-    0; the kernel: :func:`_conv_kernel_init`) unless ``use_bias`` is off."""
+    0; the kernel: :func:`_conv_kernel_init`) unless ``use_bias`` is off,
+    and ``ops/ssd.causal_conv1d``'s ``activation``. Called with several
+    runs of channels (the parts the mixer splits its projection into) it
+    is one ``[width, channels]`` kernel over them side by side and returns
+    as many: a channel's conv reads no other channel, so each run goes
+    through on its own, from the projection's columns to the consumer's
+    operand, and nothing is split out of one ``[b, T, channels]`` result."""
     width: int
     use_bias: bool = True
+    activation: Optional[str] = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, *parts):
+        channels = [p.shape[-1] for p in parts]
+        cuts = np.cumsum(channels)[:-1]
         kernel = self.param("kernel", _conv_kernel_init(self.width),
-                            (self.width, x.shape[-1]), jnp.float32)
-        bias = self.param("bias", nn.initializers.zeros, (x.shape[-1],),
+                            (self.width, sum(channels)), jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (sum(channels),),
                           jnp.float32) if self.use_bias else None
-        return ssd.causal_conv1d(x, kernel, bias)
+        kernels = jnp.split(kernel, cuts, axis=1)
+        biases = [None] * len(parts) if bias is None \
+            else jnp.split(bias, cuts)
+        out = tuple(ssd.causal_conv1d(p, k, b, activation=self.activation)
+                    for p, k, b in zip(parts, kernels, biases))
+        return out if len(out) > 1 else out[0]
 
 
 class MambaMixer(nn.Module):
@@ -138,11 +153,11 @@ class MambaMixer(nn.Module):
     def __call__(self, h):
         b, t, d_model = h.shape
         inner, bc = self.heads * self.head_dim, self.groups * self.state
-        z, xbc, dt = jnp.split(
+        z, x, B, C, dt = jnp.split(
             _dense(2 * inner + 2 * bc + self.heads, self.dtype, "in_proj")(h),
-            [inner, 2 * inner + 2 * bc], axis=-1)
-        xbc = nn.silu(CausalConv(self.conv_width, name="conv")(xbc))
-        x, B, C = jnp.split(xbc, [inner, inner + bc], axis=-1)
+            [inner, 2 * inner, 2 * inner + bc, 2 * inner + 2 * bc], axis=-1)
+        x, B, C = CausalConv(self.conv_width, activation="silu",
+                             name="conv")(x, B, C)
         if self.groups > 1:
             B, C = (a.reshape(b, t, self.groups, self.state) for a in (B, C))
 
@@ -193,9 +208,9 @@ class GatedDeltaMixer(nn.Module):
         b, t, d_model = h.shape
         heads, rep = self.value_heads, self.value_heads // self.key_heads
         keys, values = self.key_heads * self.key_dim, heads * self.value_dim
-        qkv, z = jnp.split(
+        q, k, v, z = jnp.split(
             _dense(2 * keys + 2 * values, self.dtype, "in_proj")(h),
-            [2 * keys + values], axis=-1)
+            [keys, 2 * keys, 2 * keys + values], axis=-1)
         write, decay = jnp.split(
             _dense(2 * heads, self.dtype, "in_gates")(h), 2, axis=-1)
 
@@ -206,9 +221,10 @@ class GatedDeltaMixer(nn.Module):
                                                      dtype=jnp.float32)))
         steps = jnp.exp(jnp.linspace(jnp.log(1e-3), jnp.log(1e-1), heads))
         dt_bias = per_head("dt_bias", steps + jnp.log(-jnp.expm1(-steps)))
-        conv = CausalConv(self.conv_width, use_bias=False, name="conv")
+        conv = CausalConv(self.conv_width, use_bias=False, activation="silu",
+                          name="conv")
         with jax.named_scope("prep"):
-            q, k, v = jnp.split(nn.silu(conv(qkv)), [keys, 2 * keys], axis=-1)
+            q, k, v = conv(q, k, v)
             beta = nn.sigmoid(write.astype(jnp.float32))
             g = -jnp.exp(a_log) * jax.nn.softplus(
                 decay.astype(jnp.float32) + dt_bias)

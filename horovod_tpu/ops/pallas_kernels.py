@@ -3515,6 +3515,276 @@ def delta_rule(q, k, v, g, beta):
     return o2[:, :t].reshape(v.shape)
 
 
+# ------------------------------------------------- depthwise causal conv
+# ``ops/ssd.causal_conv1d`` (which has the jnp form this is measured against)
+# as two kernels over ``[b, T, C]``, grid ``(batch, lane tile, T tile)``, the
+# T tiles last and in order. A cell reads its own ``[tile_t, tile_c]`` block
+# of ``x`` and, through a second block spec on the same operand, the
+# ``_CONV_HALO`` rows before it (the block index held at 0, the rows zeroed
+# at the sequence's start), lays both as float32 in one VMEM scratch, and
+# takes every tap as a load of that scratch at its own row offset: ``x`` is
+# read from HBM once and nothing is padded there. The backward kernel reads
+# the halo after the tile as well (``dy``'s, and ``x``'s for the
+# pre-activation of those rows, made again here for the activation's
+# derivative: K multiply-adds a point against a ``[T, C]`` residual), and
+# sums ``dkernel`` and ``dbias`` over the T tiles into its float32 output
+# block, which stays in VMEM while the lane tile does. Every product and sum
+# float32, one cast at the end, as the jnp form. The taps and the bias come
+# as one float32 ``[8 or 16, C]`` operand, tap ``i`` row ``i``, the bias (or
+# zeros) row ``K``; its gradient goes back the same way.
+
+#: the kernel tiles, ``T`` and lanes; the rows of a tile a kernel takes at
+#: a time (:func:`_conv_runs`); and the halo's rows: a bf16 sublane tile,
+#: which holds the ``K - 1 <= 7`` rows a tile needs of its neighbour. Read
+#: on a v5e with the runs unrolled, one delta-rule layer of the Qwen3-Next
+#: cell (q, k, v: 16,384 x 2,048, 2,048 and 4,096 channels, 4 taps, SiLU,
+#: bf16), the three parts' forward / backward kernels in ms (PERF.md §6, PR
+#: 51; the jnp form 1.20 and 11.68): tiles 512 x 512 whole 1.57 / 3.52, in
+#: runs of 64 rows 1.17 / 2.37, of 32 1.18 / 2.35; 1024 x 512 in runs of 64
+#: 1.11 / 2.34; 1024 x 256 in runs of 32 1.17 / 2.28, of 64 1.14 / 2.07, of
+#: 128 1.14 / 2.18; 2048 x 256 1.07 / 2.05; 1024 x 128 1.27 / 1.77; 2048 x
+#: 128 1.05 / 1.54; 4096 x 128 in runs of 64 0.97 / 1.41: a run of sixteen
+#: registers an array or fewer stays in them, a lane tile of one lane width
+#: makes the backward's runs short, and a tall tile reads its halos once in
+#: 4,096 rows (two such tiles with their float32 scratch are what Mosaic's
+#: default 16 MiB holds). Unrolled, a 4096-row tile's 64 runs cost 3.3 s of
+#: tracing and lowering a shape at every start; as a loop, 0.1, and the
+#: same layer's kernels read 0.99 / 1.59 in runs of 128 or 256 rows, 1.03 /
+#: 1.89 in runs of 64. The lane tile stays one lane width: a load at a
+#: traced row offset plus a tap's own is one Mosaic takes for a single
+#: lane width only (at 256 lanes: "cannot statically prove that index in
+#: dimension 0 is a multiple of 8").
+_CONV_TILE_T = 4096
+_CONV_TILE_C = 128
+_CONV_CHUNK = 128
+_CONV_HALO = 16
+_CONV_TAPS = 8
+
+
+def conv_route(t: int, c: int, k: int, itemsize: int) -> dict:
+    """Which path the depthwise causal conv of ``t`` positions, ``c``
+    channels and ``k`` taps takes, and at which kernel tiles: ``{"path",
+    "tile_t", "tile_c"}``, ``path`` ``"pallas"`` or ``"reference"`` (the
+    shifted sums in XLA: channels that are not whole lane widths, more
+    than ``_CONV_TAPS`` taps, positions that are not whole halos, an
+    element that is not 2 or 4 bytes). The dispatcher (``ops/ssd.py``)
+    and the tests both read it. No JAX."""
+    if c < _LANES or c % _LANES or not 1 <= k <= _CONV_TAPS \
+            or t < _CONV_HALO or t % _CONV_HALO or itemsize not in (2, 4):
+        return {"path": "reference", "tile_t": None, "tile_c": None}
+    return {"path": "pallas", "tile_t": _pick_block(t, _CONV_TILE_T),
+            "tile_c": _lane_tile(c, _CONV_TILE_C)}
+
+
+def conv_supported(x, kernel) -> bool:
+    """``x`` ``[b, T, C]`` and ``kernel`` ``[K, C]``."""
+    return x.ndim == 3 and conv_route(
+        x.shape[1], x.shape[2], kernel.shape[0],
+        x.dtype.itemsize)["path"] == "pallas"
+
+
+def _conv_taps(w_ref, src_ref, first: int, rows: int, k: int):
+    """``bias + sum_i w[i] * src[first + i : first + i + rows]``, float32,
+    the bias first and the taps in order."""
+    acc = w_ref[k:k + 1, :]
+    for i in range(k):
+        acc = acc + src_ref[pl.ds(first + i, rows), :] * w_ref[i:i + 1, :]
+    return acc
+
+
+def _conv_runs(rows: int, body) -> None:
+    """``body(first, size)`` over ``rows`` rows in runs of ``_CONV_CHUNK``:
+    every sum of a run is made and written before the next is begun, so
+    that a run's values stay in registers. The whole runs are one
+    ``lax.fori_loop`` (``first`` a traced multiple of the run), so a
+    kernel's body is traced and lowered once whatever the tile; a shorter
+    last run comes after it."""
+    whole, rest = divmod(rows, _CONV_CHUNK)
+
+    def step(i, carry):
+        body(pl.multiple_of(i * _CONV_CHUNK, _CONV_CHUNK), _CONV_CHUNK)
+        return carry
+
+    if whole == 1:
+        body(0, _CONV_CHUNK)
+    elif whole:
+        lax.fori_loop(0, whole, step, 0)
+    if rest:
+        body(whole * _CONV_CHUNK, rest)
+
+
+def _conv_fwd_kernel(x_ref, before_ref, w_ref, y_ref, xs_ref, *, k, silu):
+    halo, f32 = _CONV_HALO, jnp.float32
+    xs_ref[:halo] = jnp.where(pl.program_id(2) > 0,
+                              before_ref[0].astype(f32), 0.0)
+    xs_ref[halo:] = x_ref[0].astype(f32)
+
+    def run(first, rows):
+        y = _conv_taps(w_ref, xs_ref, halo - (k - 1) + first, rows, k)
+        if silu:
+            y = y * jax.nn.sigmoid(y)
+        y_ref[0, pl.ds(first, rows)] = y.astype(y_ref.dtype)
+
+    _conv_runs(x_ref.shape[1], run)
+
+
+def _conv_bwd_kernel(x_ref, before_ref, after_ref, dy_ref, dy_after_ref,
+                     w_ref, dx_ref, dw_ref, xs_ref, dp_ref, *, k, silu):
+    halo, f32 = _CONV_HALO, jnp.float32
+    tile_t = x_ref.shape[1]
+    i, nt = pl.program_id(2), pl.num_programs(2)
+    xs_ref[:halo] = jnp.where(i > 0, before_ref[0].astype(f32), 0.0)
+    xs_ref[halo:halo + tile_t] = x_ref[0].astype(f32)
+    dp_ref[:tile_t] = dy_ref[0].astype(f32)
+    dp_ref[tile_t:] = jnp.where(i < nt - 1, dy_after_ref[0].astype(f32), 0.0)
+    if silu:
+        # d silu at the pre-activation of the tile's rows and of the halo
+        # after it, whose dy this tile's dx reads
+        xs_ref[halo + tile_t:] = after_ref[0].astype(f32)
+
+        def derivative(first, rows):
+            pre = _conv_taps(w_ref, xs_ref, halo - (k - 1) + first, rows, k)
+            s = jax.nn.sigmoid(pre)
+            dp_ref[pl.ds(first, rows)] = dp_ref[pl.ds(first, rows)] * (
+                s * (1.0 + pre * (1.0 - s)))
+
+        _conv_runs(tile_t + halo, derivative)
+
+    @pl.when(i == 0)
+    def _a_lane_tile_starts():
+        dw_ref[...] = jnp.zeros(dw_ref.shape, f32)
+
+    def run(first, rows):
+        # dx_t = sum_i w[i] dp_{t + K - 1 - i}
+        dx = jnp.zeros((rows, x_ref.shape[2]), f32)
+        for j in range(k):
+            dx = dx + dp_ref[pl.ds(first + k - 1 - j, rows), :] \
+                * w_ref[j:j + 1, :]
+        dx_ref[0, pl.ds(first, rows)] = dx.astype(dx_ref.dtype)
+        dp = dp_ref[pl.ds(first, rows)]
+        for j in range(k):
+            dw_ref[0, j:j + 1, :] += jnp.sum(
+                dp * xs_ref[pl.ds(halo - (k - 1) + first + j, rows), :],
+                axis=0, keepdims=True)
+        dw_ref[0, k:k + 1, :] += jnp.sum(dp, axis=0, keepdims=True)
+
+    _conv_runs(tile_t, run)
+
+
+def _conv_specs(t: int, tile_t: int, tile_c: int, rows: int):
+    """The block specs both conv kernels share, by operand name."""
+    per, last = tile_t // _CONV_HALO, t // _CONV_HALO - 1
+    return {
+        "x": pl.BlockSpec((1, tile_t, tile_c), lambda b, j, i: (b, i, j)),
+        "before": pl.BlockSpec(
+            (1, _CONV_HALO, tile_c),
+            lambda b, j, i: (b, jnp.maximum(i * per - 1, 0), j)),
+        "after": pl.BlockSpec(
+            (1, _CONV_HALO, tile_c),
+            lambda b, j, i: (b, jnp.minimum((i + 1) * per, last), j)),
+        "w": pl.BlockSpec((rows, tile_c), lambda b, j, i: (0, j)),
+        "dw": pl.BlockSpec((1, rows, tile_c), lambda b, j, i: (b, 0, j)),
+    }
+
+
+def _conv_params(fused: int, operands: int):
+    """Three grid axes, the T tiles sequential; the first ``fused``
+    operands (the views of ``x``) take XLA's producer: the slice of the
+    projection's output that the conv reads, else a copy of ``[T, C]``."""
+    params = _sem_par2_arb()
+    fusion = _input_fusion(params, "t" * fused + "s" * (operands - fused),
+                           True).allow_input_fusion
+    return params if fusion is None else dataclasses.replace(
+        params, allow_input_fusion=fusion[1:])
+
+
+# Jitted by the rule below :func:`_named_call`: a step calls the conv a
+# mixer layer, pass and part.
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
+def _conv_fwd(x, w, k, silu, tile_t, tile_c, interpret):
+    b, t, c = x.shape
+    spec = _conv_specs(t, tile_t, tile_c, w.shape[0])
+    return _named_call(
+        "conv_fwd", functools.partial(_conv_fwd_kernel, k=k, silu=silu),
+        grid=(b, c // tile_c, t // tile_t),
+        in_specs=[spec["x"], spec["before"], spec["w"]],
+        out_specs=spec["x"],
+        out_shape=_struct(x.shape, x.dtype, x, w),
+        scratch_shapes=[pltpu.VMEM((_CONV_HALO + tile_t, tile_c),
+                                   jnp.float32)],
+        compiler_params=_conv_params(2, 3),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * k * x.size, transcendentals=x.size if silu else 0,
+            bytes_accessed=2 * x.size * x.dtype.itemsize),
+        interpret=interpret,
+    )(x, x, w)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _conv_bwd(x, w, dy, k, silu, tile_t, tile_c, interpret):
+    b, t, c = x.shape
+    rows = w.shape[0]
+    spec = _conv_specs(t, tile_t, tile_c, rows)
+    return _named_call(
+        "conv_bwd", functools.partial(_conv_bwd_kernel, k=k, silu=silu),
+        grid=(b, c // tile_c, t // tile_t),
+        in_specs=[spec["x"], spec["before"], spec["after"], spec["x"],
+                  spec["after"], spec["w"]],
+        out_specs=[spec["x"], spec["dw"]],
+        out_shape=[_struct(x.shape, x.dtype, x, w, dy),
+                   _struct((b, rows, c), jnp.float32, x, w, dy)],
+        scratch_shapes=[
+            pltpu.VMEM((2 * _CONV_HALO + tile_t, tile_c), jnp.float32),
+            pltpu.VMEM((tile_t + _CONV_HALO, tile_c), jnp.float32)],
+        compiler_params=_conv_params(3, 6),
+        cost_estimate=pl.CostEstimate(
+            flops=6 * k * x.size, transcendentals=x.size if silu else 0,
+            bytes_accessed=3 * x.size * x.dtype.itemsize),
+        interpret=interpret,
+    )(x, x, x, dy, dy, w)
+
+
+def _conv_tiles(x, k):
+    route = conv_route(x.shape[1], x.shape[2], k, x.dtype.itemsize)
+    return route["tile_t"], route["tile_c"]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _conv_core(x, w, k, silu):
+    return _conv_fwd(x, w, k, silu, *_conv_tiles(x, k), _interpret())
+
+
+def _conv_core_fwd(x, w, k, silu):
+    # the residuals are the operands: no float32 [T, C] is kept
+    return _conv_core(x, w, k, silu), (x, w)
+
+
+def _conv_core_bwd(k, silu, saved, dy):
+    # (the backward pass's operations carry the name stack of the call
+    # site, ``.../mixer/conv`` under the Flax module that calls this:
+    # ``causal_conv_ms`` reads the scope)
+    x, w = saved
+    dx, dw = _conv_bwd(x, w, dy, k, silu, *_conv_tiles(x, k), _interpret())
+    return dx, jnp.sum(dw, axis=0)
+
+
+_conv_core.defvjp(_conv_core_fwd, _conv_core_bwd)
+
+
+def causal_conv(x, kernel, bias=None, *, activation=None):
+    """``ops/ssd.causal_conv1d`` on the kernels above, for operands
+    ``conv_supported`` admits: the same mathematics at the same precision
+    (every product and sum float32, the bias first and the taps in order,
+    the SiLU on the float32 sum, one cast to ``x.dtype``; the gradients of
+    ``kernel`` and ``bias`` summed in float32 and returned so)."""
+    k, f32 = kernel.shape[0], jnp.float32
+    bias = jnp.zeros((x.shape[2],), f32) if bias is None else bias
+    w = jnp.concatenate([kernel.astype(f32), bias.astype(f32)[None]])
+    # whole float32 sublane tiles of rows
+    w = jnp.pad(w, ((0, -(k + 1) % 8), (0, 0)))
+    return _conv_core(x, w, k, activation == "silu")
+
+
 # ------------------------------------------------------------- path gates
 # dispatcher name -> shape gate over the dispatcher's operands; the only
 # reader is kernel_path above (which adds the mode and vma conditions)
@@ -3538,4 +3808,6 @@ _GATES = {
     "ssd_scan": ssd_supported,
     # q, k [b, T, H, K] and v [b, T, H, V]
     "gated_delta": delta_supported,
+    # x [b, T, C] and the taps [K, C]
+    "causal_conv": conv_supported,
 }

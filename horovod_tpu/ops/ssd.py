@@ -23,7 +23,16 @@ which path a call takes.
 ``causal_conv1d`` and ``gated_rms_norm`` are the depthwise convolution before
 the scan and the gated normalisation after it (``models/hybrid.py``); the
 gated delta rule's mixer (``ops/gated_delta.py``) uses both, the norm in
-its other order.
+its other order, and the gated short convolution the conv alone. The conv
+has two paths as the scan has: on the chip, for whole lane widths of
+channels, a Pallas kernel pair under a hand-written backward pass
+(``pallas_kernels.causal_conv``: ``conv_fwd``, ``conv_bwd``; residuals ``x``
+and the taps, no padded or float32 copy of ``[T, C]`` in HBM); off the
+chip, with kernels off, for a shape ``pallas_kernels.conv_route`` refuses
+and for varying operands under ``shard_map``, K shifted multiply-adds in
+jnp that autodiff takes back, which is also what the tests hold the
+kernels to. ``pallas_kernels.kernel_path("causal_conv", x, kernel)`` says
+which path a call takes.
 """
 
 from __future__ import annotations
@@ -118,17 +127,31 @@ def ssd_chunked(x, dt, A, B, C, D, chunk: int = 256):
         return y.reshape(b, t + pad, h, p)[:, :t].astype(x.dtype)
 
 
-def causal_conv1d(x, kernel, bias=None):
-    """Depthwise causal convolution over time: ``y_t = bias + sum_k
-    kernel[k] * x_{t-(K-1)+k}`` for ``x`` ``[b, T, C]``, ``kernel``
+def causal_conv1d(x, kernel, bias=None, *, activation=None):
+    """Depthwise causal convolution over time: ``y_t = act(bias + sum_k
+    kernel[k] * x_{t-(K-1)+k})`` for ``x`` ``[b, T, C]``, ``kernel``
     ``[K, C]``, positions before the sequence zero; no ``bias`` is a bias
-    of zero. K shifted multiply-adds accumulated in float32 (K is 3 or 4:
-    not worth a convolution's layout)."""
+    of zero; ``activation`` is ``None`` or ``"silu"``. K shifted
+    multiply-adds accumulated in float32 (K is 3 or 4: not worth a
+    convolution's layout), the bias first and the taps in order, the
+    activation on the float32 sum, one cast to ``x.dtype``.
+
+    Where ``pallas_kernels.conv_route`` admits the shape (on the chip:
+    whole lane widths of channels, at most 8 taps) that is a Pallas kernel
+    pair under a backward pass of its own, whose residuals are ``x`` and
+    the taps (``pallas_kernels.causal_conv``); everywhere else the sums
+    below, which autodiff takes back."""
+    if activation not in (None, "silu"):
+        raise ValueError(f"activation {activation!r}: None or 'silu'")
+    if pk.kernel_path("causal_conv", x, kernel) == "pallas":
+        return pk.causal_conv(x, kernel, bias, activation=activation)
     k, t = kernel.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
     y = 0.0 if bias is None else bias.astype(jnp.float32)
     for i in range(k):
         y = y + padded[:, i:i + t] * kernel[i].astype(jnp.float32)
+    if activation == "silu":
+        y = jax.nn.silu(y)
     return y.astype(x.dtype)
 
 

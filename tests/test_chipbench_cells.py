@@ -11,6 +11,7 @@ import functools
 import pytest
 
 from chipbench import harness
+from chipbench.tests.test_causal_conv_ms import *  # noqa: F401,F403
 from chipbench.tests.test_cells import *  # noqa: F401,F403
 from chipbench.tests.test_moe_dispatch_ms import *  # noqa: F401,F403
 from chipbench.tests.test_rehearsal import KEYS, rehearse
@@ -18,13 +19,14 @@ from chipbench.tests.test_setup_phases import *  # noqa: F401,F403
 
 #: cell -> the per-layer metrics that are its architecture's own
 HYBRID_CELLS = {
-    "granite4hm-train-s4096": {"ssd_ms", "ssd_roofline"},
+    "granite4hm-train-s4096": {"ssd_ms", "ssd_roofline", "causal_conv_ms"},
     "lfm2moe-train-s8192": {"moe_ms", "moe_experts_ms", "moe_dispatch_ms",
                             "moe_experts_roofline", "short_conv_ms"},
     "nemotron3s-train-s4096": {"ssd_ms", "ssd_roofline", "moe_ms",
                                "moe_experts_ms", "moe_experts_roofline",
                                "moe_route_ms", "moe_dispatch_ms",
-                               "moe_shared_ms", "moe_latent_ms", "lm_head_ms"},
+                               "moe_shared_ms", "moe_latent_ms", "lm_head_ms",
+                               "causal_conv_ms"},
     "lagunas-train-s8192": {"moe_ms", "moe_experts_ms",
                             "moe_experts_roofline", "moe_route_ms",
                             "moe_dispatch_ms", "moe_shared_ms", "lm_head_ms",
@@ -36,7 +38,7 @@ HYBRID_CELLS = {
                                "moe_dispatch_ms", "moe_shared_ms",
                                "lm_head_ms", "attn_gate_ms",
                                "delta_rule_ms", "delta_rule_roofline",
-                               "delta_rule_prep_ms"},
+                               "delta_rule_prep_ms", "causal_conv_ms"},
     "sdarmoe-train-s8192": {"moe_ms", "moe_experts_ms", "moe_route_ms",
                             "moe_dispatch_ms", "lm_head_ms",
                             "attn_blockdiff_relayout_ms", "denoise_io_ms"}}

@@ -840,6 +840,8 @@ def test_the_cell_is_declared():
     new = [m for m in bench["per_layer"]
            if m["name"] in ("attn_blockdiff_relayout_ms", "denoise_io_ms")]
     assert [m["workloads"] for m in new] == [[cell.name]] * 2
-    assert bench["per_layer"][-2:] == new
+    # side by side where PR 50 appended them (later metrics come after)
+    at = bench["per_layer"].index(new[0])
+    assert bench["per_layer"][at:at + 2] == new
     assert bench["workloads"][-1]["name"] == cell.name
     assert bench["configs"][-1]["name"] == "SDAR-30B-A3B-Chat"

@@ -771,6 +771,36 @@ def test_hybrid_step_runs_the_scan_kernels_under_the_ssd_scope(remat,
 
 
 @pytest.mark.parametrize("remat", ["none", "full"])
+def test_hybrid_step_runs_the_conv_kernels_under_the_conv_scope(remat,
+                                                                 v5e_devices):
+    """A Mamba-2 layer's conv is ``conv_fwd`` a part (``x``, ``B``, ``C``)
+    in the forward pass, again in a recomputed block, and ``conv_bwd`` a
+    part; every one sits under ``block_<i>/mixer/conv``, which
+    ``causal_conv_ms`` reads, the backward kernel too. A call whose operand
+    took XLA's producer (the slice of ``in_proj``'s output) is a ``fusion``
+    of kind ``kCustom`` that carries the call's name and path. No float32
+    copy of a part crosses HBM: not ``padded``, not the pre-activation."""
+    from chipbench.layer_metrics import causal_conv_ms
+
+    text = _hybrid_grad_text(v5e_devices, remat)
+    entry = text[text.index("\nENTRY "):]
+    calls = re.findall(
+        r"%(conv_fwd|conv_bwd)[.\d]* = [^\n]*? (?:custom-call|fusion)\("
+        r'[^\n]*op_name="([^"]*)"', entry)
+    layers, parts = 2, 3
+    assert sorted(name for name, _ in calls) == \
+        ["conv_bwd"] * layers * parts \
+        + ["conv_fwd"] * layers * parts * (2 if remat == "full" else 1)
+    for name, path in calls:
+        assert re.search(causal_conv_ms.PATTERN, path), path
+        assert re.search(
+            r"block_\d/mixer/conv/jit\(_" + name + r"\)/" + name, path)
+        assert ("transpose(" in path) == (name == "conv_bwd"
+                                          or "rematted_computation" in path)
+    assert not re.search(r"f32\[1,25[69],(128|384)\]", entry)
+
+
+@pytest.mark.parametrize("remat", ["none", "full"])
 def test_window_and_full_attention_sites_are_named_and_classed(remat,
                                                                v5e_devices):
     """A model with window and full attention layers of two head counts (the
