@@ -98,33 +98,64 @@ def quantization_ratio():
 def expert_load():
     return get_registry().gauge(
         "hvd_expert_load",
-        "Tokens routed to each expert in the latest capacity-dispatch MoE "
-        "step (parallel/expert.py; global count, identical on every "
-        "rank).", labels=("expert",), agg="max")
+        "Tokens routed to each expert in the latest MoE report: a routed "
+        "layer of the normal path (ops/moe.report_load, from inside the "
+        "step under HOROVOD_MOE_REPORT; whichever layer reported last) "
+        "or the capacity-dispatch step of parallel/expert.py (global "
+        "count, identical on every rank).", labels=("expert",), agg="max")
 
 
 def moe_load_imbalance():
     return get_registry().gauge(
         "hvd_moe_load_imbalance",
-        "max/mean expert load of the latest MoE step (1.0 = perfectly "
-        "balanced router; sustained high values mean dropped tokens and "
-        "idle experts — the anomaly watch tracks this like straggler "
-        "skew).", agg="max")
+        "max/mean expert load of the latest MoE report, set by the same "
+        "two paths as hvd_expert_load (over the experts held here on the "
+        "normal path; 1.0 = perfectly balanced router; sustained high "
+        "values mean idle experts and, under capacity dispatch, dropped "
+        "tokens: the anomaly watch tracks this like straggler skew).",
+        agg="max")
+
+
+def moe_rows():
+    return get_registry().gauge(
+        "hvd_moe_rows",
+        "Rows (token, expert assignments) a routed layer held in its "
+        "latest report (ops/moe.report_load under HOROVOD_MOE_REPORT: "
+        "sum(group_sizes), what picks the row capacity that runs).",
+        labels=("layer",), agg="max")
+
+
+def moe_rows_over_balanced():
+    return get_registry().gauge(
+        "hvd_moe_rows_over_balanced",
+        "hvd_moe_rows over the rows a balanced router sends the layer "
+        "(assignments x held / experts).", labels=("layer",), agg="max")
+
+
+def moe_reports():
+    return get_registry().counter(
+        "hvd_moe_reports_total",
+        "Reports of a routed layer by the row capacity its rows select: "
+        "capacity=all is the worst-case program (every assignment), fit "
+        "any smaller one. A report is one traced execution of the layer, "
+        "not a step: a recomputed forward pass reports again.",
+        labels=("layer", "capacity"))
 
 
 def moe_dropped_tokens():
     return get_registry().counter(
         "hvd_moe_dropped_tokens_total",
-        "Tokens dropped by capacity-factor MoE dispatch (routed past "
-        "their expert's buffer; they contribute zero to the MoE output "
-        "— docs/moe.md).")
+        "Tokens dropped by capacity-factor MoE dispatch (parallel/expert.py "
+        "alone: routed past their expert's buffer, they contribute zero "
+        "to the MoE output; ops/moe.routed_ffn drops none — docs/moe.md).")
 
 
 def moe_capacity_factor():
     return get_registry().gauge(
         "hvd_moe_capacity_factor",
-        "Capacity factor of the running MoE train step (buffer slots = "
-        "ceil(CF * tokens / experts)).", agg="max")
+        "Capacity factor of the running parallel/expert.py train step "
+        "(buffer slots = ceil(CF * tokens / experts); ops/moe.routed_ffn "
+        "has none).", agg="max")
 
 
 def bitwidth_decisions():

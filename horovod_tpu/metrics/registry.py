@@ -210,6 +210,15 @@ class Gauge(_Metric):
         with self._lock:
             self._children[key] = float(value)
 
+    def set_each(self, values):
+        """``labels(<the one label>=str(i)).set(v)`` for every ``i, v`` of
+        ``values``, under one lock: a vector that arrives whole (an
+        expert's load a report, from inside a step)."""
+        (label,) = self.label_names
+        with self._lock:
+            for i, value in enumerate(values):
+                self._children[((label, str(i)),)] = float(value)
+
     def _observe(self, key, value):
         raise TypeError(f"{self.name}: gauges have no observe()")
 

@@ -456,7 +456,9 @@ class RoutedFeedForward(nn.Module):
     auxiliary balancing loss times that coefficient rides the backward
     pass (``ops/moe.balancing``). Sows the
     experts each token chose, their scores and the tokens an expert
-    (``intermediates``: free unless asked for)."""
+    (``intermediates``: free unless asked for), and hands the layer its
+    module path (``block_<i>/ffn``) as the ``label`` of what it reports
+    under ``HOROVOD_MOE_REPORT`` (``ops/moe.report_load``)."""
     experts: int
     held: Tuple[int, ...]
     top_k: int
@@ -491,7 +493,7 @@ class RoutedFeedForward(nn.Module):
             held=self.held, top_k=self.top_k, x=x,
             activation=self.activation, scale=self.scale,
             norm_eps=self.norm_eps, scoring=self.scoring,
-            aux_loss=self.aux_loss)
+            aux_loss=self.aux_loss, label="/".join(self.path))
         self.sow("intermediates", "chosen", chosen.reshape(b, t, self.top_k))
         self.sow("intermediates", "scores", scores.reshape(b, t, self.experts))
         self.sow("intermediates", "load", load)

@@ -36,6 +36,17 @@ row counts, up to the worst case, every assignment landing here
 (``tokens * k`` rows), and a step runs the smallest that holds its rows
 (:func:`capacities`), so that the cost follows the rows really routed here.
 
+**What a trace and a run say of it.** The layer's operations lie under the
+name scopes ``moe`` and, inside it, ``router``, ``dispatch``, ``experts``
+and ``combine``; the branch of the row capacity that ran is one scope more,
+named for what it is and not for its place among the sizes:
+``capacity_all`` for the worst case and ``capacity_fit`` for any smaller
+count (``.../ffn/moe/cond/branch_<n>_fun/capacity_fit/experts/...``), in the
+forward pass, the recomputed one and the backward pass alike. The rows a
+layer held and its experts' load leave the step only where
+``HOROVOD_MOE_REPORT`` is set as the layer is traced: one host callback a
+layer and execution, into :func:`report_load` (docs/moe.md).
+
 **Which kernel runs where.** A grouped product is one of three:
 :func:`grouped_matmul` (rows against their group's matrix),
 :func:`grouped_matmul_t` (against its transpose) and :func:`grouped_outer`
@@ -68,13 +79,18 @@ capacity with drops, its own train step); this layer lives inside
 from __future__ import annotations
 
 import math
+import statistics
+import sys
+import threading
+from collections import deque
 from functools import partial
-from typing import Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.env import env_on
 from . import pallas_kernels as pk
 
 #: added to the sum of a token's chosen scores before it divides them
@@ -205,7 +221,10 @@ def capacities(assignments: int, held: int, num_experts: int) -> Tuple[int, ...]
     20 ms a layer from 2,816 rows to 90,112 and by the whole spread of a
     cell's runs at 10,240 of 81,920: PERF.md, PR 32, PR 34 and PR 39. Under
     an eighth of the worst case a smaller count saves little and is
-    crossed.)"""
+    crossed.) Which count a layer ran is in every trace, the scopes
+    ``capacity_fit`` / ``capacity_all``: a crossing is read in the
+    benchmark's ``moe_worst_case_ms``, and counted a layer by
+    :func:`report_load`."""
     balanced = assignments * held / num_experts
     margin = max(2.0, math.log2(num_experts / held))
     smaller = max(int(margin * balanced), assignments // 8)
@@ -435,10 +454,18 @@ def _experts_bwd_at(rows: int, h, w_in, w_out, weights, order, per_token,
 
 def _smallest_that_holds(sizes: Tuple[int, ...], group_sizes, fn, *operands):
     """``fn(size, *operands)`` at the smallest of ``sizes`` (ascending, the
-    last one the worst case) that is at least ``sum(group_sizes)``."""
+    last one the worst case) that is at least ``sum(group_sizes)``, under
+    the name scope ``capacity_all`` for the last size and ``capacity_fit``
+    for any other (a single size is no ``cond``, and ``capacity_all``)."""
+    def at(size):
+        def branch(*operands):
+            with jax.named_scope("capacity_all" if size == sizes[-1]
+                                 else "capacity_fit"):
+                return fn(size, *operands)
+        return branch
+
     index = jnp.sum(jnp.sum(group_sizes) > jnp.asarray(sizes[:-1], jnp.int32))
-    return jax.lax.switch(index, [partial(fn, size) for size in sizes],
-                          *operands)
+    return jax.lax.switch(index, [at(size) for size in sizes], *operands)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 8))
@@ -469,7 +496,8 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
 def routed_ffn(h, router, bias, w_in, w_out, *, held: Tuple[int, ...],
                top_k: int, x=None, activation: str = "swiglu",
                scale: float = 1.0, norm_eps: float = NORM_EPS,
-               scoring: str = "sigmoid", aux_loss: float = 0.0):
+               scoring: str = "sigmoid", aux_loss: float = 0.0,
+               label: str = ""):
     """The layer above for ``h`` ``[N, d]``: ``router`` ``[d, E]`` and
     ``bias`` ``[E]`` (float32), ``w_in`` ``[H, d, 2 f]`` (gate and up side
     by side; ``[H, d, f]`` for ``activation="relu2"``) and ``w_out``
@@ -479,7 +507,12 @@ def routed_ffn(h, router, bias, w_in, w_out, *, held: Tuple[int, ...],
     then ``l``, and so is ``y``'s). ``scale``, ``norm_eps``, ``scoring``
     and ``aux_loss`` are :func:`route`'s. Returns ``(y [N, d], chosen [N,
     k], scores [N, E], load [E])``: ``load`` counts the tokens each of the
-    ``E`` experts was chosen by (held or not).
+    ``E`` experts was chosen by (held or not). ``label`` names the layer
+    (a module's path, ``block_<i>/ffn``) in what :func:`report_load` is
+    told of it: where ``HOROVOD_MOE_REPORT`` is on as the layer is traced,
+    each execution hands the host the rows held here and ``load`` through
+    one ``jax.debug.callback``; where it is off there is no callback in the
+    program and no operand kept for one.
 
     The expert stage keeps the layer's inputs and the routing, not the
     ``[rows, 2 f]`` activations, whatever the model's ``remat``: its
@@ -506,22 +539,121 @@ def routed_ffn(h, router, bias, w_in, w_out, *, held: Tuple[int, ...],
                                           dtype=jnp.int32), axis=(0, 1))
         with jax.named_scope("dispatch"):
             order, per_token, group_sizes = dispatch(chosen, held)
-        y = _experts(capacities(order.shape[0], len(held), num_experts),
-                     h if x is None else x, w_in, w_out, weights, order,
-                     per_token, group_sizes, activation)
+        sizes = capacities(order.shape[0], len(held), num_experts)
+        if env_on("HOROVOD_MOE_REPORT"):
+            jax.debug.callback(
+                partial(report_load, held=held, layer=label, sizes=sizes,
+                        balanced=order.shape[0] * len(held) / num_experts),
+                load, rows=jnp.sum(group_sizes))
+        y = _experts(sizes, h if x is None else x, w_in, w_out, weights,
+                     order, per_token, group_sizes, activation)
     return y, chosen, scores, load
 
 
-def report_load(load, held: Sequence[int]) -> float:
-    """Set ``hvd_expert_load`` (tokens an expert, by id) and
-    ``hvd_moe_load_imbalance`` (max over mean, over the experts ``held``)
-    from one layer's ``load`` counts, on the host. Returns the imbalance."""
-    from ..metrics import instruments
+#: a layer's latest reports whose rows the summary's min / median / max are
+#: over; its counts and its worst load are over every report
+KEPT_REPORTS = 65536
 
-    load = np.asarray(load)
-    for expert, count in enumerate(load):
-        instruments.expert_load().labels(expert=str(expert)).set(float(count))
-    here = load[list(held)].astype(np.float64)
-    imbalance = float(here.max() / max(here.mean(), 1e-9))
-    instruments.moe_load_imbalance().set(imbalance)
-    return imbalance
+
+class _LayerReports:
+    """What :func:`report_load` keeps of one layer for
+    :func:`routing_summary`."""
+
+    def __init__(self, balanced: float, sizes: Tuple[int, ...]):
+        self.balanced, self.sizes = balanced, sizes
+        self.reports = self.at_all = 0
+        self.worst = 0.0
+        self.rows = deque(maxlen=KEPT_REPORTS)
+
+    def line(self, layer: str) -> str:
+        rows = (min(self.rows), statistics.median(self.rows), max(self.rows))
+        return (
+            f"moe report {layer or '(no label)'}: {self.reports} reports, "
+            f"rows min / median / max {rows[0]} / {rows[1]:g} / {rows[2]}, "
+            + " / ".join(f"{r / self.balanced:.2f}" for r in rows)
+            + f" x the balanced {self.balanced:g}, capacities {self.sizes}, "
+            f"{100.0 * self.at_all / self.reports:.1f}% at capacity_all, "
+            f"worst load max / mean {self.worst:.2f}")
+
+
+_reports: Dict[str, _LayerReports] = {}
+_reports_lock = threading.Lock()   # callbacks come from the runtime's threads
+
+
+def report_load(load, held: Sequence[int], rows=None, *, layer: str = "",
+                sizes: Tuple[int, ...] = (), balanced: float = 0.0) -> float:
+    """The one host sink for a routed layer's counts. From ``load`` (tokens
+    an expert, ``[E]``) it sets ``hvd_expert_load`` (by expert id) and
+    ``hvd_moe_load_imbalance`` (max over mean, over the experts ``held``),
+    and returns the imbalance: the gauges ``parallel/expert.py``'s
+    stand-alone block sets for itself, so that the anomaly watch follows
+    either path. A caller that has only a layer's sown ``load`` stops
+    there.
+
+    :func:`routed_ffn` under ``HOROVOD_MOE_REPORT`` calls it from inside the
+    step with ``rows`` (``sum(group_sizes)``: the rows the layer ``layer``
+    held in that execution), the ``sizes`` it is compiled at
+    (:func:`capacities`) and the ``balanced`` row count. That sets, by
+    ``layer``, ``hvd_moe_rows`` and ``hvd_moe_rows_over_balanced`` and
+    counts the report in ``hvd_moe_reports_total{layer, capacity}``:
+    ``all`` where the rows select the worst-case program and ``fit``
+    otherwise, by ``_smallest_that_holds``' comparison. A **report is one
+    traced execution of the layer, not a step**: a model that recomputes its
+    blocks runs the layer's forward pass twice a step and reports twice.
+    The body runs under ``phases.phase("moe/report", program=layer)``, so
+    each report is a host span beside the set-up's (and, under an active
+    profile, a ``TraceAnnotation`` ``hvd/moe/report`` beside the device's
+    operations), and is kept for :func:`routing_summary`, which
+    ``hvd.shutdown()`` prints."""
+    from ..metrics import instruments, phases
+
+    with phases.phase("moe/report", program=layer):
+        load = np.asarray(load)
+        instruments.expert_load().set_each(load.tolist())
+        here = load[list(held)].astype(np.float64)
+        imbalance = float(here.max() / max(here.mean(), 1e-9))
+        instruments.moe_load_imbalance().set(imbalance)
+        if rows is None:
+            return imbalance
+        rows = int(rows)
+        at_all = all(rows > size for size in sizes[:-1])
+        instruments.moe_rows().labels(layer=layer).set(float(rows))
+        instruments.moe_rows_over_balanced().labels(layer=layer).set(
+            rows / balanced)
+        instruments.moe_reports().labels(
+            layer=layer, capacity="all" if at_all else "fit").inc()
+        with _reports_lock:
+            if not _reports:   # the first report of a run
+                from .. import basics
+
+                basics.register_shutdown_hook(_say_summary)
+            kept = _reports.get(layer)
+            if kept is None:
+                kept = _reports[layer] = _LayerReports(balanced, sizes)
+            kept.reports += 1
+            kept.at_all += at_all
+            kept.worst = max(kept.worst, imbalance)
+            kept.rows.append(rows)
+        return imbalance
+
+
+def routing_summary(clear: bool = False) -> List[str]:
+    """One line a routed layer that has reported (:func:`report_load` with
+    ``rows``), in the order the layers first did: how many reports, the rows
+    held (min / median / max of the latest ``KEPT_REPORTS``) as counts and
+    over the balanced count, the sizes compiled, the share of reports whose
+    rows select ``capacity_all``, and the worst max / mean load. Empty
+    where nothing has reported. ``clear`` forgets the reports."""
+    with _reports_lock:
+        lines = [kept.line(layer) for layer, kept in _reports.items()]
+        if clear:
+            _reports.clear()
+    return lines
+
+
+def _say_summary() -> None:
+    """``hvd.shutdown()``'s hook: the summary on standard error (a run's
+    standard output may be a protocol), once."""
+    jax.effects_barrier()   # a callback still on its way counts
+    for line in routing_summary(clear=True):
+        print(line, file=sys.stderr, flush=True)

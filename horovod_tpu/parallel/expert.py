@@ -463,8 +463,7 @@ def _record_moe(stats, capacity_factor: float, wire: str, per_peer: int,
     from ..ops import compression as comp
 
     load = np.asarray(stats["load"], dtype=np.float64)
-    for i, v in enumerate(load):
-        instruments.expert_load().labels(expert=str(i)).set(float(v))
+    instruments.expert_load().set_each(load.tolist())
     mean = float(load.mean()) if load.size else 0.0
     instruments.moe_load_imbalance().set(
         float(load.max()) / mean if mean > 0 else 0.0)

@@ -13,39 +13,44 @@ import pytest
 from chipbench import harness
 from chipbench.tests.test_causal_conv_ms import *  # noqa: F401,F403
 from chipbench.tests.test_cells import *  # noqa: F401,F403
+from chipbench.tests.test_moe_capacity_ms import *  # noqa: F401,F403
 from chipbench.tests.test_moe_dispatch_ms import *  # noqa: F401,F403
 from chipbench.tests.test_rehearsal import KEYS, rehearse
 from chipbench.tests.test_setup_phases import *  # noqa: F401,F403
 
+#: which row capacity ran and the router: every cell with a routed layer
+CAPACITY = {"moe_worst_case_ms", "moe_fit_ms", "moe_router_ms"}
 #: cell -> the per-layer metrics that are its architecture's own
 HYBRID_CELLS = {
     "granite4hm-train-s4096": {"ssd_ms", "ssd_roofline", "causal_conv_ms"},
-    "lfm2moe-train-s8192": {"moe_ms", "moe_experts_ms", "moe_dispatch_ms",
-                            "moe_experts_roofline", "short_conv_ms"},
-    "nemotron3s-train-s4096": {"ssd_ms", "ssd_roofline", "moe_ms",
-                               "moe_experts_ms", "moe_experts_roofline",
-                               "moe_route_ms", "moe_dispatch_ms",
-                               "moe_shared_ms", "moe_latent_ms", "lm_head_ms",
-                               "causal_conv_ms"},
-    "lagunas-train-s8192": {"moe_ms", "moe_experts_ms",
-                            "moe_experts_roofline", "moe_route_ms",
-                            "moe_dispatch_ms", "moe_shared_ms", "lm_head_ms",
-                            "attn_gate_ms", "attn_window_kernel_ms"},
-    "kanana2-train-s16384": {"moe_ms", "moe_experts_ms", "moe_route_ms",
-                             "moe_dispatch_ms", "moe_shared_ms", "lm_head_ms",
-                             "mla_latent_ms", "mla_assemble_ms"},
-    "qwen3next-train-s16384": {"moe_ms", "moe_experts_ms", "moe_route_ms",
-                               "moe_dispatch_ms", "moe_shared_ms",
-                               "lm_head_ms", "attn_gate_ms",
-                               "delta_rule_ms", "delta_rule_roofline",
-                               "delta_rule_prep_ms", "causal_conv_ms"},
-    "sdarmoe-train-s8192": {"moe_ms", "moe_experts_ms", "moe_route_ms",
-                            "moe_dispatch_ms", "lm_head_ms",
-                            "attn_blockdiff_relayout_ms", "denoise_io_ms"}}
+    "lfm2moe-train-s8192": CAPACITY | {
+        "moe_ms", "moe_experts_ms", "moe_dispatch_ms", "moe_experts_roofline",
+        "short_conv_ms"},
+    "nemotron3s-train-s4096": CAPACITY | {
+        "ssd_ms", "ssd_roofline", "moe_ms", "moe_experts_ms",
+        "moe_experts_roofline", "moe_route_ms", "moe_dispatch_ms",
+        "moe_shared_ms", "moe_latent_ms", "lm_head_ms", "causal_conv_ms"},
+    "lagunas-train-s8192": CAPACITY | {
+        "moe_ms", "moe_experts_ms", "moe_experts_roofline", "moe_route_ms",
+        "moe_dispatch_ms", "moe_shared_ms", "lm_head_ms", "attn_gate_ms",
+        "attn_window_kernel_ms"},
+    "kanana2-train-s16384": CAPACITY | {
+        "moe_ms", "moe_experts_ms", "moe_route_ms", "moe_dispatch_ms",
+        "moe_shared_ms", "lm_head_ms", "mla_latent_ms", "mla_assemble_ms"},
+    "qwen3next-train-s16384": CAPACITY | {
+        "moe_ms", "moe_experts_ms", "moe_route_ms", "moe_dispatch_ms",
+        "moe_shared_ms", "lm_head_ms", "attn_gate_ms", "delta_rule_ms",
+        "delta_rule_roofline", "delta_rule_prep_ms", "causal_conv_ms"},
+    "sdarmoe-train-s8192": CAPACITY | {
+        "moe_ms", "moe_experts_ms", "moe_route_ms", "moe_dispatch_ms",
+        "lm_head_ms", "attn_blockdiff_relayout_ms", "denoise_io_ms"}}
 
 #: the interpreter's kernels are no custom calls: a reader of class
 #: ``attention_kernel`` finds its scope and no time under it
 NO_KERNEL_TIME = {"attn_window_kernel_ms"}
+#: 0.0 where no routed layer of the traced steps ran that capacity: which
+#: did is the toy router's to say, and one of the two always has
+EITHER_CAPACITY = {"moe_worst_case_ms", "moe_fit_ms"}
 
 
 #: the eight metrics that split ``setup_s`` (``chipbench/setup_phases.py``)
@@ -117,7 +122,7 @@ def test_hybrid_cell_rehearsal_reads_every_per_layer_metric_it_lists(cell):
               for k, v in line["metrics"].items()}
     assert values["blocks_recompute_ms"] > 0
     assert all(values[name] > 0 for name in HYBRID_CELLS[cell]
-               - NO_KERNEL_TIME)
+               - NO_KERNEL_TIME - EITHER_CAPACITY)
     assert all(values[name] == 0 for name in HYBRID_CELLS[cell]
                & NO_KERNEL_TIME)
     if "ssd_ms" in HYBRID_CELLS[cell]:
@@ -130,6 +135,11 @@ def test_hybrid_cell_rehearsal_reads_every_per_layer_metric_it_lists(cell):
         # ``dispatch`` and ``experts`` are scopes beside each other
         assert values["moe_dispatch_ms"] + values["moe_experts_ms"] \
             < values["moe_ms"]
+        # the expert stage by the capacity that ran, and the router beside
+        # it: parts of the layer, the stage's never both empty
+        assert min(values[name] for name in EITHER_CAPACITY) >= 0
+        assert values["moe_experts_ms"] <= values["moe_worst_case_ms"] \
+            + values["moe_fit_ms"] < values["moe_ms"] - values["moe_router_ms"]
         if "moe_route_ms" in HYBRID_CELLS[cell]:
             # what is under ``moe`` and not under ``experts``; the latent's
             # and the shared expert's products lie beside ``moe``, the

@@ -829,6 +829,7 @@ def test_the_cell_is_declared():
     assert {"attn_blockdiff_relayout_ms", "denoise_io_ms",
             "flash_attention_roofline", "attn_kernel_ms", "train_mfu",
             "moe_experts_ms", "moe_route_ms", "moe_dispatch_ms",
+            "moe_worst_case_ms", "moe_fit_ms", "moe_router_ms",
             "lm_head_ms", "blocks_recompute_ms"} <= names
     assert not {"ssd_ms", "short_conv_ms", "moe_shared_ms", "attn_gate_ms",
                 "attn_window_kernel_ms", "delta_rule_ms", "allreduce_ms",

@@ -647,6 +647,78 @@ def test_a_recomputed_block_sums_rows_back_only_where_the_sum_is_kept(
                for path in sums) == each
 
 
+@pytest.mark.parametrize("latent", [0, 128], ids=["plain", "latent"])
+def test_the_capacity_that_ran_is_a_scope_in_every_pass(latent, v5e_devices):
+    """The routed blocks of a model under ``remat="full"``, compiled for a
+    v5e: every operation of the expert stage lies under ``capacity_fit``
+    (the branch of the smaller size) or ``capacity_all`` (the worst case's),
+    whatever the branch's index: in the forward pass, in the hand-written
+    backward pass and, where something after the experts keeps their sum (a
+    latent's up-projection), in the recomputed forward; the router and the
+    assignments' order lie under neither. Read with the benchmark's own
+    patterns: ``moe_worst_case_ms`` / ``moe_fit_ms`` / ``moe_router_ms``
+    class the paths by those scopes, and each older ``moe_*`` reader
+    matches a path exactly where it matched the parent's, the same path
+    without the new element."""
+    from chipbench.layer_metrics import (moe_dispatch_ms, moe_experts_ms,
+                                         moe_fit_ms, moe_latent_ms, moe_ms,
+                                         moe_router_ms, moe_shared_ms,
+                                         moe_worst_case_ms)
+    from horovod_tpu.models.hybrid import HybridLM
+    from horovod_tpu.ops import moe
+
+    layers, seq, top_k, experts, held = 2, 256, 2, 8, (0, 1)
+    model = HybridLM(
+        vocab_size=512, layer_kinds=("attention",) * layers, d_model=128,
+        ffn_width=256, attn_heads=2, attn_kv_heads=2, attn_head_dim=64,
+        ffn_kinds=("moe",) * layers, moe_experts=experts, moe_held=held,
+        moe_top_k=top_k, moe_width=128, moe_latent=latent,
+        moe_shared_width=128, remat="full")
+    assert moe.capacities(seq * top_k, len(held), experts) == (256, 512)
+    text = _model_grad_text(model, seq, v5e_devices)
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+
+    def passes(scope):
+        """The passes in which some operation lies under ``scope``."""
+        return {"recomputed" if "rematted_computation" in p else
+                "backward" if "transpose(" in p else "forward"
+                for p in paths if f"/{scope}/" in p}
+
+    everywhere = {"forward", "backward", "recomputed"}
+    for scope in ("capacity_fit", "capacity_all"):
+        assert passes(scope) == (everywhere if latent
+                                 else everywhere - {"recomputed"}), scope
+    assert passes("moe/router") == everywhere
+    stage = {p for p in paths if re.search(r"/moe/cond/branch_\d_fun", p)}
+    assert stage and all(re.search(
+        r"/moe/cond/branch_(0_fun/capacity_fit|1_fun/capacity_all)(/|$)", p)
+        for p in stage)
+    # the grouped products and ``put_rows``' sums: half under each
+    calls = [path for _, _, path in _kernel_calls(text, "moe_t?gmm")]
+    assert len(calls) == layers * 2 * (12 if latent else 9)
+    assert sum("/capacity_fit/" in p for p in calls) \
+        == sum("/capacity_all/" in p for p in calls) == len(calls) // 2
+
+    older = (moe_ms, moe_experts_ms, moe_dispatch_ms, moe_shared_ms,
+             moe_latent_ms)
+    read = dict.fromkeys(older, 0)
+    for path in paths:
+        as_the_parent_wrote_it = re.sub(r"/capacity_(fit|all)(?=/|$)", "",
+                                        path)
+        for module in older:
+            hit = bool(re.search(module.PATTERN, path))
+            assert hit is bool(re.search(module.PATTERN,
+                                         as_the_parent_wrote_it)), path
+            read[module] += hit
+        for module, scope in ((moe_worst_case_ms, "/capacity_all"),
+                              (moe_fit_ms, "/capacity_fit"),
+                              (moe_router_ms, "/moe/router")):
+            assert bool(re.search(module.PATTERN, path)) \
+                is bool(re.search(scope + "(/|$)", path)), path
+    assert all(read[module] for module in older
+               if latent or module is not moe_latent_ms), read
+
+
 # name -> heads, groups (None: B and C [b, T, N]), chunk, head width,
 # state, positions: the Mamba-2 layer of granite-4.0-h-micro and of
 # NVIDIA-Nemotron-3-Super-120B-A12B at the cells' one sequence of 4096
